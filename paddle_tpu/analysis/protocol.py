@@ -41,7 +41,7 @@ Design — replay-based explicit-state DFS:
   finding (``Finding.trace``) is the full schedule; ``replay()`` runs
   it alone on a fresh model and reproduces the violation
   deterministically — the debugging loop is one function call, not a
-  tunnel session.
+  multi-process repro.
 
 Invariants asserted at every state (the shipped models split them):
 exactly-once (no retried seq applied twice), quiescence/no-deadlock

@@ -2,7 +2,7 @@
 
 Reference parity: `paddle/fluid/platform/enforce.h:302-355`
 (PADDLE_ENFORCE/PADDLE_THROW with typed payloads), `platform/
-error_codes.proto` (the error taxonomy), and `framework/op_call_stack.cc`
+error_codes.proto` (the error classes), and `framework/op_call_stack.cc`
 (python creation-site tracebacks attached to op errors so users see
 WHERE in their model code the failing op was built).
 """

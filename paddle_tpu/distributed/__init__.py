@@ -34,26 +34,23 @@ def init_parallel_env(backend="xla"):
     nhosts = penv.trainer_num()
     if nhosts > 1 and penv.trainer_endpoints():
         coord = penv.trainer_endpoints()[0]
-        try:
-            # CPU backend: cross-process collectives (multihost
-            # device_put, psum over DCN) need the gloo transport; the
-            # default CPU backend refuses multiprocess computations.
-            # Read the platform from config/env only — probing the
-            # backend here would initialize it BEFORE distributed init.
-            platforms = (getattr(jax.config, "jax_platforms", None)
-                         or os.environ.get("JAX_PLATFORMS", ""))
-            if platforms and "cpu" in str(platforms):
-                try:
-                    jax.config.update(
-                        "jax_cpu_collectives_implementation", "gloo")
-                except Exception:  # noqa: BLE001 - knob absent: ignore
-                    pass
+        # CPU backend: cross-process collectives (multihost
+        # device_put, psum over DCN) need the gloo transport; the
+        # default CPU backend refuses multiprocess computations.
+        # Read the platform from config/env only — probing the
+        # backend here would initialize it BEFORE distributed init.
+        platforms = (getattr(jax.config, "jax_platforms", None)
+                     or os.environ.get("JAX_PLATFORMS", ""))
+        if platforms and "cpu" in str(platforms):
+            jax.config.update(
+                "jax_cpu_collectives_implementation", "gloo")
+        # a second init_parallel_env() in one process is fine; a
+        # coordinator that cannot be reached raises
+        if not jax.distributed.is_initialized():
             jax.distributed.initialize(
                 coordinator_address=coord,
                 num_processes=nhosts,
                 process_id=penv.trainer_id())
-        except Exception:
-            pass  # already initialized or single-host fallback
     from jax.sharding import Mesh
 
     devs = np.array(jax.devices())

@@ -6,6 +6,9 @@ drives all local chips (SPMD over the mesh), so the launcher spawns one
 process per HOST, keeping the same PADDLE_* env contract:
   PADDLE_TRAINER_ID, PADDLE_CURRENT_ENDPOINT, PADDLE_TRAINERS_NUM,
   PADDLE_TRAINER_ENDPOINTS.
+No worker is given a chip of its own: several endpoints on ONE host is
+the CPU test shape of the host tier, never a way to split a TPU host's
+chips (the workers would all open the same chips and all but one fail).
 
 Supervision (pod-scale preemption is the common case, not the
 exception):
@@ -221,7 +224,7 @@ def _worker_env(endpoints, tid, restart_no, base_env=None,
         # across restarts/elastic transitions: a relaunched worker
         # deserializes its XLA executables instead of recompiling, so
         # recovery is coordination-bound, not compile-bound
-        env.setdefault("FLAGS_tpu_compile_cache_dir", compile_cache_dir)
+        env["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir
     if hang_timeout_s and hang_timeout_s > 0:
         # one knob arms both tiers: the workers' in-process watchdogs
         # (stack + in-flight dumps, `hang`/`heartbeat` events) and the
@@ -273,19 +276,20 @@ def _telemetry_dir_for(args):
     return None
 
 
-def _compile_cache_dir_for(args):
-    """Where the workers' persistent compilation cache lives: an
-    explicit FLAGS_tpu_compile_cache_dir in the launcher env wins;
-    otherwise <log_dir>/compile_cache; None without either (workers
-    then run with the persistent tier off). NOT collected into
-    postmortem/ between attempts — surviving restarts is its entire
-    point."""
-    explicit = os.environ.get("FLAGS_tpu_compile_cache_dir")
-    if explicit:
-        return explicit
-    if args.log_dir:
-        return os.path.join(args.log_dir, "compile_cache")
-    return None
+def _compile_cache_dir():
+    """Where the workers' persistent compilation cache lives: the
+    directory JAX_COMPILATION_CACHE_DIR names in the launcher's
+    environment, passed through untouched; otherwise the fixed
+    `<checkout>/.jax_cache` (the path is part of jax's cache key, so it
+    is never derived from a log dir, a pid or the time). It survives
+    restarts — that is its entire point — and is never collected into
+    postmortem/."""
+    # == fluid.compile_cache.default_dir(), spelled out: the supervisor
+    # stays a subprocess babysitter and does not import the jax stack
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
+        os.path.join(checkout, ".jax_cache")
 
 
 def _collect_flight_dumps(args, attempt):
@@ -434,7 +438,7 @@ class _TransitionWatch:
       compile_s       the new cohort's first-step compile (max over
                       ranks of the first step record's compile_ms) —
                       the part the persistent compilation cache
-                      (FLAGS_tpu_compile_cache_dir) collapses from
+                      (JAX_COMPILATION_CACHE_DIR) collapses from
                       minutes to ~0
 
     recovery_s = coordination_s + compile_s. Workers that emit no
@@ -666,7 +670,7 @@ def _spawn_cohort(args, endpoints, local_ids, restart_no, npods=1):
     tdir = _telemetry_dir_for(args)
     if tdir:
         os.makedirs(tdir, exist_ok=True)
-    ccdir = _compile_cache_dir_for(args)
+    ccdir = _compile_cache_dir()
     if ccdir:
         os.makedirs(ccdir, exist_ok=True)
     for tid in local_ids:

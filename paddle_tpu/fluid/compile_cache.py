@@ -1,6 +1,6 @@
 """Persistent, cross-process compilation cache for the Executor.
 
-BENCH_r02 measured 94.7s of XLA compile for one BERT-base step, and the
+A cold XLA compile of one BERT-base step takes tens of seconds, and the
 elastic restart path (PR 9) made restarts *routine*: every transition
 re-paid full compilation across the whole cohort. This module is the
 persistent tier layered UNDER the Executor's in-memory LRU
@@ -8,9 +8,16 @@ persistent tier layered UNDER the Executor's in-memory LRU
 
 - the XLA executables themselves persist through
   `jax.experimental.compilation_cache` (`_configure_jax`), rooted at
-  `FLAGS_tpu_compile_cache_dir` — the launch supervisor exports the
-  same directory to every worker and across restarts, so a restarted
-  N' cohort deserializes executables in seconds instead of recompiling;
+  `cache_dir()`: `JAX_COMPILATION_CACHE_DIR` where the environment sets
+  it (the cache can be placed from outside, and nothing in the tree
+  points jax anywhere else then), `FLAGS_tpu_compile_cache_dir`
+  otherwise. The entry points (`bench.py`, `chip_smoke.py`, the launch
+  supervisor) call `use_default_dir()`: the fixed `<checkout>/.jax_cache`
+  where the environment names none — the path is part of jax's cache
+  key, so it is never made from a temporary name, a pid or the time.
+  The supervisor exports the directory to every worker and across
+  restarts, so a restarted N' cohort deserializes executables in
+  seconds instead of recompiling;
 - a *fingerprint index* (`index/<fp>.json` sentinels) keyed on
   (canonicalized lowered StableHLO, mesh topology, the
   lowering-relevant `FLAGS_tpu_*` set, jax/jaxlib version + backend)
@@ -26,9 +33,10 @@ persistent tier layered UNDER the Executor's in-memory LRU
   (observability/publish.py) and `tools/perf_analysis.py
   --compile-cache`.
 
-Everything here is inert while `FLAGS_tpu_compile_cache_dir` is unset:
-`enabled()` is False, no jax config is touched, no listeners install,
-and the Executor's behavior is byte-identical to a cache-less build.
+Everything here is inert while neither the environment variable nor
+the flag is set: `enabled()` is False, no jax config is touched, no
+listeners install, and the Executor's behavior is byte-identical to a
+cache-less build.
 """
 from __future__ import annotations
 
@@ -40,7 +48,8 @@ import threading
 import time
 from typing import Dict, Optional
 
-__all__ = ["cache_dir", "enabled", "donation_safe", "ensure", "disable",
+__all__ = ["cache_dir", "default_dir", "use_default_dir", "enabled",
+           "ensure", "disable",
            "lowering_flags", "fingerprint", "index_lookup",
            "index_store", "install_listeners", "jax_stats",
            "stats_delta", "record_event", "stats",
@@ -76,41 +85,42 @@ _stats = {"hits": 0, "misses": 0, "compile_ms_total": 0.0,
           "saved_ms_total": 0.0, "warmups": 0}
 
 
+_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+
 def cache_dir() -> Optional[str]:
-    """The persistent tier's root (FLAGS_tpu_compile_cache_dir), or
-    None when the tier is off."""
+    """The persistent tier's root: `JAX_COMPILATION_CACHE_DIR` where
+    the environment sets it, else FLAGS_tpu_compile_cache_dir, else
+    None (the tier is off)."""
     from ..utils.flags import get_flag
 
-    d = str(get_flag("FLAGS_tpu_compile_cache_dir", "") or "")
+    d = os.environ.get(_ENV_VAR) or \
+        str(get_flag("FLAGS_tpu_compile_cache_dir", "") or "")
     return d or None
+
+
+def default_dir() -> str:
+    """`<checkout>/.jax_cache`: where the entry points keep the cache
+    when the environment names no directory."""
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(checkout, ".jax_cache")
+
+
+def use_default_dir() -> str:
+    """Entry points only (bench.py, chip_smoke.py, the launcher): keep
+    the directory the environment names, or name `default_dir()`, and
+    wire the tier. Raises if the directory cannot be used."""
+    os.environ.setdefault(_ENV_VAR, default_dir())
+    d = ensure()
+    if d is None:
+        raise RuntimeError("compile cache directory %r is unusable"
+                           % (os.environ[_ENV_VAR],))
+    return d
 
 
 def enabled() -> bool:
     return cache_dir() is not None
-
-
-def donation_safe() -> bool:
-    """XLA:CPU intermittently mis-executes input/output-ALIASED
-    (donated) executables DESERIALIZED from the persistent cache
-    (jaxlib 0.4.37): the fetch outputs come back correct while the
-    aliased state outputs are garbage/NaN — race-shaped, reproduced by
-    running tests/compile_cache_runner.py's crash+resume pair in a
-    loop, all the way to segfaults, on a stock jax env-var cache with
-    no framework code in the loop. With the tier enabled on the CPU
-    backend the executor therefore compiles WITHOUT donation
-    (lowering.compile_block consults this) — correctness over
-    in-place buffer reuse; CPU runs are tests/dev, where HBM pressure
-    is moot. On TPU — the production target, whose serialized-
-    executable path is the mature one — donation stays on. Returns
-    True when donation may be used."""
-    if not enabled():
-        return True
-    try:
-        import jax
-
-        return jax.default_backend() != "cpu"
-    except Exception:  # noqa: BLE001 - no backend yet: be conservative
-        return False
 
 
 def ensure() -> Optional[str]:
@@ -143,13 +153,8 @@ def _configure_jax(d: str) -> None:
     import jax
 
     jax.config.update("jax_compilation_cache_dir", d)
-    for knob, val in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:  # noqa: BLE001 - older jax: keep defaults
-            pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _reset_jax_cache_instance()
 
 

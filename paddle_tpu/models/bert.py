@@ -189,8 +189,8 @@ def bert_encoder(src_ids, pos_ids, sent_ids, input_mask, cfg,
                  scan_remat=False):
     """Returns [B, S, H] sequence output. When `checkpoints_out` is a
     list, each encoder layer's output var is appended — the natural
-    remat segmentation for RecomputeOptimizer (PERF_ANALYSIS_r4:
-    batch 512 needs activation checkpointing to fit 16G HBM).
+    remat segmentation for RecomputeOptimizer (BERT-base needs
+    activation checkpointing to fit 16 GB of HBM from batch 256 on).
     scan_layers=True builds the stack as one layers.Scan
     (`_scan_encoder_stack`) — per-layer checkpointing then comes from
     scan_remat, not RecomputeOptimizer."""

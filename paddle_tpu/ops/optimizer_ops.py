@@ -383,92 +383,39 @@ def _dgc_clip_by_norm(ins, attrs):
 # ---------------------------------------------------------------------------
 # Coalesced optimizer updates (reference: the fuse_optimizer_ops_pass
 # family, framework/ir/fuse_optimizer_ops_pass/ — per-group fused sgd/
-# momentum/adam kernels over coalesced gradient buffers). Here the group
-# flattens into ONE [total] vector so the update lowers to a handful of
-# HLO ops instead of ~6 per parameter: on ResNet50 the per-param
-# optimizer chains were ~60% of the train step's StableHLO lines.
-# Exact math preservation: elementwise updates are concat/split-stable;
-# per-parameter scalars (adam beta pows) broadcast into their segment.
+# momentum/adam kernels over coalesced gradient buffers). The group is ONE
+# Program op; its compute applies the single-tensor kernel to each member,
+# so the math is bit-identical to the unfused ops. The members are NOT
+# concatenated: a flat [total] vector of a 133 M-parameter model is a
+# hundred-million-element 1-D array, which the TPU compiler views as
+# [N/2, 2] and pads from 2 to 128 lanes (64x its size — the BERT-base
+# step was refused for 34 GB). XLA's horizontal fusion merges the
+# per-member loops at run time anyway.
 # ---------------------------------------------------------------------------
 
-def _concat_flat(tensors, dtype=None):
-    return jnp.concatenate([
-        (t if dtype is None else t.astype(dtype)).reshape(-1)
-        for t in tensors])
-
-
-def _split_back(vec, like):
-    import numpy as np
-
-    outs, off = [], 0
-    for t in like:
-        size = int(np.prod(t.shape)) if t.shape else 1
-        outs.append(vec[off:off + size].reshape(t.shape))
-        off += size
+def _per_member(kernel, ins, attrs):
+    """Apply the single-tensor `kernel` to every member of a fused
+    group; outputs are per-slot lists in member order."""
+    shared = {k: v for k, v in ins.items() if k == "LearningRate"}
+    outs = {}
+    for i in range(len(ins["Param"])):
+        one = {k: [v[i]] for k, v in ins.items() if k not in shared}
+        one.update(shared)
+        for slot, val in kernel(one, attrs).items():
+            outs.setdefault(slot, []).append(val)
     return outs
 
 
 @register_op("fused_sgd")
 def _fused_sgd(ins, attrs):
-    ps, gs = ins["Param"], ins["Grad"]
-    lr = _lr(ins).astype(ps[0].dtype)
-    pc = _concat_flat(ps)
-    gc = _concat_flat(gs, ps[0].dtype)
-    return {"ParamOut": _split_back(pc - lr * gc, ps)}
+    return _per_member(_sgd, ins, attrs)
 
 
 @register_op("fused_momentum")
 def _fused_momentum(ins, attrs):
-    ps, gs, vs = ins["Param"], ins["Grad"], ins["Velocity"]
-    dtype = ps[0].dtype
-    lr = _lr(ins).astype(dtype)
-    mu = attrs.get("mu", 0.9)
-    pc = _concat_flat(ps)
-    gc = _concat_flat(gs, dtype)
-    vc = _concat_flat(vs)
-    v_out = mu * vc + gc
-    if attrs.get("use_nesterov", False):
-        p_out = pc - (gc + mu * v_out) * lr
-    else:
-        p_out = pc - lr * v_out
-    return {"ParamOut": _split_back(p_out, ps),
-            "VelocityOut": _split_back(v_out, vs)}
+    return _per_member(_momentum, ins, attrs)
 
 
 @register_op("fused_adam")
 def _fused_adam(ins, attrs):
-    import numpy as np
-
-    ps, gs = ins["Param"], ins["Grad"]
-    m1s, m2s = ins["Moment1"], ins["Moment2"]
-    b1ps, b2ps = ins["Beta1Pow"], ins["Beta2Pow"]
-    lr = _lr(ins)
-    b1 = attrs.get("beta1", 0.9)
-    b2 = attrs.get("beta2", 0.999)
-    eps = attrs.get("epsilon", 1e-8)
-    pc = _concat_flat(ps, jnp.float32)
-    gc = _concat_flat(gs, jnp.float32)
-    m1c = _concat_flat(m1s)
-    m2c = _concat_flat(m2s)
-    m1o = b1 * m1c + (1 - b1) * gc
-    m2o = b2 * m2c + (1 - b2) * jnp.square(gc)
-    # per-parameter bias-corrected step size, broadcast into segments —
-    # beta pows are per-param state vars, so equality across the group
-    # is NOT assumed
-    sizes = [int(np.prod(p.shape)) if p.shape else 1 for p in ps]
-    alphas = []
-    for b1p, b2p, size in zip(b1ps, b2ps, sizes):
-        b1pf = b1p.reshape(()).astype(jnp.float32)
-        b2pf = b2p.reshape(()).astype(jnp.float32)
-        a = lr * jnp.sqrt(1 - b2pf * b2) / (1 - b1pf * b1)
-        alphas.append(jnp.broadcast_to(a, (size,)))
-    alpha_vec = jnp.concatenate(alphas)
-    p_out = pc - alpha_vec * m1o / (jnp.sqrt(m2o) + eps)
-    return {
-        "ParamOut": [o.astype(p.dtype) for o, p in
-                     zip(_split_back(p_out, ps), ps)],
-        "Moment1Out": _split_back(m1o, m1s),
-        "Moment2Out": _split_back(m2o, m2s),
-        "Beta1PowOut": [b1p * b1 for b1p in b1ps],
-        "Beta2PowOut": [b2p * b2 for b2p in b2ps],
-    }
+    return _per_member(_adam, ins, attrs)

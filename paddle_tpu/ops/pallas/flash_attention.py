@@ -28,14 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 _LANES = 128  # VREG lane count: scratch stats are replicated across lanes
@@ -72,34 +65,24 @@ def _dropout_mask(seed, bh, row0, col0, block_q, block_k, p_drop):
 
 def _seed_spec():
     # scalar dropout seed rides in SMEM (full-array spec; one int32)
-    if _HAS_PLTPU:
-        return pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.BlockSpec(memory_space=pl.MemorySpace.ANY)  # pragma: no cover
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _interpret_default() -> bool:
-    # Real Mosaic kernels only lower for TPU; interpret everywhere else
-    # (CPU tests, GPU installs).
+    """Mosaic compiles the kernels on a tpu backend, always; the Pallas
+    interpreter is the CPU test mode and nothing selects it on a chip."""
     return jax.default_backend() != "tpu"
 
 
 def _compiler_params():
     # Outer two grid dims are embarrassingly parallel; only the innermost
     # (the online-softmax / accumulation dim) is sequential.
-    if _HAS_PLTPU:
-        try:
-            return pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-        except Exception:  # older jax: TPUCompilerParams
-            return pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-    return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _vmem(shape, dtype):
-    if _HAS_PLTPU:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemorySpace.ANY(shape, dtype)  # pragma: no cover
+    return pltpu.VMEM(shape, dtype)
 
 
 # ---------------------------------------------------------------------------

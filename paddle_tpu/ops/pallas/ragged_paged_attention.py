@@ -53,9 +53,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .flash_attention import (_HAS_PLTPU, _LANES, _NEG_INF,
-                              _compiler_params, _interpret_default,
-                              _vmem, pltpu)
+from .flash_attention import (_LANES, _NEG_INF, _compiler_params,
+                              _interpret_default, _vmem, pltpu)
 
 __all__ = ["ragged_paged_attention", "ragged_paged_attention_reference"]
 
@@ -177,7 +176,10 @@ def _rpa_kernel(tbl_ref, ctx_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
     The q block is the GQA-packed [G*Q, D] row block for (seq, kv
     head); row r maps to query group g = r // Q, row i = r % Q.
     `ks_ref`/`vs_ref` (quantized pool only) hold the page's per-slot
-    fp32 scales; int8 K/V dequantize in VMEM right after the load."""
+    fp32 scales as a lane-layout [1, page] row: a slot's scale factors
+    out of both matmuls, so K's scales multiply the score columns and
+    V's the prob columns — no [page, D] dequantized copy, and no
+    lane-to-sublane move of the scale row."""
     s_idx = pl.program_id(0)
     j = pl.program_id(2)
     npages = pl.num_programs(2)
@@ -195,11 +197,11 @@ def _rpa_kernel(tbl_ref, ctx_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)          # [GQ, D]
         k = k_ref[0, 0].astype(jnp.float32)          # [page, D]
-        if ks_ref is not None:
-            k = k * ks_ref[0][:, None]
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
         s = s * sm_scale                             # [GQ, page]
+        if ks_ref is not None:
+            s = s * ks_ref[0]                        # [1, page] scales
         rows = lax.broadcasted_iota(jnp.int32, (gq_rows, page_size), 0)
         qi = rows - (rows // q_rows) * q_rows        # row i within Q
         kpos = j * page_size + lax.broadcasted_iota(
@@ -220,7 +222,7 @@ def _rpa_kernel(tbl_ref, ctx_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
         m_scr[:] = m_next
         v = v_ref[0, 0].astype(jnp.float32)
         if vs_ref is not None:
-            v = v * vs_ref[0][:, None]
+            p = p * vs_ref[0]
         pv = lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
         acc_scr[:] = acc_scr[:] * alpha[:, :1] + pv
@@ -271,13 +273,14 @@ def _rpa_call_impl(q_packed, k_heads, v_heads, block_tables,
     operands = [block_tables, context_lens, q_lens, q_packed, k_heads,
                 v_heads]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, page_size),
-                         lambda s, h, j, tbl, ctx, ql: (tbl[s, j], 0)),
-            pl.BlockSpec((1, page_size),
-                         lambda s, h, j, tbl, ctx, ql: (tbl[s, j], 0)),
-        ]
-        operands += [k_scale, v_scale]
+        # [P, 1, page]: Mosaic wants a block's last two dims to be
+        # multiples of (8, 128) or the whole array's — (1, page) is the
+        # whole of the trailing [1, page]
+        scale_spec = pl.BlockSpec(
+            (1, 1, page_size),
+            lambda s, h, j, tbl, ctx, ql: (tbl[s, j], 0, 0))
+        in_specs += [scale_spec, scale_spec]
+        operands += [k_scale[:, None, :], v_scale[:, None, :]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -309,8 +312,10 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
 
     impl: "kernel" = the Pallas kernel (Mosaic on TPU, interpreter
     elsewhere), "reference" = the jittable pure-JAX gather reference,
-    "auto" = kernel on TPU, reference on CPU/GPU — the interpreter is
-    grid-sequential and only meant for kernel parity tests.
+    "auto" = kernel on TPU, reference on the CPU test backend — the
+    interpreter is grid-sequential and only meant for kernel parity
+    tests. `interpret` (None = interpreter off the TPU only) is for
+    those tests too; on a tpu backend nothing turns it on by itself.
 
     k_scale/v_scale ([num_pages, page_size] fp32, both or neither):
     per-slot dequantization scales for int8 pages — kernel and
@@ -333,15 +338,8 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
     if impl not in ("auto", "kernel", "reference"):
         raise ValueError("impl must be auto|kernel|reference, got %r"
                          % (impl,))
-    if impl == "kernel" and not _HAS_PLTPU:
-        raise ImportError(
-            "impl='kernel' needs jax.experimental.pallas.tpu "
-            "(PrefetchScalarGridSpec) — this install lacks it; use "
-            "impl='reference'")
-    use_kernel = _HAS_PLTPU and (
-        impl == "kernel"
-        or (impl == "auto" and not _interpret_default()))
-    if not use_kernel:
+    # on a tpu backend only a caller that names "reference" gets it
+    if impl == "reference" or (impl == "auto" and _interpret_default()):
         return ragged_paged_attention_reference(
             q, k_pages, v_pages, block_tables, context_lens, q_lens,
             sm_scale=sm_scale, k_scale=k_scale, v_scale=v_scale)

@@ -137,8 +137,6 @@ def _score(compiled, mem_budget):
     if mem_budget is not None and peak > mem_budget:
         return float("inf"), peak
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x: [dict], newer: dict
-        ca = ca[0] if ca else {}
     t = (float(ca.get("flops", 0.0)) / _FLOP_RATE
          + float(ca.get("bytes accessed", 0.0)) / _BW)
     return t, peak

@@ -70,31 +70,20 @@ def active_axes() -> Optional[dict]:
 
 
 def axis_size_compat(axis_name):
-    """`lax.axis_size` across jax versions: 0.4.x lacks it; psum of a
-    literal 1 over the axis constant-folds to the axis size at trace
-    time, so there is no runtime collective either way."""
+    """Size of a live mesh axis at trace time (no runtime collective)."""
     from jax import lax
 
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    return lax.axis_size(axis_name)
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: the top-level `jax.shard_map`
-    (with `check_vma`) only exists in newer jax; 0.4.x ships it as
-    `jax.experimental.shard_map.shard_map` with the equivalent knob
-    named `check_rep`. Every shard_map call in the tree routes through
-    here so version skew breaks exactly one spot."""
+    """The one spelling of `jax.shard_map` every call in the tree uses
+    (check_vma off by default: the lowerings place their own
+    collectives)."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_name_for_ring(ring_id: int):
@@ -275,7 +264,7 @@ def create_hybrid_mesh(nranks=None, dcn=None, mp=None, devices=None):
 
             dev_arr = mesh_utils.create_hybrid_device_mesh(
                 (1, ici), (dcn, 1), devices=devices)
-        except Exception as e:  # noqa: BLE001 - single-slice / old jax
+        except Exception as e:  # noqa: BLE001 - single-slice
             warnings.warn(
                 "create_hybrid_device_mesh failed (%s); using "
                 "row-major pod blocks" % (e,))

@@ -192,14 +192,10 @@ class Engine:
         self._closed = False
         self._draining = False
 
-        # donation of the page state into the step is gated exactly
-        # like the executor's: the persistent tier's deserialized
-        # executables corrupt donated outputs on XLA:CPU (PR 13)
-        from ..fluid import compile_cache as cc
+        # the page state is donated into the step, like the executor's
         from ..utils.flags import get_flag
 
-        donate = bool(get_flag("FLAGS_tpu_donate_buffers", True)) and \
-            cc.donation_safe()
+        donate = bool(get_flag("FLAGS_tpu_donate_buffers", True))
         self._donate = donate
         self._copy_fn = None  # lazy-jitted COW page copier
 

@@ -458,11 +458,13 @@ def test_supervised_elastic_shrink_warm_restart_splits_recovery(
         tmp_path):
     """2-rank cohort loses rank 1 for good; the supervisor shrinks to
     world 1 and respawns. The respawned worker compiles THROUGH the
-    supervisor-exported <log_dir>/compile_cache (attempt 1 records
-    HITS where attempt 0 recorded misses) and the elastic_transition
-    event splits recovery into coordination_s + compile_s."""
+    directory the supervisor passes on — the JAX_COMPILATION_CACHE_DIR
+    of its own environment — so attempt 1 records HITS where attempt 0
+    recorded misses, and the elastic_transition event splits recovery
+    into coordination_s + compile_s."""
     log_dir = str(tmp_path / "logs")
-    env = _base_env()
+    ccdir = str(tmp_path / "placed_from_outside")
+    env = _base_env(JAX_COMPILATION_CACHE_DIR=ccdir)
     env.pop("FLAGS_tpu_compile_cache_dir", None)
     env.pop("FLAGS_tpu_telemetry_dir", None)
     proc = _sp.run(
@@ -511,9 +513,11 @@ def test_supervised_elastic_shrink_warm_restart_splits_recovery(
     warm = _events_under(tdir)
     assert warm and all(e["status"] == "hit" for e in warm), warm
 
-    # the persistent tier itself lives beside the logs and survived
-    ccdir = _os.path.join(log_dir, "compile_cache")
+    # the persistent tier lives where the environment put it — the
+    # executables and the fingerprint index both — and nowhere else
     assert _os.path.isdir(_os.path.join(ccdir, "index"))
+    assert any(f.endswith("-cache") for f in _os.listdir(ccdir))
+    assert not _os.path.exists(_os.path.join(log_dir, "compile_cache"))
 
     # perf_analysis --compile-cache aggregates the whole run
     _sys.path.insert(0, _os.path.join(_REPO, "tools"))

@@ -9,7 +9,7 @@ from paddle_tpu.core import errors, memory
 from paddle_tpu.fluid import framework
 
 
-def test_enforce_taxonomy():
+def test_enforce_error_classes():
     with pytest.raises(errors.InvalidArgumentError):
         errors.enforce(False, "bad arg")
     with pytest.raises(errors.NotFoundError):

@@ -97,15 +97,9 @@ def test_parallel_executor_compat_runs():
 
 
 def test_load_op_library_loads_native_so():
-    import os
+    from paddle_tpu.core.native import build as native_build
 
-    import paddle_tpu
-
-    so = os.path.join(os.path.dirname(paddle_tpu.__file__), "core",
-                      "native", "libpaddle_tpu_native.so")
-    if not os.path.exists(so):
-        pytest.skip("native lib not built")
-    lib = fluid.load_op_library(so)
+    lib = fluid.load_op_library(native_build.build())
     assert lib is not None
 
 

@@ -386,9 +386,8 @@ def test_serving_events_schema_valid(tmp_path):
 
 def test_bench_serving_leg_inprocess():
     """bench.py's --serving leg returns the registry-assembled block
-    and a tokens/sec headline (run in-process, tiny trace). The leg
-    arms the repo-local compile cache — restore the flag/jax config so
-    later tests keep their donation behavior."""
+    and a tokens/sec headline (run in-process, tiny trace; the command
+    line itself refuses a backend that is not tpu)."""
     from paddle_tpu.fluid import compile_cache as cc
     from paddle_tpu.utils.flags import get_flag, set_flags
 

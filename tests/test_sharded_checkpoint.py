@@ -47,7 +47,7 @@ def test_sharded_roundtrip_preserves_sharding():
     mgr.close()
 
 
-def test_restore_relays_out_on_a_different_world_size():
+def test_restore_lays_out_again_on_a_different_world_size():
     """Elastic restart (N' != N): a checkpoint written by a 4-device dp
     mesh restores DIRECTLY into a template laid out on a 2-device mesh
     (and vice versa back to 4) — orbax re-lays shards out against the

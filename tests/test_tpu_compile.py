@@ -1,0 +1,150 @@
+"""The Pallas kernels of the main paths, compiled by the TPU's own
+compiler (Mosaic through XLA:TPU) for a DESCRIBED v5e chip — nothing
+executes, no chip is needed. Interpret-mode tests cannot see what this
+sees: block shapes Mosaic refuses, VMEM overuse, layouts that do not
+lower.
+
+Only one process may load the TPU library, and it keeps it until it
+exits: the topology is described inside a module-scoped fixture (never
+at import, in a skipif or in parametrize), every compile happens in
+this process, and all of these tests live in this ONE file.
+"""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# the package re-exports functions under the modules' own names
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+rpa = importlib.import_module(
+    "paddle_tpu.ops.pallas.ragged_paged_attention")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe skips
+        pytest.skip("no v5e:2x2 topology can be described here: %r" % (e,))
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Steer the kernels' backend probe to its TPU side: the process
+    runs on the CPU, the compile is for the chip."""
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    monkeypatch.setattr(rpa, "_interpret_default", lambda: False)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# BERT long-context shapes: B2 H12 S4096 D64 in bf16
+_QKV = ((2, 12, 4096, 64), jnp.bfloat16)
+
+
+def test_flash_forward(one_chip, mosaic):
+    _compile(lambda q, k, v: fa.flash_attention(q, k, v),
+             one_chip, _QKV, _QKV, _QKV)
+
+
+def test_flash_forward_backward_dropout_key_bias(one_chip, mosaic):
+    def loss(q, k, v, bias, seed):
+        o = fa.flash_attention(q, k, v, key_bias=bias, dropout_p=0.1,
+                               dropout_seed=seed)
+        return jnp.sum(o.astype(jnp.float32))
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, _QKV, _QKV,
+             _QKV, ((2, 4096), jnp.float32), ((1,), jnp.int32))
+
+
+def test_flash_causal(one_chip, mosaic):
+    def loss(q, k, v):
+        o = fa.flash_attention(q, k, v, causal=True)
+        return jnp.sum(o.astype(jnp.float32))
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, _QKV, _QKV,
+             _QKV)
+
+
+def _rpa_shapes(seqs, q_rows, hq, hkv, d, pages, page, per_seq, dtype,
+                scales=False):
+    shapes = [((seqs, q_rows, hq, d),
+               jnp.bfloat16 if dtype == jnp.int8 else dtype),
+              ((pages, page, hkv, d), dtype),
+              ((pages, page, hkv, d), dtype),
+              ((seqs, per_seq), jnp.int32),
+              ((seqs,), jnp.int32), ((seqs,), jnp.int32)]
+    if scales:
+        shapes += [((pages, page), jnp.float32)] * 2
+    return shapes
+
+
+def _rpa(q, kp, vp, tbl, ctx, ql, ks=None, vs=None):
+    return rpa.ragged_paged_attention(q, kp, vp, tbl, ctx, ql,
+                                      impl="kernel", k_scale=ks,
+                                      v_scale=vs)
+
+
+# the serving engine's own shapes (TinyDecoderLM: 4 q heads, 2 kv heads,
+# D16) for f32, and a real-width GQA shape for bf16 and int8 pages
+@pytest.mark.parametrize("name,shapes", [
+    ("decode_f32", _rpa_shapes(8, 1, 4, 2, 16, 256, 8, 16, jnp.float32)),
+    ("prefill_f32", _rpa_shapes(4, 32, 4, 2, 16, 256, 16, 8,
+                                jnp.float32)),
+    ("decode_bf16_wide", _rpa_shapes(16, 1, 32, 8, 128, 1024, 16, 64,
+                                     jnp.bfloat16)),
+    ("decode_int8_wide", _rpa_shapes(16, 1, 32, 8, 128, 1024, 16, 64,
+                                     jnp.int8, scales=True)),
+    ("prefill_int8", _rpa_shapes(4, 32, 4, 2, 16, 256, 8, 16, jnp.int8,
+                                 scales=True)),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_ragged_paged_attention(one_chip, mosaic, name, shapes):
+    _compile(_rpa, one_chip, *shapes)
+
+
+def test_int8_scales_factor_out_of_the_matmuls(mosaic):
+    """The kernel applies a slot's scale to score and prob COLUMNS
+    instead of dequantizing [page, D] rows; same answer as the
+    reference's dequantize-then-attend, here under the interpreter."""
+    r = np.random.RandomState(0)
+    S, Q, Hq, Hkv, D, P, page, per_seq = 3, 4, 4, 2, 16, 12, 8, 3
+    q = jnp.asarray(r.randn(S, Q, Hq, D), jnp.float32)
+    kp = jnp.asarray(r.randint(-127, 128, (P, page, Hkv, D)), jnp.int8)
+    vp = jnp.asarray(r.randint(-127, 128, (P, page, Hkv, D)), jnp.int8)
+    ks = jnp.asarray(r.rand(P, page) * 0.02 + 1e-3, jnp.float32)
+    vs = jnp.asarray(r.rand(P, page) * 0.02 + 1e-3, jnp.float32)
+    tbl = jnp.asarray(r.permutation(P)[:S * per_seq].reshape(S, per_seq),
+                      jnp.int32)
+    ctx = jnp.asarray([20, 9, 24], jnp.int32)
+    ql = jnp.asarray([4, 1, 0], jnp.int32)
+    got = rpa.ragged_paged_attention(q, kp, vp, tbl, ctx, ql,
+                                     impl="kernel", interpret=True,
+                                     k_scale=ks, v_scale=vs)
+    want = rpa.ragged_paged_attention_reference(
+        q, kp, vp, tbl, ctx, ql, k_scale=ks, v_scale=vs)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
