@@ -44,6 +44,7 @@ DEVICE_PEAKS = {
 
 BATCH = 256
 SEQ_LEN = 128
+BERT_LR = 1e-4
 WARMUP = 3
 STEPS = 10
 # Long-context leg: BERT-base at seq 4096, where the Pallas flash kernel
@@ -149,7 +150,7 @@ def build_bert_train_program(seq_len: int = SEQ_LEN, cfg=None):
                 cfg, seq_len, is_test=False, scan_layers=True,
                 scan_remat=True)
             opt = mixed_precision.decorate(
-                fluid.optimizer.AdamOptimizer(learning_rate=1e-4),
+                fluid.optimizer.AdamOptimizer(learning_rate=BERT_LR),
                 use_dynamic_loss_scaling=False)
             opt.minimize(total)
     return main_p, startup_p, total, cfg

@@ -223,7 +223,8 @@ def _worker_env(endpoints, tid, restart_no, base_env=None,
         # persistent compilation cache shared across the cohort AND
         # across restarts/elastic transitions: a relaunched worker
         # deserializes its XLA executables instead of recompiling, so
-        # recovery is coordination-bound, not compile-bound
+        # recovery is coordination-bound, not compile-bound. The
+        # supervisor always names one; other callers may leave it out
         env["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir
     if hang_timeout_s and hang_timeout_s > 0:
         # one knob arms both tiers: the workers' in-process watchdogs
@@ -284,12 +285,10 @@ def _compile_cache_dir():
     is never derived from a log dir, a pid or the time). It survives
     restarts — that is its entire point — and is never collected into
     postmortem/."""
-    # == fluid.compile_cache.default_dir(), spelled out: the supervisor
-    # stays a subprocess babysitter and does not import the jax stack
-    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
+    from ..fluid import compile_cache
+
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
-        os.path.join(checkout, ".jax_cache")
+        compile_cache.default_dir()
 
 
 def _collect_flight_dumps(args, attempt):
@@ -671,8 +670,7 @@ def _spawn_cohort(args, endpoints, local_ids, restart_no, npods=1):
     if tdir:
         os.makedirs(tdir, exist_ok=True)
     ccdir = _compile_cache_dir()
-    if ccdir:
-        os.makedirs(ccdir, exist_ok=True)
+    os.makedirs(ccdir, exist_ok=True)
     for tid in local_ids:
         env = _worker_env(endpoints, tid, restart_no,
                           telemetry_dir=tdir, npods=npods,

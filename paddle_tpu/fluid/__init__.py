@@ -22,7 +22,6 @@ from ..core.lod import (  # noqa: F401
 )
 from .executor import Executor, LazyFetch  # noqa: F401
 from .backward import append_backward, gradients  # noqa: F401
-from .fuse_optimizer import fuse_optimizer_ops  # noqa: F401
 from .compiler import (  # noqa: F401
     CompiledProgram, BuildStrategy, ExecutionStrategy,
 )

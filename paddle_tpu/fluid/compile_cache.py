@@ -8,16 +8,16 @@ persistent tier layered UNDER the Executor's in-memory LRU
 
 - the XLA executables themselves persist through
   `jax.experimental.compilation_cache` (`_configure_jax`), rooted at
-  `cache_dir()`: `JAX_COMPILATION_CACHE_DIR` where the environment sets
-  it (the cache can be placed from outside, and nothing in the tree
-  points jax anywhere else then), `FLAGS_tpu_compile_cache_dir`
-  otherwise. The entry points (`bench.py`, `chip_smoke.py`, the launch
-  supervisor) call `use_default_dir()`: the fixed `<checkout>/.jax_cache`
-  where the environment names none — the path is part of jax's cache
-  key, so it is never made from a temporary name, a pid or the time.
-  The supervisor exports the directory to every worker and across
-  restarts, so a restarted N' cohort deserializes executables in
-  seconds instead of recompiling;
+  `cache_dir()`. One rule places it: `JAX_COMPILATION_CACHE_DIR` where
+  the environment sets it (the cache can be placed from outside, and
+  nothing in the tree points jax anywhere else then); where it does
+  not, an entry point (`bench.py`, `chip_smoke.py`) that called
+  `use_default_dir()` gets the fixed `<checkout>/.jax_cache` — the path
+  is part of jax's cache key, so it is never made from a temporary
+  name, a pid or the time — and a library user gets no persistent
+  tier. The launch supervisor exports the same choice to every worker
+  and across restarts, so a restarted N' cohort deserializes
+  executables in seconds instead of recompiling;
 - a *fingerprint index* (`index/<fp>.json` sentinels) keyed on
   (canonicalized lowered StableHLO, mesh topology, the
   lowering-relevant `FLAGS_tpu_*` set, jax/jaxlib version + backend)
@@ -33,10 +33,9 @@ persistent tier layered UNDER the Executor's in-memory LRU
   (observability/publish.py) and `tools/perf_analysis.py
   --compile-cache`.
 
-Everything here is inert while neither the environment variable nor
-the flag is set: `enabled()` is False, no jax config is touched, no
-listeners install, and the Executor's behavior is byte-identical to a
-cache-less build.
+Everything here is inert while no directory is named: `enabled()` is
+False, no jax config is touched, no listeners install, and the
+Executor's behavior is byte-identical to a cache-less build.
 """
 from __future__ import annotations
 
@@ -74,6 +73,9 @@ LOWERING_FLAGS = (
 
 _lock = threading.RLock()
 _configured_dir: Optional[str] = None
+#: the directory an entry point chose through use_default_dir() when
+#: the environment named none; process state, never exported
+_entry_dir: Optional[str] = None
 _listeners_installed = False
 #: cumulative jax-tier stats fed by the monitoring listeners; snapshot
 #: with jax_stats() / delta with stats_delta() around a compile
@@ -90,13 +92,9 @@ _ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
 def cache_dir() -> Optional[str]:
     """The persistent tier's root: `JAX_COMPILATION_CACHE_DIR` where
-    the environment sets it, else FLAGS_tpu_compile_cache_dir, else
-    None (the tier is off)."""
-    from ..utils.flags import get_flag
-
-    d = os.environ.get(_ENV_VAR) or \
-        str(get_flag("FLAGS_tpu_compile_cache_dir", "") or "")
-    return d or None
+    the environment sets it, else the directory an entry point chose
+    (`use_default_dir()`), else None (the tier is off)."""
+    return os.environ.get(_ENV_VAR) or _entry_dir
 
 
 def default_dir() -> str:
@@ -108,14 +106,16 @@ def default_dir() -> str:
 
 
 def use_default_dir() -> str:
-    """Entry points only (bench.py, chip_smoke.py, the launcher): keep
-    the directory the environment names, or name `default_dir()`, and
-    wire the tier. Raises if the directory cannot be used."""
-    os.environ.setdefault(_ENV_VAR, default_dir())
+    """Entry points only (bench.py, chip_smoke.py): keep the directory
+    the environment names, or choose `default_dir()` for this process
+    (the environment is left as it was), and wire the tier. Raises if
+    the directory cannot be used."""
+    global _entry_dir
+    _entry_dir = default_dir()
     d = ensure()
     if d is None:
         raise RuntimeError("compile cache directory %r is unusable"
-                           % (os.environ[_ENV_VAR],))
+                           % (cache_dir(),))
     return d
 
 
@@ -125,11 +125,11 @@ def enabled() -> bool:
 
 def ensure() -> Optional[str]:
     """Idempotently wire the persistent tier: point
-    jax.experimental.compilation_cache at the flag directory (min
+    jax.experimental.compilation_cache at `cache_dir()` (min
     compile time / entry size floors dropped so EVERY executor
     executable persists — a 40ms test program and a 90s BERT step both
     must round-trip) and install the monitoring listeners. Returns the
-    active directory, or None when the flag is unset. Never raises —
+    active directory, or None when no directory is named. Never raises —
     an unwritable directory degrades to cache-off, it must not take
     down a training step."""
     global _configured_dir
@@ -160,7 +160,7 @@ def _configure_jax(d: str) -> None:
 
 def _reset_jax_cache_instance() -> None:
     """jax memoizes its cache object at first use — a dir change
-    mid-process (tests; a launcher re-pointing the flag) must drop the
+    mid-process (tests re-pointing the directory) must drop the
     memo or writes keep landing in the OLD directory."""
     try:
         from jax.experimental.compilation_cache import (
@@ -516,9 +516,9 @@ def stats() -> dict:
 
 
 def _reset_for_tests() -> None:
-    global _configured_dir
+    global _configured_dir, _entry_dir
     with _lock:
-        _configured_dir = None
+        _configured_dir = _entry_dir = None
         for k in _jax:
             _jax[k] = 0 if isinstance(_jax[k], int) else 0.0
         for k in _stats:

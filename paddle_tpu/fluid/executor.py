@@ -181,10 +181,6 @@ class Executor:
             # idempotent markers make later runs no-ops). Fetch names
             # guard the passes from fusing away an observed var.
             bsty = _compiled._build_strategy
-            if getattr(bsty, "fuse_all_optimizer_ops", None):
-                from .fuse_optimizer import fuse_optimizer_ops
-
-                fuse_optimizer_ops(program)
             if getattr(bsty, "fuse_elewise_add_act_ops", None):
                 from .fusion_passes import fuse_elewise_add_act
 
@@ -328,7 +324,7 @@ class Executor:
                 raise
         if fresh_compile:
             # persistent compile-cache tier
-            # (FLAGS_tpu_compile_cache_dir): fingerprint the lowered
+            # (JAX_COMPILATION_CACHE_DIR): fingerprint the lowered
             # StableHLO at the exact avals the dispatch below will use
             # and look up the cross-process index — the lowering also
             # warms jax's trace cache, so the first dispatch re-pays
@@ -508,7 +504,7 @@ class Executor:
         checks -> LRU insert. Evicted entries drop their AOT-compiled
         artifacts EAGERLY (a dead in-memory entry must not pin
         compiled XLA executables in host RAM); the persistent tier
-        (FLAGS_tpu_compile_cache_dir) survives eviction, so a
+        (JAX_COMPILATION_CACHE_DIR) survives eviction, so a
         re-admitted program is a persistent-cache hit, not a fresh
         compile."""
         from . import compile_cache as _cc
@@ -638,7 +634,7 @@ class Executor:
         example array, or jax.ShapeDtypeStruct) the program is
         compiled and ONE discarded step executes on state COPIES — so
         both jax's in-process executable cache and the persistent tier
-        (FLAGS_tpu_compile_cache_dir) are warm, and the first real
+        (JAX_COMPILATION_CACHE_DIR) are warm, and the first real
         step of that shape dispatches with compile_ms ~ 0 — without
         mutating any scope state or the program's RNG stream.
 

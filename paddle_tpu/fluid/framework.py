@@ -592,7 +592,6 @@ class Program:
             "sgd", "momentum", "adam", "adamw", "adamax", "adagrad",
             "decayed_adagrad", "adadelta", "rmsprop", "ftrl", "lamb",
             "lars_momentum", "dpsgd", "backward",
-            "fused_sgd", "fused_momentum", "fused_adam",
         }
         for b in self.blocks:
             b.ops = [op for op in b.ops if op.type not in opt_types

@@ -89,7 +89,7 @@ class LoweredFunction:
         # every audit of this executable instead of one per call
         self.aot_compiled = None
         # persistent compile-cache classification (fluid/compile_cache,
-        # FLAGS_tpu_compile_cache_dir): the program fingerprint and the
+        # JAX_COMPILATION_CACHE_DIR): the program fingerprint and the
         # prior compile's index sentinel (None = first-ever compile)
         self.cc_fingerprint = None
         self.cc_prev = None

@@ -17,7 +17,7 @@ continuous batching across concurrent requests, a paged KV cache, and
 AOT-warmed decode-step buckets — see ``paddle_tpu.serving``
 (serving/README.md); `Predictor.warmup(shapes=...)` pre-compiles this
 predictor's own input-shape buckets through the same persistent
-compile cache (FLAGS_tpu_compile_cache_dir) so a serving process
+compile cache (JAX_COMPILATION_CACHE_DIR) so a serving process
 restart answers its first request without paying XLA compilation.
 """
 from __future__ import annotations
@@ -347,7 +347,7 @@ class Predictor:
     def warmup(self, shapes, meshes=None, background=False):
         """AOT-compile this predictor's program for the given
         input-shape buckets BEFORE traffic (PR 13 machinery:
-        `Executor.warmup` + the FLAGS_tpu_compile_cache_dir persistent
+        `Executor.warmup` + the JAX_COMPILATION_CACHE_DIR persistent
         tier). `shapes` is a list of dicts mapping input name ->
         concrete shape tuple / example array / ShapeDtypeStruct; each
         bucket executes one discarded run on state copies, so the

@@ -445,7 +445,7 @@ def static_checks_block(program) -> Optional[dict]:
 
 def compile_cache_block() -> Optional[dict]:
     """Persistent compile-cache evidence (fluid/compile_cache,
-    FLAGS_tpu_compile_cache_dir): the process's hit/miss tally at the
+    JAX_COMPILATION_CACHE_DIR): the process's hit/miss tally at the
     framework fingerprint granularity, compile milliseconds paid vs
     saved, and the on-disk tier inventory. None when the tier is off
     AND no compile was ever classified — cold-start cost only shows up

@@ -378,44 +378,3 @@ def _dgc_clip_by_norm(ins, attrs):
     clipped = _clip_by_norm(
         {"X": [x]}, {"max_norm": attrs.get("max_norm", 1.0)})["Out"]
     return {"Out": jnp.where(step >= rampup, clipped, x)}
-
-
-# ---------------------------------------------------------------------------
-# Coalesced optimizer updates (reference: the fuse_optimizer_ops_pass
-# family, framework/ir/fuse_optimizer_ops_pass/ — per-group fused sgd/
-# momentum/adam kernels over coalesced gradient buffers). The group is ONE
-# Program op; its compute applies the single-tensor kernel to each member,
-# so the math is bit-identical to the unfused ops. The members are NOT
-# concatenated: a flat [total] vector of a 133 M-parameter model is a
-# hundred-million-element 1-D array, which the TPU compiler views as
-# [N/2, 2] and pads from 2 to 128 lanes (64x its size — the BERT-base
-# step was refused for 34 GB). XLA's horizontal fusion merges the
-# per-member loops at run time anyway.
-# ---------------------------------------------------------------------------
-
-def _per_member(kernel, ins, attrs):
-    """Apply the single-tensor `kernel` to every member of a fused
-    group; outputs are per-slot lists in member order."""
-    shared = {k: v for k, v in ins.items() if k == "LearningRate"}
-    outs = {}
-    for i in range(len(ins["Param"])):
-        one = {k: [v[i]] for k, v in ins.items() if k not in shared}
-        one.update(shared)
-        for slot, val in kernel(one, attrs).items():
-            outs.setdefault(slot, []).append(val)
-    return outs
-
-
-@register_op("fused_sgd")
-def _fused_sgd(ins, attrs):
-    return _per_member(_sgd, ins, attrs)
-
-
-@register_op("fused_momentum")
-def _fused_momentum(ins, attrs):
-    return _per_member(_momentum, ins, attrs)
-
-
-@register_op("fused_adam")
-def _fused_adam(ins, attrs):
-    return _per_member(_adam, ins, attrs)

@@ -437,43 +437,35 @@ def _pad_to(x, axis, mult, value=0.0):
     return jnp.pad(x, widths, constant_values=value)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _flash_core(q, k, v, key_bias, seed, sm_scale, causal, block_q,
                 block_k, p_drop):
-    """custom_vjp wrapper. The int32 dropout `seed` is deliberately NOT
-    a differentiable positional arg of the custom_vjp (integer tangents
-    are float0 on current JAX, but relying on the bwd returning a None
-    cotangent for it is exactly the structure detail that breaks across
-    JAX upgrades — ADVICE r5): the vjp is built per-call with `seed`
-    closed over, so only the genuinely differentiable q/k/v/bias appear
-    in the vjp signature. Building it per call costs one python closure
-    per trace — the pallas_call inside dominates by orders of
-    magnitude, and under jit it traces exactly as often as before."""
+    """The int32 dropout `seed` is an ARGUMENT of the custom_vjp, with a
+    None (zero) cotangent: a seed closed over instead is a tracer the
+    vjp functions capture, and under `jax.checkpoint` inside a scan body
+    (the long-context train step) that tracer escapes its trace."""
+    o, _ = _fwd_call(q, k, v, key_bias, seed, sm_scale, causal,
+                     block_q, block_k, p_drop, _interpret_default())
+    return o
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-    def core(q, k, v, key_bias, sm_scale, causal, block_q, block_k,
-             p_drop):
-        o, _ = _fwd_call(q, k, v, key_bias, seed, sm_scale, causal,
-                         block_q, block_k, p_drop, _interpret_default())
-        return o
 
-    def core_fwd(q, k, v, key_bias, sm_scale, causal, block_q, block_k,
-                 p_drop):
-        o, lse = _fwd_call(q, k, v, key_bias, seed, sm_scale, causal,
-                           block_q, block_k, p_drop,
-                           _interpret_default())
-        return o, (q, k, v, key_bias, o, lse)
+def _flash_core_fwd(q, k, v, key_bias, seed, sm_scale, causal, block_q,
+                    block_k, p_drop):
+    o, lse = _fwd_call(q, k, v, key_bias, seed, sm_scale, causal,
+                       block_q, block_k, p_drop, _interpret_default())
+    return o, (q, k, v, key_bias, seed, o, lse)
 
-    def core_bwd(sm_scale, causal, block_q, block_k, p_drop, res, do):
-        q, k, v, key_bias, o, lse = res
-        dq, dk, dv = _bwd_call(q, k, v, key_bias, seed, o, lse, do,
-                               sm_scale, causal, block_q, block_k,
-                               p_drop, _interpret_default())
-        dbias = None if key_bias is None else jnp.zeros_like(key_bias)
-        return dq, dk, dv, dbias
 
-    core.defvjp(core_fwd, core_bwd)
-    return core(q, k, v, key_bias, sm_scale, causal, block_q, block_k,
-                p_drop)
+def _flash_core_bwd(sm_scale, causal, block_q, block_k, p_drop, res, do):
+    q, k, v, key_bias, seed, o, lse = res
+    dq, dk, dv = _bwd_call(q, k, v, key_bias, seed, o, lse, do,
+                           sm_scale, causal, block_q, block_k,
+                           p_drop, _interpret_default())
+    dbias = None if key_bias is None else jnp.zeros_like(key_bias)
+    return dq, dk, dv, dbias, None
+
+
+_flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
 def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,

@@ -35,8 +35,8 @@ data-parallel programs lowered through `fluid/lowering._compile_dp`:
   `PartitionSpec(dp_axis)` in/out specs and the executor lays the scope
   arrays out as `NamedSharding(mesh, P(dp))` flat buffers, so per-
   replica optimizer HBM is ~1/N from the first step on.
-- Elementwise optimizers (sgd/momentum/adam/... and the fused_* group
-  kernels) run their REGISTERED compute on the flat shards unchanged —
+- Elementwise optimizers (sgd/momentum/adam/...) run their REGISTERED
+  compute on the flat shards unchanged —
   elementwise updates are concat/split-stable. LAMB and LARS need their
   trust-ratio/local-lr norms over the FULL parameter: those norms are
   computed as a psum of local partial sums over the dp axis.
@@ -68,7 +68,6 @@ _log = logging.getLogger("paddle_tpu.sharded_update")
 _ELEMENTWISE_OPT = frozenset({
     "sgd", "momentum", "adam", "adamw", "adamax", "adagrad",
     "decayed_adagrad", "adadelta", "rmsprop", "ftrl",
-    "fused_sgd", "fused_momentum", "fused_adam",
 })
 # Norm-coupled optimizers: the update needs ||param|| / ||update|| over
 # the FULL tensor — computed with a psum over shard-local partial sums.
@@ -96,11 +95,11 @@ _TENSOR_OUT_SLOTS = frozenset({
 # param-shaped state slots per optimizer type: these become sharded
 # scope state (flat 1/N buffers per replica across steps).
 _OPT_STATE_SLOTS: Dict[str, Tuple[str, ...]] = {
-    "sgd": (), "fused_sgd": (),
-    "momentum": ("Velocity",), "fused_momentum": ("Velocity",),
+    "sgd": (),
+    "momentum": ("Velocity",),
     "lars_momentum": ("Velocity",),
     "adam": ("Moment1", "Moment2"), "adamw": ("Moment1", "Moment2"),
-    "lamb": ("Moment1", "Moment2"), "fused_adam": ("Moment1", "Moment2"),
+    "lamb": ("Moment1", "Moment2"),
     "adamax": ("Moment", "InfNorm"),
     "adagrad": ("Moment",), "decayed_adagrad": ("Moment",),
     "adadelta": ("AvgSquaredGrad", "AvgSquaredUpdate"),

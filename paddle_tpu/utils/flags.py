@@ -48,6 +48,12 @@ _FLAGS: Dict[str, object] = {
     # async input pipeline: how many batches the device prefetcher
     # (reader/prefetcher.py) keeps in HBM ahead of the consuming step
     "FLAGS_tpu_prefetch_depth": 2,
+    # batch-tail bucketing (Executor._tail_bucket): an epoch's short
+    # last batch is replicated up to an already-compiled batch size
+    # instead of compiling its own executable, when the cached batch is
+    # a whole multiple of it no larger than the max multiple
+    "FLAGS_batch_tail_bucketing": True,
+    "FLAGS_batch_tail_max_multiple": 8,
     # deferred fetches: hapi fit keeps losses/metric inputs
     # device-resident and syncs to host only every log_freq steps
     "FLAGS_tpu_deferred_fetch": True,
@@ -123,24 +129,13 @@ _FLAGS: Dict[str, object] = {
     # 9.8ms even at S=2048 fwd); flash's win is O(S) memory at long seq.
     "FLAGS_flash_attention_min_seq": 4096,
     "FLAGS_tpu_compile_cache_size": 128,
-    # Persistent, cross-process compilation cache (fluid/compile_cache):
-    # a directory (conventionally inside the checkpoint/telemetry root;
-    # the launch supervisor exports <log_dir>/compile_cache to every
-    # worker and across restarts) where compiled XLA executables
-    # persist via jax.experimental.compilation_cache, keyed by
-    # (lowered-StableHLO fingerprint, mesh topology, lowering-relevant
-    # FLAGS_tpu_* set, jax/backend version). A restarted (or elastic
-    # N') cohort then resumes in seconds instead of re-paying the full
-    # compile, and every fresh compile lands a `compile_cache`
-    # hit/miss telemetry event. "" (default) disables the persistent
-    # tier entirely — byte-identical behavior to a cache-less build.
-    "FLAGS_tpu_compile_cache_dir": "",
     # After the first data-parallel step of a program, pre-compile this
     # many likely elastic N' mesh variants in a background thread
     # (Executor.warmup machinery over parallel.env.
     # elastic_mesh_variants) so a future shrink's recompile is already
-    # in the persistent cache before the failure happens. Requires
-    # FLAGS_tpu_compile_cache_dir; 0 (default) = off.
+    # in the persistent cache before the failure happens. Requires the
+    # persistent tier (fluid/compile_cache: JAX_COMPILATION_CACHE_DIR
+    # in the environment); 0 (default) = off.
     "FLAGS_tpu_warmup_elastic_variants": 0,
     # Mixed-precision override for mixed_precision.decorate()'d
     # programs: "" follows the decorate(amp_level=...) argument;

@@ -2,7 +2,7 @@
 
 Two uses:
   - direct subprocess (warm-restart proof): the parent sets
-    FLAGS_tpu_compile_cache_dir (+ FLAGS_tpu_telemetry_dir) in the env
+    JAX_COMPILATION_CACHE_DIR (+ FLAGS_tpu_telemetry_dir) in the env
     and runs this twice — the second process must classify every fresh
     compile as a persistent-cache HIT and produce bit-identical
     losses;
@@ -11,7 +11,7 @@ Two uses:
     attempt 0 exits 7 after its steps (the lost machine) and the
     survivor sleeps until the fail-fast teardown, so the supervisor
     shrinks the world and the respawned attempt-1 cohort re-compiles
-    THROUGH the supervisor-exported <log_dir>/compile_cache.
+    THROUGH the directory the supervisor exports.
 
 argv: [<steps>] ["elastic"]. Prints one line:
 RESULT {"losses": [...17-digit strs...], "hits": N, "misses": N, ...}

@@ -104,12 +104,10 @@ def test_tpu_place_out_of_range_raises(index):
 @pytest.fixture
 def cc():
     from paddle_tpu.fluid import compile_cache
-    from paddle_tpu.utils.flags import get_flag, set_flags
 
-    old = get_flag("FLAGS_tpu_compile_cache_dir")
+    compile_cache._reset_for_tests()
     yield compile_cache
     compile_cache.disable()
-    set_flags({"FLAGS_tpu_compile_cache_dir": old})
     compile_cache._reset_for_tests()
 
 
@@ -117,28 +115,46 @@ def test_cache_dir_is_the_env_var_and_nothing_else(cc, tmp_path,
                                                    monkeypatch):
     import jax
 
-    from paddle_tpu.utils.flags import set_flags
-
     want = str(tmp_path / "from_env")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
-    set_flags({"FLAGS_tpu_compile_cache_dir": str(tmp_path / "flag")})
+    monkeypatch.setattr(cc, "default_dir",
+                        lambda: str(tmp_path / "default"))
     assert cc.cache_dir() == want
     assert cc.use_default_dir() == want
     assert jax.config.jax_compilation_cache_dir == want
     assert os.path.isdir(os.path.join(want, "index"))   # the index too
-    assert not os.path.exists(str(tmp_path / "flag"))
+    assert not os.path.exists(str(tmp_path / "default"))
 
 
-def test_cache_dir_unset_is_checkout_jax_cache(cc, monkeypatch):
+def test_cache_dir_unset_is_checkout_jax_cache(cc, tmp_path, monkeypatch):
+    """Unset: the library stays inert, an entry point gets the fixed
+    `<checkout>/.jax_cache`, and the environment is left as it was (a
+    value put there would outlive the call and re-place every later
+    cache of the process)."""
     import jax
 
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     assert cc.default_dir() == os.path.join(_REPO, ".jax_cache")
-    assert cc.cache_dir() is None          # the library stays inert
-    assert cc.use_default_dir() == os.path.join(_REPO, ".jax_cache")
-    assert jax.config.jax_compilation_cache_dir == \
-        os.path.join(_REPO, ".jax_cache")
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")  # use_default set it
+    assert cc.cache_dir() is None
+    # the rule, not the checkout: this test writes nothing into it
+    stand_in = str(tmp_path / ".jax_cache")
+    monkeypatch.setattr(cc, "default_dir", lambda: stand_in)
+    assert cc.use_default_dir() == stand_in
+    assert cc.cache_dir() == stand_in
+    assert jax.config.jax_compilation_cache_dir == stand_in
+    assert os.path.isdir(os.path.join(stand_in, "index"))
+    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
+
+
+def test_no_test_child_caches_in_the_checkout(tmp_path):
+    """Every child a test starts gets a cache directory of its own (the
+    launcher would otherwise export `<checkout>/.jax_cache`, and cold
+    compiles would depend on what an earlier run left there)."""
+    a, b = (cpu_child_env()["JAX_COMPILATION_CACHE_DIR"] for _ in "ab")
+    assert a != b and os.path.isdir(a) and os.path.isdir(b)
+    assert not a.startswith(os.path.join(_REPO, ".jax_cache"))
+    placed = cpu_child_env({"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+    assert placed["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path)
 
 
 def test_launcher_passes_the_env_var_through(monkeypatch, tmp_path):
@@ -253,10 +269,53 @@ def test_smoke_data_parallel_phase(smoke):
     got = smoke.phase_bert_data_parallel(batch=16, seq_len=32,
                                          cfg=_tiny_bert, steps=4, ndev=8)
     assert got["mesh"] == {"dp": 8} and got["per_device_batch"] == 2
-    assert got["max_rel_loss_diff"] <= got["tolerance"]
+    tol = got["tolerances"]
+    assert max(got["loss_rel_diff"]) <= tol["loss_rel"]
+    for run in ("after_first_update", "control_rows_permuted_one_chip"):
+        assert got[run]["moment1_rel_l2"] <= tol["moment1_rel_l2"]
+        assert got[run]["master_max_abs_diff_in_lr"] <= \
+            tol["master_abs_in_lr"]
     assert got["sharded_state_arrays"] > 0
     hlo = got["collectives_in_hlo"]
     assert hlo["all-reduce"] + hlo["reduce-scatter"] > 0
+
+
+@pytest.mark.parametrize("fault, match", [
+    (None, None),
+    ("sum_for_mean", "averaged gradients"),
+    ("replica_lost_from_the_sum", "averaged gradients"),
+    ("wrong_step_size", "two learning rates"),
+])
+def test_smoke_update_agreement_catches(smoke, fault, match):
+    """The data-parallel phase's check of the first update refuses what
+    a loss comparison lets through: Adam's step does not depend on the
+    gradient's scale."""
+    import numpy as np
+
+    r = np.random.RandomState(0)
+    grads = [r.randn(4, 64).astype("float32") for _ in range(3)]
+    lr = 1e-4
+    want = {"moment1": {"m%d" % i: 0.1 * g.mean(0)
+                        for i, g in enumerate(grads)},
+            "master": {"w%d" % i: -lr * np.sign(g.mean(0))
+                       for i, g in enumerate(grads)}}
+    scale = 4.0 if fault == "sum_for_mean" else 1.0
+    kept = 3 if fault == "replica_lost_from_the_sum" else 4
+    step = 3.2 * lr if fault == "wrong_step_size" else lr
+
+    class Scope:
+        def find_var(self, name):
+            g = grads[int(name[1:])]
+            if name[0] == "m":
+                return 0.1 * scale * g[:kept].sum(0) / 4   # share lost
+            return -step * np.sign(g.mean(0))
+
+    if fault is None:
+        got = smoke._update_agreement(want, Scope(), lr, smoke.DP_TOL)
+        assert got["moment1_rel_l2"] == 0.0
+        return
+    with pytest.raises(AssertionError, match=match):
+        smoke._update_agreement(want, Scope(), lr, smoke.DP_TOL)
 
 
 def test_smoke_fails_whole_when_a_phase_raises(smoke, monkeypatch, capsys):

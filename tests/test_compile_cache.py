@@ -30,17 +30,18 @@ def _base_env(**extra):
 
 
 @pytest.fixture
-def cc_env(tmp_path):
+def cc_env(tmp_path, monkeypatch):
     """Arm the persistent tier at a tmp dir for one test; restore the
-    flag, jax config, module stats and registry afterwards."""
+    environment, the size flag, jax config, module stats and registry
+    afterwards."""
     from paddle_tpu import observability as obs
     from paddle_tpu.fluid import compile_cache as cc
     from paddle_tpu.utils.flags import get_flag, set_flags
 
-    old = {k: get_flag(k) for k in ("FLAGS_tpu_compile_cache_dir",
-                                    "FLAGS_tpu_compile_cache_size")}
+    old = {"FLAGS_tpu_compile_cache_size":
+           get_flag("FLAGS_tpu_compile_cache_size")}
     cdir = str(tmp_path / "cache")
-    set_flags({"FLAGS_tpu_compile_cache_dir": cdir})
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cdir)
     cc._reset_for_tests()
     obs.reset_registry()
     from paddle_tpu.observability import flight
@@ -98,7 +99,7 @@ def test_warm_restart_second_process_hits_bit_identical(tmp_path):
         tdir = str(tmp_path / ("telemetry%d" % i))
         proc = _sp.run(
             [_sys.executable, _RUNNER, "3"],
-            env=_base_env(FLAGS_tpu_compile_cache_dir=cache,
+            env=_base_env(JAX_COMPILATION_CACHE_DIR=cache,
                           FLAGS_tpu_telemetry_dir=tdir),
             cwd=_REPO, stdout=_sp.PIPE, stderr=_sp.STDOUT, text=True,
             timeout=240)
@@ -433,7 +434,7 @@ def test_compile_cache_bench_block_registry_assembled(cc_env):
 
 
 def test_disabled_tier_emits_nothing():
-    """FLAGS_tpu_compile_cache_dir unset (the default): no events, no
+    """No directory named (the library default): no events, no
     classification, entries carry no fingerprint — byte-identical to
     the pre-cache executor."""
     import paddle_tpu.fluid as fluid
@@ -465,7 +466,6 @@ def test_supervised_elastic_shrink_warm_restart_splits_recovery(
     log_dir = str(tmp_path / "logs")
     ccdir = str(tmp_path / "placed_from_outside")
     env = _base_env(JAX_COMPILATION_CACHE_DIR=ccdir)
-    env.pop("FLAGS_tpu_compile_cache_dir", None)
     env.pop("FLAGS_tpu_telemetry_dir", None)
     proc = _sp.run(
         [_sys.executable, "-m", "paddle_tpu.distributed.launch",

@@ -65,6 +65,7 @@ def test_memory_optimize_release_memory_warn_noop():
 
 def test_parallel_executor_compat_runs():
     main, startup = framework.Program(), framework.Program()
+    main.random_seed = startup.random_seed = 11
     with framework.program_guard(main, startup):
         with fluid.unique_name.guard():
             x = fluid.layers.data(name="x", shape=[4], dtype="float32")
@@ -89,9 +90,10 @@ def test_parallel_executor_compat_runs():
         l0 = pe.run([loss.name], feed=feed)[0]
         # deprecated feed_dict alias + legacy positional fetch_list
         l1 = pe.run(fetch_list=[loss.name], feed_dict=feed)[0]
-        assert np.isfinite(float(np.asarray(l0).reshape(-1)[0]))
-        assert float(np.asarray(l1).reshape(-1)[0]) <= \
-            float(np.asarray(l0).reshape(-1)[0]) + 1e-6
+        # one loss per replica, each over its share of the batch: the
+        # step lowers their mean, not every replica's own
+        assert np.isfinite(np.asarray(l0)).all()
+        assert float(np.mean(l1)) <= float(np.mean(l0)) + 1e-6
         pe.drop_local_exe_scopes()  # API-compat no-op
         assert pe.device_count >= 1
 

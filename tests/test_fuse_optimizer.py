@@ -1,8 +1,9 @@
-"""fuse_optimizer_ops — the reference's fuse_optimizer_ops_pass family
-(framework/ir/fuse_optimizer_ops_pass/) as a program rewrite: N
-same-configured sgd/momentum/adam ops collapse into one fused_* op over
-the coalesced group. Losses must match the unfused program exactly
-step-for-step."""
+"""`BuildStrategy.fuse_all_optimizer_ops` — the reference's
+fuse_optimizer_ops_pass family (framework/ir/fuse_optimizer_ops_pass/).
+Here the knob is accepted and changes nothing: XLA fuses the
+per-parameter update loops itself, and the coalesced flat update vector
+the reference builds is what the TPU compiler refused for a 133 M
+parameter model. The program keeps its N optimizer ops and its losses."""
 import numpy as np
 import pytest
 
@@ -38,150 +39,54 @@ def _fresh():
     scope_mod._global_scope = scope_mod.Scope()
 
 
-def _run_steps(loss, steps=5):
+def _batch():
+    r = np.random.RandomState(0)
+    return {"x": r.randn(16, 16).astype("float32"),
+            "y": r.randint(0, 4, (16, 1)).astype("int64")}
+
+
+def _run_steps(loss, program=None, steps=5):
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(framework.default_startup_program())
-    r = np.random.RandomState(0)
-    xs = r.randn(16, 16).astype("float32")
-    ys = r.randint(0, 4, (16, 1)).astype("int64")
     return [float(np.asarray(exe.run(
-        feed={"x": xs, "y": ys}, fetch_list=[loss])[0]).ravel()[0])
+        program, feed=_batch(), fetch_list=[loss])[0]).mean())
         for _ in range(steps)]
 
 
+def _with_knob(prog, **kw):
+    bs = fluid.BuildStrategy()
+    bs.fuse_all_optimizer_ops = True
+    return fluid.CompiledProgram(prog, build_strategy=bs, **kw)
+
+
 @pytest.mark.parametrize("opt_name", ["sgd", "momentum", "adam"])
-def test_fused_matches_unfused(opt_name):
+def test_knob_keeps_the_ops_and_the_losses(opt_name):
+    with framework.unique_name_guard():
+        base = _run_steps(_build(opt_name))
+
+    _fresh()
     with framework.unique_name_guard():
         loss = _build(opt_name)
-        base = _run_steps(loss)
+        prog = framework.default_main_program()
+        ops_before = [op.type for op in prog.global_block().ops]
+        got = _run_steps(loss, _with_knob(prog))
+        assert [op.type for op in prog.global_block().ops] == ops_before
+        assert ops_before.count(opt_name) > 1
+    assert got == base
 
+
+def test_knob_under_data_parallel_matches_single():
+    """The knob x with_data_parallel: losses match the single-device
+    run."""
     _fresh()
     with framework.unique_name_guard():
-        loss2 = _build(opt_name)
-        prog = framework.default_main_program()
-        n_before = len(prog.global_block().ops)
-        fused = fluid.fuse_optimizer_ops(prog)
-        n_after = len(prog.global_block().ops)
-        assert fused > 0, "nothing fused"
-        assert n_after == n_before - fused
-        assert any(op.type == "fused_" + opt_name
-                   for op in prog.global_block().ops)
-        got = _run_steps(loss2)
-    np.testing.assert_allclose(got, base, rtol=1e-6, atol=1e-7)
-
-
-def test_clone_for_test_drops_fused_ops():
-    """clone(for_test=True) must prune fused_* updates like the plain
-    optimizer ops — otherwise the inference clone reads @GRAD vars that
-    are never produced."""
-    _fresh()
-    with framework.unique_name_guard():
-        loss = _build("momentum")
-        prog = framework.default_main_program()
-        assert fluid.fuse_optimizer_ops(prog) > 0
-        test_p = prog.clone(for_test=True)
-        assert not any(op.type.startswith("fused_")
-                       for op in test_p.global_block().ops)
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(framework.default_startup_program())
-        r = np.random.RandomState(0)
-        out = exe.run(test_p,
-                      feed={"x": r.randn(8, 16).astype("float32"),
-                            "y": r.randint(0, 4, (8, 1)).astype(
-                                "int64")},
-                      fetch_list=[loss])
-        assert np.isfinite(np.asarray(out[0])).all()
-
-
-def test_interleaved_grad_write_blocks_fusion():
-    """An op that rewrites a member's Grad between two group members
-    makes the group unfusable: the fused op planted at the last
-    position would read the mutated grad."""
-    from paddle_tpu.fluid.fuse_optimizer import fuse_optimizer_ops
-
-    _fresh()
-    with framework.unique_name_guard():
-        loss = _build("sgd")
-        prog = framework.default_main_program()
-        block = prog.global_block()
-        sgd_idxs = [i for i, op in enumerate(block.ops)
-                    if op.type == "sgd"]
-        assert len(sgd_idxs) >= 2
-        # mutate the FIRST sgd's grad between the first and last member
-        g_name = block.ops[sgd_idxs[0]].input_names["Grad"][0]
-        g_var = block._find_var_recursive(g_name)
-        from paddle_tpu.fluid.framework import Operator
-
-        scale_op = Operator(block, "scale", inputs={"X": [g_var]},
-                            outputs={"Out": [g_var]},
-                            attrs={"scale": 2.0, "bias": 0.0,
-                                   "bias_after_scale": True})
-        block.ops.insert(sgd_idxs[0] + 1, scale_op)
-        assert fuse_optimizer_ops(prog) == 0
-        assert not any(op.type.startswith("fused_") for op in block.ops)
-
-
-def test_fuse_is_idempotent():
-    _fresh()
-    with framework.unique_name_guard():
-        _build("momentum")
-        prog = framework.default_main_program()
-        assert fluid.fuse_optimizer_ops(prog) > 0
-        assert fluid.fuse_optimizer_ops(prog) == 0
-
-
-def test_fused_under_data_parallel_matches_single():
-    """fuse_all_optimizer_ops x with_data_parallel: the implicit grad
-    pmean runs before the fused update reads the grads — losses match
-    the single-device fused run exactly."""
-    r = np.random.RandomState(1)
-    xs = r.randn(16, 16).astype("float32")
-    ys = r.randint(0, 4, (16, 1)).astype("int64")
+        base = _run_steps(_build("momentum"), steps=4)
 
     _fresh()
     with framework.unique_name_guard():
         loss = _build("momentum")
-        prog = framework.default_main_program()
-        fluid.fuse_optimizer_ops(prog)
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(framework.default_startup_program())
-        base = [float(np.asarray(exe.run(
-            feed={"x": xs, "y": ys}, fetch_list=[loss])[0]).ravel()[0])
-            for _ in range(4)]
-
-    _fresh()
-    with framework.unique_name_guard():
-        loss2 = _build("momentum")
-        prog2 = framework.default_main_program()
-        bs = fluid.BuildStrategy()
-        bs.fuse_all_optimizer_ops = True
-        compiled = fluid.CompiledProgram(
-            prog2, build_strategy=bs).with_data_parallel(
-                loss_name=loss2.name)
-        exe2 = fluid.Executor(fluid.CPUPlace())
-        exe2.run(framework.default_startup_program())
-        dp = [float(np.asarray(exe2.run(
-            compiled, feed={"x": xs, "y": ys},
-            fetch_list=[loss2])[0]).mean()) for _ in range(4)]
+        compiled = _with_knob(
+            framework.default_main_program()).with_data_parallel(
+                loss_name=loss.name)
+        dp = _run_steps(loss, compiled, steps=4)
     np.testing.assert_allclose(base, dp, rtol=2e-4, atol=1e-5)
-
-
-def test_build_strategy_drives_fusion():
-    _fresh()
-    with framework.unique_name_guard():
-        loss = _build("momentum")
-        prog = framework.default_main_program()
-        bs = fluid.BuildStrategy()
-        bs.fuse_all_optimizer_ops = True
-        compiled = fluid.CompiledProgram(prog, build_strategy=bs)
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(framework.default_startup_program())
-        r = np.random.RandomState(0)
-        out = exe.run(compiled,
-                      feed={"x": r.randn(8, 16).astype("float32"),
-                            "y": r.randint(0, 4, (8, 1)).astype(
-                                "int64")},
-                      fetch_list=[loss])
-        assert np.isfinite(np.asarray(out[0])).all()
-        assert any(op.type == "fused_momentum"
-                   for op in prog.global_block().ops)

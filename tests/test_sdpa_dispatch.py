@@ -61,3 +61,51 @@ def test_eval_routes_to_flash_without_dropout(monkeypatch):
 def test_short_seq_stays_off_flash(monkeypatch):
     calls, _ = _run_sdpa(monkeypatch, seq=128, p_drop=0.1, min_seq=256)
     assert not calls  # below the measured crossover: XLA path
+
+
+def test_flash_with_dropout_trains_inside_a_rematted_scan(monkeypatch):
+    """The long-context train step: scan over layers with per-layer
+    remat, dropout on, sequence at the flash threshold — the REAL kernel
+    (under the interpreter), not a stub. A dropout seed that the
+    kernel's custom_vjp closes over is a tracer that escapes the remat
+    trace; this path first ran when the chip did."""
+    import importlib
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import bench
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.core.scope import Scope
+    from paddle_tpu.models import bert
+    from paddle_tpu.utils.flags import get_flag, set_flags
+
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    calls = []
+    real_fwd = fa._fwd_call
+    monkeypatch.setattr(fa, "_fwd_call", lambda *a, **kw: (
+        calls.append(1), real_fwd(*a, **kw))[1])
+    monkeypatch.setattr(fa, "_interpret_default", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    old = get_flag("FLAGS_flash_attention_min_seq")
+    set_flags({"FLAGS_flash_attention_min_seq": 128})
+    try:
+        cfg = bert.BertConfig(vocab_size=256, hidden_size=32,
+                              num_hidden_layers=2, num_attention_heads=2,
+                              intermediate_size=64,
+                              max_position_embeddings=128)
+        main_p, startup_p, total, cfg = bench.build_bert_train_program(
+            128, cfg)
+        assert cfg.attention_probs_dropout_prob > 0
+        scope = Scope()
+        exe = fluid.Executor(fluid.TPUPlace(0))
+        exe.run(startup_p, scope=scope)
+        feed = bench.bert_feed(cfg, 2, 128)
+        losses = [float(np.asarray(exe.run(
+            main_p, feed=feed, fetch_list=[total], scope=scope)[0]).mean())
+            for _ in range(2)]
+    finally:
+        set_flags({"FLAGS_flash_attention_min_seq": old})
+    assert calls, "the train step did not dispatch to the flash kernel"
+    assert np.all(np.isfinite(losses))

@@ -283,15 +283,13 @@ def test_close_cancels_everything():
 
 # -- AOT warmup through the persistent compile cache ------------------------
 
-def test_warmup_all_hit_on_restart(tmp_path):
+def test_warmup_all_hit_on_restart(tmp_path, monkeypatch):
     """Cold engine warmup: every bucket a classified MISS; a second
     engine (the restarted serving process) warms ALL-HIT from the
     fingerprint index — with serving_decode/serving_prefill sources."""
     from paddle_tpu.fluid import compile_cache as cc
-    from paddle_tpu.utils.flags import get_flag, set_flags
 
-    old = get_flag("FLAGS_tpu_compile_cache_dir")
-    set_flags({"FLAGS_tpu_compile_cache_dir": str(tmp_path / "cc")})
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
     cc._reset_for_tests()
     try:
         model = serving.TinyDecoderLM(serving.TinyLMConfig(
@@ -311,7 +309,6 @@ def test_warmup_all_hit_on_restart(tmp_path):
             2 * len(cold["buckets"])
     finally:
         cc.disable()
-        set_flags({"FLAGS_tpu_compile_cache_dir": old})
         cc._reset_for_tests()
 
 
@@ -388,21 +385,12 @@ def test_bench_serving_leg_inprocess():
     """bench.py's --serving leg returns the registry-assembled block
     and a tokens/sec headline (run in-process, tiny trace; the command
     line itself refuses a backend that is not tpu)."""
-    from paddle_tpu.fluid import compile_cache as cc
-    from paddle_tpu.utils.flags import get_flag, set_flags
-
     sys.path.insert(0, _REPO)
     try:
         import bench
     finally:
         sys.path.pop(0)
-    old = get_flag("FLAGS_tpu_compile_cache_dir")
-    try:
-        out = bench._bench_serving(n_requests=4, seed=1)
-    finally:
-        cc.disable()
-        set_flags({"FLAGS_tpu_compile_cache_dir": old})
-        cc._reset_for_tests()
+    out = bench._bench_serving(n_requests=4, seed=1)
     assert out["metric"] == "serving_tokens_per_sec"
     assert out["value"] > 0
     assert out["serving"]["requests_submitted"] == 4

@@ -55,7 +55,7 @@ def _batch():
             r.randint(0, 4, (64, 1)).astype("int64"))
 
 
-def _train(opt_fn, flag, ndev=8, clip=False, reg=False, fuse=False,
+def _train(opt_fn, flag, ndev=8, clip=False, reg=False,
            steps=8, want_plan=True):
     """Losses of `steps` steps of the MLP under with_data_parallel on an
     ndev-device mesh; returns (losses, executor, program, plan)."""
@@ -77,10 +77,6 @@ def _train(opt_fn, flag, ndev=8, clip=False, reg=False, fuse=False,
         opt_fn(**kwargs).minimize(loss)
         fluid.clip._clip_attr.clear()
         prog = fluid.default_main_program()
-        if fuse:
-            from paddle_tpu.fluid.fuse_optimizer import fuse_optimizer_ops
-
-            assert fuse_optimizer_ops(prog) > 0
         fluid.CompiledProgram(prog).with_data_parallel(
             loss_name=loss.name)
         if ndev != 8:
@@ -106,9 +102,9 @@ O = fluid.optimizer
 @pytest.mark.parametrize("name,opt_fn,kw,exact", [
     ("adam_clip", lambda **k: O.AdamOptimizer(learning_rate=0.01, **k),
      dict(clip=True), True),
-    ("adam_reg_fused",
+    ("adam_reg",
      lambda **k: O.AdamOptimizer(learning_rate=0.01, **k),
-     dict(reg=True, fuse=True), True),
+     dict(reg=True), True),
     ("momentum_4dev",
      lambda **k: O.MomentumOptimizer(learning_rate=0.1, momentum=0.9,
                                      **k), dict(ndev=4), True),
@@ -119,8 +115,8 @@ O = fluid.optimizer
      dict(ndev=4, clip=True), False),
 ])
 def test_sharded_vs_replicated_parity(name, opt_fn, kw, exact):
-    """Sharded == replicated for Adam (+global-norm clip, +L2 reg,
-    +fused groups), Momentum, SGD and LAMB (trust-ratio psum) across
+    """Sharded == replicated for Adam (+global-norm clip, +L2 reg),
+    Momentum, SGD and LAMB (trust-ratio psum) across
     2/4/8-device meshes with an uneven (31-wide) parameter. SGD/
     Momentum/Adam are bit-identical; LAMB's psum'd norms match within
     fp32 reduction-order tolerance."""

@@ -57,6 +57,10 @@ def test_static_rnn_matches_numpy(rng):
 
 def test_static_rnn_trains(rng):
     T, B, D = 3, 4, 5
+    # seeded: an unseeded program draws its init from numpy's global
+    # stream, and eight Adam steps at 0.05 do not fall from every init
+    fluid.default_main_program().random_seed = 11
+    fluid.default_startup_program().random_seed = 11
     x = fluid.layers.data(name="x", shape=[B, D],
                           append_batch_size=False, dtype="float32")
     x.shape = (T, B, D)

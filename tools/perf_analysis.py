@@ -1263,8 +1263,10 @@ def compile_cache_report(telemetry_dir=None, log_dir=None,
     if cache_dir is None:
         # where the launcher keeps it: the environment's directory,
         # else the fixed <checkout>/.jax_cache
+        from paddle_tpu.fluid import compile_cache as _cc
+
         cand = os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
-            os.path.join(_REPO, ".jax_cache")
+            _cc.default_dir()
         cache_dir = cand if os.path.isdir(cand) else None
     if not telemetry_dir or not os.path.isdir(telemetry_dir):
         print("no telemetry dir at %r" % telemetry_dir)
