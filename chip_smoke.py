@@ -63,10 +63,8 @@ def _process_peak_hbm_gb(devices):
 def _step_memory_gb(exe, program, feed, loss, scope):
     """What the compiled step itself needs on one device, from the
     compiler's `memory_analysis()` of the executable that ran."""
-    entry, lowered, smut = exe._cached_lowerable(program, feed, [loss],
-                                                 scope)[:3]
-    ma = exe._aot_compile(entry, lowered, smut).memory_analysis()
-    return {k: round(getattr(ma, k + "_size_in_bytes") / 1e9, 3)
+    step = exe.step_memory(program, feed, [loss], scope)
+    return {k: round(step[k] / 1e9, 3)
             for k in ("argument", "output", "alias", "temp")}
 
 

@@ -58,10 +58,8 @@ class LazyFetch:
     def numpy(self):
         from . import profiler as _prof
 
-        t0 = _time.perf_counter()
-        out = Executor._fetch_to_numpy(self._v)
-        _prof.record_step_phase("sync", _time.perf_counter() - t0, t0)
-        return out
+        with _prof.span("exe.sync"):
+            return Executor._fetch_to_numpy(self._v)
 
     def __array__(self, dtype=None, copy=None):
         a = self.numpy()
@@ -92,7 +90,11 @@ class Executor:
         """One step. Per-step wall time is split into the profiler's
         step phases (feed / dispatch / sync / host, plus compile on a
         cache miss) so infeed/compute overlap is measurable — see
-        fluid/profiler.py step_phase_summary."""
+        fluid/profiler.py step_phase_summary. Each phase is a
+        `profiler.span` (`exe.feed`, `exe.bind`, `exe.compile`,
+        `exe.dispatch`, `exe.writeback`, `exe.sync`) nested under one
+        `exe.step`, so a jax profile shows them beside the device's
+        line."""
         from . import profiler as _prof
         from .. import observability as _obs
 
@@ -100,23 +102,21 @@ class Executor:
         # (FLAGS_tpu_hang_timeout_s); a bare global check when off
         _obs.on_step_begin()
         t_step = _time.perf_counter()
+        # this step's own seconds in the phases `host` is the residual
+        # of; the spans add to it and to the process-wide counters
         ph = {"feed": 0.0, "dispatch": 0.0, "sync": 0.0, "compile": 0.0}
         comm0 = _prof.step_phase_total("comm")
         lanes0 = {ln: _prof.step_phase_total(ln)
                   for ln in ("comm_ici", "comm_dcn", "comm_mp")}
         try:
-            return self._run_impl(program, feed, fetch_list, scope,
-                                  return_numpy, use_program_cache, ph)
+            with _prof.step_span("exe.step"):
+                return self._run_impl(program, feed, fetch_list, scope,
+                                      return_numpy, use_program_cache, ph)
         finally:
             total = _time.perf_counter() - t_step
             if ph["dispatch"] > 0.0:
                 # a run that failed before dispatching is not a step:
-                # recording it would inflate the summary's per-step
-                # denominator and skew every average
-                for name in ("feed", "dispatch", "sync"):
-                    _prof.record_step_phase(name, ph[name])
-                if ph["compile"]:
-                    _prof.record_step_phase("compile", ph["compile"])
+                # it gets no `host` residual and no telemetry record.
                 # host-collective time recorded DURING this step (PS
                 # barriers, cross-rank agreement) already counted
                 # itself into the comm phase — keep host disjoint
@@ -151,14 +151,7 @@ class Executor:
 
     def _run_impl(self, program, feed, fetch_list, scope, return_numpy,
                   use_program_cache, ph):
-        from . import profiler as _prof
-
-        def _mark(name, t0):
-            # accumulate into this step's phase AND emit the live
-            # chrome-trace span at its real start time
-            d = _time.perf_counter() - t0
-            ph[name] += d
-            _prof.record_step_trace(name, t0, d)
+        from .profiler import span
 
         program = program or framework.default_main_program()
         # CompiledProgram front (compiler.py) wraps a Program
@@ -224,21 +217,159 @@ class Executor:
             self._elastic_resume(program, ecfg, scope)
 
         block = program.global_block()
-        _t = _time.perf_counter()
-        feed_arrays = self._prepare_feed(block, feed)
-        _mark("feed", _t)
+        with span("exe.feed", ph):
+            feed_arrays = self._prepare_feed(block, feed)
         if ps_cfg is not None and ps_cfg.get("sparse_tables"):
             # distributed_lookup_table: fetch this batch's unique rows
             # into the @PREFETCH/@REMAP feeds before compiling/running
             comm = self._ps_communicator(program, ps_cfg, scope)
             comm.prefetch(feed_arrays, scope)
 
-        key = self._cache_key(program, feed_arrays, fetch_names, scope)
+        with span("exe.bind"):
+            key = self._cache_key(program, feed_arrays, fetch_names, scope)
+            entry, tail_n, feed_arrays = self._lookup_entry(
+                program, feed_arrays, fetch_names, scope, key,
+                use_program_cache)
+        fresh_compile = entry is None
+        if fresh_compile:
+            with span("exe.compile", ph):
+                entry = self._compile_and_cache(
+                    program, block, feed_arrays, fetch_names, scope, key,
+                    use_program_cache)
+        with span("exe.bind"):
+            states_mut, states_ro = self._bind_state(entry, scope)
+        self._check_sparse_ids(program, feed_arrays)
+        if fresh_compile:
+            # OOM pre-flight (FLAGS_tpu_hbm_budget_mb, off by default):
+            # reject a program whose modeled HBM peak exceeds the
+            # budget BEFORE the first dispatch, naming the consumers.
+            # A failed gate EVICTS the just-cached entry — same
+            # invariant as the post-compile static checks: a caught-
+            # and-retried run must re-enter the gate, not cache-hit
+            # past it and dispatch the known-over-budget program
+            try:
+                self._hbm_preflight(program, entry, feed_arrays,
+                                    states_mut, states_ro, scope)
+            except Exception:
+                self._cache.pop(key, None)
+                raise
+        if fresh_compile:
+            # persistent compile-cache tier
+            # (JAX_COMPILATION_CACHE_DIR): fingerprint the lowered
+            # StableHLO at the exact avals the dispatch below will use
+            # and look up the cross-process index — the lowering also
+            # warms jax's trace cache, so the first dispatch re-pays
+            # (at most) the backend compile the persistent tier
+            # eliminates. No-op when the tier is off.
+            with span("exe.compile", ph):
+                self._cc_classify(entry, feed_arrays, states_mut,
+                                  states_ro)
+        seed = framework._global_seed_and_bump(program)
+        with span("exe.feed", ph):
+            feeds_dev = self._shard_feeds(entry, feed_arrays)
+        cc_snap = None
+        if fresh_compile and entry.cc_fingerprint is not None:
+            from . import compile_cache as _cc
+
+            cc_snap = (_cc.jax_stats(), _time.time())
+        try:
+            with span("exe.dispatch", ph, fresh=int(fresh_compile)):
+                fetches, new_states = entry.jitted(
+                    feeds_dev, states_mut, states_ro,
+                    np.uint32(seed % (2**31)))
+        except Exception as e:
+            from ..observability import attribution as _attr
+
+            if _attr.is_resource_exhausted(e):
+                # OOM forensics: land the attributed memory breakdown
+                # in the flight-recorder dump so the postmortem answers
+                # "what was resident" without a repro; the original
+                # error still propagates
+                _attr.record_oom_forensics(
+                    program, block, self._shard_plan_of(program),
+                    self._shard_count(entry), feed_arrays,
+                    list(entry.state_mut_names)
+                    + list(entry.state_ro_names), scope, e)
+            raise
+        if cc_snap is not None:
+            # hit/miss verdict + compile_cache event; the measured
+            # backend-compile seconds move from the dispatch phase into
+            # compile_ms, so a warm restart's first step shows
+            # compile_ms ~ 0 where a cold one shows the full XLA cost
+            self._cc_finish(entry, ph, cc_snap)
+        if fresh_compile:
+            self._maybe_elastic_warmup(program, entry, feed_arrays,
+                                       fetch_names, scope)
+        with span("exe.writeback"):
+            for n, v in new_states.items():
+                scope.set_var(n, v)
+            if tail_n is not None:
+                fetches = self._unreplicate_tail(block, fetch_names,
+                                                 fetches, tail_n)
+        if ecfg is not None:
+            self._elastic_tick(program, ecfg, scope)
+
+        from ..utils.flags import get_flag
+
+        if get_flag("FLAGS_check_nan_inf"):
+            with span("exe.sync", ph):
+                self._check_nan_inf(fetch_names, fetches, new_states)
+        if get_flag("FLAGS_benchmark"):
+            # per-step device sync (reference: operator.cc:997)
+            import jax
+
+            with span("exe.sync", ph):
+                jax.block_until_ready(fetches)
+
+        if ps_cfg is not None:
+            comm = self._ps_communicator(program, ps_cfg, scope)
+            if ps_cfg["mode"] in ("sync", "async", "half_async"):
+                # the communicator pushes THIS step's grads over RPC —
+                # a required host sync, kept on every step
+                with span("exe.sync", ph):
+                    sparse_gvals = {
+                        w: np.asarray(
+                            fetches[fetch_names.index(m["grad"])])
+                        for w, m in ps_cfg.get("sparse_tables",
+                                               {}).items()}
+                    gvals = {}
+                    for g, p in ps_cfg["grad_of"].items():
+                        gvals[p] = np.asarray(
+                            fetches[fetch_names.index(g)])
+                if sparse_gvals:
+                    comm.push_sparse(sparse_gvals)
+                comm.step(gvals, scope)
+            else:
+                comm.step({}, scope)
+            fetches = fetches[:n_user_fetches]
+        if return_numpy:
+            with span("exe.sync", ph):
+                return [self._fetch_to_numpy(v) for v in fetches]
+        return [LazyFetch(v) for v in fetches]
+
+    @staticmethod
+    def _unreplicate_tail(block, fetch_names, fetches, tail_n):
+        """Batch-majored fetches of a bucketed tail cut back to its
+        rows (leading program dim -1 marks the batch axis; fixed-shape
+        fetches pass through)."""
+        sliced = []
+        for fname, v in zip(fetch_names, fetches):
+            fv = block._find_var_recursive(fname)
+            shp = tuple(getattr(fv, "shape", ()) or ()) if fv is not None \
+                else ()
+            if shp[:1] == (-1,) and getattr(v, "ndim", 0) >= 1:
+                v = v[:tail_n]
+            sliced.append(v)
+        return sliced
+
+    def _lookup_entry(self, program, feed_arrays, fetch_names, scope, key,
+                      use_program_cache):
+        """(cached entry or None, rows of a bucketed batch tail or None,
+        the feeds as that entry takes them)."""
         entry = self._cache.get(key) if use_program_cache else None
         if entry is not None:
             self._cache.move_to_end(key)
         tail_n = None
-        fresh_compile = False
         if entry is None and use_program_cache:
             # batch-tail bucketing (SURVEY §7 hard part (d); reference
             # contract executor.cc:184 — any batch size runs without
@@ -261,14 +392,12 @@ class Executor:
                     n: (self._replicate_rows(a, m)
                         if n in rep_names else a)
                     for n, a in feed_arrays.items()}
-        if entry is None:
-            _t = _time.perf_counter()
-            entry = self._compile_and_cache(program, block, feed_arrays,
-                                            fetch_names, scope, key,
-                                            use_program_cache)
-            fresh_compile = True
-            _mark("compile", _t)
+        return entry, tail_n, feed_arrays
 
+    @staticmethod
+    def _bind_state(entry, scope):
+        """(states_mut, states_ro) of one step: the entry's state read
+        from the scope, in the layout the executable was compiled for."""
         states_mut = {n: scope.find_var(n) for n in entry.state_mut_names}
         states_ro = {n: scope.find_var(n) for n in entry.state_ro_names}
         if entry.sharded_state:
@@ -307,126 +436,7 @@ class Executor:
                             v, info, entry.mesh, entry.dp_axis)
                         d[n] = v
                         scope.set_var(n, v)
-        self._check_sparse_ids(program, feed_arrays)
-        if fresh_compile:
-            # OOM pre-flight (FLAGS_tpu_hbm_budget_mb, off by default):
-            # reject a program whose modeled HBM peak exceeds the
-            # budget BEFORE the first dispatch, naming the consumers.
-            # A failed gate EVICTS the just-cached entry — same
-            # invariant as the post-compile static checks: a caught-
-            # and-retried run must re-enter the gate, not cache-hit
-            # past it and dispatch the known-over-budget program
-            try:
-                self._hbm_preflight(program, entry, feed_arrays,
-                                    states_mut, states_ro, scope)
-            except Exception:
-                self._cache.pop(key, None)
-                raise
-        if fresh_compile:
-            # persistent compile-cache tier
-            # (JAX_COMPILATION_CACHE_DIR): fingerprint the lowered
-            # StableHLO at the exact avals the dispatch below will use
-            # and look up the cross-process index — the lowering also
-            # warms jax's trace cache, so the first dispatch re-pays
-            # (at most) the backend compile the persistent tier
-            # eliminates. No-op when the tier is off.
-            _t = _time.perf_counter()
-            self._cc_classify(entry, feed_arrays, states_mut, states_ro)
-            _mark("compile", _t)
-        seed = framework._global_seed_and_bump(program)
-        _t = _time.perf_counter()
-        feeds_dev = self._shard_feeds(entry, feed_arrays)
-        _mark("feed", _t)
-        cc_snap = None
-        if fresh_compile and entry.cc_fingerprint is not None:
-            from . import compile_cache as _cc
-
-            cc_snap = (_cc.jax_stats(), _time.time())
-        _t = _time.perf_counter()
-        try:
-            fetches, new_states = entry.jitted(feeds_dev, states_mut,
-                                               states_ro,
-                                               np.uint32(seed % (2**31)))
-        except Exception as e:
-            from ..observability import attribution as _attr
-
-            if _attr.is_resource_exhausted(e):
-                # OOM forensics: land the attributed memory breakdown
-                # in the flight-recorder dump so the postmortem answers
-                # "what was resident" without a repro; the original
-                # error still propagates
-                _attr.record_oom_forensics(
-                    program, block, self._shard_plan_of(program),
-                    self._shard_count(entry), feed_arrays,
-                    list(entry.state_mut_names)
-                    + list(entry.state_ro_names), scope, e)
-            raise
-        _mark("dispatch", _t)
-        if cc_snap is not None:
-            # hit/miss verdict + compile_cache event; the measured
-            # backend-compile seconds move from the dispatch phase into
-            # compile_ms, so a warm restart's first step shows
-            # compile_ms ~ 0 where a cold one shows the full XLA cost
-            self._cc_finish(entry, ph, cc_snap)
-        if fresh_compile:
-            self._maybe_elastic_warmup(program, entry, feed_arrays,
-                                       fetch_names, scope)
-        for n, v in new_states.items():
-            scope.set_var(n, v)
-        if ecfg is not None:
-            self._elastic_tick(program, ecfg, scope)
-        if tail_n is not None:
-            # un-replicate batch-majored fetches (leading program dim -1
-            # marks the batch axis; fixed-shape fetches pass through)
-            sliced = []
-            for fname, v in zip(fetch_names, fetches):
-                fv = block._find_var_recursive(fname)
-                shp = tuple(getattr(fv, "shape", ()) or ()) if fv is not None \
-                    else ()
-                if shp[:1] == (-1,) and getattr(v, "ndim", 0) >= 1:
-                    v = v[:tail_n]
-                sliced.append(v)
-            fetches = sliced
-
-        from ..utils.flags import get_flag
-
-        if get_flag("FLAGS_check_nan_inf"):
-            _t = _time.perf_counter()
-            self._check_nan_inf(fetch_names, fetches, new_states)
-            _mark("sync", _t)
-        if get_flag("FLAGS_benchmark"):
-            # per-step device sync (reference: operator.cc:997)
-            import jax
-
-            _t = _time.perf_counter()
-            jax.block_until_ready(fetches)
-            _mark("sync", _t)
-
-        if ps_cfg is not None:
-            comm = self._ps_communicator(program, ps_cfg, scope)
-            if ps_cfg["mode"] in ("sync", "async", "half_async"):
-                # the communicator pushes THIS step's grads over RPC —
-                # a required host sync, kept on every step
-                _t = _time.perf_counter()
-                sparse_gvals = {
-                    w: np.asarray(fetches[fetch_names.index(m["grad"])])
-                    for w, m in ps_cfg.get("sparse_tables", {}).items()}
-                gvals = {}
-                for g, p in ps_cfg["grad_of"].items():
-                    gvals[p] = np.asarray(fetches[fetch_names.index(g)])
-                _mark("sync", _t)
-                if sparse_gvals:
-                    comm.push_sparse(sparse_gvals)
-                comm.step(gvals, scope)
-            else:
-                comm.step({}, scope)
-            fetches = fetches[:n_user_fetches]
-        if return_numpy:
-            _t = _time.perf_counter()
-            out = [self._fetch_to_numpy(v) for v in fetches]
-            _mark("sync", _t)
-            return out
-        return [LazyFetch(v) for v in fetches]
+        return states_mut, states_ro
 
     @staticmethod
     def _check_sparse_ids(program, feed_arrays):
@@ -593,16 +603,18 @@ class Executor:
         the `compile_cache` telemetry event, and write the index
         sentinel the next process's classification reads."""
         from . import compile_cache as _cc
+        from . import profiler as _prof
 
         before, t0 = cc_snap
         d = _cc.stats_delta(before)
         comp_s = max(0.0, d["backend_compile_s"])
         if ph is not None and comp_s > 0.0 and ph["dispatch"] > 0.0:
-            moved = min(comp_s, ph["dispatch"])
             # keep dispatch strictly positive: a zeroed dispatch would
-            # drop the whole step from the phase summary
-            ph["dispatch"] = max(ph["dispatch"] - moved, 1e-9)
+            # drop the whole step from the telemetry stream
+            moved = max(0.0, min(comp_s, ph["dispatch"] - 1e-9))
+            ph["dispatch"] -= moved
             ph["compile"] += moved
+            _prof.move_step_phase("dispatch", "compile", moved)
         prev = entry.cc_prev
         hit = prev is not None or d["persistent_hits"] > 0
         saved_ms = max(0.0, d["saved_s"] * 1e3)
@@ -1500,6 +1512,25 @@ class Executor:
         if got is None:
             return None
         return self._donation_report_from(program, *got[:4])
+
+    def step_memory(self, program=None, feed=None, fetch_list=None,
+                    scope=None):
+        """What the compiled step needs on one device, in bytes, by the
+        compiler's `memory_analysis()` of the EXECUTOR path's cached
+        executable (run the program once first so the entry exists):
+        {argument, output, alias, temp, generated_code}. Its peak is
+        argument + output - alias + temp + generated_code; the
+        allocator's `peak_bytes_in_use` counts live arrays and misses a
+        running step's temporaries. None when the entry isn't
+        jit-lowered (eager fallback / unknown program)."""
+        got = self._cached_lowerable(program, feed, fetch_list, scope)
+        if got is None:
+            return None
+        entry, lowered, smut = got[:3]
+        ma = self._aot_compile(entry, lowered, smut).memory_analysis()
+        return {k: int(getattr(ma, k + "_size_in_bytes", 0))
+                for k in ("argument", "output", "alias", "temp",
+                          "generated_code")}
 
     def _donation_report_from(self, program, entry, lowered, smut,
                               favals):

@@ -3,16 +3,21 @@
 
 TPU-native: the device tracer is jax.profiler (XPlane/perfetto, viewable in
 TensorBoard or chrome://tracing); the `profiler(state, tracer_option,
-profile_path)` context-manager API is preserved. RecordEvent maps to
-jax.profiler.TraceAnnotation.
+profile_path)` context-manager API is preserved. `span` is the one way
+the program marks its own time (profile host plane + phase counter +
+legacy chrome buffer); RecordEvent is a span over the host-event table.
 """
 from __future__ import annotations
 
 import contextlib
+import itertools
 import os
 import threading
 import time
 from collections import defaultdict
+
+from jax.profiler import StepTraceAnnotation as _StepTraceAnnotation
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 # ONE lock for every counter table below: the counters are mutated from
 # the main step loop AND background threads (the device prefetcher's
@@ -49,12 +54,23 @@ _trace_enabled = False
 # In a well-overlapped pipeline feed+sync+host ≈ 0 at steady state and
 # dispatch-to-dispatch time ≈ device compute time.
 STEP_PHASES = ("feed", "dispatch", "comm", "sync", "host")
+#: breakdowns shown beside the phases and never added to `total_ms`:
+#: the hybrid-mesh lanes of `comm` (host_collectives._comm_phase on a
+#: PADDLE_NUM_PODS / PADDLE_MP_DEGREE launch) and the executor's two
+#: named parts of `host` (`exe.bind`: cache lookup + state read from the
+#: scope; `exe.writeback`: new state written back to it)
+PHASE_BREAKDOWNS = ("comm_ici", "comm_dcn", "comm_mp", "bind", "writeback")
 _step_phases = defaultdict(lambda: [0, 0.0, 0.0])  # -> [count, total_s, max_s]
+# seconds per phase over the life of the process: what
+# `step_phase_summary(reset=True)` clears above stays here, so a
+# caller that resets per window can still ask what set-up compiled
+_phase_lifetime = defaultdict(float)
 
 
 def record_step_phase(name, dt, t0=None):
-    """Accumulate `dt` seconds into step-phase counter `name`; also
-    emits a chrome-trace event ("phase/<name>") when tracing is live.
+    """Accumulate `dt` seconds into step-phase counter `name` (and its
+    lifetime total); with the segment's real start `t0`, also emits a
+    chrome-trace event ("phase/<name>") while `profiler()` is on.
     Thread-safe: callers include the prefetcher's producer thread and
     RPC handler threads, concurrent with the main step loop."""
     with _lock:
@@ -62,18 +78,74 @@ def record_step_phase(name, dt, t0=None):
         ev[0] += 1
         ev[1] += dt
         ev[2] = max(ev[2], dt)
-    record_step_trace(name, t0, dt)
-
-
-def record_step_trace(name, t0, dt):
-    """Trace-only phase event (no counter): the executor calls this at
-    each timed segment with the segment's real start time, so a live
-    trace shows phase/<name> spans where they actually happened; the
-    per-step counter aggregation rides separately in run()'s finally."""
-    if _trace_enabled and t0 is not None:
-        with _lock:
+        _phase_lifetime[name] += dt
+        if _trace_enabled and t0 is not None:
             _trace_events.append(("phase/" + name, t0 * 1e6, dt * 1e6,
                                   threading.get_ident() % 100000))
+
+
+def move_step_phase(src, dst, dt):
+    """Re-attribute `dt` seconds already counted under `src` to `dst`
+    (the executor moves a first dispatch's measured backend compile
+    into `compile`); `src` keeps its count, so `steps` is unchanged."""
+    with _lock:
+        _step_phases[src][1] -= dt
+        _phase_lifetime[src] -= dt
+        ev = _step_phases[dst]
+        ev[0] += 1
+        ev[1] += dt
+        ev[2] = max(ev[2], dt)
+        _phase_lifetime[dst] += dt
+
+
+class span:
+    """One named interval of the program's own time, in three places
+    at once: the jax profile's host plane (a `TraceAnnotation(name,
+    **args)`, on the device trace's clock), the step-phase counter of
+    that name (`exe.feed` feeds `feed`: the part after the last dot)
+    with its lifetime total, and the legacy chrome buffer while
+    `profiler()` is on. `into` is a dict that also gets the seconds
+    under the counter's name (the executor's per-step account).
+
+    With no trace on, a span costs one TraceMe flag test, two clock
+    reads and one locked add. A span whose body raised stays in the
+    profile and out of the counters: a run that failed is not a step."""
+
+    __slots__ = ("name", "_into", "_ann", "_t0")
+
+    def __init__(self, name, into=None, **args):
+        self.name = name
+        self._into = into
+        self._ann = _TraceAnnotation(name, **args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            self._record(dt)
+        return False
+
+    def _record(self, dt):
+        phase = self.name.rpartition(".")[2]
+        record_step_phase(phase, dt, self._t0)
+        if self._into is not None:
+            self._into[phase] = self._into.get(phase, 0.0) + dt
+
+
+_step_nums = itertools.count()
+
+
+def step_span(name):
+    """A `StepTraceAnnotation` for one step of the program: the spans
+    entered inside it nest under it in the profile's host plane, and
+    its `step_num` (counted over the process) is the identifier they
+    share. It feeds no counter."""
+    return _StepTraceAnnotation(name, step_num=next(_step_nums))
 
 
 def step_phase_total(name):
@@ -82,6 +154,14 @@ def step_phase_total(name):
     disjoint from collective time recorded by host_collectives."""
     with _lock:
         return _step_phases[name][1] if name in _step_phases else 0.0
+
+
+def phase_lifetime_s(name):
+    """Seconds counted under one phase since the process started;
+    no reset clears it (`benchmark/readers/compile_total.py` reads
+    `compile` after set-up and the window)."""
+    with _lock:
+        return _phase_lifetime.get(name, 0.0)
 
 
 def reset_step_phases():
@@ -111,10 +191,7 @@ def step_phase_summary(reset=False):
             # they never pollute host_ms, but the summary still shows them
             out["compile_ms"] = round(
                 _step_phases["compile"][1] * 1e3 / denom, 3)
-        for lane in ("comm_ici", "comm_dcn", "comm_mp"):
-            # hybrid-mesh comm lanes (host_collectives._comm_phase on a
-            # PADDLE_NUM_PODS / PADDLE_MP_DEGREE launch): a BREAKDOWN
-            # of comm_ms by interconnect tier, never added to the total
+        for lane in PHASE_BREAKDOWNS:
             if lane in _step_phases:
                 out[lane + "_ms"] = round(
                     _step_phases[lane][1] * 1e3 / denom, 3)
@@ -161,29 +238,19 @@ def _native_trace():
         return None
 
 
-class RecordEvent:
-    """Host-side RAII event (reference: platform/profiler.h:126);
-    also emits a device trace annotation when a jax trace is active."""
+class RecordEvent(span):
+    """Host-side RAII event (reference: platform/profiler.h:126): a
+    `span` that counts into the host-event table (`event_count`,
+    `profiler_summary_rows`) in place of a step phase and, while
+    `profiler()` is on, lands in the native event store."""
+
+    __slots__ = ("_nid",)
 
     def __init__(self, name, event_type=None):
-        self.name = name
-        self._t0 = None
-        self._ann = None
+        super().__init__(name)
         self._nid = None
 
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        try:
-            import jax.profiler
-
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
-        except Exception:
-            self._ann = None
-        return self
-
-    def __exit__(self, *a):
-        dt = time.perf_counter() - self._t0
+    def _record(self, dt):
         with _lock:
             ev = _host_events[self.name]
             ev[0] += 1
@@ -201,8 +268,6 @@ class RecordEvent:
                 with _lock:
                     _trace_events.append((self.name, self._t0 * 1e6,
                                           dt * 1e6, tid))
-        if self._ann is not None:
-            self._ann.__exit__(*a)
 
 
 @contextlib.contextmanager

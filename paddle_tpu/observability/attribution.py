@@ -39,11 +39,16 @@ a microsecond. Three pieces:
    breakdown in the flight-recorder dump (`record_oom_forensics`), so
    the postmortem answers "what was resident" without a repro.
 
-`time_attribution` folds chrome-trace device op durations (the
-`trace.json.gz` inside a PR 7 `capture.py` xplane dir) back through the
-markers to per-op / per-layer / per-bucket time —
+`time_attribution` folds a profile's device time (the `XLA Ops` thread
+of each `/device:TPU:<n>` process in the `trace.json.gz` sidecar that
+`jax.profiler.stop_trace` writes beside the `.xplane.pb`; self times, a
+loop's body counted once) back through the markers and the name stack
+to per-op / per-layer / per-bucket time and to the step's regions:
+forward, recompute, backward, update, collective. The sidecar is the
+file to read because `jax.profiler.ProfileData` hands out an event's
+own stats and not its metadata's, where the scope path lives.
 `perf_analysis.py --stragglers --xplane-dir D` blames a *layer*, not
-just a phase.
+just a phase; the benchmark's `device_*_ms` metrics read the regions.
 """
 from __future__ import annotations
 
@@ -63,7 +68,8 @@ __all__ = [
     "optimizer_state_vars", "classify_state_var", "build_report",
     "cross_check_donation", "static_breakdown", "budget_bytes",
     "HbmBudgetExceeded", "is_resource_exhausted",
-    "record_oom_forensics", "load_trace_events", "time_attribution",
+    "record_oom_forensics", "load_trace_events", "device_op_rows",
+    "region_of", "time_attribution", "REGIONS",
 ]
 
 #: marker grammar: `pp[<field>;<field>;...]` — `;` and `]` never occur
@@ -869,47 +875,185 @@ def _event_paths(ev):
                 yield v
 
 
+def _scope_path(ev) -> str:
+    """An operation's scope path: `args.tf_op` (jax's name stack, where
+    the sidecar has it), else the first of the event's strings that
+    holds a marker, else nothing."""
+    args = ev.get("args")
+    tf_op = args.get("tf_op") if isinstance(args, dict) else None
+    if isinstance(tf_op, str) and tf_op:
+        return tf_op
+    for path in _event_paths(ev):
+        if _MARKER_RE.search(path):
+            return path
+    return ""
+
+
+_DEVICE_PROCESS_RE = re.compile(r"^/device:TPU:(\d+)$")
+#: the thread of a device process with one event per executed
+#: operation, and the one with one event per executed module
+_OPS_THREAD = "XLA Ops"
+_MODULES_THREAD = "XLA Modules"
+
+#: where in the program a device operation ran, by `region_of`
+REGIONS = ("forward", "recompute", "backward", "update", "collective",
+           "unattributed")
+_COLLECTIVE_KINDS = ("bucket", "grad_sync", "gather")
+
+
+def _interval(ev):
+    """(start, duration) of a device event in picoseconds: the device's
+    own integers where the sidecar carries them (they nest exactly),
+    else the chrome microseconds."""
+    args = ev.get("args")
+    if isinstance(args, dict) and "device_offset_ps" in args \
+            and "device_duration_ps" in args:
+        return int(args["device_offset_ps"]), \
+            int(args["device_duration_ps"])
+    return float(ev.get("ts", 0.0)) * 1e6, \
+        float(ev.get("dur", 0.0) or 0.0) * 1e6
+
+
+def device_op_rows(events) -> dict:
+    """The device operations of a profile with their self times:
+    {"devices": n, "steps": executions of the step's module on one
+    device, "rows": [(name, scope path, self microseconds)]}.
+
+    Kept are the events of each `/device:TPU:<n>` process's `XLA Ops`
+    thread that start inside an execution of the step's module (the
+    module of the `XLA Modules` thread with most device time; without
+    that thread every operation is kept and `steps` is 0). A self time
+    is an operation's duration less that of the operations nested
+    directly inside it: the thread nests, a `while` holds every
+    operation of its body, and a plain sum counts a loop's work twice.
+    Host threads and the `Steps`/`XLA Modules` threads are not
+    operations and are never counted."""
+    procs, threads = {}, {}
+    for ev in events:
+        if ev.get("ph") != "M":
+            continue
+        name = (ev.get("args") or {}).get("name")
+        if ev.get("name") == "process_name":
+            procs[ev.get("pid")] = name
+        elif ev.get("name") == "thread_name":
+            threads[(ev.get("pid"), ev.get("tid"))] = name
+    devices = {pid for pid, name in procs.items()
+               if _DEVICE_PROCESS_RE.match(str(name))}
+    ops = {pid: [] for pid in devices}
+    modules = {pid: {} for pid in devices}
+    for ev in events:
+        pid = ev.get("pid")
+        if ev.get("ph") != "X" or pid not in devices:
+            continue
+        thread = threads.get((pid, ev.get("tid")))
+        start, dur = _interval(ev)
+        if dur <= 0:
+            continue
+        if thread == _OPS_THREAD:
+            ops[pid].append((start, dur, ev))
+        elif thread == _MODULES_THREAD:
+            modules[pid].setdefault(str(ev.get("name", "")), []).append(
+                (start, start + dur))
+    rows, steps = [], 0
+    for pid in sorted(devices):
+        runs = None
+        if modules[pid]:
+            runs = sorted(max(modules[pid].values(),
+                              key=lambda r: sum(e - s for s, e in r)))
+            steps += len(runs)
+        stack, r = [], 0   # [index into rows, end] of the open parents
+        for start, dur, ev in sorted(ops[pid],
+                                     key=lambda o: (o[0], -o[1])):
+            if runs is not None:
+                while r < len(runs) and runs[r][1] <= start:
+                    r += 1
+                if r == len(runs) or start < runs[r][0]:
+                    continue
+            while stack and stack[-1][1] <= start:
+                stack.pop()
+            if stack:
+                rows[stack[-1][0]][2] -= dur / 1e6
+            stack.append((len(rows), start + dur))
+            rows.append([str(ev.get("name", "")), _scope_path(ev),
+                         dur / 1e6])
+    n = len(devices)
+    return {"devices": n, "steps": steps // n if n else 0,
+            "rows": [tuple(row) for row in rows]}
+
+
+def region_of(path, prov, differentiated=True) -> str:
+    """The region of the program a device operation ran in, from its
+    scope path and innermost marker; the first rule that holds.
+    `rematted_computation`, `transpose(` and `jvp(` are jax's own
+    name-stack grammar (tests/test_attribution.py holds a compiled
+    program to it). What is jitted outside the differentiated function
+    (the optimizer, master-weight casts, loss scaling) is `update`;
+    in a trace that differentiates nothing, an inference program,
+    marked operations are `forward`."""
+    if "rematted_computation" in path:
+        return "recompute"
+    if "transpose(" in path:
+        return "backward"
+    if "jvp(" in path:
+        return "forward"
+    if prov is not None and prov.get("kind") in _COLLECTIVE_KINDS:
+        return "collective"
+    if prov is not None and not differentiated:
+        return "forward"
+    return "update" if path else "unattributed"
+
+
 def time_attribution(events) -> dict:
-    """Fold profiler op durations back through the provenance markers:
-    {"by_op": {key: us}, "by_layer": {layer: us}, "by_bucket":
-    {bucket_id: us}, "matched_us", "unmatched_us", "total_us"} over the
-    duration ("ph" == "X") events. The per-layer view is the straggler
-    answer one level deeper than PR 7's phase blame: WHICH layer's ops
-    ate the step."""
+    """Fold a profile's device time back through the provenance markers
+    and the name stack: {"steps", "devices", "by_region": {region: us},
+    "by_op_type": {fluid op type: us}, "by_op": {key: us}, "by_layer":
+    {layer: us}, "by_bucket": {bucket_id: us}, "matched_us" (under a
+    marker), "unmatched_us", "unattributed_us" (no scope path at all),
+    "total_us"}, each of self time (`device_op_rows`) over the traced
+    executions of the step's module, in microseconds of ONE device.
+    The per-layer view is the straggler answer one level deeper than
+    PR 7's phase blame (WHICH layer's ops ate the step); the regions
+    say where in the step: forward, recompute, backward, update."""
+    got = device_op_rows(events)
+    scale = 1.0 / max(got["devices"], 1)
+    by_region = dict.fromkeys(REGIONS, 0.0)
+    by_op_type: Dict[str, float] = {}
     by_op: Dict[str, float] = {}
     by_layer: Dict[str, float] = {}
     by_bucket: Dict[int, float] = {}
     matched = unmatched = total = 0.0
-    for ev in events:
-        if ev.get("ph") != "X":
-            continue
-        dur = float(ev.get("dur", 0.0) or 0.0)
-        if dur <= 0:
-            continue
-        total += dur
-        prov = None
-        for path in _event_paths(ev):
-            prov = provenance_of(path)
-            if prov is not None:
-                break
+    differentiated = any("jvp(" in path or "transpose(" in path
+                         for _name, path, _us in got["rows"])
+    for _name, path, us in got["rows"]:
+        us *= scale
+        total += us
+        prov = provenance_of(path)
+        by_region[region_of(path, prov, differentiated)] += us
         if prov is None:
-            unmatched += dur
+            unmatched += us
             continue
-        matched += dur
+        matched += us
         key = _prov_key(prov)
-        by_op[key] = by_op.get(key, 0.0) + dur
-        if prov.get("kind") == "bucket":
+        by_op[key] = by_op.get(key, 0.0) + us
+        kind = prov["op_type"] if prov["kind"] == "op" else prov["kind"]
+        by_op_type[kind] = by_op_type.get(kind, 0.0) + us
+        if prov["kind"] == "bucket":
             b = int(prov["bucket"])
-            by_bucket[b] = by_bucket.get(b, 0.0) + dur
+            by_bucket[b] = by_bucket.get(b, 0.0) + us
         var = prov.get("var")
         if var:
             lk = layer_of(var)
-            by_layer[lk] = by_layer.get(lk, 0.0) + dur
+            by_layer[lk] = by_layer.get(lk, 0.0) + us
+
+    def by_time(d):
+        return dict(sorted(d.items(), key=lambda kv: -kv[1]))
+
     return {
-        "by_op": dict(sorted(by_op.items(), key=lambda kv: -kv[1])),
-        "by_layer": dict(sorted(by_layer.items(),
-                                key=lambda kv: -kv[1])),
+        "steps": got["steps"], "devices": got["devices"],
+        "by_region": by_region, "by_op_type": by_time(by_op_type),
+        "by_op": by_time(by_op), "by_layer": by_time(by_layer),
         "by_bucket": dict(sorted(by_bucket.items())),
         "matched_us": matched, "unmatched_us": unmatched,
+        "unattributed_us": by_region["unattributed"],
         "total_us": total,
     }

@@ -89,7 +89,7 @@ class InflightToken:
         barrier RPC was sent); it is now waiting on its peers. The
         desync analyzer uses exactly this edge: a wedged rank still in
         state "inflight" never arrived — it is the guilty one."""
-        self._trace._mark(self._entry, "arrived")
+        self._trace._set_state(self._entry, "arrived")
 
     def done(self, ok: bool = True) -> None:
         self._trace._finish(self._entry, ok)
@@ -153,7 +153,7 @@ class InflightTrace:
             self._open[self._seq] = entry
         return InflightToken(self, entry)
 
-    def _mark(self, entry, state) -> None:
+    def _set_state(self, entry, state) -> None:
         with self._lock:
             if entry["state"] == "inflight":
                 entry["state"] = state

@@ -271,14 +271,20 @@ def test_step_phases_recorded():
     assert s["dispatch_ms"] > 0.0
     line = profiler.step_phase_line()
     assert "feed" in line and "dispatch" in line
-    # phase events reach the chrome-trace buffer when tracing is live
+    # the executor's own spans reach the chrome-trace buffer when
+    # tracing is live, each at its real start
     profiler.reset_profiler()
     profiler._trace_enabled = True
     try:
-        profiler.record_step_phase("feed", 0.001, time.perf_counter())
+        exe.run(feed=next(_batches(1)), fetch_list=[loss])
     finally:
         profiler._trace_enabled = False
-    assert any(n == "phase/feed" for n, *_ in profiler._trace_events)
+    names = [n for n, *_ in profiler._trace_events]
+    for phase in ("feed", "bind", "dispatch", "writeback", "sync"):
+        assert "phase/" + phase in names, names
+    starts = {n: ts for n, ts, *_ in reversed(profiler._trace_events)}
+    assert starts["phase/feed"] < starts["phase/dispatch"] \
+        < starts["phase/writeback"] < starts["phase/sync"]
 
 
 def test_donation_audit_executor_path():
@@ -295,6 +301,26 @@ def test_donation_audit_executor_path():
     assert rep["mut_bytes"] > 0
     assert rep["aliases_state"], rep
     assert rep["feed_donate"] is True
+
+
+def test_step_memory_is_the_compilers_account_of_the_cached_step():
+    loss = _build_mlp(7)
+    fluid.optimizer.AdamOptimizer(1e-3).minimize(loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(framework.default_startup_program())
+    feed = next(_batches(1))
+    # nothing has run: there is no compiled step to account for
+    assert exe.step_memory(feed=feed, fetch_list=[loss]) is None
+    exe.run(feed=feed, fetch_list=[loss])
+    mem = exe.step_memory(feed=feed, fetch_list=[loss])
+    assert set(mem) == {"argument", "output", "alias", "temp",
+                        "generated_code"}
+    assert all(isinstance(v, int) and v >= 0 for v in mem.values())
+    rep = exe.donation_report(feed=feed, fetch_list=[loss])
+    # the state (weights + Adam moments) is among the arguments, and
+    # donated: the same alias bytes the donation audit reads
+    assert mem["argument"] >= rep["mut_bytes"] > 0
+    assert mem["alias"] == rep["alias_bytes"]
 
 
 def test_parity_prefetch_and_lazy_vs_sync():

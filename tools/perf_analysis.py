@@ -52,11 +52,12 @@ predicted peak fails PRE-dispatch with a structured error naming the
 top consumers. Writes artifacts/attribution.json; exits nonzero when
 any of those do not hold.
 
-`--stragglers --xplane-dir DIR` additionally folds the profiler op
-durations of a capture window (the trace.json.gz inside a PR 7
-capture.py xplane dir) back through the provenance markers to
-per-layer / per-bucket device time — the blame one level below the
-phase verdict.
+`--stragglers --xplane-dir DIR` additionally folds the device time of
+a capture window (self times of the `XLA Ops` thread in the
+trace.json.gz inside a PR 7 capture.py xplane dir) back through the
+provenance markers to the step's regions (forward / recompute /
+backward / update), fluid op types and per-layer / per-bucket device
+time — the blame one level below the phase verdict.
 
 `--hierarchy` is the offline evidence for the hierarchical DCN+ICI
 grad collectives (FLAGS_tpu_dcn_replicas, hybrid multi-pod mesh): it
@@ -1178,20 +1179,35 @@ def attribution_audit(batch=16, seq_len=32, bucket_mb=0.25):
 
 
 def xplane_blame(xplane_dir):
-    """Fold a capture window's device op durations through the
-    provenance markers: the per-layer / per-bucket device-time blame
-    (--stragglers --xplane-dir). Returns the attribution dict."""
+    """Fold a capture window's device time (self times of the `XLA Ops`
+    thread, over the traced executions of the step's module) through
+    the provenance markers: where in the step it went (forward /
+    recompute / backward / update / collective), by fluid op type, and
+    the per-layer / per-bucket blame (--stragglers --xplane-dir).
+    Returns the attribution dict."""
     from paddle_tpu.observability import attribution as attr
 
     events = attr.load_trace_events(xplane_dir)
     t = attr.time_attribution(events)
     if not t["total_us"]:
-        print("xplane dir %s: no duration events found" % xplane_dir)
+        print("xplane dir %s: no device operation found (the fold reads "
+              "the *.trace.json.gz sidecar's /device:TPU:<n> processes)"
+              % xplane_dir)
         return t
-    print("device-time attribution over %s (%.1f ms total, %.0f%% "
-          "matched to provenance markers):"
-          % (xplane_dir, t["total_us"] / 1e3,
-             100.0 * t["matched_us"] / max(t["total_us"], 1)))
+    steps = max(t["steps"], 1)
+    print("device-time attribution over %s (%d step(s) on %d device(s), "
+          "%.1f ms a step, %.1f%% under provenance markers, %.1f%% with "
+          "no scope path):"
+          % (xplane_dir, t["steps"], t["devices"],
+             t["total_us"] / steps / 1e3,
+             100.0 * t["matched_us"] / t["total_us"],
+             100.0 * t["unattributed_us"] / t["total_us"]))
+    for region, us in t["by_region"].items():
+        if us:
+            print("  region %-27s %10.1f us/step %5.1f%%"
+                  % (region, us / steps, 100.0 * us / t["total_us"]))
+    for op_type, us in list(t["by_op_type"].items())[:12]:
+        print("  op type %-26s %10.1f us/step" % (op_type, us / steps))
     for layer, us in list(t["by_layer"].items())[:10]:
         print("  layer %-28s %10.1f us" % (layer, us))
     for b, us in t["by_bucket"].items():
