@@ -6,7 +6,9 @@ TPU-native design threads counter-based stateless keys instead
 (deterministic given program.random_seed + op index). This module picks
 the key *implementation*: threefry2x32 is JAX's portable default but
 generates bits with long serial VPU ops — on a BERT-base step the
-dropout masks alone are ~1.2G draws while the MXU idles. XLA's
+dropout masks alone are ~1.2G draws in the forward pass while the MXU
+idles (a scan's per-layer recompute keeps the masks and draws nothing
+again: ops/remat_names.py). XLA's
 RngBitGenerator ("rbg") uses the hardware RNG path on TPU. Controlled by
 FLAGS_prng_impl ("auto" = rbg on TPU, threefry on CPU so seeded CPU
 tests keep their exact streams).

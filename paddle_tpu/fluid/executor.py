@@ -1532,6 +1532,21 @@ class Executor:
                 for k in ("argument", "output", "alias", "temp",
                           "generated_code")}
 
+    def remat_saved(self, program=None):
+        """What each `layers.Scan(remat=True)` of the program keeps
+        across its per-layer checkpoint besides the carry, as the
+        lowering recorded it when the step was traced (run the program
+        once first): {scan op's provenance marker: {n, kept: [{name,
+        shape, dtype, bytes}], bytes_per_layer, bytes_over_scan}}.
+        Empty for a program with no such scan. `step_memory` reads the
+        effect on the compiled step; this says what was chosen."""
+        from . import compiler as _compiler
+
+        prog = program or framework.default_main_program()
+        if isinstance(prog, _compiler.CompiledProgram):
+            prog = prog._unwrap()
+        return dict(getattr(prog, "_remat_saved", None) or {})
+
     def _donation_report_from(self, program, entry, lowered, smut,
                               favals):
         """donation_report's body for callers that already hold the

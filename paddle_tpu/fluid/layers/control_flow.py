@@ -67,8 +67,13 @@ class Scan:
 
     remat=True wraps the body in ``jax.checkpoint``: per-iteration
     activation recompute (the scan-over-layers equivalent of
-    RecomputeOptimizer's checkpoint segments) — memory O(n * boundary)
-    instead of O(n * body-internals).
+    RecomputeOptimizer's checkpoint segments). Held across the n
+    iterations are the boundary (the carry) and the few values of the
+    body that cost less to keep than to make again — every dropout's
+    boolean keep mask and the output of a matmul narrower than what it
+    contracts (``ops/remat_names.py``) — instead of O(n *
+    body-internals); ``Executor.remat_saved`` says what a traced
+    program keeps, in bytes.
 
     Usage::
 

@@ -668,12 +668,19 @@ def _exec_scan(op, env, key0, op_idx, amp_lists):
     per-iteration slices of the stacked inputs arrive as scan xs; with
     attrs['remat'] the body is wrapped in jax.checkpoint, giving
     activation recompute per layer without RecomputeOptimizer's
-    segment machinery. Reverse-mode grads fall out of the ordinary
-    jax.vjp over lax.scan (no recurrent_grad op — contrast
-    reference recurrent_op.cc's scope-mutation step loop)."""
+    segment machinery. What the forward scan stacks for the backward
+    is then the carry and the few values the ops named as cheaper to
+    keep than to make again (ops/remat_names.py: dropout keep masks,
+    narrow matmul products); everything else in the body is computed a
+    second time. `program._remat_saved` holds what was kept, per scan
+    op (Executor.remat_saved reads it). Reverse-mode grads fall out of
+    the ordinary jax.vjp over lax.scan (no recurrent_grad op —
+    contrast reference recurrent_op.cc's scope-mutation step loop)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
+
+    from ..ops import remat_names
 
     prog = op.block.program
     sub = prog.block(op.attrs["sub_block"])
@@ -689,8 +696,11 @@ def _exec_scan(op, env, key0, op_idx, amp_lists):
     base_key = jax.random.fold_in(key0, op_idx)
 
     iter_name = op.attrs.get("iter_var") or None
+    kept = [] if op.attrs.get("remat") else None
 
     def body(carry, xs):
+        if kept is not None:
+            kept.clear()  # a retrace of the body starts its list anew
         it = carry[0]
         e = dict(env)
         e.update(zip(carry_names, carry[1:]))
@@ -702,12 +712,54 @@ def _exec_scan(op, env, key0, op_idx, amp_lists):
                  amp_lists=amp_lists)
         return ((it + 1,) + tuple(e[nm] for nm in carry_names)), None
 
-    if op.attrs.get("remat"):
-        body = jax.checkpoint(body)
+    if kept is not None:
+        # prevent_cse=False: forward and recompute sit in two loops, so
+        # there is nothing to eliminate, and the barrier that would
+        # prevent it makes XLA copy every stacked value out of its
+        # buffer before the recompute may read it
+        body = jax.checkpoint(
+            body, prevent_cse=False,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *remat_names.KEPT))
     init = (jnp.int32(0),) + tuple(env[nm] for nm in carry_names)
     xs = tuple(env[nm] for nm in xs_stacked)
-    final, _ = lax.scan(body, init, xs, length=n)
+    with remat_names.collecting(kept):
+        final, _ = lax.scan(body, init, xs, length=n)
+    if kept is not None:
+        _record_remat_saved(op, op_idx, n, kept)
     env.update(zip(carry_names, final[1:]))
+
+
+def _record_remat_saved(op, op_idx, n, kept):
+    """Trace-time account of what a remat scan's checkpoint keeps, on
+    the program under the scan op's provenance marker: the named
+    values with shape, dtype and bytes, bytes a layer and over the
+    scan's `n` layers (the carry is stacked besides, as before).
+    Logged when it is new or has changed, so once a compile."""
+    import logging
+
+    from ..observability import attribution as _attr
+
+    rows = [{"name": name, "shape": list(shape), "dtype": str(dtype),
+             "bytes": int(np.prod(shape, dtype=np.int64))
+             * np.dtype(dtype).itemsize}
+            for name, shape, dtype in kept]
+    per_layer = sum(r["bytes"] for r in rows)
+    record = {"n": n, "kept": rows, "bytes_per_layer": per_layer,
+              "bytes_over_scan": per_layer * n}
+    prog = op.block.program
+    saved = getattr(prog, "_remat_saved", None)
+    if saved is None:
+        saved = prog._remat_saved = {}
+    marker = _attr.op_marker(op, op_idx)
+    if saved.get(marker) != record:
+        saved[marker] = record
+        logging.getLogger(__name__).info(
+            "scan %s keeps across its checkpoint, besides the carry: "
+            "%s; %d bytes a layer, %d over %d layers", marker,
+            ", ".join("%s %s%s" % (r["name"], r["dtype"], r["shape"])
+                      for r in rows) or "nothing",
+            per_layer, per_layer * n, n)
 
 
 def _branch_out_names(op, env, blocks):

@@ -113,8 +113,11 @@ def _scan_encoder_stack(x, attn_bias, cfg, is_test=False, remat=False):
     (vs `encoder_layer` unrolling: ~12x smaller HLO, proportionally
     faster XLA compiles). Math is identical to the unrolled stack with
     q/k/v fused into one [H, 3H] projection (one MXU matmul instead of
-    three). remat=True checkpoints activations per layer inside the
-    scan (replaces RecomputeOptimizer segmentation for this model)."""
+    three). remat=True checkpoints per layer inside the scan (replaces
+    RecomputeOptimizer segmentation for this model): the backward pass
+    is handed each layer's input, its three dropout keep masks and the
+    FFN's output product (the one matmul narrower than what it
+    contracts), and makes the rest of the layer again."""
     from ..fluid import initializer
     from ..fluid.layers import Scan
 

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from .registry import register_op
+from .remat_names import keep_narrow_product
 from ..core.types import to_numpy_dtype
 
 
@@ -62,7 +63,7 @@ def _mul(ins, attrs):
     yn = attrs.get("y_num_col_dims", 1)
     x2 = x.reshape((int(np.prod(x.shape[:xn])), -1))
     y2 = y.reshape((int(np.prod(y.shape[:yn])), -1))
-    out = x2 @ y2
+    out = keep_narrow_product(x2 @ y2, y2)
     return {"Out": out.reshape(x.shape[:xn] + y.shape[yn:])}
 
 
@@ -82,7 +83,7 @@ def _matmul(ins, attrs):
     out = jnp.matmul(x, y)
     if alpha != 1.0:
         out = out * alpha
-    return {"Out": out}
+    return {"Out": keep_narrow_product(out, y)}
 
 
 @register_op("matmul_v2")
@@ -92,7 +93,7 @@ def _matmul_v2(ins, attrs):
         x = jnp.swapaxes(x, -1, -2)
     if attrs.get("trans_y", False):
         y = jnp.swapaxes(y, -1, -2)
-    return {"Out": jnp.matmul(x, y)}
+    return {"Out": keep_narrow_product(jnp.matmul(x, y), y)}
 
 
 @register_op("scale")

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import remat_names
 from .registry import register_op
 
 
@@ -428,7 +429,8 @@ def _dropout(ins, attrs):
         out = x if impl == "upscale_in_train" else x * (1.0 - p)
         return {"Out": out, "Mask": jnp.ones(x.shape, jnp.uint8)}
     key = attrs["_rng_key"]
-    keep = jax.random.bernoulli(key, 1.0 - p, x.shape)
+    keep = remat_names.keep(jax.random.bernoulli(key, 1.0 - p, x.shape),
+                            remat_names.DROPOUT_MASK)
     if impl == "upscale_in_train":
         out = jnp.where(keep, x / (1.0 - p), jnp.zeros_like(x))
     else:
@@ -619,8 +621,9 @@ def _sdpa(ins, attrs):
         s = jnp.where(rows >= cols, s, -1e30)
     probs = jax.nn.softmax(s, axis=-1)
     if drop_active:
-        keep = jax.random.bernoulli(attrs["_rng_key"], 1.0 - p_drop,
-                                    probs.shape)
+        keep = remat_names.keep(
+            jax.random.bernoulli(attrs["_rng_key"], 1.0 - p_drop,
+                                 probs.shape), remat_names.DROPOUT_MASK)
         probs = jnp.where(keep, probs / (1.0 - p_drop), 0.0)
     out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype), v,
                      preferred_element_type=jnp.float32)

@@ -592,12 +592,19 @@ def test_region_fold_counts_device_self_time_once():
         in rows["rows"]
 
 
-def test_regions_follow_jax_name_stack_of_a_compiled_scanned_bert():
+@pytest.mark.parametrize("policy", [True, False])
+def test_regions_follow_jax_name_stack_of_a_compiled_scanned_bert(
+        monkeypatch, policy):
     """`jvp(`, `transpose(` and `rematted_computation` are jax's
     name-stack grammar, not ours: a tiny scanned BERT with per-layer
     recompute, compiled on the CPU, must show all four regions in the
     optimized HLO's op_name metadata. A jax upgrade that renames them
-    fails here, not by zeroing a metric on the chip."""
+    fails here, not by zeroing a metric on the chip. The checkpoint
+    that carries a policy (what `_exec_scan` builds) and the bare one
+    must both leave a `recompute` region, or `device_recompute_ms`
+    falls silent."""
+    import jax
+
     from paddle_tpu.fluid import lowering
     from paddle_tpu.fluid.contrib import mixed_precision
     from paddle_tpu.models import bert
@@ -605,6 +612,9 @@ def test_regions_follow_jax_name_stack_of_a_compiled_scanned_bert():
     sys.path.insert(0, _REPO)
     from __graft_entry__ import _bert_feed
 
+    if not policy:
+        monkeypatch.setattr(jax.checkpoint_policies,
+                            "save_only_these_names", lambda *names: None)
     _fresh()
     cfg = bert.BertConfig.tiny()
     main_p, startup_p = framework.Program(), framework.Program()

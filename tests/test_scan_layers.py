@@ -180,3 +180,246 @@ def test_scan_bert_train_decreases_and_per_layer_dropout_differs():
     ls = [float(step().ravel()[0]) for _ in range(6)]
     assert np.isfinite(ls).all()
     assert ls[-1] < ls[0], ls
+
+
+# ---------------------------------------------------------------------------
+# remat=True with a policy: the checkpoint keeps the dropout masks and
+# the narrow matmul product (ops/remat_names.py), recomputes the rest
+# ---------------------------------------------------------------------------
+
+_B, _S = 3, 8  # with BertConfig.tiny: H 64, F 128, 4 heads, 2 layers
+
+
+def _bare_checkpoint(monkeypatch):
+    """The lowering as it was: jax.checkpoint(body, policy=None)."""
+    import jax
+
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: None)
+
+
+def _build_scan_bert(remat=True, amp=True, seed=9):
+    from paddle_tpu.fluid.contrib import mixed_precision
+
+    cfg = bert.BertConfig.tiny()
+    main, st = framework.Program(), framework.Program()
+    main.random_seed = st.random_seed = seed
+    with framework.program_guard(main, st):
+        with framework.unique_name_guard():
+            total, _, _, _ = bert.bert_pretrain_loss(
+                cfg, _S, is_test=False, scan_layers=True,
+                scan_remat=remat)
+            opt = fluid.optimizer.AdamOptimizer(1e-3)
+            if amp:
+                opt = mixed_precision.decorate(
+                    opt, use_dynamic_loss_scaling=False)
+            opt.minimize(total)
+    return cfg, main, st, total, _bert_feed(cfg, _B, _S, max_pred=2)
+
+
+def _step_jaxpr(main, st, feed, fetch):
+    """The jaxpr of the whole train step, traced and not compiled."""
+    import jax
+    from paddle_tpu.fluid import lowering
+
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(st)
+    block = main.global_block()
+    feeds = exe._prepare_feed(block, feed)
+    state_in, _ = lowering.analyze_block(block, list(feeds), [fetch.name])
+    specs = {n: global_scope().find_var(n) for n in state_in}
+    entry = lowering.compile_block(main, block, feeds, [fetch.name], specs)
+    return entry.jitted.trace(
+        {n: exe._aval_of(a) for n, a in feeds.items()},
+        {n: exe._aval_of(specs[n]) for n in entry.state_mut_names},
+        {n: exe._aval_of(specs[n]) for n in entry.state_ro_names},
+        jax.ShapeDtypeStruct((), np.uint32)).jaxpr.jaxpr
+
+
+def _walk(jaxpr, path=()):
+    """(names of the enclosing equations, equation), depth first."""
+    for eqn in jaxpr.eqns:
+        yield path, eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk(sub, path + (eqn.primitive.name,))
+
+
+def _layer_scans(jaxpr, n_layers):
+    """(forward, backward) scan equations of the encoder stack: the
+    forward draws the masks, the backward holds the recompute."""
+    fwd = bwd = None
+    for path, eqn in _walk(jaxpr):
+        if eqn.primitive.name != "scan" or path \
+                or eqn.params["length"] != n_layers:
+            continue
+        inner = {e.primitive.name for _, e in _walk(eqn.params["jaxpr"].jaxpr)}
+        if "random_bits" in inner and fwd is None:
+            fwd = eqn
+        elif "remat2" in inner or "checkpoint" in inner:
+            bwd = eqn
+    assert fwd is not None and bwd is not None
+    return fwd, bwd
+
+
+def _dots(scan_eqn):
+    """(lhs shape, rhs shape, contracted rhs dim) of every dot_general
+    under a scan equation."""
+    out = []
+    for _, e in _walk(scan_eqn.params["jaxpr"].jaxpr):
+        if e.primitive.name == "dot_general":
+            (_, rc), _ = e.params["dimension_numbers"]
+            out.append((tuple(e.invars[0].aval.shape),
+                        tuple(e.invars[1].aval.shape), tuple(rc)))
+    return out
+
+
+@pytest.mark.parametrize("policy,draws", [(True, 1), (False, 2)])
+def test_remat_scan_draws_each_mask_once_with_the_policy(
+        monkeypatch, policy, draws):
+    """The differentiated step holds each mask's random_bits once where
+    the bare checkpoint holds it twice (forward and recompute)."""
+    if not policy:
+        _bare_checkpoint(monkeypatch)
+    cfg, main, st, total, feed = _build_scan_bert()
+    jaxpr = _step_jaxpr(main, st, feed, total)
+    heads = cfg.num_attention_heads
+    per_shape = {(_B, heads, _S, _S): 0, (_B, _S, cfg.hidden_size): 0}
+    for path, eqn in _walk(jaxpr):
+        if eqn.primitive.name == "random_bits" and "scan" in path:
+            per_shape[tuple(eqn.outvars[0].aval.shape)] += 1
+    # one attention mask and two hidden masks a layer
+    assert per_shape == {(_B, heads, _S, _S): draws,
+                         (_B, _S, cfg.hidden_size): 2 * draws}
+
+
+def test_remat_scan_recompute_drops_only_the_narrow_product():
+    cfg, main, st, total, feed = _build_scan_bert()
+    h, f = cfg.hidden_size, cfg.intermediate_size
+    fwd, bwd = _layer_scans(_step_jaxpr(main, st, feed, total),
+                            cfg.num_hidden_layers)
+    ffn_out = ((_B, _S, f), (f, h), (0,))
+    still = [((_B, _S, h), (h, 3 * h), (0,)),      # q/k/v
+             ((_B, _S, h), (h, f), (0,)),          # FFN-in
+             ((_B, _S, h), (h, h), (0,))]          # attention output
+    assert ffn_out in _dots(fwd)
+    bwd_dots = _dots(bwd)
+    assert ffn_out not in bwd_dots
+    for dot in still:
+        assert dot in _dots(fwd) and dot in bwd_dots, dot
+
+
+def test_remat_scan_stacks_carry_masks_and_narrow_product():
+    """What the forward scan hands the backward: the carry at each
+    layer's entry, three boolean masks and the bf16 FFN-out product —
+    no other activation."""
+    cfg, main, st, total, feed = _build_scan_bert()
+    L, h = cfg.num_hidden_layers, cfg.hidden_size
+    fwd, _ = _layer_scans(_step_jaxpr(main, st, feed, total), L)
+    n_carry = fwd.params["num_carry"]
+    stacked = sorted((str(v.aval.dtype), tuple(v.aval.shape))
+                     for v in fwd.outvars[n_carry:])
+    assert stacked == sorted([
+        ("bool", (L, _B, cfg.num_attention_heads, _S, _S)),
+        ("bool", (L, _B, _S, h)), ("bool", (L, _B, _S, h)),
+        ("bfloat16", (L, _B, _S, h)),
+        ("float32", (L, _B, _S, h))])
+
+
+def test_remat_policy_is_bit_equal_to_bare_checkpoint(monkeypatch):
+    """Same keys, same masks, same products: three Adam steps with the
+    policy equal three under a bare jax.checkpoint to the last bit."""
+    def three_steps():
+        _, main, st, total, feed = _build_scan_bert()
+        _, step = _run(main, st, feed, total)
+        losses = [step() for _ in range(3)]
+        return losses, _snapshot_params(main)
+
+    losses_p, params_p = three_steps()
+    with monkeypatch.context() as m:
+        _bare_checkpoint(m)
+        losses_b, params_b = three_steps()
+    np.testing.assert_array_equal(losses_p, losses_b)
+    assert params_p.keys() == params_b.keys()
+    for name in params_p:
+        np.testing.assert_array_equal(params_p[name], params_b[name],
+                                      err_msg=name)
+
+
+def _build_resnet50():
+    from paddle_tpu.fluid.contrib import mixed_precision
+    from paddle_tpu.models import resnet
+
+    main, st = framework.Program(), framework.Program()
+    main.random_seed = st.random_seed = 3
+    with framework.program_guard(main, st):
+        with framework.unique_name_guard():
+            img = fluid.layers.data("image", shape=[3, 32, 32],
+                                    dtype="float32")
+            label = fluid.layers.data("label", shape=[1], dtype="int64")
+            loss = fluid.layers.mean(
+                fluid.layers.softmax_with_cross_entropy(
+                    resnet.resnet(img, class_dim=1000, depth=50), label))
+            mixed_precision.decorate(
+                fluid.optimizer.MomentumOptimizer(0.02, 0.9),
+                use_dynamic_loss_scaling=False).minimize(loss)
+    feed = {"image": np.zeros((2, 3, 32, 32), np.float32),
+            "label": np.zeros((2, 1), np.int64)}
+    return main, st, feed, loss
+
+
+def _build_scan_bert_no_remat():
+    _, main, st, total, feed = _build_scan_bert(remat=False)
+    return main, st, feed, total
+
+
+@pytest.mark.parametrize("build", [_build_scan_bert_no_remat,
+                                   _build_resnet50])
+def test_programs_without_remat_lower_as_before(monkeypatch, build):
+    """Outside a remat scan no value is named: the jaxpr is the one the
+    ops gave before they could name anything (`keep` the identity)."""
+    from paddle_tpu.ops import remat_names
+
+    jaxpr = _step_jaxpr(*build())
+    assert not any(e.primitive.name == "name" for _, e in _walk(jaxpr))
+    monkeypatch.setattr(remat_names, "keep", lambda x, name: x)
+    assert str(_step_jaxpr(*build())) == str(jaxpr)
+
+
+def test_remat_saved_record_matches_hand_arithmetic(caplog):
+    import logging
+
+    cfg, main, st, total, feed = _build_scan_bert()
+    L, h, heads = (cfg.num_hidden_layers, cfg.hidden_size,
+                   cfg.num_attention_heads)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(st)
+    assert exe.remat_saved(main) == {}  # nothing traced yet
+    with caplog.at_level(logging.INFO, logger="paddle_tpu.fluid.lowering"):
+        for _ in range(2):
+            exe.run(main, feed=feed, fetch_list=[total])
+    (marker, rec), = exe.remat_saved(main).items()
+    assert marker.startswith("pp[b0;") and ";scan;" in marker
+    attn_mask = _B * heads * _S * _S          # one byte an element
+    hidden_mask = _B * _S * h
+    product = _B * _S * h * 2                 # bfloat16
+    assert rec["n"] == L
+    assert [(r["name"], r["shape"], r["dtype"], r["bytes"])
+            for r in rec["kept"]] == [
+        ("dropout_keep_mask", [_B, heads, _S, _S], "bool", attn_mask),
+        ("dropout_keep_mask", [_B, _S, h], "bool", hidden_mask),
+        ("narrow_matmul_product", [_B, _S, h], "bfloat16", product),
+        ("dropout_keep_mask", [_B, _S, h], "bool", hidden_mask)]
+    assert rec["bytes_per_layer"] == attn_mask + 2 * hidden_mask + product
+    assert rec["bytes_over_scan"] == L * rec["bytes_per_layer"]
+    # logged once for the compiled entry, not once a step or a trace
+    said = [r for r in caplog.records if "keeps across" in r.getMessage()]
+    assert len(said) == 1 and str(rec["bytes_over_scan"]) in \
+        said[0].getMessage()
+    # a program whose scan has no remat keeps nothing and says nothing
+    _, main_n, st_n, total_n, _ = _build_scan_bert(remat=False)
+    exe.run(st_n)
+    exe.run(main_n, feed=feed, fetch_list=[total_n])
+    assert exe.remat_saved(main_n) == {}
