@@ -154,7 +154,7 @@ class OptimizerWithMixedPrecision:
 
         if level == "O2":
             self._master_of = rewrite_master_weights(
-                program, startup, compute_dtype)
+                program, startup, compute_dtype, self._amp_lists)
             program._amp_master_of = dict(self._master_of)
         if fp8:
             self._fp8_state = wire_fp8_delayed_scaling(

@@ -23,6 +23,8 @@ black_list = {
     "softmax_with_cross_entropy", "cross_entropy", "exp", "log",
     "mean", "sum", "reduce_mean", "reduce_sum", "softmax",
     "sigmoid_cross_entropy_with_logits", "layer_norm", "batch_norm",
+    # a routing decided in 16 bits flips at near-ties: scores in fp32
+    "moe_router",
 }
 
 # neutral: follow inputs
@@ -45,6 +47,17 @@ fp8_white_list = {
 }
 
 
+# input slots whose PARAMETERS stay fp32 at level O2 (no 16-bit live
+# copy, so no master either): the op reads them in fp32 whatever its
+# other inputs are, and a 16-bit copy would round what the op is
+# careful about (the router's matrix decides near-ties; the scan's
+# A_log and dt_bias sit inside exp(dt * A), its D beside it)
+fp32_param_slots = {
+    "moe_router": ("W", "Bias"),
+    "ssd_chunk_scan": ("ALog", "DtBias", "D"),
+}
+
+
 class AutoMixedPrecisionLists:
     def __init__(self, custom_white_list=None, custom_black_list=None,
                  custom_black_varnames=None, custom_fp8_white_list=None):
@@ -52,6 +65,7 @@ class AutoMixedPrecisionLists:
         self.black_list = set(black_list)
         self.gray_list = set(gray_list)
         self.fp8_white_list = set(fp8_white_list)
+        self.fp32_param_slots = dict(fp32_param_slots)
         if custom_white_list:
             self.white_list |= set(custom_white_list)
             self.black_list -= set(custom_white_list)

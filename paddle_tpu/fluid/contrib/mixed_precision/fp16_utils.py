@@ -41,11 +41,26 @@ def master_name(param_name: str) -> str:
     return param_name + MASTER_SUFFIX
 
 
-def rewrite_master_weights(program, startup_program, compute_dtype):
+def _fp32_pinned_params(block, amp_lists):
+    """Names read through a slot of `amp_lists.fp32_param_slots`, in
+    this block or a sub-block: parameters that stay fp32."""
+    slots = getattr(amp_lists, "fp32_param_slots", None) or {}
+    pinned = set()
+    for blk in block.program.blocks:
+        for op in blk.ops:
+            for slot in slots.get(op.type, ()):
+                pinned.update(op.input_names.get(slot, []))
+    return pinned
+
+
+def rewrite_master_weights(program, startup_program, compute_dtype,
+                           amp_lists=None):
     """Rewire every optimizer op's Param/ParamOut to an fp32 master var,
     flip the live params (and their grads) to `compute_dtype`, and
     append one ``cast`` op per param re-deriving the live value from the
-    updated master. Returns {param_name: master_name}.
+    updated master. Returns {param_name: master_name}. A parameter read
+    through one of `amp_lists.fp32_param_slots` keeps its fp32 value as
+    the live one and gets no master.
 
     Startup contract: the initializer op still fills the EXACT fp32
     init value; the master is assigned from it BEFORE the live param is
@@ -58,6 +73,7 @@ def rewrite_master_weights(program, startup_program, compute_dtype):
                     if op.type == "backward"), None)
     post = block.ops[bwd_idx + 1:] if bwd_idx is not None else block.ops
 
+    pinned = _fp32_pinned_params(block, amp_lists)
     master_of = {}
     for op in post:
         params = op.input_names.get("Param", [])
@@ -65,7 +81,7 @@ def rewrite_master_weights(program, startup_program, compute_dtype):
         if not params or not pouts:
             continue
         for i, p in enumerate(params):
-            if p.endswith(MASTER_SUFFIX):
+            if p.endswith(MASTER_SUFFIX) or p in pinned:
                 continue
             v = block._find_var_recursive(p)
             if v is None or str(v.dtype) != "float32" \

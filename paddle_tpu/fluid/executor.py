@@ -1534,11 +1534,13 @@ class Executor:
 
     def remat_saved(self, program=None):
         """What each `layers.Scan(remat=True)` of the program keeps
-        across its per-layer checkpoint besides the carry, as the
-        lowering recorded it when the step was traced (run the program
-        once first): {scan op's provenance marker: {n, kept: [{name,
+        across its per-layer checkpoint besides the carry, and each
+        segment of an unrolled stack under `RecomputeOptimizer` besides
+        its outputs, as the lowering recorded it when the step was
+        traced (run the program once first): {scan op's provenance
+        marker, or `<backward op's marker>/seg<i>`: {n, kept: [{name,
         shape, dtype, bytes}], bytes_per_layer, bytes_over_scan}}.
-        Empty for a program with no such scan. `step_memory` reads the
+        Empty for a program with neither. `step_memory` reads the
         effect on the compiled step; this says what was chosen."""
         from . import compiler as _compiler
 

@@ -45,6 +45,7 @@ def gather_tree(ids, parents):
 from . import learning_rate_scheduler  # noqa: F401
 from .nn import *  # noqa: F401,F403
 from .nn_extra import *  # noqa: F401,F403
+from .hybrid import *  # noqa: F401,F403
 from . import nn_extra  # noqa: F401
 from . import detection  # noqa: F401
 from .detection import *  # noqa: F401,F403

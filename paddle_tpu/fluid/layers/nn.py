@@ -17,7 +17,7 @@ __all__ = [
     "layer_norm", "group_norm", "instance_norm", "dropout", "relu",
     "sigmoid", "tanh", "sqrt", "square", "exp", "log", "abs", "ceil",
     "floor", "round", "reciprocal", "gelu", "leaky_relu", "elu", "relu6",
-    "softplus", "softsign", "swish", "hard_sigmoid", "hard_swish", "prelu",
+    "softplus", "softsign", "swish", "silu", "relu2", "hard_sigmoid", "hard_swish", "prelu",
     "softmax", "log_softmax", "matmul", "mul", "elementwise_add",
     "elementwise_sub", "elementwise_mul", "elementwise_div",
     "elementwise_max", "elementwise_min", "elementwise_pow",
@@ -379,6 +379,8 @@ relu6 = _make_act("relu6")
 softplus = _make_act("softplus")
 softsign = _make_act("softsign")
 swish = _make_act("swish")
+silu = _make_act("silu")
+relu2 = _make_act("relu2")
 hard_sigmoid = _make_act("hard_sigmoid")
 hard_swish = _make_act("hard_swish")
 logsigmoid = _make_act("logsigmoid")

@@ -726,19 +726,21 @@ def _exec_scan(op, env, key0, op_idx, amp_lists):
     with remat_names.collecting(kept):
         final, _ = lax.scan(body, init, xs, length=n)
     if kept is not None:
-        _record_remat_saved(op, op_idx, n, kept)
+        from ..observability import attribution as _attr
+
+        _record_remat_saved(prog, _attr.op_marker(op, op_idx), n, kept)
     env.update(zip(carry_names, final[1:]))
 
 
-def _record_remat_saved(op, op_idx, n, kept):
-    """Trace-time account of what a remat scan's checkpoint keeps, on
-    the program under the scan op's provenance marker: the named
-    values with shape, dtype and bytes, bytes a layer and over the
-    scan's `n` layers (the carry is stacked besides, as before).
+def _record_remat_saved(prog, marker, n, kept):
+    """Trace-time account of what a checkpoint keeps, on the program
+    under `marker` (a remat scan op's provenance marker, or
+    `<backward op's marker>/seg<i>` for a segment of an unrolled stack
+    under RecomputeOptimizer): the named values with shape, dtype and
+    bytes, bytes a layer and over the `n` layers the checkpoint is
+    applied to (a scan's carry, a segment's outputs are kept besides).
     Logged when it is new or has changed, so once a compile."""
     import logging
-
-    from ..observability import attribution as _attr
 
     rows = [{"name": name, "shape": list(shape), "dtype": str(dtype),
              "bytes": int(np.prod(shape, dtype=np.int64))
@@ -747,15 +749,13 @@ def _record_remat_saved(op, op_idx, n, kept):
     per_layer = sum(r["bytes"] for r in rows)
     record = {"n": n, "kept": rows, "bytes_per_layer": per_layer,
               "bytes_over_scan": per_layer * n}
-    prog = op.block.program
     saved = getattr(prog, "_remat_saved", None)
     if saved is None:
         saved = prog._remat_saved = {}
-    marker = _attr.op_marker(op, op_idx)
     if saved.get(marker) != record:
         saved[marker] = record
         logging.getLogger(__name__).info(
-            "scan %s keeps across its checkpoint, besides the carry: "
+            "%s keeps across its checkpoint, besides what it hands on: "
             "%s; %d bytes a layer, %d over %d layers", marker,
             ", ".join("%s %s%s" % (r["name"], r["dtype"], r["shape"])
                       for r in rows) or "nothing",
@@ -1392,15 +1392,33 @@ def build_block_fn(program, block, feed_names, fetch_names,
                 if segments is None:
                     _run_ops(fwd_ops, e, key0, amp_lists=amp_lists)
                 else:
-                    for start, stop, needed in segments:
+                    # the scan's policy on an unrolled stack: a segment
+                    # hands on its outputs and the few values its ops
+                    # named as cheaper to keep (ops/remat_names.py)
+                    from ..observability import attribution as _attr
+                    from ..ops import remat_names
+
+                    for i, (start, stop, needed) in enumerate(segments):
+                        kept = []
+
                         def seg_fn(carry, _ops=fwd_ops[start:stop],
-                                   _start=start, _needed=needed):
+                                   _start=start, _needed=needed,
+                                   _kept=kept):
+                            _kept.clear()
                             ee = dict(carry)
                             _run_ops(_ops, ee, key0, base_idx=_start,
                                      amp_lists=amp_lists)
                             return {n: ee[n] for n in _needed if n in ee}
 
-                        e.update(jax.checkpoint(seg_fn)(e))
+                        with remat_names.collecting(kept):
+                            e.update(jax.checkpoint(
+                                seg_fn,
+                                policy=jax.checkpoint_policies
+                                .save_only_these_names(
+                                    *remat_names.KEPT))(e))
+                        _record_remat_saved(
+                            program, "%s/seg%d" % (
+                                _attr.op_marker(bop, bwd_idx), i), 1, kept)
                 loss_sum = jnp.sum(e[loss_name].astype(jnp.float32))
                 return loss_sum, e
 

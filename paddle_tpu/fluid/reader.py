@@ -368,7 +368,14 @@ class DataLoader:
                 if w.is_alive():
                     w.terminate()
             for w in workers:
-                w.join()
+                # a worker forked from a many-threaded process may never
+                # act on SIGTERM (seen under pytest-xdist: the join below
+                # waited for ever), so one that outlives its grace is
+                # killed outright
+                w.join(timeout=5.0)
+                if w.is_alive():
+                    w.kill()
+                    w.join()
 
     def __len__(self):
         if self._batch_sampler is not None:

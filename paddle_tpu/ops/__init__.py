@@ -30,3 +30,4 @@ from . import framework_ops  # noqa: F401
 from . import specialty_ops  # noqa: F401
 from . import ps_ops  # noqa: F401
 from . import detection_extra_ops  # noqa: F401
+from . import hybrid_ops  # noqa: F401

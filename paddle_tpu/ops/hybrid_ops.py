@@ -1,0 +1,414 @@
+"""Ops of hybrid state-space / routed-expert decoders: RMS norm, the
+causal depthwise convolution and the chunked state-space scan of a
+Mamba-2 mixer, and a routed-expert layer in two ops (the router, and
+the experts a chip holds as one grouped matrix product).
+
+Precision is part of each op, not of an AMP list: the norm's
+statistics, the scan's step sizes, decays, cumulative sums and chunk
+states, and the router's scores are float32 whatever the inputs'
+dtype; the matrix products run at the inputs' dtype with float32
+accumulation. `fp16_lists.fp32_param_slots` keeps the parameters
+behind those float32 parts (the scan's `A_log`, `dt_bias`, `D`; the
+router's matrix and bias) float32 under `decorate`.
+"""
+from __future__ import annotations
+
+import functools
+import logging
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .registry import get_op, register_op
+
+_F32 = jnp.float32
+
+
+# ---------------------------------------------------------------------------
+# RMS norm, causal depthwise convolution
+# ---------------------------------------------------------------------------
+
+@register_op("rms_norm")
+def _rms_norm(ins, attrs):
+    """Y = X * rsqrt(mean(X^2) + epsilon) * Scale over the last axis,
+    or over each of `groups` equal parts of it; statistics in float32,
+    Y at X's dtype."""
+    x = ins["X"][0]
+    groups = int(attrs.get("groups", 1))
+    eps = float(attrs.get("epsilon", 1e-5))
+    xf = x.astype(_F32).reshape(x.shape[:-1] + (groups, -1))
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                       + eps)
+    y = y.reshape(x.shape)
+    if ins.get("Scale"):
+        y = y * ins["Scale"][0].astype(_F32)
+    return {"Y": y.astype(x.dtype)}
+
+
+@register_op("causal_conv1d")
+def _causal_conv1d(ins, attrs):
+    """Depthwise causal convolution along a sequence: X [B, S, C],
+    Filter [C, K]; Out[t] = sum_k Filter[:, k] X[t - (K-1) + k] + Bias,
+    positions before the sequence's start reading zero. `activation`
+    `silu` is applied where named. Summed in float32."""
+    x, w = ins["X"][0], ins["Filter"][0]
+    k = w.shape[1]
+    s = x.shape[1]
+    xp = jnp.pad(x.astype(_F32), ((0, 0), (k - 1, 0), (0, 0)))
+    wf = w.astype(_F32)
+    out = sum(xp[:, i:i + s, :] * wf[:, i] for i in range(k))
+    if ins.get("Bias"):
+        out = out + ins["Bias"][0].astype(_F32)
+    if attrs.get("activation", "") == "silu":
+        out = jax.nn.silu(out)
+    return {"Out": out.astype(x.dtype)}
+
+
+# ---------------------------------------------------------------------------
+# The chunked state-space scan (Mamba-2, arXiv:2405.21060, section 6)
+# ---------------------------------------------------------------------------
+
+def _ssd_group(xdt, cs, bm, cm, chunk):
+    """One B/C group in `jax.numpy`. xdt [B, S, Hg, P] (inputs times
+    step sizes), bm/cm [B, S, N], cs [B, S, Hg] float32 (cumulative
+    `dt * A` inside each chunk). Inside a chunk a masked,
+    decay-weighted (C B^T) product; between chunks a scan over the
+    chunk states [Hg, P, N], kept float32."""
+    b, s, hg, p = xdt.shape
+    n = bm.shape[-1]
+    nc, cd = s // chunk, xdt.dtype
+    x5 = jnp.transpose(xdt.reshape(b, nc, chunk, hg, p), (0, 1, 3, 2, 4))
+    b4, c4 = bm.reshape(b, nc, chunk, n), cm.reshape(b, nc, chunk, n)
+    cs4 = jnp.transpose(cs.reshape(b, nc, chunk, hg), (0, 1, 3, 2))
+    cb = jnp.einsum("bcln,bcsn->bcls", c4, b4,
+                    preferred_element_type=_F32)
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(
+        causal, cs4[..., :, None] - cs4[..., None, :], -jnp.inf))
+    y = jnp.einsum("bchls,bchsp->bchlp",
+                   (cb[:, :, None] * decay).astype(cd), x5,
+                   preferred_element_type=_F32)
+    last = cs4[..., -1:]                                 # [B, nc, Hg, 1]
+    xw = (x5.astype(_F32) * jnp.exp(last - cs4)[..., None]).astype(cd)
+    states = jnp.einsum("bcsn,bchsp->bchpn", b4, xw,
+                        preferred_element_type=_F32)
+
+    def step(prev, inp):
+        st, dec = inp
+        return prev * dec[..., None, None] + st, prev
+
+    _, before = lax.scan(
+        step, jnp.zeros((b, hg, p, n), _F32),
+        (jnp.moveaxis(states, 1, 0), jnp.moveaxis(jnp.exp(last[..., 0]),
+                                                  1, 0)))
+    before = jnp.moveaxis(before, 0, 1)                  # [B, nc, Hg, P, N]
+    y = y + jnp.exp(cs4)[..., None] * jnp.einsum(
+        "bcln,bchpn->bchlp", c4, before.astype(cd),
+        preferred_element_type=_F32)
+    return jnp.transpose(y, (0, 1, 3, 2, 4)).reshape(b, s, hg, p).astype(cd)
+
+
+def _by_group(fn, bm, cm, *by_head):
+    """`fn(*by_head_g, bm_g, cm_g)` for one group after another
+    (`lax.map`: one group's [L, L] intermediates live at a time);
+    `by_head` are [B, S, H, ...], `bm`/`cm` [B, S, G, N]. Every result
+    comes back with the group axis first."""
+    g = bm.shape[2]
+    split = lambda t: jnp.moveaxis(  # noqa: E731
+        t.reshape(t.shape[:2] + (g, -1) + t.shape[3:]), 2, 0)
+    return lax.map(lambda a: fn(*a), tuple(map(split, by_head)) + (
+        jnp.moveaxis(bm, 2, 0), jnp.moveaxis(cm, 2, 0)))
+
+
+def _join_groups(t):
+    """[G, B, S, Hg, ...] -> [B, S, G * Hg, ...]"""
+    t = jnp.moveaxis(t, 0, 2)
+    return t.reshape(t.shape[:2] + (-1,) + t.shape[4:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _ssd(xdt, bm, cm, cs, chunk, kernel):
+    if kernel:
+        from .pallas.ssd_scan import ssd_chunk_scan_fwd
+
+        return ssd_chunk_scan_fwd(xdt, bm, cm, cs, chunk)
+    return _join_groups(_by_group(
+        functools.partial(_ssd_group, chunk=chunk), bm, cm, xdt, cs))
+
+
+def _ssd_fwd(xdt, bm, cm, cs, chunk, kernel):
+    return _ssd(xdt, bm, cm, cs, chunk, kernel), (xdt, bm, cm, cs)
+
+
+def _ssd_bwd(chunk, kernel, res, dy):
+    """Group by group: the group's forward made again in `jax.numpy`
+    and transposed, so that no [L, L] value outlives its group."""
+    xdt, bm, cm, cs = res
+
+    def one(x_g, cs_g, dy_g, b_g, c_g):
+        return jax.vjp(functools.partial(_ssd_group, chunk=chunk),
+                       x_g, cs_g, b_g, c_g)[1](dy_g)
+
+    dx, dcs, db, dc = _by_group(one, bm, cm, xdt, cs, dy)
+    return (_join_groups(dx), jnp.moveaxis(db, 0, 2),
+            jnp.moveaxis(dc, 0, 2), _join_groups(dcs))
+
+
+_ssd.defvjp(_ssd_fwd, _ssd_bwd)
+
+
+def ssd_chunk_scan(x, dt, dt_bias, a_log, bm, cm, d, chunk, kernel=None):
+    """y_t = S_t C_t + D x_t with S_t = exp(dt_t A) S_{t-1} +
+    dt_t x_t B_t^T, a head at a time: x [B, S, H, P], dt [B, S, H]
+    (before `softplus`), bm/cm [B, S, G, N] (head j reads group
+    j // (H / G)), dt_bias, a_log, d [H]. The step sizes, the decays and
+    their sums are float32. `kernel` None takes the Pallas forward on a
+    TPU and `jax.numpy` elsewhere."""
+    b, s, h, p = x.shape
+    if kernel is None:
+        kernel = jax.default_backend() == "tpu"
+    step = jax.nn.softplus(dt.astype(_F32) + dt_bias.astype(_F32))
+    a = -jnp.exp(a_log.astype(_F32))
+    xdt = (x.astype(_F32) * step[..., None]).astype(x.dtype)
+    pad = -s % chunk
+    if pad:
+        # a padded position has a step of nought: it decays nothing
+        # and adds nothing
+        xdt, bm, cm, step = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (xdt, bm, cm, step))
+    da = (step * a).reshape(b, -1, chunk, h)
+    cs = jnp.cumsum(da, axis=2).reshape(b, -1, h)
+    y = _ssd(xdt, bm, cm, cs, chunk, bool(kernel))[:, :s]
+    return (y.astype(_F32)
+            + d.astype(_F32)[:, None] * x.astype(_F32)).astype(x.dtype)
+
+
+@register_op("ssd_chunk_scan")
+def _ssd_chunk_scan(ins, attrs):
+    """The chunked state-space scan of a Mamba-2 mixer
+    (`ssd_chunk_scan` above): X [B, S, H, P], Dt [B, S, H], B and C
+    [B, S, G, N], DtBias, ALog, D [H]; `chunk_size` positions a chunk
+    (a sequence that is no whole number of chunks is padded inside)."""
+    return {"Out": ssd_chunk_scan(
+        ins["X"][0], ins["Dt"][0], ins["DtBias"][0], ins["ALog"][0],
+        ins["B"][0], ins["C"][0], ins["D"][0],
+        int(attrs.get("chunk_size", 128)))}
+
+
+# ---------------------------------------------------------------------------
+# Routed experts
+# ---------------------------------------------------------------------------
+
+@register_op("moe_router")
+def _moe_router(ins, attrs):
+    """Scores s = sigmoid(X W) over ALL experts in float32; the `top_k`
+    largest of s + Bias (Bias steers the choice only and gets no
+    gradient); weights s_k / sum_k s_k where `norm_topk_prob`, times
+    `routed_scaling_factor`. X [..., H] -> TopkIdx [T, k] int32,
+    TopkWeight [T, k] float32, T the flattened leading axes."""
+    x, w = ins["X"][0], ins["W"][0]
+    k = int(attrs["top_k"])
+    x2 = x.reshape(-1, x.shape[-1]).astype(_F32)
+    s = jax.nn.sigmoid(jnp.dot(x2, w.astype(_F32),
+                               precision=lax.Precision.HIGHEST))
+    pick = s
+    if ins.get("Bias"):
+        pick = s + lax.stop_gradient(ins["Bias"][0].astype(_F32))
+    _, idx = lax.top_k(lax.stop_gradient(pick), k)
+    weight = jnp.take_along_axis(s, idx, axis=1)
+    if attrs.get("norm_topk_prob", True):
+        weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+    weight = weight * float(attrs.get("routed_scaling_factor", 1.0))
+    return {"TopkIdx": idx.astype(jnp.int32), "TopkWeight": weight}
+
+
+def _tile(n, most=1024):
+    """The largest multiple of 128 up to `most` that divides n, else
+    the largest up to `most` (the kernel masks the remainder)."""
+    for t in range(most, 0, -128):
+        if n % t == 0:
+            return t
+    return min(most, -(-n // 128) * 128)
+
+
+def _megablox():
+    """The Pallas grouped products of `jax.experimental` without their
+    `jit` wrappers: under a wrapper the compiled kernel is named after
+    it (`gmm`), here after the scope it is called in, so a trace names
+    the op type."""
+    import importlib
+
+    # the package re-exports a function under the module's own name
+    backend = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    return backend.gmm.__wrapped__, backend.tgmm.__wrapped__
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_product_tpu(lhs, rhs, sizes, transpose_rhs=False):
+    gmm, _ = _megablox()
+    k, n = rhs.shape[1:][::-1] if transpose_rhs else rhs.shape[1:]
+    with jax.named_scope("moe_experts_gmm"):
+        return gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype,
+                   tiling=(min(512, lhs.shape[0]), _tile(k), _tile(n)),
+                   transpose_rhs=transpose_rhs)
+
+
+def _grouped_product_tpu_fwd(lhs, rhs, sizes, transpose_rhs):
+    return (_grouped_product_tpu(lhs, rhs, sizes, transpose_rhs),
+            (lhs, rhs, sizes))
+
+
+def _grouped_product_tpu_bwd(transpose_rhs, res, ct):
+    lhs, rhs, sizes = res
+    _, tgmm = _megablox()
+    d_lhs = _grouped_product_tpu(ct, rhs, sizes, not transpose_rhs)
+    k, n = lhs.shape[1], ct.shape[1]
+    with jax.named_scope("moe_experts_tgmm"):
+        d_rhs = tgmm(lhs.swapaxes(0, 1), ct, sizes,
+                     preferred_element_type=rhs.dtype,
+                     tiling=(min(512, lhs.shape[0]), _tile(k), _tile(n)),
+                     num_actual_groups=rhs.shape[0])
+    return (d_lhs, d_rhs.swapaxes(1, 2) if transpose_rhs else d_rhs, None)
+
+
+_grouped_product_tpu.defvjp(_grouped_product_tpu_fwd,
+                            _grouped_product_tpu_bwd)
+
+
+def _grouped_product(lhs, rhs, sizes):
+    """Rows of `lhs` [R, K], sorted by group, times their group's
+    matrix of `rhs` [G, K, N]; rows past sum(sizes) are not computed and
+    hold anything. On a TPU the Pallas grouped product (work by the
+    rows there are), elsewhere `lax.ragged_dot`."""
+    if jax.default_backend() == "tpu":
+        return _grouped_product_tpu(lhs, rhs, sizes)
+    return lax.ragged_dot(lhs, rhs, sizes).astype(lhs.dtype)
+
+
+@jax.custom_vjp
+def _permute(x, index, inverse):
+    """x[index] for a permutation `index` handed over with its
+    inverse: a gather both ways, no scatter."""
+    return jnp.take(x, index, axis=0)
+
+
+def _permute_fwd(x, index, inverse):
+    return jnp.take(x, index, axis=0), inverse
+
+
+def _permute_bwd(inverse, ct):
+    return jnp.take(ct, inverse, axis=0), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+@jax.custom_vjp
+def _rows_of_pairs(x, order, place):
+    """Row r is the token of the pair sorted to r: x[order // k], k
+    pairs a token. Transposed, each token sums the rows of its k pairs,
+    found through `place` (pair -> row): again a gather."""
+    return jnp.take(x, order // (order.shape[0] // x.shape[0]), axis=0)
+
+
+def _rows_of_pairs_fwd(x, order, place):
+    return _rows_of_pairs(x, order, place), (place, x.shape[0])
+
+
+def _rows_of_pairs_bwd(res, ct):
+    place, t = res
+    return (jnp.take(ct, place, axis=0).reshape(t, -1, ct.shape[-1])
+            .sum(axis=1), None, None)
+
+
+_rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
+
+
+def _held_experts(x, idx, weight, w_up, w_down, held_start, activation):
+    """One block of tokens through the held experts: the block's
+    (token, expert) pairs sorted by expert (the pairs of experts held
+    elsewhere last), their tokens gathered, the two products run as
+    grouped products over the sorted rows, each pair's output back to
+    its token times its weight. Returns (out [T, H], pairs a held
+    expert [E_held])."""
+    t, h = x.shape
+    k = idx.shape[1]
+    n_held = w_up.shape[0]
+    local = idx.reshape(-1) - held_start                 # [T * k]
+    held = (local >= 0) & (local < n_held)
+    key = jnp.where(held, local, n_held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    place = jnp.argsort(order).astype(jnp.int32)         # pair -> row
+    sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
+                    axis=0).astype(jnp.int32)
+    live = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
+
+    rows = jnp.where(live, _rows_of_pairs(x, order, place), 0)
+    mid = get_op(activation).compute(
+        {"X": [_grouped_product(rows, w_up, sizes)]}, {})["Out"]
+    out = jnp.where(live, _grouped_product(mid, w_down, sizes), 0)
+    pairs = _permute(out, place, order).reshape(t, k, h)
+    scale = jnp.where(held.reshape(t, k), weight, 0.0).astype(_F32)
+    out = jnp.sum(pairs.astype(_F32) * scale[..., None], axis=1)
+    return out.astype(x.dtype), sizes
+
+
+#: tokens that go through the held experts at a time
+TOKEN_BLOCK = 4096
+
+
+def moe_experts(x, idx, weight, w_up, w_down, held_start=0,
+                activation="relu2"):
+    """The part of a routed layer's output that the experts
+    [held_start, held_start + w_up.shape[0]) give: every pair routed to
+    one of them is computed, none dropped, so the sorted rows are sized
+    for the worst routing (every pair held here: T * k rows), though
+    the products only visit the rows there are. `TOKEN_BLOCK` tokens go
+    through at a time, each block made again in the backward pass, so
+    that one block's rows are live at once and not the layer's.
+    Returns (out [T, H], pairs a held expert [E_held])."""
+    t = x.shape[0]
+    fn = functools.partial(_held_experts, held_start=held_start,
+                           activation=activation)
+    if t <= TOKEN_BLOCK or t % TOKEN_BLOCK:
+        return fn(x, idx, weight, w_up, w_down)
+    blocks = lambda v: v.reshape((-1, TOKEN_BLOCK) + v.shape[1:])  # noqa: E731
+    out, sizes = lax.map(
+        jax.checkpoint(lambda a: fn(*a, w_up, w_down)),
+        (blocks(x), blocks(idx), blocks(weight)))
+    return out.reshape(x.shape), jnp.sum(sizes, axis=0)
+
+
+@register_op("moe_experts")
+def _moe_experts(ins, attrs):
+    """The held experts' part of a routed layer (`moe_experts` above).
+    X [..., H]; TopkIdx, TopkWeight [T, k] from `moe_router`; WUp
+    [E_held, H, F], WDown [E_held, F, H]; `held_start` the first held
+    expert's number of `num_experts`, `activation` (a registered activation op) between
+    the two products. Out like X;
+    HeldPairs [1] (pairs computed here) and LoadMaxOverMean [1] (the
+    fullest held expert's pairs over the mean), float32 counters of the
+    step."""
+    x, idx = ins["X"][0], ins["TopkIdx"][0]
+    first, n_held = int(attrs.get("held_start", 0)), ins["WUp"][0].shape[0]
+    of = int(attrs.get("num_experts", first + n_held))
+    if first < 0 or first + n_held > of:
+        raise ValueError("moe_experts holds experts [%d, %d) of %d"
+                         % (first, first + n_held, of))
+    # said where the op is traced: at the build's shape inference and
+    # once a compile and layer
+    logging.getLogger(__name__).info(
+        "moe_experts holds experts [%d, %d) of %d, top-%d", first,
+        first + n_held, of, idx.shape[-1])
+    out, sizes = moe_experts(
+        x.reshape(-1, x.shape[-1]), idx, ins["TopkWeight"][0],
+        ins["WUp"][0], ins["WDown"][0], first,
+        attrs.get("activation", "relu2"))
+    load = lax.stop_gradient(sizes).astype(_F32)
+    return {"Out": out.reshape(x.shape),
+            "HeldPairs": jnp.sum(load).reshape(1),
+            "LoadMaxOverMean": (jnp.max(load) / jnp.maximum(
+                jnp.mean(load), 1.0)).reshape(1)}

@@ -66,6 +66,7 @@ _register_act("gelu", lambda x, a: jax.nn.gelu(
 _register_act("swish", lambda x, a: x * jax.nn.sigmoid(
     a.get("beta", 1.0) * x))
 _register_act("silu", lambda x, a: jax.nn.silu(x))
+_register_act("relu2", lambda x, a: jnp.square(jax.nn.relu(x)))
 _register_act("mish", lambda x, a: x * jnp.tanh(jax.nn.softplus(x)))
 _register_act("hard_sigmoid", lambda x, a: jnp.clip(
     a.get("slope", 0.2) * x + a.get("offset", 0.5), 0.0, 1.0))
@@ -538,8 +539,10 @@ def _pad2d(ins, attrs):
 
 @register_op("scaled_dot_product_attention", needs_rng=True)
 def _sdpa(ins, attrs):
-    """Fused attention. Q,K,V: [B, H, S, D]; optional KeyBias: [B, Sk]
-    additive key bias. On TPU with no attention-prob dropout this lowers
+    """Fused attention. Q: [B, H, S, D]; K, V: [B, Hkv, S, D] with H a
+    multiple of Hkv (grouped-query attention: query head j reads
+    key/value head j // (H / Hkv); the flash kernel reads them in
+    place); optional KeyBias: [B, Sk] additive key bias. On TPU with no attention-prob dropout this lowers
     to the Pallas flash kernel (paddle_tpu/ops/pallas/flash_attention.py);
     otherwise the XLA reference path (identical semantics) runs, with
     upscale_in_train dropout on the normalized probs.
@@ -591,6 +594,9 @@ def _sdpa(ins, attrs):
             return {"Out": _ref_attn(q, k, v, key_bias=bias,
                                      causal=causal, sm_scale=sm_scale)}
 
+    if k.shape[1] != q.shape[1]:
+        k, v = (jnp.repeat(t, q.shape[1] // k.shape[1], axis=1)
+                for t in (k, v))
     # Unfused path with dropout on probs (matches layers.softmax+dropout).
     # MXU note: keep the matmul inputs in their compute dtype (bf16 under
     # AMP) with f32 ACCUMULATION — an f32 upcast before the einsum would

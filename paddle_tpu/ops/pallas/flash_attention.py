@@ -63,6 +63,14 @@ def _dropout_mask(seed, bh, row0, col0, block_q, block_k, p_drop):
                      0.0).astype(jnp.float32)
 
 
+#: what a trace calls the three kernels (forward, dK/dV, dQ): the
+#: benchmark's `flash_attn_*` metrics match the op type and
+#: `tpu_custom_call` in an operation's text, so the names start with it
+KERNEL_NAMES = ("scaled_dot_product_attention_flash_fwd",
+                "scaled_dot_product_attention_flash_bwd_dkv",
+                "scaled_dot_product_attention_flash_bwd_dq")
+
+
 def _seed_spec():
     # scalar dropout seed rides in SMEM (full-array spec; one int32)
     return pl.BlockSpec(memory_space=pltpu.SMEM)
@@ -152,17 +160,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref, lse_ref,
         lse_ref[0] = m_row + jnp.log(l_safe)                # [bq, 1]
 
 
+def _kv_row(kv_rep):
+    """Query-head program b -> its row of K and V: `kv_rep` query heads
+    in a row share one key/value head (grouped-query attention), so K
+    and V are read where they lie and never repeated in memory."""
+    if kv_rep == 1:
+        return lambda b: b
+    return lambda b: b // kv_rep
+
+
 def _fwd_call(q, k, v, key_bias, seed, sm_scale, causal, block_q,
-              block_k, p_drop, interpret):
+              block_k, p_drop, interpret, kv_rep=1):
     BH, S, D = q.shape
     Sk = k.shape[1]
     nq, nk = S // block_q, Sk // block_k
     grid = (BH, nq, nk)
+    kv = _kv_row(kv_rep)
 
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+        pl.BlockSpec((1, block_k, D), lambda b, i, j: (kv(b), j, 0)),
+        pl.BlockSpec((1, block_k, D), lambda b, i, j: (kv(b), j, 0)),
     ]
     args = [q, k, v]
     has_bias = key_bias is not None
@@ -207,6 +225,7 @@ def _fwd_call(q, k, v, key_bias, seed, sm_scale, causal, block_q,
         ],
         interpret=interpret,
         compiler_params=_compiler_params(),
+        name=KERNEL_NAMES[0],
     )(*args)
     return o, lse
 
@@ -326,9 +345,10 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 
 def _bwd_call(q, k, v, key_bias, seed, o, lse, do, sm_scale, causal,
-              block_q, block_k, p_drop, interpret):
+              block_q, block_k, p_drop, interpret, kv_rep=1):
     BH, S, D = q.shape
     Sk = k.shape[1]
+    kv = _kv_row(kv_rep)
     nq, nk = S // block_q, Sk // block_k
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)                   # [BH, S, 1]
@@ -352,8 +372,8 @@ def _bwd_call(q, k, v, key_bias, seed, o, lse, do, sm_scale, causal,
         pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),  # do
         pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),  # lse
         pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),  # delta
-        pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),  # k
-        pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),  # v
+        pl.BlockSpec((1, block_k, D), lambda b, j, i: (kv(b), j, 0)),  # k
+        pl.BlockSpec((1, block_k, D), lambda b, j, i: (kv(b), j, 0)),  # v
     ]
     args = [q, do, lse, delta, k, v]
     if has_bias:
@@ -382,7 +402,12 @@ def _bwd_call(q, k, v, key_bias, seed, o, lse, do, sm_scale, causal,
         ],
         interpret=interpret,
         compiler_params=_compiler_params(),
+        name=KERNEL_NAMES[1],
     )(*args)
+    if kv_rep > 1:
+        # a key/value head's gradient is the sum over its query heads
+        dk, dv = (t.astype(jnp.float32).reshape(-1, kv_rep, Sk, D).sum(1)
+                  .astype(t.dtype) for t in (dk, dv))
 
     def dq_kernel(*refs):
         n_in = 6 + int(has_bias) + int(has_drop)
@@ -400,8 +425,8 @@ def _bwd_call(q, k, v, key_bias, seed, o, lse, do, sm_scale, causal,
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),  # do
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),  # lse
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),  # delta
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),  # k
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),  # v
+        pl.BlockSpec((1, block_k, D), lambda b, i, j: (kv(b), j, 0)),  # k
+        pl.BlockSpec((1, block_k, D), lambda b, i, j: (kv(b), j, 0)),  # v
     ]
     if has_bias:
         in_specs_q.append(
@@ -418,6 +443,7 @@ def _bwd_call(q, k, v, key_bias, seed, o, lse, do, sm_scale, causal,
         scratch_shapes=[_vmem((block_q, D), jnp.float32)],
         interpret=interpret,
         compiler_params=_compiler_params(),
+        name=KERNEL_NAMES[2],
     )(*args)
 
     return dq, dk, dv
@@ -437,30 +463,33 @@ def _pad_to(x, axis, mult, value=0.0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash_core(q, k, v, key_bias, seed, sm_scale, causal, block_q,
-                block_k, p_drop):
+                block_k, p_drop, kv_rep=1):
     """The int32 dropout `seed` is an ARGUMENT of the custom_vjp, with a
     None (zero) cotangent: a seed closed over instead is a tracer the
     vjp functions capture, and under `jax.checkpoint` inside a scan body
     (the long-context train step) that tracer escapes its trace."""
     o, _ = _fwd_call(q, k, v, key_bias, seed, sm_scale, causal,
-                     block_q, block_k, p_drop, _interpret_default())
+                     block_q, block_k, p_drop, _interpret_default(),
+                     kv_rep)
     return o
 
 
 def _flash_core_fwd(q, k, v, key_bias, seed, sm_scale, causal, block_q,
-                    block_k, p_drop):
+                    block_k, p_drop, kv_rep=1):
     o, lse = _fwd_call(q, k, v, key_bias, seed, sm_scale, causal,
-                       block_q, block_k, p_drop, _interpret_default())
+                       block_q, block_k, p_drop, _interpret_default(),
+                       kv_rep)
     return o, (q, k, v, key_bias, seed, o, lse)
 
 
-def _flash_core_bwd(sm_scale, causal, block_q, block_k, p_drop, res, do):
+def _flash_core_bwd(sm_scale, causal, block_q, block_k, p_drop, kv_rep,
+                    res, do):
     q, k, v, key_bias, seed, o, lse = res
     dq, dk, dv = _bwd_call(q, k, v, key_bias, seed, o, lse, do,
                            sm_scale, causal, block_q, block_k,
-                           p_drop, _interpret_default())
+                           p_drop, _interpret_default(), kv_rep)
     dbias = None if key_bias is None else jnp.zeros_like(key_bias)
     return dq, dk, dv, dbias, None
 
@@ -473,8 +502,9 @@ def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
                     dropout_seed=None):
     """Blockwise (flash) attention.
 
-    q: [B, H, Sq, D]; k, v: [B, H, Sk, D]; key_bias: optional [B, Sk]
-    additive bias on keys (e.g. `(mask - 1) * 1e4` padding bias;
+    q: [B, H, Sq, D]; k, v: [B, Hkv, Sk, D] with H a multiple of Hkv
+    (query head j reads key/value head j // (H / Hkv), in place);
+    key_bias: optional [B, Sk] additive bias on keys (e.g. `(mask - 1) * 1e4` padding bias;
     non-differentiable). Returns [B, H, Sq, D] in q.dtype.
 
     dropout_p > 0 applies upscale-in-train dropout to the normalized
@@ -484,7 +514,9 @@ def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
     int32 scalar (traced is fine), required when dropout_p > 0.
     """
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Hkv, Sk = k.shape[1], k.shape[2]
+    if H % Hkv:
+        raise ValueError("%d query heads on %d key/value heads" % (H, Hkv))
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
     dropout_p = float(dropout_p)
@@ -500,8 +532,8 @@ def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
     block_k = min(block_k, -(-Sk // 8) * 8)
 
     qf = _pad_to(q.reshape(B * H, Sq, D), 1, block_q)
-    kf = _pad_to(k.reshape(B * H, Sk, D), 1, block_k)
-    vf = _pad_to(v.reshape(B * H, Sk, D), 1, block_k)
+    kf = _pad_to(k.reshape(B * Hkv, Sk, D), 1, block_k)
+    vf = _pad_to(v.reshape(B * Hkv, Sk, D), 1, block_k)
 
     pad_k = (-Sk) % block_k
     bias = key_bias
@@ -514,7 +546,8 @@ def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
         bias = jnp.repeat(bias, H, axis=0)[:, None, :]
 
     o = _flash_core(qf, kf, vf, bias, seed, float(sm_scale),
-                    bool(causal), int(block_q), int(block_k), dropout_p)
+                    bool(causal), int(block_q), int(block_k), dropout_p,
+                    H // Hkv)
     return o[:, :Sq, :].reshape(B, H, Sq, D)
 
 
@@ -524,6 +557,9 @@ def reference_attention(q, k, v, key_bias=None, causal=False,
     D = q.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
+    if k.shape[1] != q.shape[1]:
+        k, v = (jnp.repeat(t, q.shape[1] // k.shape[1], axis=1)
+                for t in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * sm_scale
     if key_bias is not None:

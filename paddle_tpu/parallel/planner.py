@@ -26,7 +26,24 @@ from typing import Dict, NamedTuple, Optional
 
 from . import env as penv
 
-__all__ = ["ParallelPlan", "plan_parallel", "param_tp_dims"]
+__all__ = ["ParallelPlan", "plan_parallel", "param_tp_dims",
+           "experts_held"]
+
+
+def experts_held(num_experts: int, ep_size: int, ep_rank: int = 0):
+    """(first expert, how many) that rank `ep_rank` of an
+    expert-parallel group of `ep_size` holds: contiguous equal shares.
+    A routed layer built with this range (`layers.moe_experts`) routes
+    over all `num_experts` and computes its own experts' part; the sum
+    over the group's ranks is the layer. On one chip of the group the
+    layer runs as it is, without the exchange: nothing stands in for
+    the other ranks (the all-to-all over several chips is not built
+    yet: ROADMAP R2)."""
+    if num_experts % ep_size or not 0 <= ep_rank < ep_size:
+        raise ValueError("%d experts over %d ranks, rank %d"
+                         % (num_experts, ep_size, ep_rank))
+    share = num_experts // ep_size
+    return ep_rank * share, share
 
 
 class ParallelPlan(NamedTuple):

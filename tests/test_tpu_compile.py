@@ -90,6 +90,80 @@ def test_flash_causal(one_chip, mosaic):
              _QKV)
 
 
+def _kernels_are_called(compiled, names):
+    """What a trace will call each Mosaic kernel of the program (the
+    custom calls' instruction names: the kernel's `name`, wrapped in
+    the transformations it was traced under, as `jvp_<name>_`): every
+    kernel carries one of `names`, and each of `names` is there."""
+    called = {line.strip().split(" = ")[0]
+              for line in compiled.as_text().splitlines()
+              if "tpu_custom_call" in line}
+    assert called and all(any(n in c for n in names) for c in called), called
+    assert all(any(n in c for c in called) for n in names), called
+
+
+def test_flash_grouped_query_at_8k_and_what_a_trace_calls_it(one_chip,
+                                                             mosaic):
+    """The hybrid decoder's attention layer: 32 query heads on 2
+    key/value heads of 128 at 8,192, causal, K and V in place. The
+    kernels' names are what the benchmark's `flash_attn_*` metrics
+    match."""
+    def loss(q, k, v):
+        o = fa.flash_attention(q, k, v, causal=True)
+        return jnp.sum(jnp.sin(o.astype(jnp.float32)))
+
+    q = ((2, 32, 8192, 128), jnp.bfloat16)
+    kv = ((2, 2, 8192, 128), jnp.bfloat16)
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q,
+                        kv, kv)
+    _kernels_are_called(compiled, fa.KERNEL_NAMES)
+
+
+def test_ssd_chunk_scan_at_the_published_widths(one_chip, monkeypatch):
+    """Mamba-2's scan as Nemotron-3-Nano runs it: 64 heads of 64, 8
+    groups of state 128, chunks of 128, two sequences of 8,192. The
+    forward is the Pallas kernel, named for the benchmark's
+    `ssd_scan_*` metrics; the backward is `jax.numpy`."""
+    ssd = importlib.import_module("paddle_tpu.ops.pallas.ssd_scan")
+    hybrid = importlib.import_module("paddle_tpu.ops.hybrid_ops")
+    monkeypatch.setattr(ssd, "_interpret_default", lambda: False)
+
+    def loss(x, dt, dt_bias, a_log, b, c, d):
+        y = hybrid.ssd_chunk_scan(x, dt, dt_bias, a_log, b, c, d, 128,
+                                  kernel=True)
+        return jnp.sum(jnp.sin(y.astype(jnp.float32)))
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    compiled = _compile(
+        jax.grad(loss, argnums=tuple(range(7))), one_chip,
+        ((2, 8192, 64, 64), bf), ((2, 8192, 64), bf), ((64,), f32),
+        ((64,), f32), ((2, 8192, 8, 128), bf), ((2, 8192, 8, 128), bf),
+        ((64,), f32))
+    _kernels_are_called(compiled, ["ssd_chunk_scan_fwd"])
+
+
+def test_held_experts_grouped_products_at_the_published_widths(
+        one_chip, monkeypatch):
+    """8 held experts of 2688 x 1856 over 16,384 tokens routed top-6:
+    the grouped products compile for the chip (1856 is no multiple of
+    128: the kernel masks the remainder) under the names the
+    benchmark's `moe_experts_*` metrics match."""
+    hybrid = importlib.import_module("paddle_tpu.ops.hybrid_ops")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(x, idx, w, w_up, w_down):
+        out, _ = hybrid.moe_experts(x, idx, w, w_up, w_down)
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+
+    bf = jnp.bfloat16
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 2, 3, 4)), one_chip,
+        ((16384, 2688), bf), ((16384, 6), jnp.int32),
+        ((16384, 6), jnp.float32), ((8, 2688, 1856), bf),
+        ((8, 1856, 2688), bf))
+    _kernels_are_called(compiled, ["moe_experts_gmm", "moe_experts_tgmm"])
+
+
 def _rpa_shapes(seqs, q_rows, hq, hkv, d, pages, page, per_seq, dtype,
                 scales=False):
     shapes = [((seqs, q_rows, hq, d),
