@@ -568,10 +568,15 @@ def _sdpa(ins, attrs):
     drop_active = (not is_test) and p_drop > 0.0
 
     if mask is None:
-        # Pallas flash only where its O(S) memory matters: below the
-        # threshold XLA's fused softmax-attention is faster on v5e
-        # (FLAGS_flash_attention_min_seq; measured: flash loses up to at
-        # least S=2048 forward, but avoids the S^2 score buffer).
+        # The Pallas flash kernels from FLAGS_flash_attention_min_seq
+        # keys up (4,096): no S^2 score buffer, and since PR 28 (operands
+        # in their own dtype, 512 x 512 tiles) the faster path too.
+        # Measured on a v5e (tools/attn_ab.py, B2 H12 D64 bfloat16,
+        # forward + backward; PERF.md section 6, PR 28): 5.5 ms against
+        # XLA's 20.2 at 4,096, 1.5 against 5.1 at 2,048, even from
+        # 1,024 down (0.5 ms either way). The flag was not moved there:
+        # below it the unfused path at the end of this op runs, which
+        # the bert-base-s128 cell times (ROADMAP S8a).
         # Dropout-active training takes this path too: the kernel
         # applies prob-dropout in-VMEM (mask regenerated in backward
         # from the seed — no S^2 mask buffer in HBM).

@@ -124,9 +124,11 @@ _FLAGS: Dict[str, object] = {
     # the device count or the mesh falls back to flat with a warning.
     # See paddle_tpu/parallel/README.md "Tensor parallelism".
     "FLAGS_tpu_model_parallel": 0,
-    # Pallas flash attention engages only at/above this key length: the
-    # XLA fused path wins below it (measured on v5e: flash 13.6ms vs XLA
-    # 9.8ms even at S=2048 fwd); flash's win is O(S) memory at long seq.
+    # Pallas flash attention engages only at/above this key length.
+    # Since PR 28 the kernels win from 1,024 keys up on a v5e
+    # (tools/attn_ab.py: PERF.md section 6); the value was left where it
+    # was, a change of bert-base-s128's program being a PR of its own
+    # (ROADMAP S8a).
     "FLAGS_flash_attention_min_seq": 4096,
     "FLAGS_tpu_compile_cache_size": 128,
     # After the first data-parallel step of a program, pre-compile this

@@ -114,6 +114,41 @@ def _launch_env():
     return cpu_child_env()
 
 
+def _lost_machines_src(log_dir, world, lost):
+    """Source of the attempt-0 tail of a launch worker: ranks `lost`
+    die for good, the others wait to be torn down. The supervisor reads
+    "who died on their own" off ONE poll of its cohort and kills the
+    survivors at once, so on a busy machine a rank that starts late is
+    torn down before its first line, and two deaths a few milliseconds
+    apart are two transitions. Hence: the first lost rank waits until
+    every rank's log holds its attempt-0 line, then kills the other
+    lost ranks (by the pids they left beside the logs) and exits within
+    the same few microseconds."""
+    return (
+        "if attempt == 0:\n"
+        "    import signal\n"
+        "    lost, log_dir = %r, %r\n"
+        "    pid = os.path.join(log_dir, 'lost.%%d.pid')\n"
+        "    if tid in lost:\n"
+        "        with open(pid %% tid + '.tmp', 'w') as f:\n"
+        "            f.write(str(os.getpid()))\n"
+        "        os.replace(pid %% tid + '.tmp', pid %% tid)\n"
+        "    if tid == lost[0]:\n"
+        "        def up(t):\n"
+        "            log = os.path.join(log_dir, 'workerlog.%%d' %% t)\n"
+        "            return os.path.exists(log) and \\\n"
+        "                'ATTEMPT 0' in open(log).read()\n"
+        "        deadline = time.time() + 60\n"
+        "        while time.time() < deadline and not (\n"
+        "                all(up(t) for t in range(%d))\n"
+        "                and all(os.path.exists(pid %% t) for t in lost)):\n"
+        "            time.sleep(0.01)\n"
+        "        for t in lost[1:]:\n"
+        "            os.kill(int(open(pid %% t).read()), signal.SIGKILL)\n"
+        "        os._exit(7)  # the lost machine\n"
+        "    time.sleep(30)\n" % (tuple(lost), log_dir, world))
+
+
 def _loss_lines(text):
     return [ln for ln in text.splitlines() if ln.startswith("LOSS")]
 
@@ -486,10 +521,7 @@ def test_launch_elastic_shrink_drops_dead_rank_and_reassigns(tmp_path):
         "      'RANK', tid, 'ATTEMPT', attempt,\n"
         "      'EPS', os.environ['PADDLE_TRAINER_ENDPOINTS'],\n"
         "      flush=True)\n"
-        "if attempt == 0:\n"
-        "    if tid == 1:\n"
-        "        sys.exit(7)  # the lost machine\n"
-        "    time.sleep(30)\n")
+        + _lost_machines_src(str(tmp_path / "logs"), 3, [1]))
     log_dir = str(tmp_path / "logs")
     proc = _sp.run(
         [_sys.executable, "-m", "paddle_tpu.distributed.launch",
@@ -545,6 +577,8 @@ def test_launch_pod_aware_shrink_flat_fallback_and_rectangular(
     def run(kill_tids, ports):
         script = tmp_path / ("worker_%s.py" % "_".join(
             str(t) for t in kill_tids))
+        log_dir = str(tmp_path / ("logs_%s" % "_".join(
+            str(t) for t in kill_tids)))
         script.write_text(
             "import os, sys, time\n"
             "tid = int(os.environ['PADDLE_TRAINER_ID'])\n"
@@ -554,13 +588,7 @@ def test_launch_pod_aware_shrink_flat_fallback_and_rectangular(
             "      'PODS', os.environ.get('PADDLE_NUM_PODS', '-'),\n"
             "      'POD', os.environ.get('PADDLE_POD_ID', '-'),\n"
             "      flush=True)\n"
-            "if attempt == 0:\n"
-            "    if tid in (%s,):\n"
-            "        sys.exit(7)\n"
-            "    time.sleep(30)\n"
-            % ",".join(str(t) for t in kill_tids))
-        log_dir = str(tmp_path / ("logs_%s" % "_".join(
-            str(t) for t in kill_tids)))
+            + _lost_machines_src(log_dir, len(ports), kill_tids))
         proc = _sp.run(
             [_sys.executable, "-m", "paddle_tpu.distributed.launch",
              "--hosts", ",".join("127.0.0.1:%d" % p for p in ports),

@@ -101,22 +101,36 @@ def test_bfloat16_close():
 # the identical mask.
 # ---------------------------------------------------------------------------
 
-def _host_dropout_mask(seed, BH, S, Sk, p):
-    """Numpy replica of flash_attention._dropout_mask over the full
-    [BH, S, Sk] lattice (blocking-independent by construction)."""
-    r = np.arange(S, dtype=np.uint32)[None, :, None]
-    c = np.arange(Sk, dtype=np.uint32)[None, None, :]
-    b = np.arange(BH, dtype=np.uint32)[:, None, None]
+def _fmix(x):
+    """murmur3's finalizer on uint32."""
+    x = x ^ (x >> np.uint32(16))
+    x = x * np.uint32(0x85EBCA6B)
+    x = x ^ (x >> np.uint32(13))
+    x = x * np.uint32(0xC2B2AE35)
+    return x ^ (x >> np.uint32(16))
+
+
+def _host_keep(seed, BH, S, Sk, p):
+    """Numpy replica of the kernels' `_dropout_keep` over the full
+    [BH, S, Sk] lattice (blocking-independent by construction): a hash
+    a row and batch * head, a hash a column, one xor and one wrapping
+    product an element, compared as int32."""
+    r = np.arange(S, dtype=np.uint32)[None, :]
+    c = np.arange(Sk, dtype=np.uint32)
+    b = np.arange(BH, dtype=np.uint32)[:, None]
     with np.errstate(over="ignore"):
-        x = (r * np.uint32(0x9E3779B1)) ^ (c * np.uint32(0x85EBCA77))
-        x = x ^ (b * np.uint32(0xC2B2AE3D)) ^ np.uint32(seed)
-        x = x ^ (x >> np.uint32(16))
-        x = x * np.uint32(0x85EBCA6B)
-        x = x ^ (x >> np.uint32(13))
-        x = x * np.uint32(0xC2B2AE35)
-        x = x ^ (x >> np.uint32(16))
-    thresh = np.uint32(min(int(p * 4294967296.0), 0xFFFFFFFF))
-    return np.where(x >= thresh, 1.0 / (1.0 - p), 0.0).astype(np.float32)
+        rows = _fmix((r * np.uint32(0x9E3779B1))
+                     ^ (b * np.uint32(0xC2B2AE3D)) ^ np.uint32(seed))
+        cols = _fmix((c * np.uint32(0x85EBCA77)) ^ np.uint32(seed)
+                     ^ np.uint32(0x27D4EB2F))
+        x = (rows[:, :, None] ^ cols[None, None, :]) * np.uint32(0x9E3779B1)
+    thresh = np.int32(min(int(p * 4294967296.0), 0xFFFFFFFF) - 2 ** 31)
+    return x.view(np.int32) >= thresh
+
+
+def _host_dropout_mask(seed, BH, S, Sk, p):
+    return np.where(_host_keep(seed, BH, S, Sk, p), 1.0 / (1.0 - p),
+                    0.0).astype(np.float32)
 
 
 def _masked_reference(q, k, v, mask_bhsk, sm_scale=None):
@@ -249,3 +263,209 @@ def test_decode_q_len_1_unaligned_context():
     out = flash_attention(q, k, v, block_k=64)
     ref = reference_attention(q, k, v)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+# -- PR 28: operands in their own dtype, blocks from the shapes -------------
+
+def _loss_and_grads(fn, q, k, v, w, **kw):
+    def loss(q, k, v):
+        o = fn(q, k, v, **kw)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    (_, o), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                       has_aux=True)(q, k, v)
+    return (o,) + tuple(grads)
+
+
+@pytest.mark.parametrize("grouped", [False, True])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("with_bias", [False, True])
+def test_bfloat16_forward_and_grads_against_float32_reference(
+        with_bias, causal, grouped):
+    """bfloat16 operands go to the products as they are (float32
+    accumulation and statistics; P and dS rounded once): forward and all
+    three gradients against the reference on the float32 upcast of the
+    same inputs."""
+    rng = np.random.default_rng(20)
+    B, H, S, D = 2, 4, 256, 64
+    q, k, v = (t.astype(jnp.bfloat16)
+               for t in _rand_qkv(rng, B, H, S, S, D))
+    if grouped:
+        k, v = k[:, :2], v[:, :2]
+    w = jnp.asarray(rng.standard_normal((B, H, S, D)).astype("float32"))
+    bias = None
+    if with_bias:
+        mask = np.ones((B, S), np.float32)
+        mask[0, 200:] = 0.0
+        bias = jnp.asarray((mask - 1.0) * 1e4)
+    got = _loss_and_grads(flash_attention, q, k, v, w, key_bias=bias,
+                          causal=causal)
+    want = _loss_and_grads(
+        reference_attention, *(t.astype(jnp.float32) for t in (q, k, v)),
+        w, key_bias=bias, causal=causal)
+    assert all(g.dtype == jnp.bfloat16 for g in got)
+    for g, r, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(g, np.float32), r,
+                                   atol=3e-2, rtol=3e-2,
+                                   err_msg="%s mismatch" % name)
+
+
+@pytest.fixture(scope="module")
+def causal_in_blocks_of_16():
+    rng = np.random.default_rng(21)
+    q, k, v = _rand_qkv(rng, 1, 2, 512, 512, 64)
+    k, v = k[:, :1], v[:, :1]       # grouped: two query heads on one
+    w = jnp.asarray(rng.standard_normal((1, 2, 512, 64)).astype("float32"))
+    return (q, k, v, w), _loss_and_grads(
+        flash_attention, q, k, v, w, causal=True, block_q=16, block_k=16)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(128, 256), (256, 128),
+                                             (512, 256), (None, None)])
+def test_causal_blocks_of_several_sub_blocks(monkeypatch, block_q, block_k,
+                                             causal_in_blocks_of_16):
+    """block_q != block_k and blocks of two sub-blocks (the cap on a
+    sub-block steered down to 128 rows): steps above the diagonal name
+    the resident block and run nothing, the mask runs only where the
+    diagonal crosses, and all of it gives what blocks of 16 give."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "_SUB_ROWS", 128)
+    if block_q is None:     # the rule's own choice, with smaller caps
+        monkeypatch.setattr(fa, "_RESIDENT_ROWS", 128)
+        monkeypatch.setattr(fa, "_STREAMED_BYTES", 256 * 128 * 4)
+        blocks = fa.block_rule(512, 512, 64, "float32", True)
+        assert (blocks.block_q, blocks.block_k, blocks.sub_k) == \
+            (128, 256, 128)
+        assert (blocks.block_k_dkv, blocks.block_q_dkv, blocks.sub_q) == \
+            (128, 256, 128)
+    (q, k, v, w), want = causal_in_blocks_of_16
+    got = _loss_and_grads(flash_attention, q, k, v, w, causal=True,
+                          block_q=block_q, block_k=block_k)
+    for g, r, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(g, r, atol=3e-5, rtol=3e-5,
+                                   err_msg="%s mismatch" % name)
+
+
+def test_many_sub_blocks_are_walked_in_groups(monkeypatch):
+    """More sub-blocks than are unrolled into one line of code: a loop
+    over groups and then the rest (5 = 2 groups of 2 and 1), forward and
+    both backward kernels, with the key bias and the mask drawn."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "_SUB_ROWS", 128)
+    monkeypatch.setattr(fa, "_UNROLL", 2)
+    rng = np.random.default_rng(23)
+    q, k, v = _rand_qkv(rng, 1, 2, 640, 640, 64)
+    w = jnp.asarray(rng.standard_normal((1, 2, 640, 64)).astype("float32"))
+    pad = np.ones((1, 640), np.float32)
+    pad[0, 600:] = 0.0
+    kw = dict(key_bias=jnp.asarray((pad - 1.0) * 1e4), dropout_p=0.1,
+              dropout_seed=jnp.int32(5))
+    got = _loss_and_grads(flash_attention, q, k, v, w, block_q=640,
+                          block_k=640, **kw)
+    want = _loss_and_grads(flash_attention, q, k, v, w, block_q=128,
+                           block_k=128, **kw)
+    for g, r, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(g, r, atol=3e-5, rtol=3e-5,
+                                   err_msg="%s mismatch" % name)
+
+
+@pytest.fixture(scope="module")
+def dropout_in_one_block():
+    rng = np.random.default_rng(22)
+    q, k, v = _rand_qkv(rng, 1, 2, 256, 256, 64)
+    w = jnp.asarray(rng.standard_normal((1, 2, 256, 64)).astype("float32"))
+    return (q, k, v, w), _loss_and_grads(
+        flash_attention, q, k, v, w, dropout_p=0.2,
+        dropout_seed=jnp.int32(99))
+
+
+@pytest.mark.parametrize("block_q,block_k", [(64, 64), (128, 32),
+                                             (32, 256)])
+def test_dropout_mask_is_one_in_all_three_kernels_at_any_blocks(
+        block_q, block_k, dropout_in_one_block):
+    """The forward, the dK/dV (transposed tile) and the dQ kernels
+    regenerate one mask from (seed, coordinates), whatever the blocks."""
+    (q, k, v, w), want = dropout_in_one_block
+    got = _loss_and_grads(flash_attention, q, k, v, w, dropout_p=0.2,
+                          dropout_seed=jnp.int32(99), block_q=block_q,
+                          block_k=block_k)
+    for g, r, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(g, r, atol=3e-5, rtol=3e-5,
+                                   err_msg="%s mismatch" % name)
+
+
+def test_dropout_mask_is_even_along_rows_columns_and_heads():
+    """One xor and one product an element over a hashed row term and a
+    hashed column term: the rate holds on every row, column and head,
+    and neighbours along either axis are kept independently."""
+    p = 0.3
+    keep = _host_keep(4242, 4, 512, 512, p)
+    for axis in ((1, 2), (0, 2), (0, 1)):       # per head, row, column
+        rate = 1.0 - keep.mean(axis=axis)
+        assert np.abs(rate - p).max() < 0.06, (axis, rate.min(), rate.max())
+    drop = (~keep).astype(np.float64) - p
+    for a, b in ((drop[:, :-1], drop[:, 1:]),          # next row
+                 (drop[:, :, :-1], drop[:, :, 1:]),    # next column
+                 (drop[:-1], drop[1:])):               # next head
+        assert abs((a * b).mean()) / (p * (1 - p)) < 0.01
+    # four corners of a rectangle: what a bare xor of two terms would tie
+    corners = (keep[:, :-1, :-1] ^ keep[:, 1:, :-1] ^ keep[:, :-1, 1:]
+               ^ keep[:, 1:, 1:])
+    q = 1.0 - p
+    odd = 4 * q * p ** 3 + 4 * p * q ** 3       # independent corners
+    assert abs(corners.mean() - odd) < 0.01
+
+
+_CELLS = [
+    # Sq, Sk, D, dtype, causal, dropout
+    (4096, 4096, 64, "bfloat16", False, True),     # bert-base-s4096
+    (8192, 8192, 128, "bfloat16", True, False),    # nemotron3-nano-...-s8192
+    (100, 100, 64, "float32", False, False),
+    (1, 256, 64, "float32", False, False),
+    (4100, 4100, 64, "bfloat16", True, True),
+    (600, 1000, 128, "float32", True, False),
+    (2048, 2048, 256, "float32", False, True),
+]
+
+
+@pytest.mark.parametrize("sq,sk,d,dtype,causal,dropout", _CELLS)
+def test_block_rule_is_a_pure_function_of_shapes_and_dtype(
+        monkeypatch, sq, sk, d, dtype, causal, dropout):
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    first = fa.block_rule(sq, sk, d, dtype, causal, dropout)
+    nq, nk = fa._padded(sq), fa._padded(sk)
+    assert nq >= sq and nk >= sk and nq - sq < 128 and nk - sk < 128
+    for block, n in ((first.block_q, nq), (first.block_q_dkv, nq),
+                     (first.block_k, nk), (first.block_k_dkv, nk),
+                     (first.sub_k, first.block_k),
+                     (first.sub_q, first.block_q_dkv)):
+        assert block > 0 and block % 8 == 0 and n % block == 0
+    assert 0 < first.vmem_bytes <= fa._VMEM_BUDGET
+    # nothing but its arguments: no backend, device, flag or environment
+    def refuse(*a, **kw):
+        raise AssertionError("the block rule looked outside its arguments")
+
+    import os
+
+    from paddle_tpu.utils import flags
+    for owner, name in ((jax, "default_backend"), (jax, "devices"),
+                        (flags, "get_flags"), (flags, "get_flag"),
+                        (os, "getenv")):
+        monkeypatch.setattr(owner, name, refuse)
+    assert fa.block_rule(sq, sk, d, dtype, causal, dropout) == first
+
+
+def test_block_rule_on_the_two_cells():
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    s4096 = fa.block_rule(*_CELLS[0])
+    nemotron = fa.block_rule(*_CELLS[1])
+    # the grid of a forward call: 96 x 32 x 32 and 64 x 64 x 64 before
+    assert 96 * (4096 // s4096.block_q) * (4096 // s4096.block_k) <= 6144
+    assert 64 * (8192 // nemotron.block_q) * (8192 // nemotron.block_k) \
+        <= 16384
+    for blocks in (s4096, nemotron):
+        assert min(blocks[:6]) >= 256 and blocks.sub_k % 128 == 0

@@ -109,3 +109,51 @@ def test_flash_with_dropout_trains_inside_a_rematted_scan(monkeypatch):
         set_flags({"FLAGS_flash_attention_min_seq": old})
     assert calls, "the train step did not dispatch to the flash kernel"
     assert np.all(np.isfinite(losses))
+
+
+def test_engagement_record_is_logged_once_a_signature(monkeypatch, caplog):
+    """Where the op hands a call to the kernels, the kernels' module
+    says once per distinct signature what it was handed and how it steps
+    through it: shapes, operand dtype, blocks, grid steps and, when
+    causal, the share of them that are live."""
+    import importlib
+    import logging
+    import re
+
+    import paddle_tpu.ops.nn_ops  # noqa: F401 - op registered
+    from paddle_tpu.utils.flags import get_flag, set_flags
+
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "_interpret_default", lambda: True)
+    monkeypatch.setattr(fa, "_said", set())
+    monkeypatch.setattr(fa, "_RESIDENT_ROWS", 128)   # 3 x 3 blocks at 384
+    monkeypatch.setattr(fa, "_STREAMED_BYTES", 128 * 64 * 2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    old = get_flag("FLAGS_flash_attention_min_seq")
+    set_flags({"FLAGS_flash_attention_min_seq": 256})
+
+    def run(causal, dtype=jnp.bfloat16):
+        q = jnp.ones((1, 2, 384, 64), dtype)
+        return ops_lib.run_op(
+            "scaled_dot_product_attention",
+            {"Q": [q], "K": [q[:, :1]], "V": [q[:, :1]]},
+            {"causal": causal, "is_test": True, "_rng_key": make_key(0)})
+
+    try:
+        with caplog.at_level(logging.INFO, logger=fa.__name__):
+            run(True), run(True), run(False), run(True, jnp.float32)
+    finally:
+        set_flags({"FLAGS_flash_attention_min_seq": old})
+    said = [r.getMessage() for r in caplog.records
+            if r.name == fa.__name__]
+    assert len(said) == 3, said          # the repeated call said nothing
+    causal, plain, wide = said
+    blocks = fa.block_rule(384, 384, 64, jnp.bfloat16, True)
+    assert (blocks.block_q, blocks.block_k) == (128, 128)
+    assert "q [2, 384, 64] on k/v [1, 384, 64] in bfloat16" in causal
+    assert "blocks 128 x 128 in sub-blocks of 128" in causal
+    assert "18 grid steps a forward call" in causal
+    assert "66.7 % of them live (causal)" in causal    # 6 of 9 blocks
+    assert "live" not in plain and "bfloat16" in plain
+    assert "float32" in wide
+    assert re.search(r"\d+ bytes of VMEM", causal)

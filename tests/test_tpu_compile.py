@@ -90,6 +90,25 @@ def test_flash_causal(one_chip, mosaic):
              _QKV)
 
 
+def test_flash_at_16k_walks_its_sub_blocks_in_groups(one_chip, mosaic):
+    """Past 4,096 keys the streamed block holds more sub-blocks than are
+    unrolled into one line of code (8,192 rows of 64: two groups of
+    eight): the loop over groups slices K, V, Q, dO and the key bias at
+    offsets known only at run time."""
+    blocks = fa.block_rule(16384, 16384, 64, jnp.bfloat16, False, True)
+    assert blocks.block_k // blocks.sub_k > fa._UNROLL
+    assert blocks.block_q_dkv // blocks.sub_q > fa._UNROLL
+
+    def loss(q, k, v, bias, seed):
+        o = fa.flash_attention(q, k, v, key_bias=bias, dropout_p=0.1,
+                               dropout_seed=seed)
+        return jnp.sum(o.astype(jnp.float32))
+
+    qkv = ((1, 2, 16384, 64), jnp.bfloat16)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv,
+             ((1, 16384), jnp.float32), ((1,), jnp.int32))
+
+
 def _kernels_are_called(compiled, names):
     """What a trace will call each Mosaic kernel of the program (the
     custom calls' instruction names: the kernel's `name`, wrapped in

@@ -15,10 +15,13 @@ dropout settings — paste that into FLAGS_flash_attention_min_seq
 from __future__ import annotations
 
 import json
+import os
 import sys
 import time
 
 import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def _bench_one(fn, args, iters=20, warmup=3):
