@@ -24,11 +24,8 @@ Six checkers (see README.md in this directory for the full catalog):
    flagged (sharding.py).
 6. ``dtype-contract`` — declared vs computed out dtype/shape, silent
    fp64 promotions, redundant AMP cast round-trips, plus quantized
-   programs: fp8 delayed-scaling state ownership (reads/writes outside
-   the backward op's Fp8ScaleState slots and save/load = ERROR), fp8
-   white-list sites missing wired scale state = ERROR, and slim/PTQ
-   fake-quant ops missing their calibrated scale input = ERROR
-   (contracts.py).
+   programs: slim/PTQ fake-quant ops missing their calibrated scale
+   input = ERROR (contracts.py).
 
 Surfaces: ``tools/tpu_lint.py`` (CLI, JSON artifact, --fail-on),
 ``FLAGS_tpu_static_checks={off,warn,error}`` (Executor compile-time
@@ -137,8 +134,7 @@ def run_static_checks(program, feed_names=None, fetch_names=None,
                                         fetch_names=fetch_names)
     if "dtype-contract" in sel:
         findings += check_dtype_shape_contracts(program)
-        # quantized programs: fp8 scale-state ownership + site wiring,
-        # PTQ calibrated-scale presence (ERROR severity — wrong math,
-        # not drifted declarations)
+        # quantized programs: PTQ calibrated-scale presence (ERROR
+        # severity — wrong math, not drifted declarations)
         findings += check_quantization_contracts(program)
     return sort_findings(findings)

@@ -40,27 +40,15 @@ reader, and an up-cast to fp32 feeding ONLY white-list ops (the policy
 re-casts those inputs straight back down).
 
 Quantized programs (`check_quantization_contracts`, run as part of the
-same checker): fp8 delayed-scaling state vars (the ``@FP8_SCALE`` /
-``@FP8_AMAX_HIST`` persistables the backward op threads through its
-``Fp8ScaleState`` slots) are owned by the scaling recipe — any OTHER
-op reading or writing one is an **error** (a foreign read observes a
-scale mid-update; a foreign write corrupts the amax window). And every
-fp8-white-list op's float input must have its scale state wired — an
-fp8 cast site without a delayed scale is an **error** (it would
-quantize at an uncalibrated or stale scale). The slim/PTQ fake-quant
-ops get the same treatment: scale-consuming quantizers missing their
-calibrated scale input are errors.
+same checker): a slim/PTQ fake-quant op that consumes a scale and is
+missing its calibrated scale input is an **error**, and so is a PTQ
+inference quantizer under ``is_test`` without its ``static_scale``.
 """
 from __future__ import annotations
 
 from typing import List
 
 from .findings import Finding
-
-#: ops allowed to touch fp8 delayed-scaling state vars: the backward op
-#: (through its Fp8ScaleState slots) and checkpoint persistence.
-_FP8_STATE_SANCTIONED = {"backward", "save", "load", "save_combine",
-                         "load_combine"}
 
 #: slim/PTQ quantizer ops -> the input slot(s) carrying their
 #: calibrated scale; empty slot = uncalibrated quantization.
@@ -236,71 +224,11 @@ def check_dtype_shape_contracts(program) -> List[Finding]:
 
 def check_quantization_contracts(program) -> List[Finding]:
     """Quantization-tier contracts (part of the dtype-contract
-    checker): fp8 scale-state ownership, fp8 site wiring completeness,
-    and calibrated-scale presence on the slim/PTQ fake-quant ops. See
-    the module docstring; these are ERRORS, not warnings — each one is
-    a proven wrong-math path, not a drifted declaration."""
-    from ..fluid import lowering
-
+    checker): calibrated-scale presence on the slim/PTQ fake-quant ops.
+    See the module docstring; these are ERRORS, not warnings — each one
+    is a proven wrong-math path, not a drifted declaration."""
     findings: List[Finding] = []
     for block in program.blocks:
-        bwd = bwd_idx = None
-        for i, op in enumerate(block.ops):
-            if op.type == "backward":
-                bwd, bwd_idx = op, i
-                break
-        cfg = bwd.attrs.get("fp8_delayed_scaling") \
-            if bwd is not None else None
-        if cfg is None and block.idx == 0 and \
-                getattr(program, "_amp_fp8", None) is not None:
-            findings.append(Finding(
-                "dtype-contract", "error",
-                "program is marked fp8 (_amp_fp8) but its backward op "
-                "carries no fp8_delayed_scaling attr — the qdq sites "
-                "would quantize at uncalibrated scales (a pass "
-                "stripped the recipe after decorate()).",
-                block_idx=block.idx))
-        if cfg is not None:
-            wired = dict(cfg.get("inputs", {}))
-            state_vars = set()
-            for st in list(wired.values()) + \
-                    list(cfg.get("grads", {}).values()):
-                state_vars.add(st["hist"])
-                state_vars.add(st["scale"])
-            fp8_ops = set(cfg.get("ops", ()))
-            for op_idx, op in enumerate(block.ops):
-                if op is bwd or op.type in _FP8_STATE_SANCTIONED:
-                    continue
-                reads, writes = lowering._op_reads_writes(op)
-                for n in sorted(state_vars & (set(reads)
-                                              | set(writes))):
-                    verb = "writes" if n in set(writes) else "reads"
-                    findings.append(Finding(
-                        "dtype-contract", "error",
-                        "fp8 scale-state var %r is %s by op %r outside "
-                        "the sanctioned set (backward's Fp8ScaleState "
-                        "slots + save/load) — a foreign read observes "
-                        "the scale mid-update, a foreign write "
-                        "corrupts the amax window." % (
-                            n, verb, op.type),
-                        block_idx=block.idx, op_idx=op_idx,
-                        op_type=op.type, var=n))
-                if op_idx < bwd_idx and op.type in fp8_ops:
-                    for n in op.input_arg_names:
-                        v = block._find_var_recursive(n)
-                        if v is None or str(v.dtype) not in (
-                                "float32", "bfloat16", "float16"):
-                            continue
-                        if n not in wired:
-                            findings.append(Finding(
-                                "dtype-contract", "error",
-                                "fp8 cast without scale: float input "
-                                "%r of fp8-white-list op %r has no "
-                                "delayed-scaling state wired — it "
-                                "would quantize at an uncalibrated "
-                                "scale." % (n, op.type),
-                                block_idx=block.idx, op_idx=op_idx,
-                                op_type=op.type, var=n))
         for op_idx, op in enumerate(block.ops):
             slots = _QUANT_SCALE_SLOTS.get(op.type)
             if slots is not None:

@@ -38,24 +38,18 @@ from __future__ import annotations
 from ... import framework
 from .fp16_lists import AutoMixedPrecisionLists
 
-#: accepted spellings of the fp8 training tier's amp_dtype
-_FP8_DTYPE = "float8_e4m3"
-_FP8_ALIASES = ("float8_e4m3", "float8_e4m3fn", "float8", "fp8")
-
 
 def _normalize_amp_dtype(amp_dtype):
-    """bf16/fp16 via the canonical normalizer; fp8 spellings collapse to
-    "float8_e4m3" (the forward operand format — grads always e5m2)."""
-    if isinstance(amp_dtype, str) and \
-            amp_dtype.lower() in _FP8_ALIASES:
-        return _FP8_DTYPE
     from ....core.types import normalize_dtype
 
-    d = normalize_dtype(amp_dtype)
+    try:
+        d = normalize_dtype(amp_dtype)
+    except ValueError:
+        d = None
     if d not in ("bfloat16", "float16"):
         raise ValueError(
-            "amp_dtype must be 'bfloat16', 'float16' or 'float8_e4m3', "
-            "got %r" % (amp_dtype,))
+            "amp_dtype must be 'bfloat16' or 'float16', got %r"
+            % (amp_dtype,))
     return d
 
 
@@ -63,8 +57,7 @@ class OptimizerWithMixedPrecision:
     def __init__(self, optimizer, amp_lists=None, init_loss_scaling=2.**15,
                  use_dynamic_loss_scaling=True, incr_every_n_steps=1000,
                  decr_every_n_nan_or_inf=2, incr_ratio=2.0,
-                 decr_ratio=0.8, amp_dtype="bfloat16", amp_level="O2",
-                 fp8_amax_history_len=16):
+                 decr_ratio=0.8, amp_dtype="bfloat16", amp_level="O2"):
         self._optimizer = optimizer
         self._amp_lists = amp_lists or AutoMixedPrecisionLists()
         self._loss_scaling = float(init_loss_scaling)
@@ -74,14 +67,12 @@ class OptimizerWithMixedPrecision:
         self._incr_ratio = float(incr_ratio)
         self._decr_ratio = float(decr_ratio)
         self._amp_dtype = _normalize_amp_dtype(amp_dtype)
-        self._fp8_amax_history_len = int(fp8_amax_history_len)
         if amp_level not in ("O0", "O1", "O2"):
             raise ValueError("amp_level must be one of O0/O1/O2, got %r"
                              % (amp_level,))
         self._amp_level = amp_level
         self._master_of = {}
         self._scale_state = None
-        self._fp8_state = None
 
     def __getattr__(self, item):
         return getattr(self._optimizer, item)
@@ -116,18 +107,6 @@ class OptimizerWithMixedPrecision:
             return flag
         return self._amp_level
 
-    def _effective_dtype(self):
-        """FLAGS_tpu_amp_dtype override, else the decorate-time dtype.
-        The flag is the fp8 kill switch: "bfloat16" makes a
-        fp8-decorated program lower EXACTLY like the bf16 one (no
-        scaling state, byte-identical HLO)."""
-        from ....utils.flags import get_flag
-
-        flag = str(get_flag("FLAGS_tpu_amp_dtype", "") or "")
-        if flag:
-            return _normalize_amp_dtype(flag)
-        return self._amp_dtype
-
     def minimize(self, loss, startup_program=None, parameter_list=None,
                  no_grad_set=None):
         program = loss.block.program
@@ -135,34 +114,20 @@ class OptimizerWithMixedPrecision:
         if level == "O0":  # kill switch: lower exactly like undecorated
             return self._optimizer.minimize(loss, startup_program,
                                             parameter_list, no_grad_set)
-        amp_dtype = self._effective_dtype()
-        fp8 = amp_dtype == _FP8_DTYPE
-        # fp8 rides a bf16 carrier: the white/black-list cast policy,
-        # fp32 masters and collectives are the EXACT bf16 lowering; the
-        # e4m3/e5m2 quantize-dequantize sites stack on top at the
-        # fp8-white-list ops only
-        compute_dtype = "bfloat16" if fp8 else amp_dtype
         program._amp = True
         program._amp_lists = self._amp_lists
-        program._amp_dtype = compute_dtype
+        program._amp_dtype = self._amp_dtype
         result = self._optimizer.minimize(loss, startup_program,
                                           parameter_list, no_grad_set)
         startup = startup_program or framework.default_startup_program()
         from .fp16_utils import (rewrite_master_weights,
-                                 wire_dynamic_loss_scaling,
-                                 wire_fp8_delayed_scaling)
+                                 wire_dynamic_loss_scaling)
 
         if level == "O2":
             self._master_of = rewrite_master_weights(
-                program, startup, compute_dtype, self._amp_lists)
+                program, startup, self._amp_dtype, self._amp_lists)
             program._amp_master_of = dict(self._master_of)
-        if fp8:
-            self._fp8_state = wire_fp8_delayed_scaling(
-                program, startup, self._amp_lists,
-                amax_history_len=self._fp8_amax_history_len)
-            if self._fp8_state is not None:
-                program._amp_fp8 = self._fp8_state
-        if compute_dtype == "float16":
+        if self._amp_dtype == "float16":
             bop = next((op for op in program.global_block().ops
                         if op.type == "backward"), None)
             if bop is not None and \
@@ -197,18 +162,13 @@ def decorate(optimizer, amp_lists=None, init_loss_scaling=2.**15,
              incr_every_n_steps=1000, decr_every_n_nan_or_inf=2,
              incr_ratio=2.0, decr_ratio=0.8,
              use_dynamic_loss_scaling=True, amp_dtype="bfloat16",
-             amp_level="O2", fp8_amax_history_len=16):
+             amp_level="O2"):
     """Reference: decorator.py:218. `amp_dtype` selects the low-precision
-    compute tier: bf16 default (no loss scaling needed), fp16 (dynamic
-    loss scaling), or "float8_e4m3" — bf16 carrier compute plus e4m3
-    operand / e5m2 gradient quantize-dequantize at the fp8-white-list
-    matmul/conv sites, with per-tensor delayed scaling
-    (`fp8_amax_history_len`-step abs-max window -> scale) persisted like
-    optimizer state. `amp_level` "O1" = cast policy only, "O2"
-    (default) = policy + 16-bit live params with ZeRO-sharded fp32
-    master weights."""
+    compute tier: "bfloat16" (the default; no loss scaling needed) or
+    "float16" (dynamic loss scaling); anything else raises ValueError.
+    `amp_level` "O1" = cast policy only, "O2" (default) = policy +
+    16-bit live params with ZeRO-sharded fp32 master weights."""
     return OptimizerWithMixedPrecision(
         optimizer, amp_lists, init_loss_scaling, use_dynamic_loss_scaling,
         incr_every_n_steps, decr_every_n_nan_or_inf, incr_ratio,
-        decr_ratio, amp_dtype=amp_dtype, amp_level=amp_level,
-        fp8_amax_history_len=fp8_amax_history_len)
+        decr_ratio, amp_dtype=amp_dtype, amp_level=amp_level)

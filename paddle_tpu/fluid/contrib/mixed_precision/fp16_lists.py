@@ -35,18 +35,6 @@ gray_list = {
     "scale",
 }
 
-# fp8 tier (amp_dtype="float8_e4m3"): the NARROW subset of the white
-# list whose operands additionally pass through an e4m3
-# quantize-dequantize at the per-tensor delayed scale (grad cotangents
-# through e5m2). Deliberately excludes fused_linear_softmax_xent — its
-# fused loss epilogue is the numerically sensitive part the fusion
-# protects. bf16 stays the carrier compute dtype everywhere else.
-fp8_white_list = {
-    "conv2d", "depthwise_conv2d", "conv2d_transpose", "matmul",
-    "matmul_v2", "mul",
-}
-
-
 # input slots whose PARAMETERS stay fp32 at level O2 (no 16-bit live
 # copy, so no master either): the op reads them in fp32 whatever its
 # other inputs are, and a 16-bit copy would round what the op is
@@ -60,11 +48,10 @@ fp32_param_slots = {
 
 class AutoMixedPrecisionLists:
     def __init__(self, custom_white_list=None, custom_black_list=None,
-                 custom_black_varnames=None, custom_fp8_white_list=None):
+                 custom_black_varnames=None):
         self.white_list = set(white_list)
         self.black_list = set(black_list)
         self.gray_list = set(gray_list)
-        self.fp8_white_list = set(fp8_white_list)
         self.fp32_param_slots = dict(fp32_param_slots)
         if custom_white_list:
             self.white_list |= set(custom_white_list)
@@ -72,11 +59,4 @@ class AutoMixedPrecisionLists:
         if custom_black_list:
             self.black_list |= set(custom_black_list)
             self.white_list -= set(custom_black_list)
-            self.fp8_white_list -= set(custom_black_list)
-        if custom_fp8_white_list:
-            # fp8 sites must also be white-list (bf16 carrier) sites:
-            # the qdq rides on top of the 16-bit cast policy
-            self.fp8_white_list |= set(custom_fp8_white_list)
-            self.white_list |= set(custom_fp8_white_list)
-            self.black_list -= set(custom_fp8_white_list)
         self.black_varnames = set(custom_black_varnames or [])

@@ -187,111 +187,6 @@ def wire_dynamic_loss_scaling(program, startup_program, cfg):
     return dls
 
 
-#: e4m3 / e5m2 saturation values (finite maxima of the two fp8 formats)
-FP8_E4M3_MAX = 448.0
-FP8_E5M2_MAX = 57344.0
-
-FP8_SCALE_SUFFIX = "@FP8_SCALE"
-FP8_HIST_SUFFIX = "@FP8_AMAX_HIST"
-FP8_GRAD_SCALE_SUFFIX = "@FP8_GRAD_SCALE"
-FP8_GRAD_HIST_SUFFIX = "@FP8_GRAD_HIST"
-
-
-def wire_fp8_delayed_scaling(program, startup_program, amp_lists,
-                             amax_history_len=16):
-    """fp8 tier (amp_dtype="float8_e4m3"): create the per-tensor
-    delayed-scaling state and attach the ``fp8_delayed_scaling`` attr to
-    the backward op.
-
-    For every fp8 white-list op in the FORWARD section, each float
-    input var gets an e4m3 pair — ``<var>@FP8_AMAX_HIST`` (fp32,
-    [amax_history_len], the rolling abs-max window) and
-    ``<var>@FP8_SCALE`` (fp32, [1], ``E4M3_MAX / max(hist)``, 1.0 while
-    the window is empty) — and each float output var gets the e5m2
-    GRAD pair (``@FP8_GRAD_HIST`` / ``@FP8_GRAD_SCALE``) scaling the
-    cotangent that flows back through the op. The state rides the
-    backward op's ``Fp8ScaleState`` input/output slots exactly like
-    PR 6's ``LossScaleState``, so `lowering.analyze_block` threads it
-    as mutable scope state: it persists across steps and through
-    checkpoint save/restore (incl. elastic re-shard — the vars are
-    replicated [H]/[1] scalars, never ZeRO-sharded) like any other
-    optimizer state. The lowering's trace-time qdq sites read the
-    scales, observe this step's abs-max (fwd via env taps, grads via
-    the vjp-cotangent tap idiom), and the post-step update rolls the
-    history — pmax'd over every live mesh axis so the scale stays
-    replica-uniform under DP/DCN/TP.
-
-    Returns the attr dict (or None when the program has no backward
-    section or no fp8-eligible site)."""
-    block = program.global_block()
-    bop = next((op for op in block.ops if op.type == "backward"), None)
-    if bop is None:
-        return None
-    bwd_idx = block.ops.index(bop)
-    sb = startup_program.global_block() if startup_program is not None \
-        else None
-
-    def state(base, suffix, shape, value):
-        name = base + suffix
-        v = block.create_var(name=name, shape=list(shape),
-                             dtype="float32", persistable=True)
-        v.stop_gradient = True
-        if sb is not None and not sb.has_var(name):
-            sb.create_var(name=name, shape=list(shape), dtype="float32",
-                          persistable=True)
-            sb.append_op(type="fill_constant", outputs={"Out": [name]},
-                         attrs={"shape": list(shape), "dtype": "float32",
-                                "value": float(value)})
-        return name
-
-    fp8_ops = set(getattr(amp_lists, "fp8_white_list", ()) or ())
-    float_dtypes = ("float32", "bfloat16", "float16")
-    inputs, grads = {}, {}
-    for op in block.ops[:bwd_idx]:
-        if op.type not in fp8_ops:
-            continue
-        for n in op.input_arg_names:
-            if n in inputs:
-                continue
-            v = block._find_var_recursive(n)
-            if v is None or str(v.dtype) not in float_dtypes:
-                continue
-            inputs[n] = {
-                "hist": state(n, FP8_HIST_SUFFIX,
-                              [int(amax_history_len)], 0.0),
-                "scale": state(n, FP8_SCALE_SUFFIX, [1], 1.0),
-            }
-        for n in op.output_arg_names:
-            if n in grads:
-                continue
-            v = block._find_var_recursive(n)
-            if v is None or str(v.dtype) not in float_dtypes:
-                continue
-            grads[n] = {
-                "hist": state(n, FP8_GRAD_HIST_SUFFIX,
-                              [int(amax_history_len)], 0.0),
-                "scale": state(n, FP8_GRAD_SCALE_SUFFIX, [1], 1.0),
-            }
-    if not inputs and not grads:
-        return None
-
-    cfg = {
-        "inputs": inputs,
-        "grads": grads,
-        "amax_history_len": int(amax_history_len),
-        "fwd_max": FP8_E4M3_MAX,
-        "grad_max": FP8_E5M2_MAX,
-        "ops": sorted(fp8_ops),
-    }
-    bop.attrs["fp8_delayed_scaling"] = cfg
-    extra = [s[k] for group in (inputs, grads)
-             for s in group.values() for k in ("hist", "scale")]
-    bop.input_names["Fp8ScaleState"] = list(extra)
-    bop.output_names["Fp8ScaleState"] = list(extra)
-    program._version += 1
-    return cfg
-
-
 class EagerMasterWeightOptimizer:
     """Dygraph fp32-master shim (`hapi.Model.prepare(amp_level='O2')`):
     the live parameters stay in the 16-bit compute dtype; each step the

@@ -1644,31 +1644,6 @@ class Executor:
             out["param_fp32_replicated_bytes"] = m_logical
             out["param_masters_sharded"] = sum(
                 1 for m in amp_masters.values() if m in sharded)
-        # fp8 tier (amp_dtype="float8_e4m3"): the qdq sites keep the
-        # bf16 carrier in HBM, so the e4m3 operand bytes are a MODELED
-        # lane — what a native-fp8 layout would hold at the dot sites —
-        # reported beside the measured scale-state footprint
-        fp8_cfg = getattr(prog, "_amp_fp8", None)
-        if fp8_cfg:
-            hist_len = int(fp8_cfg.get("amax_history_len", 16))
-            sites_in = fp8_cfg.get("inputs", {}) or {}
-            sites_gr = fp8_cfg.get("grads", {}) or {}
-            out["fp8_site_inputs"] = len(sites_in)
-            out["fp8_site_grads"] = len(sites_gr)
-            out["fp8_state_bytes"] = (len(sites_in) + len(sites_gr)) \
-                * (hist_len + 1) * 4
-            block = prog.global_block()
-            carrier = modeled = 0
-            for n in sites_in:
-                v = block._find_var_recursive(n)
-                if v is None:
-                    continue
-                numel = int(np.prod(tuple(v.shape) or (1,)))
-                carrier += numel * np.dtype(
-                    to_numpy_dtype(v.dtype)).itemsize
-                modeled += numel  # e4m3: 1 byte/elem
-            out["fp8_operand_carrier_bytes"] = carrier
-            out["fp8_operand_bytes_modeled"] = modeled
         return out
 
     @staticmethod
@@ -1750,31 +1725,6 @@ class Executor:
             } for b in plan.buckets]
             census["bucket_bytes_total"] = sum(
                 b.nbytes for b in plan.buckets)
-        # fp8 tier: the grad exchange crosses ICI in the bf16 carrier
-        # dtype (measured above); an e5m2 grad wire would carry
-        # 1 byte/elem — a MODELED lane, labeled as such, beside the
-        # measured census
-        prog = program or framework.default_main_program()
-        from . import compiler as _compiler
-
-        if isinstance(prog, _compiler.CompiledProgram):
-            prog = prog._unwrap()
-        if getattr(prog, "_amp_fp8", None):
-            itemsize = {"bfloat16": 2, "float16": 2}.get(
-                str(getattr(prog, "_amp_dtype", "float32")), 4)
-            grad_tensor = grad_wire = 0
-            for kind in ("all_reduce", "reduce_scatter"):
-                rec = census.get(kind)
-                if isinstance(rec, dict):
-                    grad_tensor += rec.get("tensor_bytes", 0)
-                    grad_wire += rec.get("ici_bytes", 0)
-            census["fp8_wire"] = {
-                "modeled": True,
-                "carrier_itemsize": int(itemsize),
-                "grad_sync_wire_bytes": grad_wire,
-                "grad_sync_wire_bytes_e5m2": grad_wire // itemsize,
-                "grad_sync_tensor_bytes": grad_tensor,
-            }
         return census
 
     def attribution_report(self, program=None, feed=None,

@@ -233,14 +233,6 @@ def _exec_op_stamped(op, env, key0, op_idx, amp_lists=None):
     # casts are applied at trace time and fused by XLA)
     if amp_lists is not None:
         ins = _apply_amp_casts(t, op, ins, amp_lists)
-    # fp8 tier: inputs of fp8-white-list ops additionally qdq through
-    # e4m3 at their per-tensor delayed scale (active only inside the
-    # build_block_fn vjp region — the contextvar is unset elsewhere)
-    fp8 = _FP8_TRACE.get()
-    if fp8 is not None and t in fp8.ops:
-        ins = fp8.quantize_inputs(op, ins, env)
-    else:
-        fp8 = None
     attrs = dict(op.attrs)
     if opdef.needs_rng:
         attrs["_rng_key"] = jax.random.fold_in(key0, op_idx)
@@ -268,10 +260,6 @@ def _exec_op_stamped(op, env, key0, op_idx, amp_lists=None):
         vals = outs.get(slot, [])
         for n, v in zip(names, vals):
             env[n] = v
-    if fp8 is not None:
-        # fp8 tier: the op's outputs carry the e5m2 gradient site (the
-        # cotangent flowing back INTO this op quantizes through e5m2)
-        fp8.wrap_outputs(op, env)
 
 
 class _AmpTracePolicy:
@@ -342,186 +330,6 @@ def _apply_amp_casts(t, op, ins, amp):
     if t in lists.black_list:
         return cast_ins(low, jnp.float32)
     return ins
-
-
-# ---------------------------------------------------------------------------
-# fp8 training tier (amp_dtype="float8_e4m3"): trace-time e4m3/e5m2
-# quantize-dequantize sites with per-tensor delayed scaling
-# ---------------------------------------------------------------------------
-
-import contextvars as _contextvars
-
-#: the active _Fp8Trace for the CURRENT forward/backward trace (set by
-#: build_block_fn around the jax.vjp region only — post-backward ops
-#: never quantize). contextvar: safe under concurrent warmup traces.
-_FP8_TRACE = _contextvars.ContextVar("fp8_trace", default=None)
-
-_FP8_OBS_SUFFIX = "@FP8_AMAX_OBS"
-_FP8_GTAP_SUFFIX = "@FP8_GTAP"
-
-
-@contextlib.contextmanager
-def _fp8_trace_scope(trace):
-    tok = _FP8_TRACE.set(trace)
-    try:
-        yield
-    finally:
-        _FP8_TRACE.reset(tok)
-
-
-def _fp8_qdq(x, scale, fp8_dtype, fmax):
-    """Straight-through e4m3 quantize-dequantize at the delayed scale:
-    forward value is round-trip through fp8 (saturated at the format
-    max, exactly what XLA pattern-matches into a native fp8 matmul
-    operand on TPU), backward cotangent passes through UNCHANGED (the
-    reference quant_ops' stop_gradient STE — without it, JAX's
-    convert transpose would quantize the cotangent to e4m3 too)."""
-    import jax
-    import jax.numpy as jnp
-
-    xf = x.astype(jnp.float32) * scale
-    q = (jnp.clip(xf, -fmax, fmax).astype(fp8_dtype)
-         .astype(jnp.float32) / scale).astype(x.dtype)
-    return x + jax.lax.stop_gradient(q - x)
-
-
-def _fp8_grad_qdq_site_make():
-    """The e5m2 gradient site, built lazily (module import must not
-    require jax). Identity forward on the fp8 op's OUTPUT; the bwd rule
-    (i) quantize-dequantizes the incoming cotangent dY through e5m2 at
-    the delayed grad scale — so BOTH backward matmuls (dX and dW)
-    consume the fp8 gradient, the Transformer-Engine recipe — and
-    (ii) emits amax(|dY|) as the cotangent of the synthetic `gtap`
-    input, the vocab-sharded-embedding tap idiom carrying the
-    observation legally out of jax.vjp."""
-    import jax
-    import jax.numpy as jnp
-
-    @jax.custom_vjp
-    def site(y, gtap, gscale, fmax):
-        return y
-
-    def fwd(y, gtap, gscale, fmax):
-        return y, (gscale, fmax)
-
-    def bwd(res, ct):
-        gscale, fmax = res
-        ctf = ct.astype(jnp.float32)
-        amax = jnp.max(jnp.abs(ctf))
-        q = (jnp.clip(ctf * gscale, -fmax, fmax)
-             .astype(jnp.float8_e5m2).astype(jnp.float32)
-             / gscale).astype(ct.dtype)
-        return (q, amax.astype(jnp.float32),
-                jnp.zeros_like(gscale), jnp.zeros_like(fmax))
-
-    site.defvjp(fwd, bwd)
-    return site
-
-
-_fp8_grad_qdq_site = None
-
-
-class _Fp8Trace:
-    """Per-trace fp8 site router (one per build_block_fn vjp region),
-    driven by the backward op's ``fp8_delayed_scaling`` attr. Inputs of
-    fp8-white-list ops qdq through e4m3 at their delayed scale (amax
-    observed into ``<var>@FP8_AMAX_OBS`` env entries, which ride the
-    vjp aux env out); outputs get the e5m2 grad site fed by the
-    ``<var>@FP8_GTAP`` synthetic diff vars."""
-
-    __slots__ = ("cfg", "ops")
-
-    def __init__(self, cfg):
-        self.cfg = cfg
-        self.ops = frozenset(cfg.get("ops", ()))
-
-    def quantize_inputs(self, op, ins, env):
-        import jax.numpy as jnp
-
-        fwd_cfg = self.cfg["inputs"]
-        fmax = float(self.cfg["fwd_max"])
-        out = {}
-        for slot, vs in ins.items():
-            names = op.input_names.get(slot, [])
-            vals = []
-            for i, v in enumerate(vs):
-                n = names[i] if i < len(names) else None
-                st = fwd_cfg.get(n)
-                if st is None or st["scale"] not in env \
-                        or not hasattr(v, "dtype") \
-                        or not hasattr(v, "astype") \
-                        or not jnp.issubdtype(v.dtype, jnp.floating):
-                    vals.append(v)
-                    continue
-                scale = jnp.reshape(env[st["scale"]],
-                                    ()).astype(jnp.float32)
-                obs = n + _FP8_OBS_SUFFIX
-                amax = jnp.max(jnp.abs(v.astype(jnp.float32)))
-                prev = env.get(obs)
-                env[obs] = amax if prev is None \
-                    else jnp.maximum(prev, amax)
-                vals.append(_fp8_qdq(v, scale, jnp.float8_e4m3fn, fmax))
-            out[slot] = vals
-        return out
-
-    def wrap_outputs(self, op, env):
-        import jax.numpy as jnp
-
-        global _fp8_grad_qdq_site
-        if _fp8_grad_qdq_site is None:
-            _fp8_grad_qdq_site = _fp8_grad_qdq_site_make()
-        grad_cfg = self.cfg["grads"]
-        fmax = jnp.float32(self.cfg["grad_max"])
-        for n in op.output_arg_names:
-            st = grad_cfg.get(n)
-            tap = n + _FP8_GTAP_SUFFIX
-            if st is None or tap not in env or st["scale"] not in env:
-                continue
-            v = env[n]
-            if not hasattr(v, "dtype") or \
-                    not jnp.issubdtype(v.dtype, jnp.floating):
-                continue
-            gscale = jnp.reshape(env[st["scale"]],
-                                 ()).astype(jnp.float32)
-            env[n] = _fp8_grad_qdq_site(v, env[tap], gscale, fmax)
-
-
-def _update_fp8_scaling(cfg, env, tap_grads, axis_names):
-    """Post-step delayed-scaling state machine: roll each tensor's amax
-    history with this step's observation (0 when the site never ran —
-    e.g. dead branch), pmax'd over every LIVE mesh axis so the scale
-    stays replica-uniform (TP members see different local shards; a
-    per-member scale would make the next step's HLO diverge), and
-    recompute scale = fmax / max(history) (1.0 while the window is
-    empty). Runs unconditionally OUTSIDE any cond — like the loss-scale
-    counters, state advances even on anomalous steps."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..parallel import env as penv
-
-    axes = penv.active_axes() or {}
-    live = [a for a in axis_names if a is not None and axes.get(a, 1) > 1]
-
-    def step(st, amax, fmax):
-        amax = jnp.reshape(jnp.asarray(amax, jnp.float32), ())
-        for a in live:
-            amax = jax.lax.pmax(amax, a)
-        hist_n, scale_n = st["hist"], st["scale"]
-        hist = env[hist_n].astype(jnp.float32).reshape(-1)
-        hist = jnp.concatenate([amax[None], hist[:-1]])
-        m = jnp.max(hist)
-        scale = jnp.where(m > 0, jnp.float32(fmax) / m, jnp.float32(1.0))
-        env[hist_n] = hist.reshape(env[hist_n].shape).astype(
-            env[hist_n].dtype)
-        env[scale_n] = jnp.reshape(scale, env[scale_n].shape).astype(
-            env[scale_n].dtype)
-
-    for n, st in cfg["inputs"].items():
-        step(st, env.pop(n + _FP8_OBS_SUFFIX, 0.0), cfg["fwd_max"])
-    for n, st in cfg["grads"].items():
-        step(st, tap_grads.get(n + _FP8_GTAP_SUFFIX, 0.0),
-             cfg["grad_max"])
 
 
 def _host_callback_op(opdef, op, ins, attrs):
@@ -1359,19 +1167,6 @@ def build_block_fn(program, block, feed_names, fetch_names,
                 env.update(taps)
                 tap_names = frozenset(taps)
                 diff_names = diff_names + sorted(taps)
-            # fp8 tier: one synthetic scalar diff var per fp8 op output
-            # — its vjp cotangent carries amax(|dY|) out of the
-            # backward (the sparse-tap idiom; a site consumed twice
-            # sums, a conservative upper bound on the true amax)
-            fp8_cfg = bop.attrs.get("fp8_delayed_scaling")
-            fp8_tap_names = frozenset()
-            if fp8_cfg is not None:
-                fp8_taps = {o + _FP8_GTAP_SUFFIX:
-                            jnp.zeros((), jnp.float32)
-                            for o in fp8_cfg["grads"]}
-                env.update(fp8_taps)
-                fp8_tap_names = frozenset(fp8_taps)
-                diff_names = diff_names + sorted(fp8_taps)
 
             ckpt_names = list(bop.attrs.get("checkpoints", []) or [])
             segments = None
@@ -1379,11 +1174,6 @@ def build_block_fn(program, block, feed_names, fetch_names,
                 live_out = set(fetch_names) | set(state_out) | {loss_name}
                 for post_op in ops[bwd_idx + 1:]:
                     live_out.update(_op_reads_writes(post_op)[0])
-                if fp8_cfg is not None:
-                    # fwd amax observations must survive the remat
-                    # segment boundaries to reach the vjp aux env
-                    live_out.update(n + _FP8_OBS_SUFFIX
-                                    for n in fp8_cfg["inputs"])
                 segments = _remat_segments(fwd_ops, ckpt_names, live_out)
 
             def fseg(dvars):
@@ -1423,26 +1213,19 @@ def build_block_fn(program, block, feed_names, fetch_names,
                 return loss_sum, e
 
             diff_in = {n: env[n] for n in diff_names}
-            # the fp8 qdq sites are live ONLY inside this vjp region:
-            # forward trace AND the backward replay (remat re-traces
-            # segments under vjp_fn and must reproduce the exact same
-            # computation) — post-backward ops never quantize
-            with (_fp8_trace_scope(_Fp8Trace(fp8_cfg))
-                  if fp8_cfg is not None else contextlib.nullcontext()):
-                _, vjp_fn, env_after = jax.vjp(fseg, diff_in,
-                                               has_aux=True)
-                ct = jnp.asarray(loss_scale, jnp.float32)
-                amp_scale = None
-                if dls is not None:
-                    # scale the cotangent by the LIVE scale state so
-                    # fp16 backward intermediates stay representable
-                    amp_scale = jnp.reshape(env[dls["scale"]],
-                                            ()).astype(jnp.float32)
-                    ct = ct * amp_scale
-                elif static_ls:
-                    amp_scale = jnp.asarray(static_ls, jnp.float32)
-                    ct = ct * amp_scale
-                grads = vjp_fn(ct)[0]
+            _, vjp_fn, env_after = jax.vjp(fseg, diff_in, has_aux=True)
+            ct = jnp.asarray(loss_scale, jnp.float32)
+            amp_scale = None
+            if dls is not None:
+                # scale the cotangent by the LIVE scale state so
+                # fp16 backward intermediates stay representable
+                amp_scale = jnp.reshape(env[dls["scale"]],
+                                        ()).astype(jnp.float32)
+                ct = ct * amp_scale
+            elif static_ls:
+                amp_scale = jnp.asarray(static_ls, jnp.float32)
+                ct = ct * amp_scale
+            grads = vjp_fn(ct)[0]
             env = dict(env_after)
             tap_grads = {}
             if sparse_plan is not None:
@@ -1451,12 +1234,6 @@ def build_block_fn(program, block, feed_names, fetch_names,
                 # engine's gathered scatter-add, never via pmean
                 tap_grads = {n: grads.pop(n) for n in list(grads)
                              if n in tap_names}
-            fp8_tap_grads = {}
-            if fp8_cfg is not None:
-                # grad-amax observations: popped BEFORE the grad sync
-                # (the delayed-scaling update pmax's them itself)
-                fp8_tap_grads = {n: grads.pop(n) for n in list(grads)
-                                 if n in fp8_tap_names}
             if gm is None:
                 if shard_plan is not None and _implicit_dp:
                     if shard_plan.buckets:
@@ -1510,8 +1287,8 @@ def build_block_fn(program, block, feed_names, fetch_names,
             from ..observability import attribution as _attr
 
             for n in diff_names:
-                if n in tap_names or n in fp8_tap_names:
-                    continue  # tap cotangents feed the engines
+                if n in tap_names:
+                    continue  # tap cotangents feed the engine
                 gn = framework.grad_var_name(n)
                 # stamp the grad post-processing (unscale + dtype cast)
                 # with the gradient's provenance so its converts blame
@@ -1546,13 +1323,6 @@ def build_block_fn(program, block, feed_names, fetch_names,
                 _run_gradient_merge(ops, bwd_idx, gm, env, key0,
                                     amp_lists, sync_fn=_dp_pmean,
                                     shard_plan=shard_plan, block=block)
-            if fp8_cfg is not None:
-                # roll the delayed-scaling state AFTER the update (the
-                # scales this step consumed came from previous steps'
-                # histories — that is what makes the scaling "delayed")
-                _update_fp8_scaling(
-                    fp8_cfg, env, fp8_tap_grads,
-                    (_dp_axis_name, _dcn_axis_name, _model_axis_name))
 
         fetches = []
         for n in fetch_names:

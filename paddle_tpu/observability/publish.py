@@ -18,9 +18,7 @@ one-line BENCH summary bench.py always printed, and publishes):
     hierarchy_block(exe, p, f, fl)      "hierarchy" (hybrid multi-pod
                                         mesh: dcn/ici lane census)
     precision_block(exe, p, f, fl)      "precision"
-    quant_block(exe, p, f, fl)          "quant" (fp8 training sites/
-                                        state + modeled operand/wire
-                                        lanes; int8 serving page +
+    quant_block()                       "quant" (int8 serving page +
                                         PTQ weight byte census)
     attribution_block(exe, p, f, fl)    "attribution" (per-op HBM
                                         blame + provenance coverage)
@@ -268,30 +266,19 @@ def precision_block(exe, program, feed, fetch_list) -> Optional[dict]:
         reg = registry()
         lists = getattr(program, "_amp_lists", None)
         masters = dict(getattr(program, "_amp_master_of", None) or {})
-        fp8_cfg = getattr(program, "_amp_fp8", None)
         block = {
-            # fp8 programs carry a bf16 carrier in _amp_dtype; report
-            # the tier the user decorated for, carrier beside it
-            "amp_dtype": ("float8_e4m3" if fp8_cfg else
-                          str(getattr(program, "_amp_dtype",
-                                      "bfloat16"))),
+            "amp_dtype": str(getattr(program, "_amp_dtype", "bfloat16")),
             "level": "O2" if masters else "O1",
             "master_weights": len(masters),
             "white_list_ops": len(lists.white_list) if lists else 0,
             "black_list_ops": len(lists.black_list) if lists else 0,
         }
-        if fp8_cfg:
-            block["carrier_dtype"] = str(getattr(
-                program, "_amp_dtype", "bfloat16"))
         rep = exe.donation_report(program, feed=feed,
                                   fetch_list=fetch_list)
         for k in ("param_bf16_bytes", "param_master_bytes",
                   "param_fp32_replicated_bytes", "param_masters_sharded",
                   "grad_peak_per_replica_bytes",
-                  "grad_replicated_peak_bytes",
-                  "fp8_site_inputs", "fp8_site_grads",
-                  "fp8_state_bytes", "fp8_operand_carrier_bytes",
-                  "fp8_operand_bytes_modeled"):
+                  "grad_replicated_peak_bytes"):
             if rep and k in rep:
                 block[k] = rep[k]
         bop = next((op for op in program.global_block().ops
@@ -332,10 +319,6 @@ def precision_block(exe, program, feed, fetch_list) -> Optional[dict]:
                              "param_fp32_replicated_bytes")))
         if block["loss_scaling"]:
             msg += ", loss_scale=%s" % block["loss_scaling"]["current"]
-        if "fp8_site_inputs" in block:
-            msg += (", fp8 sites=%d+%dgrad state=%dB"
-                    % (block["fp8_site_inputs"], block["fp8_site_grads"],
-                       block["fp8_state_bytes"]))
         print(msg, flush=True)
         return block
     except Exception as e:  # noqa: BLE001 - evidence, not gating
@@ -574,77 +557,31 @@ def serving_block() -> Optional[dict]:
     return block
 
 
-def quant_block(exe=None, program=None, feed=None, fetch_list=None) \
-        -> Optional[dict]:
-    """Quantization-tier evidence: the fp8 training lane (site count,
-    delayed-scaling state bytes, modeled e4m3 operand / e5m2 grad-wire
-    bytes against the measured bf16 carrier — modeled lanes are
-    labeled) and the int8 serving lane (page dtype/bytes, resident
-    batch under the fixed pool budget, PTQ weight bytes pre/post).
-    None when neither tier is active. `tools/perf_analysis.py --quant`
-    writes the offline artifact for the same claims."""
+def quant_block() -> Optional[dict]:
+    """Quantization-tier evidence: the int8 serving lane (page
+    dtype/bytes, resident batch under the fixed pool budget, PTQ weight
+    bytes pre/post), from the gauges the serving engine published. None
+    when the tier is not active. `tools/perf_analysis.py --quant` writes
+    the offline artifact for the same claims."""
     reg = registry()
     gauges = reg.snapshot()["gauges"]
-    block = {}
-    prog = program
-    if prog is not None and hasattr(prog, "_unwrap"):
-        prog = prog._unwrap()
-    fp8_cfg = getattr(prog, "_amp_fp8", None) if prog is not None \
-        else None
-    if fp8_cfg is not None and exe is not None:
-        fp8 = {
-            "amp_dtype": "float8_e4m3",
-            "carrier_dtype": str(getattr(prog, "_amp_dtype",
-                                         "bfloat16")),
-            "amax_history_len": int(fp8_cfg.get(
-                "amax_history_len", 16)),
-        }
-        try:
-            rep = exe.donation_report(prog, feed=feed,
-                                      fetch_list=fetch_list)
-            for k in ("fp8_site_inputs", "fp8_site_grads",
-                      "fp8_state_bytes", "fp8_operand_carrier_bytes",
-                      "fp8_operand_bytes_modeled"):
-                if rep and k in rep:
-                    fp8[k] = rep[k]
-            col = exe.collective_report(prog, feed=feed,
-                                        fetch_list=fetch_list)
-            if col and col.get("fp8_wire"):
-                fp8["grad_wire"] = col["fp8_wire"]
-        except Exception as e:  # noqa: BLE001 - evidence, not gating
-            print("BENCH quant fp8 census failed: %r" % (e,),
-                  flush=True)
-        block["fp8"] = fp8
-    if gauges.get("serving.kv_page_dtype") == "int8" or \
-            gauges.get("serving.weights_quantized"):
-        srv = {
-            "kv_page_dtype": gauges.get("serving.kv_page_dtype"),
-            "kv_page_bytes": gauges.get("serving.kv_page_bytes"),
-            "kv_pool_bytes": gauges.get("serving.kv_pool_bytes"),
-            "kv_resident_batch": gauges.get(
-                "serving.kv_resident_batch"),
-        }
-        if gauges.get("serving.weights_quantized"):
-            srv["weight_bytes_dense"] = gauges.get(
-                "serving.weight_bytes_dense")
-            srv["weight_bytes"] = gauges.get("serving.weight_bytes")
-        block["int8_serving"] = srv
-    if not block:
+    if gauges.get("serving.kv_page_dtype") != "int8" and \
+            not gauges.get("serving.weights_quantized"):
         return None
+    srv = {
+        "kv_page_dtype": gauges.get("serving.kv_page_dtype"),
+        "kv_page_bytes": gauges.get("serving.kv_page_bytes"),
+        "kv_pool_bytes": gauges.get("serving.kv_pool_bytes"),
+        "kv_resident_batch": gauges.get("serving.kv_resident_batch"),
+    }
+    if gauges.get("serving.weights_quantized"):
+        srv["weight_bytes_dense"] = gauges.get(
+            "serving.weight_bytes_dense")
+        srv["weight_bytes"] = gauges.get("serving.weight_bytes")
+    block = {"int8_serving": srv}
     reg.publish_block("quant", block)
-    bits = []
-    if "fp8" in block:
-        f = block["fp8"]
-        bits.append("fp8 %d+%d sites, operand %s -> %s B (modeled)"
-                    % (f.get("fp8_site_inputs", 0),
-                       f.get("fp8_site_grads", 0),
-                       f.get("fp8_operand_carrier_bytes"),
-                       f.get("fp8_operand_bytes_modeled")))
-    if "int8_serving" in block:
-        s = block["int8_serving"]
-        bits.append("int8 serving pages %s B/page, resident batch %s"
-                    % (s["kv_page_bytes"], s["kv_resident_batch"]))
-    print("BENCH quant: " + "; ".join(bits), flush=True)
+    print("BENCH quant: int8 serving pages %s B/page, resident batch %s"
+          % (srv["kv_page_bytes"], srv["kv_resident_batch"]), flush=True)
     return block
 
 
@@ -772,7 +709,7 @@ def bench_blocks(exe, program, feed, fetch_list, group=None) -> dict:
     hierarchy_block(exe, program, feed, fetch_list)
     model_parallel_block(exe, program, feed, fetch_list)
     precision_block(exe, program, feed, fetch_list)
-    quant_block(exe, program, feed, fetch_list)
+    quant_block()
     embedding_block(exe, program, feed, fetch_list)
     attribution_block(exe, program, feed, fetch_list)
     static_checks_block(program)

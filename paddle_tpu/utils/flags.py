@@ -10,23 +10,11 @@ from typing import Dict
 _FLAGS: Dict[str, object] = {
     # numerics / debugging (reference: flags.cc check_nan_inf)
     "FLAGS_check_nan_inf": False,
-    "FLAGS_fast_check_nan_inf": False,
     "FLAGS_benchmark": False,
     "FLAGS_enable_unused_var_check": False,
-    # determinism
-    "FLAGS_cpu_deterministic": False,
-    "FLAGS_cudnn_deterministic": False,
-    # memory (fraction knobs are PJRT's on TPU; kept for compat)
-    "FLAGS_fraction_of_gpu_memory_to_use": 0.92,
-    "FLAGS_allocator_strategy": "auto_growth",
-    "FLAGS_eager_delete_tensor_gb": 0.0,
     # device selection
     "FLAGS_selected_gpus": "",
     "FLAGS_selected_tpus": "",
-    # comm
-    "FLAGS_sync_nccl_allreduce": True,
-    "FLAGS_communicator_max_merge_var_num": 20,
-    "FLAGS_communicator_send_queue_size": 20,
     # rng
     "FLAGS_seed": 0,
     # PRNG bit-generator implementation for dropout / random init keys.
@@ -147,14 +135,6 @@ _FLAGS: Dict[str, object] = {
     # weights (param HBM and param all-gather ICI bytes ~halve). See
     # paddle_tpu/parallel/README.md "Mixed precision & ZeRO-2".
     "FLAGS_tpu_amp_level": "",
-    # Mixed-precision dtype override for decorate()'d programs: ""
-    # follows the decorate(amp_dtype=...) argument; "bfloat16" is the
-    # fp8 kill switch (a program decorated with amp_dtype="float8_e4m3"
-    # lowers EXACTLY like the bf16 one — byte-identical HLO, no scaling
-    # state); "float8_e4m3" force-enables the fp8 tier (bf16 carrier
-    # compute + e4m3 matmul operands / e5m2 grads with per-tensor
-    # delayed scaling). See parallel/README.md "Quantization tier".
-    "FLAGS_tpu_amp_dtype": "",
     # tpu-lint static SPMD verifier (paddle_tpu/analysis): run the
     # collective-divergence / donation-safety / host-sync /
     # zero1-invariants / zero2-lifetimes / dtype-contract checkers at

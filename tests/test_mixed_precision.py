@@ -28,7 +28,6 @@ def _restore_flags():
     old = {k: get_flag(k) for k in ("FLAGS_tpu_sharded_weight_update",
                                     "FLAGS_tpu_comm_bucket_mb",
                                     "FLAGS_tpu_amp_level",
-                                    "FLAGS_tpu_amp_dtype",
                                     "FLAGS_tpu_model_parallel")}
     yield
     set_flags(old)
@@ -690,268 +689,11 @@ def test_hapi_amp_level_validation():
         Model(Linear(4, 2)).prepare(amp_level="O3")
 
 
-# ---------------------------------------------------------------------------
-# fp8 tier (amp_dtype="float8_e4m3"): delayed-scaling qdq on the bf16
-# carrier — parity, kill switch, state slots, checkpoint + elastic
-# survival, eager-master coexistence
-# ---------------------------------------------------------------------------
-
-def _fp8_kw():
-    return {"amp_dtype": "float8_e4m3"}
-
-
-def _scope():
-    from paddle_tpu.core import scope as scope_mod
-
-    return scope_mod._global_scope
-
-
-def _fp8_state_names(prog):
-    cfg = prog._amp_fp8
-    return sorted(s[k] for group in (cfg["inputs"], cfg["grads"])
-                  for s in group.values() for k in ("hist", "scale"))
-
-
-def _fp8_state_values(prog):
-    return {n: np.asarray(_scope().find_var(n), np.float32).copy()
-            for n in _fp8_state_names(prog)}
-
-
-@pytest.mark.parametrize("ndev,bucket_mb", [(2, 0.0), (8, 0.25)])
-def test_fp8_zero1_bit_identical_and_close_to_bf16(ndev, bucket_mb):
-    """The fp8 qdq sites live in COMPUTE, before the grad collectives:
-    ZeRO-1 sharded fp8 training is bit-identical to replicated fp8
-    (same composition theorem as bf16), and the qdq perturbation keeps
-    losses close to — but measurably distinct from — the plain bf16
-    trajectory."""
-    adam = lambda: O.AdamOptimizer(learning_rate=0.01)  # noqa: E731
-    l_rep, _, prog, *_ = _train(adam, False, ndev=ndev,
-                                decorate_kw=_fp8_kw())
-    assert prog._amp_fp8["inputs"] and prog._amp_fp8["grads"]
-    assert str(prog._amp_dtype) == "bfloat16", \
-        "fp8 programs keep the bf16 carrier dtype"
-    l_sh, _, _, _, plan, _ = _train(adam, True, ndev=ndev,
-                                    bucket_mb=bucket_mb,
-                                    decorate_kw=_fp8_kw())
-    assert plan is not None and plan.master_of
-    assert l_rep == l_sh, (l_rep, l_sh)
-    assert all(np.isfinite(l_sh)) and l_sh[-1] < l_sh[0]
-    l_bf, *_ = _train(adam, True, ndev=ndev, bucket_mb=bucket_mb)
-    assert l_sh != l_bf, "qdq must actually be in the graph"
-    assert np.allclose(l_sh, l_bf, rtol=0.2, atol=0.05), (l_sh, l_bf)
-
-
-def test_fp8_kill_switch_hlo_and_state_slots():
-    """FLAGS_tpu_amp_dtype="bfloat16" lowers an fp8-decorated program
-    byte-identically to the plain bf16 one; without the switch the HLO
-    carries e4m3 forward casts and e5m2 grad casts, and the delayed-
-    scaling state rides the backward op's Fp8ScaleState slots."""
-    x, y = _batch()
-    adam = lambda: O.AdamOptimizer(learning_rate=0.01)  # noqa: E731
-
-    def text(kw, flag_dtype=""):
-        _fresh()
-        set_flags({"FLAGS_tpu_sharded_weight_update": True,
-                   "FLAGS_tpu_comm_bucket_mb": 0.0,
-                   "FLAGS_tpu_amp_dtype": flag_dtype})
-        with framework.unique_name_guard():
-            loss = _mlp_loss()
-            mixed_precision.decorate(adam(), **kw).minimize(loss)
-            prog = fluid.default_main_program()
-            fluid.CompiledProgram(prog).with_data_parallel(
-                loss_name=loss.name)
-            exe = fluid.Executor(fluid.CPUPlace())
-            exe.run(fluid.default_startup_program())
-            exe.run(prog, feed={"img": x, "label": y},
-                    fetch_list=[loss])
-            got = exe._cached_lowerable(prog, {"img": x, "label": y},
-                                        [loss], None)
-            return got[1].as_text(), prog
-
-    t_bf, _ = text({})
-    t_f8, prog8 = text(_fp8_kw())
-    low = t_f8.lower()
-    assert "f8e4m3" in low, "forward qdq must cast through e4m3"
-    assert "f8e5m2" in low, "grad qdq must cast through e5m2"
-    bop = next(op for op in prog8.global_block().ops
-               if op.type == "backward")
-    slots = bop.input_names.get("Fp8ScaleState")
-    assert slots and slots == bop.output_names.get("Fp8ScaleState")
-    assert set(slots) == set(_fp8_state_names(prog8))
-    assert bop.attrs["fp8_delayed_scaling"] is prog8._amp_fp8
-    t_killed, progk = text(_fp8_kw(), flag_dtype="bfloat16")
-    assert t_killed == t_bf, "fp8 kill switch must reproduce the " \
-        "plain bf16 HLO byte-for-byte"
-    assert getattr(progk, "_amp_fp8", None) is None
-
-
-def test_fp8_composes_with_tensor_parallel():
-    """fp8 qdq + TP on the (dcn, ici, model) mesh: the scale update
-    pmax's over every live axis so the delayed-scaling state stays
-    replica-uniform, and losses track the bf16 TP trajectory."""
-    import jax
-    from jax.sharding import Mesh
-
-    adam = lambda: O.AdamOptimizer(learning_rate=0.01)  # noqa: E731
-    x, y = _batch()
-
-    def run(kw):
-        _fresh()
-        set_flags({"FLAGS_tpu_sharded_weight_update": True,
-                   "FLAGS_tpu_comm_bucket_mb": 0.0})
-        with framework.unique_name_guard():
-            loss = _mlp_loss()
-            mixed_precision.decorate(adam(), **kw).minimize(loss)
-            prog = fluid.default_main_program()
-            fluid.CompiledProgram(prog).with_data_parallel(
-                loss_name=loss.name)
-            prog._mesh = Mesh(
-                np.array(jax.devices()).reshape(1, 4, 2),
-                ("dcn", "ici", "model"))
-            exe = fluid.Executor(fluid.CPUPlace())
-            exe.run(fluid.default_startup_program())
-            losses = [float(exe.run(prog, feed={"img": x, "label": y},
-                                    fetch_list=[loss])[0].mean())
-                      for _ in range(4)]
-        return losses, prog
-
-    l_bf, prog_bf = run({})
-    l_f8, prog = run(_fp8_kw())
-    tpp = getattr(prog, "_tp_plan", None)
-    assert tpp is not None and tpp.params, \
-        getattr(prog, "_sharded_update_fallback", None)
-    assert prog._amp_fp8["inputs"]
-    assert all(np.isfinite(l_f8)) and l_f8[-1] < l_f8[0]
-    assert np.allclose(l_f8, l_bf, rtol=0.2, atol=0.05), (l_f8, l_bf)
-    # state is replica-uniform: every scale/hist is a plain replicated
-    # scope value, never TP- or ZeRO-sharded
-    plan = getattr(prog, "_shard_plan", None)
-    for n in _fp8_state_names(prog):
-        assert n not in tpp.params
-        assert plan is None or n not in plan.sharded_state
-
-
-def test_fp8_scale_state_advances_and_checkpoints(tmp_path):
-    """Satellite 5a: the @FP8_SCALE / @FP8_AMAX_HIST vars behave like
-    optimizer state — they advance each step, persist through
-    save_persistables / load_persistables, and a reload + continued
-    run reproduces the uninterrupted trajectory bit-for-bit."""
-    adam = lambda: O.AdamOptimizer(learning_rate=0.01)  # noqa: E731
-    x, y = _batch()
-    l_ref, *_ = _train(adam, True, steps=4, decorate_kw=_fp8_kw())
-    _, exe, prog, loss, _, _ = _train(adam, True, steps=2,
-                                      decorate_kw=_fp8_kw())
-    cfg = prog._amp_fp8
-    state = _fp8_state_values(prog)
-    some_in = next(iter(cfg["inputs"].values()))
-    assert float(state[some_in["hist"]].max()) > 0.0, \
-        "amax history must observe live abs-max values"
-    assert float(state[some_in["scale"]][0]) != 1.0, \
-        "scale must leave its init once the window is non-empty"
-    fluid.io.save_persistables(exe, str(tmp_path), main_program=prog)
-    for n, want in state.items():
-        saved = np.load(os.path.join(str(tmp_path),
-                                     n.replace("/", "%2F") + ".npy"))
-        assert np.array_equal(saved, want), n
-    fluid.io.load_persistables(exe, str(tmp_path), main_program=prog)
-    l_cont = [float(exe.run(prog, feed={"img": x, "label": y},
-                            fetch_list=[loss])[0].mean())
-              for _ in range(2)]
-    assert l_ref[2:] == l_cont, (l_ref, l_cont)
-
-
-def test_fp8_state_survives_elastic_reshard(tmp_path):
-    """Satellite 5b: fp8 state vars are replicated [H]/[1] scalars —
-    an N=8 checkpoint restores verbatim into an N'=4 world (no
-    re-shard math applies to them) and training continues finite,
-    rolling the history forward."""
-    import jax
-    from jax.sharding import Mesh
-
-    adam = lambda: O.AdamOptimizer(learning_rate=0.01)  # noqa: E731
-    x, y = _batch()
-
-    def build(ndev):
-        _fresh()
-        set_flags({"FLAGS_tpu_sharded_weight_update": True,
-                   "FLAGS_tpu_comm_bucket_mb": 0.0})
-        with framework.unique_name_guard():
-            loss = _mlp_loss()
-            mixed_precision.decorate(
-                adam(), **_fp8_kw()).minimize(loss)
-            prog = fluid.default_main_program()
-            fluid.CompiledProgram(prog).with_data_parallel(
-                loss_name=loss.name)
-            if ndev != 8:
-                prog._mesh = Mesh(np.array(jax.devices()[:ndev]),
-                                  ("dp",))
-            exe = fluid.Executor(fluid.CPUPlace())
-            exe.run(fluid.default_startup_program())
-        return exe, prog, loss
-
-    exe, prog, loss = build(8)
-    for _ in range(2):
-        exe.run(prog, feed={"img": x, "label": y}, fetch_list=[loss])
-    saved = _fp8_state_values(prog)
-    fluid.io.save_persistables(exe, str(tmp_path), main_program=prog)
-
-    exe4, prog4, loss4 = build(4)
-    assert _fp8_state_names(prog4) == sorted(saved), \
-        "same program, same state var names across world sizes"
-    fluid.io.load_persistables(exe4, str(tmp_path),
-                               main_program=prog4)
-    for n, want in saved.items():
-        assert np.array_equal(
-            np.asarray(_scope().find_var(n), np.float32), want), n
-    l = float(exe4.run(prog4, feed={"img": x, "label": y},
-                       fetch_list=[loss4])[0].mean())
-    assert np.isfinite(l)
-    after = _fp8_state_values(prog4)
-    rolled = [n for n in saved
-              if not np.array_equal(saved[n], after[n])]
-    assert rolled, "history must keep rolling after the re-shard"
-
-
-def test_fp8_state_unmoved_by_eager_master_rebind():
-    """Satellite 5c: the dygraph EagerMasterWeightOptimizer rebind
-    path (external _assign_raw -> master re-seed) runs in object
-    space and must not touch the graph program's fp8 scope state; the
-    graph keeps stepping afterwards and its state keeps advancing."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.fluid.dygraph import Linear
-    from paddle_tpu.hapi.model import Model
-
-    adam = lambda: O.AdamOptimizer(learning_rate=0.01)  # noqa: E731
-    x, y = _batch()
-    _, exe, prog, loss, _, _ = _train(adam, True, steps=1,
-                                      decorate_kw=_fp8_kw())
-    before = _fp8_state_values(prog)
-
-    r = np.random.RandomState(3)
-    dx = r.rand(32, 8).astype("float32")
-    dy = r.randint(0, 2, (32, 1)).astype("int64")
-    net = Linear(8, 2)
-    m = Model(net)
-    m.prepare(
-        O.SGDOptimizer(learning_rate=0.1,
-                       parameter_list=net.parameters()),
-        loss_function=lambda pred, label: fluid.layers.mean(
-            fluid.layers.softmax_with_cross_entropy(pred, label)),
-        amp_level="O2")
-    m.train_batch([dx], [dy])
-    loaded = jnp.asarray(
-        r.rand(*net.parameters()[0].shape).astype("float32")
-    ).astype(jnp.bfloat16)
-    net.parameters()[0]._assign_raw(loaded)
-    m.train_batch([dx], [dy])
-    assert m._optimizer._masters, "rebind path must have engaged"
-
-    mid = _fp8_state_values(prog)
-    for n, want in before.items():
-        assert np.array_equal(mid[n], want), \
-            "eager rebind must not touch graph fp8 state: %s" % n
-    exe.run(prog, feed={"img": x, "label": y}, fetch_list=[loss])
-    after = _fp8_state_values(prog)
-    assert any(not np.array_equal(after[n], before[n])
-               for n in before), "graph fp8 state must keep advancing"
+@pytest.mark.parametrize("spelling", ["float8_e4m3", "float8_e4m3fn",
+                                      "float8", "fp8"])
+def test_an_amp_dtype_the_chip_cannot_run_is_refused(spelling):
+    """bfloat16 and float16 are the tiers there are: no other dtype is
+    accepted and ignored."""
+    with pytest.raises(ValueError, match="'bfloat16' or 'float16'"):
+        mixed_precision.decorate(O.SGDOptimizer(learning_rate=0.1),
+                                 amp_dtype=spelling)

@@ -198,23 +198,24 @@ def _bare_checkpoint(monkeypatch):
                         lambda *names: None)
 
 
-def _build_scan_bert(remat=True, amp=True, seed=9):
+def _build_scan_bert(remat=True, amp=True, seed=9, seq=_S):
     from paddle_tpu.fluid.contrib import mixed_precision
 
     cfg = bert.BertConfig.tiny()
+    cfg.max_position_embeddings = max(cfg.max_position_embeddings, seq)
     main, st = framework.Program(), framework.Program()
     main.random_seed = st.random_seed = seed
     with framework.program_guard(main, st):
         with framework.unique_name_guard():
             total, _, _, _ = bert.bert_pretrain_loss(
-                cfg, _S, is_test=False, scan_layers=True,
+                cfg, seq, is_test=False, scan_layers=True,
                 scan_remat=remat)
             opt = fluid.optimizer.AdamOptimizer(1e-3)
             if amp:
                 opt = mixed_precision.decorate(
                     opt, use_dynamic_loss_scaling=False)
             opt.minimize(total)
-    return cfg, main, st, total, _bert_feed(cfg, _B, _S, max_pred=2)
+    return cfg, main, st, total, _bert_feed(cfg, _B, seq, max_pred=2)
 
 
 def _step_jaxpr(main, st, feed, fetch):
@@ -393,20 +394,59 @@ def _build_scan_bert_remat():
     return main, st, feed, total
 
 
-#: sha256 of the step's jaxpr (addresses scrubbed) as PR 26 lowered it
-#: on this container's jax; PR 27 (new ops, AMP's fp32-pinned parameter
-#: slots, the segment policy, grouped-query flash) left both as they
-#: were. A PR that means to change BERT's or ResNet's program replaces
-#: the digest and says so
+def _build_nemotron_h():
+    """The hybrid decoder's step: bf16 AMP, every block recomputed."""
+    from paddle_tpu.models import nemotron_h
+    from test_nemotron_h import _batch, _build
+
+    cfg = nemotron_h.NemotronHConfig.tiny(experts_held=(0, 4))
+    main, st, loss, _ = _build(cfg, True)
+    return main, st, _batch(cfg, 3), loss
+
+
+_FLASH_S = 128
+
+
+def _build_scan_bert_flash():
+    _, main, st, total, feed = _build_scan_bert(seq=_FLASH_S)
+    return main, st, feed, total
+
+
+def _flash_from_its_length(monkeypatch):
+    """Attention of `_FLASH_S` keys goes to the flash kernels, as on
+    the chip (tests/test_sdpa_dispatch.py does the same)."""
+    import importlib
+
+    import jax
+    from paddle_tpu.utils import flags
+
+    # the package's attribute of that name is the function
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "_interpret_default", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setitem(flags._FLAGS, "FLAGS_flash_attention_min_seq",
+                        _FLASH_S)
+
+
+#: sha256 of the step's jaxpr (addresses scrubbed) on this container's
+#: jax. The first two as PR 26 lowered them; PR 27 (new ops, AMP's
+#: fp32-pinned parameter slots, the segment policy, grouped-query
+#: flash) left both as they were. The last two as PR 28 lowered them:
+#: with these every kind of step the benchmark runs is held. A PR that
+#: means to change one of these programs replaces the digest and says so
 _STEP_DIGESTS = {
     "_build_scan_bert_remat": "f6d6b541724972c8",
     "_build_resnet50": "deed87d731a3ffb9",
+    "_build_nemotron_h": "a0331def11883d76",
+    "_build_scan_bert_flash": "7c1a665f24fa7e4a",
 }
 
 
 @pytest.mark.parametrize("build", [_build_scan_bert_remat,
-                                   _build_resnet50])
-def test_berts_and_resnets_steps_are_the_accepted_programs(build):
+                                   _build_resnet50, _build_nemotron_h,
+                                   _build_scan_bert_flash])
+def test_berts_and_resnets_steps_are_the_accepted_programs(
+        monkeypatch, build):
     import hashlib
     import re
 
@@ -414,6 +454,8 @@ def test_berts_and_resnets_steps_are_the_accepted_programs(build):
 
     if jax.__version__ != "0.9.0":
         pytest.skip("the digests were taken under jax 0.9.0")
+    if build is _build_scan_bert_flash:
+        _flash_from_its_length(monkeypatch)
     text = re.sub(r"0x[0-9a-f]+", "0x", str(_step_jaxpr(*build())))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         _STEP_DIGESTS[build.__name__]

@@ -1190,17 +1190,17 @@ def _import_tpu_lint():
 
 def test_exemplar_programs_lint_clean(tmp_path):
     """The standing tier-1 CI leg: tools/tpu_lint.py over the FULL
-    exemplar corpus — BERT-tiny DP step (plain, bf16 AMP + ZeRO-2
-    bucketed masters, AND the fp8 delayed-scaling tier), resnet scan,
-    the serving decode loop, and the 2-rank fleet-transpiled sync-PS
-    programs — through main() with --fail-on error, so the exit code
-    and artifact are exactly what CI sees."""
+    exemplar corpus — BERT-tiny DP step (plain, and bf16 AMP + ZeRO-2
+    bucketed masters), resnet scan, the serving decode loop, and the
+    2-rank fleet-transpiled sync-PS programs — through main() with
+    --fail-on error, so the exit code and artifact are exactly what CI
+    sees."""
     tpu_lint = _import_tpu_lint()
     out = tmp_path / "static_checks.json"
     rc = tpu_lint.main(["--fail-on", "error", "--out", str(out)])
     report = json.loads(out.read_text())
     assert set(report["programs"]) == {
-        "bert_tiny", "bert_tiny_amp", "bert_tiny_fp8", "bert_tiny_tp",
+        "bert_tiny", "bert_tiny_amp", "bert_tiny_tp",
         "mlp_hier", "embedding_ctr", "resnet_scan", "serving_decode",
         "serving_decode_sampled", "fleet_ps_2rank"}
     assert rc == 0 and report["ok"] and report["total_errors"] == 0, \
@@ -1218,7 +1218,7 @@ def test_cli_end_to_end(tmp_path):
     report = json.loads(out.read_text())
     assert report["ok"] and report["total_errors"] == 0
     assert set(report["programs"]) == {"bert_tiny", "bert_tiny_amp",
-                                       "bert_tiny_fp8", "bert_tiny_tp",
+                                       "bert_tiny_tp",
                                        "mlp_hier", "embedding_ctr",
                                        "resnet_scan", "serving_decode",
                                        "serving_decode_sampled",
@@ -1239,80 +1239,8 @@ def test_perf_analysis_lint_alias(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# checker — quantization-tier contracts (fp8 scale-state ownership,
-# fp8 site wiring, calibrated quantizer scales)
+# checker — quantization-tier contracts (calibrated quantizer scales)
 # ---------------------------------------------------------------------------
-
-def _fp8_program():
-    from paddle_tpu.fluid.contrib import mixed_precision
-
-    main, startup = framework.Program(), framework.Program()
-    with framework.program_guard(main, startup):
-        with framework.unique_name_guard():
-            loss = _mlp_loss()
-            mixed_precision.decorate(
-                fluid.optimizer.AdamOptimizer(1e-3),
-                amp_dtype="float8_e4m3").minimize(loss)
-    assert getattr(main, "_amp_fp8", None)
-    return main
-
-
-def test_fp8_decorated_program_lints_clean():
-    assert not analysis.check_quantization_contracts(_fp8_program())
-
-
-def test_fp8_foreign_scale_state_write_trips():
-    """A pass inserting an op that WRITES an @FP8_SCALE var outside
-    the backward op's Fp8ScaleState slots corrupts the amax window —
-    deliberate-defect twin of the clean exemplar."""
-    prog = _fp8_program()
-    blk = prog.global_block()
-    sname = next(iter(prog._amp_fp8["inputs"].values()))["scale"]
-    idx = _bwd_idx(blk) + 1
-    blk.ops.insert(idx, Operator(
-        blk, "scale", inputs={"X": [sname]}, outputs={"Out": [sname]},
-        attrs={"scale": 2.0}))
-    fs = analysis.check_quantization_contracts(prog)
-    assert len(fs) == 1
-    f = fs[0]
-    assert f.severity == "error" and f.var == sname
-    assert f.op_idx == idx and f.op_type == "scale"
-    assert "outside the sanctioned set" in f.message
-    assert "writes" in f.message
-
-
-def test_fp8_foreign_hist_read_trips():
-    """A mere READ of the amax history mid-program observes the scale
-    mid-update — still an error, reported with the read verb."""
-    prog = _fp8_program()
-    blk = prog.global_block()
-    hname = next(iter(prog._amp_fp8["inputs"].values()))["hist"]
-    peek = blk.create_var(name="lint.fp8.peek", shape=(1,),
-                          dtype="float32")
-    idx = _bwd_idx(blk)  # before backward: a forward-section consumer
-    blk.ops.insert(idx, Operator(
-        blk, "reduce_max", inputs={"X": [hname]},
-        outputs={"Out": [peek.name]}, attrs={}))
-    fs = analysis.check_quantization_contracts(prog)
-    assert len(fs) == 1
-    f = fs[0]
-    assert f.severity == "error" and f.var == hname
-    assert f.op_type == "reduce_max" and "reads" in f.message
-
-
-def test_fp8_cast_without_scale_trips():
-    """Dropping one input's delayed-scaling state from the recipe (a
-    rewrite pass that forgot to re-wire) leaves an fp8-white-list op
-    quantizing at an uncalibrated scale — every orphaned site trips."""
-    prog = _fp8_program()
-    cfg = prog._amp_fp8
-    victim = sorted(cfg["inputs"])[0]
-    del cfg["inputs"][victim]
-    fs = analysis.check_quantization_contracts(prog)
-    assert fs and all(f.severity == "error" for f in fs)
-    assert any(f.var == victim and
-               "fp8 cast without scale" in f.message for f in fs)
-
 
 def test_quantizer_missing_scale_slot_trips():
     """A slim/PTQ dequantize op with an empty Scale slot would

@@ -101,13 +101,7 @@ artifacts/sharded_update_diff.json — the no-chip evidence the
 acceptance criteria call for. Exits nonzero when the reduction does
 not hold.
 
-`--quant` is the offline evidence for the quantization tier (fp8
-training + int8 serving): it lowers the DP BERT-tiny step under
-`decorate(amp_dtype="float8_e4m3")`, asserts the StableHLO carries
-f8e4m3/f8e5m2 converts while `FLAGS_tpu_amp_dtype="bfloat16"`
-reproduces the plain-bf16 lowering byte-for-byte, records the measured
-fp8 scale-state bytes beside the MODELED (labeled) e4m3 operand /
-e5m2 grad-wire lanes, then runs the int8 serving census — KV page
+`--quant` is the offline evidence for the int8 serving tier: KV page
 bytes per dtype, resident-batch admission under a fixed pool budget
 (~2x bf16), PTQ weight bytes over the quantized subset (~4x), and the
 int8-engine batched==sequential identity. Writes
@@ -631,72 +625,15 @@ def sharded_update_diff(batch=16, seq_len=32):
     return 0 if ok else 1
 
 
-def quant_diff(batch=8, seq_len=32):
-    """Offline evidence for the quantization tier (fp8 training + int8
-    serving). Training lane: lowers the DP BERT-tiny step under
-    ``decorate(amp_dtype="float8_e4m3")`` (ZeRO-1 + 0.25 MB buckets),
-    asserts the lowered StableHLO actually carries f8e4m3/f8e5m2
-    converts, that the ``FLAGS_tpu_amp_dtype="bfloat16"`` kill switch
-    reproduces the plain-bf16 lowering BYTE-FOR-BYTE, and records the
-    measured scale-state footprint beside the MODELED (labeled) e4m3
-    operand / e5m2 grad-wire byte lanes from donation_report /
-    collective_report. Serving lane: the int8 KV page byte census vs
-    f32/bf16 at fixed geometry, the resident-batch admission a fixed
-    pool budget buys per dtype, the PTQ weight census over the
-    quantized subset, and the int8-engine batched==sequential identity.
-    Writes artifacts/quant_diff.json; exits nonzero when any reduction
-    or identity does not hold."""
+def quant_diff():
+    """Offline evidence for the int8 serving tier: the int8 KV page
+    byte census vs f32/bf16 at fixed geometry, the resident-batch
+    admission a fixed pool budget buys per dtype, the PTQ weight census
+    over the quantized subset, and the int8-engine batched==sequential
+    identity. Writes artifacts/quant_diff.json; exits nonzero when any
+    reduction or identity does not hold."""
     import json
 
-    base_flags = {"FLAGS_tpu_sharded_weight_update": True,
-                  "FLAGS_tpu_comm_bucket_mb": 0.25,
-                  "FLAGS_tpu_amp_dtype": ""}
-
-    def hlo_of(exe, prog, feed, total):
-        got = exe._cached_lowerable(prog, feed, [total], None)
-        return got[1].as_text()
-
-    # fp8 lowering
-    exe8, prog8, feed8, total8 = _bert_tiny_step(
-        batch, seq_len, dict(base_flags), amp=True,
-        amp_dtype="float8_e4m3")
-    hlo8 = hlo_of(exe8, prog8, feed8, total8)
-    don8 = exe8.donation_report(prog8, feed=feed8, fetch_list=[total8])
-    col8 = exe8.collective_report(prog8, feed=feed8,
-                                  fetch_list=[total8])
-    # plain bf16 baseline
-    exeb, progb, feedb, totalb = _bert_tiny_step(
-        batch, seq_len, dict(base_flags), amp=True)
-    hlob = hlo_of(exeb, progb, feedb, totalb)
-    # kill switch: fp8-decorated program under the bf16 flag override
-    ks_flags = dict(base_flags)
-    ks_flags["FLAGS_tpu_amp_dtype"] = "bfloat16"
-    exek, progk, feedk, totalk = _bert_tiny_step(
-        batch, seq_len, ks_flags, amp=True, amp_dtype="float8_e4m3")
-    hlok = hlo_of(exek, progk, feedk, totalk)
-    from paddle_tpu.utils.flags import set_flags
-
-    set_flags({"FLAGS_tpu_amp_dtype": ""})
-
-    low = hlo8.lower()
-    has_e4m3 = "f8e4m3" in low
-    has_e5m2 = "f8e5m2" in low
-    kill_exact = hlok == hlob
-    wire = (col8 or {}).get("fp8_wire") or {}
-    fp8 = {
-        "sites": {"inputs": don8.get("fp8_site_inputs", 0),
-                  "grads": don8.get("fp8_site_grads", 0)},
-        "state_bytes": don8.get("fp8_state_bytes", 0),
-        "operand_bytes": {
-            "carrier_measured": don8.get("fp8_operand_carrier_bytes"),
-            "e4m3_modeled": don8.get("fp8_operand_bytes_modeled")},
-        "grad_wire": wire,
-        "hlo_has_e4m3_convert": has_e4m3,
-        "hlo_has_e5m2_convert": has_e5m2,
-        "kill_switch_hlo_byte_identical": kill_exact,
-    }
-
-    # -- int8 serving lane -------------------------------------------
     import numpy as np
     from paddle_tpu.serving.engine import Engine, EngineConfig
     from paddle_tpu.serving.kv_cache import KVCacheConfig
@@ -769,41 +706,20 @@ def quant_diff(batch=8, seq_len=32):
         "engine_batched_eq_sequential": batched_eq_sequential,
     }
 
-    out = {
-        "model": "bert-tiny b%d s%d / tiny-lm serving" % (batch,
-                                                          seq_len),
-        "ndev": (col8 or {}).get("ndev"),
-        "fp8_training": fp8,
-        "int8_serving": int8_serving,
-    }
+    out = {"model": "tiny-lm serving", "int8_serving": int8_serving}
     path = os.path.join(_REPO, "artifacts", "quant_diff.json")
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
         json.dump(out, f, indent=2, sort_keys=True)
-    carrier = don8.get("fp8_operand_carrier_bytes") or 0
-    modeled = don8.get("fp8_operand_bytes_modeled") or 0
-    ok = (fp8["sites"]["inputs"] > 0 and fp8["sites"]["grads"] > 0
-          and fp8["state_bytes"] > 0
-          and has_e4m3 and has_e5m2 and kill_exact
-          and modeled > 0 and carrier >= 2 * modeled
-          and wire.get("grad_sync_wire_bytes_e5m2", 0) > 0
-          and wire.get("grad_sync_wire_bytes_e5m2", 0)
-          == wire.get("grad_sync_wire_bytes", -1)
-          // max(wire.get("carrier_itemsize", 1), 1)
-          and page_bytes["int8"] < page_bytes["bfloat16"]
+    ok = (page_bytes["int8"] < page_bytes["bfloat16"]
           < page_bytes["float32"]
           and pages["int8"] >= 1.6 * pages["bfloat16"]
           and w_quant * 3.5 <= w_dense
           and batched_eq_sequential)
-    print("quant diff: fp8 %d+%d sites (state %dB), e4m3/e5m2 "
-          "converts %s/%s, kill-switch HLO identical=%s, operand "
-          "%d -> %d B (modeled); int8 pages %s B (f32/bf16/int8 "
+    print("quant diff: int8 pages %s B (f32/bf16/int8 "
           "admission %s), PTQ weights %d -> %d B (%.2fx), "
           "batched==sequential=%s -> %s; wrote %s"
-          % (fp8["sites"]["inputs"], fp8["sites"]["grads"],
-             fp8["state_bytes"], has_e4m3, has_e5m2, kill_exact,
-             carrier, modeled,
-             [page_bytes[d] for d in ("float32", "bfloat16", "int8")],
+          % ([page_bytes[d] for d in ("float32", "bfloat16", "int8")],
              [pages[d] for d in ("float32", "bfloat16", "int8")],
              w_dense, w_quant, w_dense / max(w_quant, 1),
              batched_eq_sequential,
@@ -936,16 +852,14 @@ def serving_prefix_diff():
     return 0 if ok else 1
 
 
-def _bert_tiny_step(batch, seq_len, flags, amp=False, run=True,
-                    amp_dtype=None):
+def _bert_tiny_step(batch, seq_len, flags, amp=False, run=True):
     """One compiled data-parallel BERT-tiny Adam step under `flags`;
     returns the serving Executor + program + feed (for the report
     APIs). Fresh programs/scope per call so flag changes recompile.
     `amp`: mixed_precision.decorate the optimizer (O2 masters, static
-    scaling — the bench's AMP shape); `amp_dtype` selects the decorate
-    tier (e.g. "float8_e4m3" for the fp8 qdq lowering). `run=False`
-    skips the train-step dispatch (the OOM pre-flight leg needs a
-    program that FAILS before its first dispatch)."""
+    scaling — the bench's AMP shape). `run=False` skips the train-step
+    dispatch (the OOM pre-flight leg needs a program that FAILS before
+    its first dispatch)."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu.core import scope as scope_mod
     from paddle_tpu.fluid import framework
@@ -967,9 +881,8 @@ def _bert_tiny_step(batch, seq_len, flags, amp=False, run=True,
         if amp:
             from paddle_tpu.fluid.contrib import mixed_precision
 
-            kw = {"amp_dtype": amp_dtype} if amp_dtype else {}
             opt = mixed_precision.decorate(
-                opt, use_dynamic_loss_scaling=False, **kw)
+                opt, use_dynamic_loss_scaling=False)
         opt.minimize(total)
         prog = fluid.default_main_program()
         fluid.CompiledProgram(prog).with_data_parallel(

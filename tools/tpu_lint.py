@@ -158,50 +158,6 @@ def build_bert_tiny_amp():
     return prog, None
 
 
-def build_bert_tiny_fp8():
-    """BERT-tiny decorated at the fp8 training tier
-    (amp_dtype="float8_e4m3"): bf16 carrier AMP + ZeRO masters exactly
-    like `bert_tiny_amp`, PLUS the backward op carrying the
-    fp8_delayed_scaling recipe — per-tensor amax-history/scale
-    persistables threaded through its Fp8ScaleState slots. The
-    quantization-contract half of the dtype-contract checker verifies
-    the wiring is complete (every fp8-white-list float input has scale
-    state) and exclusive (no foreign op touches a scale-state var).
-    Zero errors required; the deliberate-defect twins live in
-    tests/test_tpu_lint.py."""
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import framework
-    from paddle_tpu.fluid.contrib import mixed_precision
-    from paddle_tpu.models import bert
-    from paddle_tpu.parallel import sharded_update as su
-    from paddle_tpu.utils.flags import get_flag, set_flags
-
-    _fresh()
-    with framework.unique_name_guard():
-        cfg = bert.BertConfig.tiny()
-        framework.default_main_program().random_seed = 7
-        total, _, _, _ = bert.bert_pretrain_loss(cfg, 32, is_test=False)
-        opt = mixed_precision.decorate(
-            fluid.optimizer.AdamOptimizer(learning_rate=1e-3),
-            amp_dtype="float8_e4m3")
-        opt.minimize(total)
-        prog = fluid.default_main_program()
-        fluid.CompiledProgram(prog).with_data_parallel(
-            loss_name=total.name)
-        old = get_flag("FLAGS_tpu_comm_bucket_mb")
-        try:
-            set_flags({"FLAGS_tpu_comm_bucket_mb": 0.25})
-            prog._shard_plan = su.plan_sharded_update(
-                prog, prog.global_block(), NDEV, "dp")
-        finally:
-            set_flags({"FLAGS_tpu_comm_bucket_mb": old})
-        bop = next(op for op in prog.global_block().ops
-                   if op.type == "backward")
-        assert bop.attrs.get("fp8_delayed_scaling"), \
-            "fp8 exemplar failed to wire delayed scaling"
-    return prog, None
-
-
 def build_bert_tiny_tp():
     """BERT-tiny under bf16 AMP + ZeRO with 2-way TENSOR PARALLELISM
     on the (dcn, ici, model) mesh: `parallel.planner.plan_parallel`
@@ -483,7 +439,6 @@ def build_fleet_ps_2rank():
 EXEMPLARS = {
     "bert_tiny": build_bert_tiny,
     "bert_tiny_amp": build_bert_tiny_amp,
-    "bert_tiny_fp8": build_bert_tiny_fp8,
     "bert_tiny_tp": build_bert_tiny_tp,
     "mlp_hier": build_mlp_hier,
     "embedding_ctr": build_embedding_ctr,
