@@ -80,11 +80,13 @@ def moe_experts(input, topk_idx, topk_weight, w_up, w_down, held_start,
     held_start + w_up.shape[0]) of `num_experts` give, no pair dropped:
     this chip's share of an expert-parallel layer (on one chip, with
     every expert held, the whole layer). Returns (out like `input`,
-    pairs computed [1], fullest held expert's pairs over the mean [1])."""
+    pairs computed [1], fullest held expert's pairs over the mean [1],
+    rows of sorted pairs made [1]: whole row blocks, as many as the
+    step's routing fills)."""
     helper = LayerHelper("moe_experts")
     out = helper.create_variable_for_type_inference(input.dtype)
     counters = []
-    for _ in range(2):
+    for _ in range(3):
         v = helper.create_variable_for_type_inference("float32")
         v.stop_gradient = True
         counters.append(v)
@@ -94,8 +96,9 @@ def moe_experts(input, topk_idx, topk_weight, w_up, w_down, held_start,
                 "TopkWeight": [topk_weight], "WUp": [w_up],
                 "WDown": [w_down]},
         outputs={"Out": [out], "HeldPairs": [counters[0]],
-                 "LoadMaxOverMean": [counters[1]]},
+                 "LoadMaxOverMean": [counters[1]],
+                 "RowsMade": [counters[2]]},
         attrs={"held_start": int(held_start),
                "num_experts": int(num_experts),
                "activation": activation})
-    return out, counters[0], counters[1]
+    return (out, *counters)

@@ -175,7 +175,7 @@ def attention_mixer(x, cfg, name):
 def experts_mixer(x, cfg, name, counters=None):
     """The shared expert for every token plus this chip's share of the
     routed experts. `counters` collects the layer's (pairs computed,
-    fullest expert over the mean)."""
+    fullest expert over the mean, rows made)."""
     h, f = cfg.hidden_size, cfg.moe_intermediate_size
     fs = cfg.moe_shared_expert_intermediate_size
     first, count = cfg.experts_held
@@ -190,11 +190,11 @@ def experts_mixer(x, cfg, name, counters=None):
         x, w_r, b_r, top_k=cfg.num_experts_per_tok,
         norm_topk_prob=cfg.norm_topk_prob,
         routed_scaling_factor=cfg.routed_scaling_factor)
-    routed, pairs, load = layers.moe_experts(
+    routed, *counted = layers.moe_experts(
         x, idx, weight, w_up, w_down, held_start=first,
         num_experts=cfg.n_routed_experts, activation="relu2")
     if counters is not None:
-        counters.append((pairs, load))
+        counters.append(counted)
     shared = layers.matmul(layers.relu2(layers.matmul(x, w_su)), w_sd)
     return layers.elementwise_add(shared, routed)
 
@@ -228,9 +228,10 @@ def nemotron_h_loss(cfg, seq_len, checkpoints_out=None):
     [B, seq_len] (the caller shifts: labels[t] is the token after
     ids[t]), the mean over all positions. Returns (loss, counters,
     feeds): `counters` is {"moe.held_pairs": var, "moe.load_max_over_mean":
-    var} (the routed layers' pairs summed, their fullest-over-mean at
-    its worst), to fetch with the loss where wanted; empty without a
-    routed layer."""
+    var, "moe.rows_made": var} (the routed layers' pairs summed, their
+    fullest-over-mean at its worst, the rows of sorted pairs they made
+    summed: whole row blocks, so no fewer than the pairs), to fetch with
+    the loss where wanted; empty without a routed layer."""
     ids = layers.data(name="ids", shape=[seq_len], dtype="int64")
     labels = layers.data(name="labels", shape=[seq_len], dtype="int64")
     per_layer = []
@@ -245,10 +246,12 @@ def nemotron_h_loss(cfg, seq_len, checkpoints_out=None):
     loss = layers.mean(per_tok)
     counters = {}
     if per_layer:
-        pairs, load = per_layer[0]
-        for p, ld in per_layer[1:]:
+        pairs, load, made = per_layer[0]
+        for p, ld, m in per_layer[1:]:
             pairs = layers.elementwise_add(pairs, p)
             load = layers.elementwise_max(load, ld)
+            made = layers.elementwise_add(made, m)
         counters = {"moe.held_pairs": pairs,
-                    "moe.load_max_over_mean": load}
+                    "moe.load_max_over_mean": load,
+                    "moe.rows_made": made}
     return loss, counters, ["ids", "labels"]

@@ -246,8 +246,16 @@ def _megablox():
     return backend.gmm.__wrapped__, backend.tgmm.__wrapped__
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _grouped_product_tpu(lhs, rhs, sizes, transpose_rhs=False):
+def _grouped_product(lhs, rhs, sizes, transpose_rhs=False):
+    """Rows of `lhs` [R, K], sorted by group, times their group's
+    matrix of `rhs` [G, K, N] ([G, N, K] where `transpose_rhs`); rows
+    past sum(sizes) are not computed and hold anything. On a TPU the
+    Pallas grouped product (work by the rows there are), elsewhere
+    `lax.ragged_dot`."""
+    if jax.default_backend() != "tpu":
+        if transpose_rhs:
+            rhs = rhs.swapaxes(1, 2)
+        return lax.ragged_dot(lhs, rhs, sizes).astype(lhs.dtype)
     gmm, _ = _megablox()
     k, n = rhs.shape[1:][::-1] if transpose_rhs else rhs.shape[1:]
     with jax.named_scope("moe_experts_gmm"):
@@ -256,130 +264,165 @@ def _grouped_product_tpu(lhs, rhs, sizes, transpose_rhs=False):
                    transpose_rhs=transpose_rhs)
 
 
-def _grouped_product_tpu_fwd(lhs, rhs, sizes, transpose_rhs):
-    return (_grouped_product_tpu(lhs, rhs, sizes, transpose_rhs),
-            (lhs, rhs, sizes))
-
-
-def _grouped_product_tpu_bwd(transpose_rhs, res, ct):
-    lhs, rhs, sizes = res
+def _grouped_outer_product(acc, lhs, ct, sizes):
+    """`acc` [G, K, N] float32 plus, a group, its rows of `lhs` [R, K]
+    transposed times its rows of `ct` [R, N]: the gradient of a grouped
+    product's matrices, summed in float32 where it lies."""
+    if jax.default_backend() != "tpu":
+        product = lambda w: lax.ragged_dot(  # noqa: E731
+            lhs.astype(_F32), w, sizes, precision=lax.Precision.HIGHEST)
+        return acc + jax.vjp(product, acc)[1](ct.astype(_F32))[0]
     _, tgmm = _megablox()
-    d_lhs = _grouped_product_tpu(ct, rhs, sizes, not transpose_rhs)
     k, n = lhs.shape[1], ct.shape[1]
     with jax.named_scope("moe_experts_tgmm"):
-        d_rhs = tgmm(lhs.swapaxes(0, 1), ct, sizes,
-                     preferred_element_type=rhs.dtype,
-                     tiling=(min(512, lhs.shape[0]), _tile(k), _tile(n)),
-                     num_actual_groups=rhs.shape[0])
-    return (d_lhs, d_rhs.swapaxes(1, 2) if transpose_rhs else d_rhs, None)
+        return tgmm(lhs.swapaxes(0, 1), ct, sizes,
+                    preferred_element_type=_F32,
+                    # a float32 tile comes in and goes out: half the
+                    # columns of the forward's fit the kernel's memory
+                    tiling=(min(512, lhs.shape[0]), _tile(k),
+                            _tile(n, 512)),
+                    existing_out=acc)
 
 
-_grouped_product_tpu.defvjp(_grouped_product_tpu_fwd,
-                            _grouped_product_tpu_bwd)
+def row_block(pairs, held, of):
+    """Rows of the sorted pairs that one trip of the loop makes, for a
+    layer of `pairs` (token, expert) pairs on a chip that holds `held`
+    of `of` experts: what a uniform routing sends here and a third
+    again, in whole 512-row tiles of the grouped product, so that a
+    share near its mean goes through in one trip and a fuller one in
+    as many as it needs (the nemotron cell's step at 8,192 rows a trip,
+    which this gives there, 629.8 ms; at 2,048 639.7, at 4,096 645.0,
+    at 1,024 647.6: PERF.md, PR 30)."""
+    tiles = -(-pairs // 512)
+    return 512 * min(tiles, -(-4 * pairs * held // (3 * 512 * of)))
 
 
-def _grouped_product(lhs, rhs, sizes):
-    """Rows of `lhs` [R, K], sorted by group, times their group's
-    matrix of `rhs` [G, K, N]; rows past sum(sizes) are not computed and
-    hold anything. On a TPU the Pallas grouped product (work by the
-    rows there are), elsewhere `lax.ragged_dot`."""
-    if jax.default_backend() == "tpu":
-        return _grouped_product_tpu(lhs, rhs, sizes)
-    return lax.ragged_dot(lhs, rhs, sizes).astype(lhs.dtype)
+def _window(i, order, sizes, block, k):
+    """Row block i of the sorted pairs: (the pairs, their tokens, the
+    share of each held expert's group that lies in the block, which
+    rows lie before the last held pair)."""
+    lo = i * block
+    pairs = lax.dynamic_slice(order, (lo,), (block,))
+    ends = jnp.cumsum(sizes)
+    part = (jnp.clip(ends, lo, lo + block)
+            - jnp.clip(ends - sizes, lo, lo + block))
+    live = (lo + jnp.arange(block) < ends[-1])[:, None]
+    return pairs, pairs // k, part, live
 
 
-@jax.custom_vjp
-def _permute(x, index, inverse):
-    """x[index] for a permutation `index` handed over with its
-    inverse: a gather both ways, no scatter."""
-    return jnp.take(x, index, axis=0)
+def _act(activation, x):
+    return get_op(activation).compute({"X": [x]}, {})["Out"]
 
 
-def _permute_fwd(x, index, inverse):
-    return jnp.take(x, index, axis=0), inverse
+def _trips(sizes, block):
+    return -(-jnp.sum(sizes) // block)
 
 
-def _permute_bwd(inverse, ct):
-    return jnp.take(ct, inverse, axis=0), None, None
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _routed_rows(x, weight, w_up, w_down, order, sizes, block, activation):
+    """The held experts' output [T, H], summed in float32: the sorted
+    pairs `order` (held experts first, `sizes` pairs each) walked
+    `block` rows at a time, as many blocks as hold a held pair."""
+    k = weight.shape[1]
+    scale = weight.reshape(-1).astype(_F32)
+
+    def trip(i, out):
+        pairs, tokens, part, live = _window(i, order, sizes, block, k)
+        rows = jnp.take(x, tokens, axis=0)
+        mid = _act(activation, _grouped_product(rows, w_up, part))
+        made = _grouped_product(mid, w_down, part).astype(_F32)
+        made = made * jnp.take(scale, pairs)[:, None]
+        return out.at[tokens].add(jnp.where(live, made, 0.0))
+
+    return lax.fori_loop(0, _trips(sizes, block), trip,
+                         jnp.zeros(x.shape, _F32)).astype(x.dtype)
 
 
-_permute.defvjp(_permute_fwd, _permute_bwd)
+def _routed_rows_fwd(x, weight, w_up, w_down, order, sizes, block,
+                     activation):
+    return (_routed_rows(x, weight, w_up, w_down, order, sizes, block,
+                         activation),
+            (x, weight, w_up, w_down, order, sizes))
 
 
-@jax.custom_vjp
-def _rows_of_pairs(x, order, place):
-    """Row r is the token of the pair sorted to r: x[order // k], k
-    pairs a token. Transposed, each token sums the rows of its k pairs,
-    found through `place` (pair -> row): again a gather."""
-    return jnp.take(x, order // (order.shape[0] // x.shape[0]), axis=0)
+def _routed_rows_bwd(block, activation, res, ct):
+    """A second walk over the same row blocks: each block's rows and
+    activations made again, then five products: the cotangent through
+    the down matrices transposed (its row product with the activations
+    is the routing weight's gradient, so the down product is not made
+    again), the two matrices' gradients, summed in float32 over the
+    blocks and rounded once, and the rows' through the up matrices."""
+    x, weight, w_up, w_down, order, sizes = res
+    k = weight.shape[1]
+    scale = weight.reshape(-1).astype(_F32)
+
+    def trip(i, carry):
+        d_x, d_scale, d_up, d_down = carry
+        pairs, tokens, part, live = _window(i, order, sizes, block, k)
+        rows = jnp.take(x, tokens, axis=0)
+        mid, act_vjp = jax.vjp(functools.partial(_act, activation),
+                               _grouped_product(rows, w_up, part))
+        ct_rows = jnp.take(ct, tokens, axis=0)
+        ct_mid = _grouped_product(ct_rows, w_down, part, True).astype(_F32)
+        d_scale = d_scale.at[pairs].add(jnp.sum(jnp.where(
+            live, mid.astype(_F32) * ct_mid, 0.0), axis=1))
+        by_pair = jnp.take(scale, pairs)[:, None]
+        d_down = _grouped_outer_product(
+            d_down, mid, (ct_rows * by_pair).astype(x.dtype), part)
+        ct_pre, = act_vjp((ct_mid * by_pair).astype(x.dtype))
+        d_up = _grouped_outer_product(d_up, rows, ct_pre, part)
+        d_rows = _grouped_product(ct_pre, w_up, part, True)
+        d_x = d_x.at[tokens].add(jnp.where(live, d_rows, 0).astype(_F32))
+        return d_x, d_scale, d_up, d_down
+
+    d_x, d_scale, d_up, d_down = lax.fori_loop(
+        0, _trips(sizes, block), trip,
+        (jnp.zeros(x.shape, _F32), jnp.zeros(scale.shape, _F32),
+         jnp.zeros(w_up.shape, _F32), jnp.zeros(w_down.shape, _F32)))
+    return (d_x.astype(x.dtype),
+            d_scale.reshape(weight.shape).astype(weight.dtype),
+            d_up.astype(w_up.dtype), d_down.astype(w_down.dtype), None,
+            None)
 
 
-def _rows_of_pairs_fwd(x, order, place):
-    return _rows_of_pairs(x, order, place), (place, x.shape[0])
-
-
-def _rows_of_pairs_bwd(res, ct):
-    place, t = res
-    return (jnp.take(ct, place, axis=0).reshape(t, -1, ct.shape[-1])
-            .sum(axis=1), None, None)
-
-
-_rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
-
-
-def _held_experts(x, idx, weight, w_up, w_down, held_start, activation):
-    """One block of tokens through the held experts: the block's
-    (token, expert) pairs sorted by expert (the pairs of experts held
-    elsewhere last), their tokens gathered, the two products run as
-    grouped products over the sorted rows, each pair's output back to
-    its token times its weight. Returns (out [T, H], pairs a held
-    expert [E_held])."""
-    t, h = x.shape
-    k = idx.shape[1]
-    n_held = w_up.shape[0]
-    local = idx.reshape(-1) - held_start                 # [T * k]
-    held = (local >= 0) & (local < n_held)
-    key = jnp.where(held, local, n_held)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    place = jnp.argsort(order).astype(jnp.int32)         # pair -> row
-    sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
-                    axis=0).astype(jnp.int32)
-    live = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
-
-    rows = jnp.where(live, _rows_of_pairs(x, order, place), 0)
-    mid = get_op(activation).compute(
-        {"X": [_grouped_product(rows, w_up, sizes)]}, {})["Out"]
-    out = jnp.where(live, _grouped_product(mid, w_down, sizes), 0)
-    pairs = _permute(out, place, order).reshape(t, k, h)
-    scale = jnp.where(held.reshape(t, k), weight, 0.0).astype(_F32)
-    out = jnp.sum(pairs.astype(_F32) * scale[..., None], axis=1)
-    return out.astype(x.dtype), sizes
-
-
-#: tokens that go through the held experts at a time
-TOKEN_BLOCK = 4096
+_routed_rows.defvjp(_routed_rows_fwd, _routed_rows_bwd)
 
 
 def moe_experts(x, idx, weight, w_up, w_down, held_start=0,
-                activation="relu2"):
+                activation="relu2", num_experts=None):
     """The part of a routed layer's output that the experts
     [held_start, held_start + w_up.shape[0]) give: every pair routed to
-    one of them is computed, none dropped, so the sorted rows are sized
-    for the worst routing (every pair held here: T * k rows), though
-    the products only visit the rows there are. `TOKEN_BLOCK` tokens go
-    through at a time, each block made again in the backward pass, so
-    that one block's rows are live at once and not the layer's.
-    Returns (out [T, H], pairs a held expert [E_held])."""
-    t = x.shape[0]
-    fn = functools.partial(_held_experts, held_start=held_start,
-                           activation=activation)
-    if t <= TOKEN_BLOCK or t % TOKEN_BLOCK:
-        return fn(x, idx, weight, w_up, w_down)
-    blocks = lambda v: v.reshape((-1, TOKEN_BLOCK) + v.shape[1:])  # noqa: E731
-    out, sizes = lax.map(
-        jax.checkpoint(lambda a: fn(*a, w_up, w_down)),
-        (blocks(x), blocks(idx), blocks(weight)))
-    return out.reshape(x.shape), jnp.sum(sizes, axis=0)
+    one of them is computed, none dropped. The (token, expert) pairs
+    are sorted by expert, the pairs of experts held elsewhere last, and
+    the held ones walked a row block (`row_block`, from the share of
+    `num_experts` held) at a time, as many blocks as this step's
+    routing fills (none where it sends nothing here, all T * k rows
+    where it sends everything): a block's tokens gathered,
+    the two products run as grouped products over the block's share of
+    each expert's group, every row times its pair's float32 weight
+    added to its token in float32. The backward pass walks the same
+    blocks again (`_routed_rows_bwd`); only token-sized values are kept
+    for it. Returns (out [T, H], pairs a held expert [E_held], rows
+    made: blocks walked x rows a block)."""
+    n_held = w_up.shape[0]
+    of = num_experts or held_start + n_held
+    block = row_block(idx.size, n_held, of)
+    # said where the op is traced: at the build's shape inference and
+    # once a compile and layer
+    logging.getLogger(__name__).info(
+        "moe_experts holds experts [%d, %d) of %d, top-%d: %d rows a "
+        "trip, %d trips if every pair is held here", held_start,
+        held_start + n_held, of, idx.shape[-1], block,
+        -(-idx.size // block))
+    local = idx.reshape(-1) - held_start                 # [T * k]
+    key = jnp.where((local >= 0) & (local < n_held), local, n_held)
+    sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
+                    axis=0).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    order = jnp.pad(order, (0, -order.shape[0] % block))
+    out = _routed_rows(x, weight, w_up, w_down, order, sizes, block,
+                       activation)
+    return out, sizes, _trips(sizes, block) * block
 
 
 @register_op("moe_experts")
@@ -387,28 +430,25 @@ def _moe_experts(ins, attrs):
     """The held experts' part of a routed layer (`moe_experts` above).
     X [..., H]; TopkIdx, TopkWeight [T, k] from `moe_router`; WUp
     [E_held, H, F], WDown [E_held, F, H]; `held_start` the first held
-    expert's number of `num_experts`, `activation` (a registered activation op) between
-    the two products. Out like X;
-    HeldPairs [1] (pairs computed here) and LoadMaxOverMean [1] (the
-    fullest held expert's pairs over the mean), float32 counters of the
-    step."""
+    expert's number of `num_experts`, `activation` (a registered
+    activation op) between the two products. Out like X; HeldPairs [1]
+    (pairs computed here), LoadMaxOverMean [1] (the fullest held
+    expert's pairs over the mean) and RowsMade [1] (rows of sorted
+    pairs the op made: whole row blocks, so HeldPairs or more), float32
+    counters of the step."""
     x, idx = ins["X"][0], ins["TopkIdx"][0]
     first, n_held = int(attrs.get("held_start", 0)), ins["WUp"][0].shape[0]
     of = int(attrs.get("num_experts", first + n_held))
     if first < 0 or first + n_held > of:
         raise ValueError("moe_experts holds experts [%d, %d) of %d"
                          % (first, first + n_held, of))
-    # said where the op is traced: at the build's shape inference and
-    # once a compile and layer
-    logging.getLogger(__name__).info(
-        "moe_experts holds experts [%d, %d) of %d, top-%d", first,
-        first + n_held, of, idx.shape[-1])
-    out, sizes = moe_experts(
+    out, sizes, made = moe_experts(
         x.reshape(-1, x.shape[-1]), idx, ins["TopkWeight"][0],
         ins["WUp"][0], ins["WDown"][0], first,
-        attrs.get("activation", "relu2"))
+        attrs.get("activation", "relu2"), of)
     load = lax.stop_gradient(sizes).astype(_F32)
     return {"Out": out.reshape(x.shape),
             "HeldPairs": jnp.sum(load).reshape(1),
             "LoadMaxOverMean": (jnp.max(load) / jnp.maximum(
-                jnp.mean(load), 1.0)).reshape(1)}
+                jnp.mean(load), 1.0)).reshape(1),
+            "RowsMade": made.astype(_F32).reshape(1)}

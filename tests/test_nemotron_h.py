@@ -239,13 +239,13 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
         four - _ref_layer(x, w_r, w_up, w_down, shared, (4, 4))))) <= 1e-4
 
 
-@pytest.mark.parametrize("token_block", [4096, 16],
+@pytest.mark.parametrize("row_block", [512, 16],
                          ids=["one_block", "blocks_of_16"])
 def test_no_token_is_dropped_under_a_routing_skewed_onto_one_expert(
-        token_block, monkeypatch):
+        row_block, monkeypatch):
     """Every token sends a pair to expert 3 (its router column is
     large): the expert takes all 48, nothing is capped or dropped."""
-    monkeypatch.setattr(hybrid_ops, "TOKEN_BLOCK", token_block)
+    monkeypatch.setattr(hybrid_ops, "row_block", lambda *a: row_block)
     x, w_r, w_up, w_down = _moe_inputs()
     x = jnp.abs(x)
     w_r = w_r.at[:, 3].set(5.0)
@@ -260,6 +260,100 @@ def test_no_token_is_dropped_under_a_routing_skewed_onto_one_expert(
     want = _ref_layer(x, w_r, w_up, w_down, zeros, (0, 4))
     assert float(jnp.max(jnp.abs(out - want))) <= 1e-4 * float(
         jnp.max(jnp.abs(want)))
+
+
+def _dense_masked_loop(x, idx, weight, w_up, w_down, first):
+    """The routed part of the reference's `_experts`, the routing
+    given: every held expert over every token, masked by the choice."""
+    out = jnp.zeros_like(x)
+    for e in range(w_up.shape[0]):
+        w_e = jnp.sum(jnp.where(idx == first + e, weight, 0.0), axis=-1)
+        out = out + w_e[:, None] * ref._relu2_mlp(x, w_up[e], w_down[e],
+                                                  None)
+    return out
+
+
+def _routing_case(name):
+    """(x, router, held experts' matrices, held) of 48 tokens routed
+    top-3 over 16 experts."""
+    x, w_r, w_up, w_down = _moe_inputs()
+    x, w_r, (first, count) = {
+        "uniform": (x, w_r, (4, 4)),
+        "skewed_onto_one_held_expert": (
+            jnp.abs(x), w_r.at[:, 6].set(5.0), (4, 4)),
+        "every_pair_held_here": (x, w_r, (0, 16)),
+        "no_pair_held_here": (
+            jnp.abs(x), w_r.at[:, 12:].set(-5.0), (12, 4)),
+    }[name]
+    return (x, w_r, w_up[first:first + count],
+            w_down[first:first + count], (first, count))
+
+
+@pytest.mark.parametrize("row_block", [8, 16, None],
+                         ids=["rows_of_8", "rows_of_16", "the_rule"])
+@pytest.mark.parametrize("routing", [
+    "uniform", "skewed_onto_one_held_expert", "every_pair_held_here",
+    "no_pair_held_here"])
+def test_the_row_blocks_a_routing_fills_give_the_dense_loops_layer(
+        routing, row_block, monkeypatch):
+    """The output and the gradients of the tokens, the routing weights
+    and both stacks of matrices against the reference's dense masked
+    loop, whatever the routing sends here: the worst case walks all
+    T * k rows, none held walks nothing and gives zeros; `HeldPairs` is
+    exact and `RowsMade` is whole row blocks."""
+    if row_block:
+        monkeypatch.setattr(hybrid_ops, "row_block", lambda *a: row_block)
+    x, w_r, w_up, w_down, (first, count) = _routing_case(routing)
+    r = run_op("moe_router", {"X": [x], "W": [w_r]},
+               {"top_k": 3, "routed_scaling_factor": 2.5})
+    idx, weight = r["TopkIdx"][0], r["TopkWeight"][0]
+    # 144 pairs are fewer than the grouped product's tile of 512 rows
+    block = hybrid_ops.row_block(idx.size, count, 16)
+    assert block == (row_block or 512)
+
+    per_expert = np.bincount(
+        np.asarray(idx).reshape(-1), minlength=16)[first:first + count]
+    held = int(per_expert.sum())
+    trips = -(-held // block)
+    if routing == "every_pair_held_here":
+        assert held == 48 * 3 and trips == -(-48 * 3 // block)
+    if routing == "no_pair_held_here":
+        assert held == 0 and trips == 0
+    if routing == "skewed_onto_one_held_expert":
+        assert per_expert[2] == 48
+    if routing == "uniform" and row_block == 8:
+        # a group that lies in two row blocks, a last block partly live
+        ends = np.cumsum(per_expert)
+        assert any((e - 1) // block > (e - n) // block
+                   for e, n in zip(ends, per_expert) if n)
+        assert held % block
+
+    def loss(fn):
+        return lambda *a: jnp.sum(jnp.sin(fn(*a)))
+
+    ours = lambda x, w, up, down: hybrid_ops.moe_experts(  # noqa: E731
+        x, idx, w, up, down, first)[0]
+    dense = lambda x, w, up, down: _dense_masked_loop(  # noqa: E731
+        x, idx, w, up, down, first)
+    args = (x, weight, w_up, w_down)
+    want, got = dense(*args), ours(*args)
+    scale = max(float(jnp.max(jnp.abs(want))), 1.0)
+    assert float(jnp.max(jnp.abs(got - want))) <= 1e-4 * scale
+    g_want = jax.grad(loss(dense), argnums=(0, 1, 2, 3))(*args)
+    g_got = jax.grad(loss(ours), argnums=(0, 1, 2, 3))(*args)
+    for a, b in zip(g_got, g_want):
+        assert a.shape == b.shape and bool(jnp.all(jnp.isfinite(a)))
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-4 * max(
+            float(jnp.max(jnp.abs(b))), 1.0)
+    if not held:
+        assert not float(jnp.max(jnp.abs(got)))
+        assert not any(float(jnp.max(jnp.abs(g))) for g in g_got)
+
+    o = run_op("moe_experts",
+               {"X": [x], "TopkIdx": [idx], "TopkWeight": [weight],
+                "WUp": [w_up], "WDown": [w_down]}, {"held_start": first})
+    assert float(o["HeldPairs"][0][0]) == held
+    assert float(o["RowsMade"][0][0]) == trips * block
 
 
 def test_the_router_and_the_decay_stay_float32_under_decorate():
@@ -309,7 +403,11 @@ def test_the_unrolled_stack_is_recomputed_a_block_at_a_time(caplog):
     assert {r["name"] for v in saved.values() for r in v["kept"]} == {
         "narrow_matmul_product"}
     said = {r.getMessage() for r in caplog.records}
-    assert said == {"moe_experts holds experts [4, 6) of 8, top-3"}
+    # at the build's stand-in batch and at the fed one (144 pairs)
+    head = "moe_experts holds experts [4, 6) of 8, top-3: "
+    assert all(m.startswith(head) for m in said)
+    assert head + ("512 rows a trip, 1 trips if every pair is held "
+                   "here") in said
     # the same loss without recompute
     main2, startup2, loss2, _ = _build(cfg, False, remat=False)
     scope = Scope()
@@ -328,10 +426,12 @@ def test_the_counters_come_with_the_loss():
     exe.run(startup, scope=scope)
     got = exe.run(main, feed=_batch(cfg, 3), scope=scope, fetch_list=[
         loss, counters["moe.held_pairs"],
-        counters["moe.load_max_over_mean"]])
-    pairs, load = (float(np.asarray(v).reshape(())) for v in got[1:])
+        counters["moe.load_max_over_mean"], counters["moe.rows_made"]])
+    pairs, load, made = (float(np.asarray(v).reshape(())) for v in got[1:])
     assert 0 < pairs <= 2 * _B * _S * 3       # two routed layers
     assert 1.0 <= load <= 4.0
+    # 144 pairs a layer are fewer than a row block: one trip of 512 each
+    assert made == 2 * 512 >= pairs
 
 
 @pytest.mark.parametrize("experts, ranks", [(128, 16), (8, 4)])

@@ -165,13 +165,14 @@ def test_held_experts_grouped_products_at_the_published_widths(
         one_chip, monkeypatch):
     """8 held experts of 2688 x 1856 over 16,384 tokens routed top-6:
     the grouped products compile for the chip (1856 is no multiple of
-    128: the kernel masks the remainder) under the names the
-    benchmark's `moe_experts_*` metrics match."""
+    128: the kernel masks the remainder) inside the loops over row
+    blocks, whose trip counts come from the routing, under the names
+    the benchmark's `moe_experts_*` metrics match."""
     hybrid = importlib.import_module("paddle_tpu.ops.hybrid_ops")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def loss(x, idx, w, w_up, w_down):
-        out, _ = hybrid.moe_experts(x, idx, w, w_up, w_down)
+        out = hybrid.moe_experts(x, idx, w, w_up, w_down)[0]
         return jnp.sum(jnp.sin(out.astype(jnp.float32)))
 
     bf = jnp.bfloat16
@@ -181,6 +182,12 @@ def test_held_experts_grouped_products_at_the_published_widths(
         ((16384, 6), jnp.float32), ((8, 2688, 1856), bf),
         ((8, 1856, 2688), bf))
     _kernels_are_called(compiled, ["moe_experts_gmm", "moe_experts_tgmm"])
+    # two products in the forward's loop, five in the backward's
+    in_loop = [line for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line and "/while/body/moe_experts_"
+               in line]
+    assert len(in_loop) == 7
+    assert sum("transpose(jvp())" in line for line in in_loop) == 5
 
 
 def _rpa_shapes(seqs, q_rows, hq, hkv, d, pages, page, per_seq, dtype,
