@@ -38,11 +38,13 @@ gray_list = {
 # input slots whose PARAMETERS stay fp32 at level O2 (no 16-bit live
 # copy, so no master either): the op reads them in fp32 whatever its
 # other inputs are, and a 16-bit copy would round what the op is
-# careful about (the router's matrix decides near-ties; the scan's
-# A_log and dt_bias sit inside exp(dt * A), its D beside it)
+# careful about (the router's matrix decides near-ties; the scans'
+# A_log and dt_bias sit inside an exponential of an exponential, the
+# state-space scan's D beside it)
 fp32_param_slots = {
     "moe_router": ("W", "Bias"),
     "ssd_chunk_scan": ("ALog", "DtBias", "D"),
+    "gated_delta_rule": ("ALog", "DtBias"),
 }
 
 

@@ -223,19 +223,9 @@ def nemotron_h_decoder(ids, cfg, checkpoints_out=None, counters=None):
         epsilon=cfg.layer_norm_epsilon)
 
 
-def nemotron_h_loss(cfg, seq_len, checkpoints_out=None):
-    """Next-token cross-entropy over feed vars `ids` and `labels`
-    [B, seq_len] (the caller shifts: labels[t] is the token after
-    ids[t]), the mean over all positions. Returns (loss, counters,
-    feeds): `counters` is {"moe.held_pairs": var, "moe.load_max_over_mean":
-    var, "moe.rows_made": var} (the routed layers' pairs summed, their
-    fullest-over-mean at its worst, the rows of sorted pairs they made
-    summed: whole row blocks, so no fewer than the pairs), to fetch with
-    the loss where wanted; empty without a routed layer."""
-    ids = layers.data(name="ids", shape=[seq_len], dtype="int64")
-    labels = layers.data(name="labels", shape=[seq_len], dtype="int64")
-    per_layer = []
-    hidden = nemotron_h_decoder(ids, cfg, checkpoints_out, per_layer)
+def next_token_loss(hidden, labels, cfg):
+    """The mean over all positions of the next-token cross-entropy of
+    `hidden` [B, S, H] under an untied head `lm_head` [H, vocab]."""
     per_tok = layers.loss.fused_linear_softmax_xent(
         layers.reshape(hidden, [-1, cfg.hidden_size]),
         layers.reshape(labels, [-1, 1]), cfg.vocab_size,
@@ -243,15 +233,36 @@ def nemotron_h_loss(cfg, seq_len, checkpoints_out=None):
             name="lm_head", initializer=initializer.TruncatedNormal(
                 0.0, cfg.initializer_range)),
         bias_attr=False)
-    loss = layers.mean(per_tok)
-    counters = {}
-    if per_layer:
-        pairs, load, made = per_layer[0]
-        for p, ld, m in per_layer[1:]:
-            pairs = layers.elementwise_add(pairs, p)
-            load = layers.elementwise_max(load, ld)
-            made = layers.elementwise_add(made, m)
-        counters = {"moe.held_pairs": pairs,
-                    "moe.load_max_over_mean": load,
-                    "moe.rows_made": made}
-    return loss, counters, ["ids", "labels"]
+    return layers.mean(per_tok)
+
+
+def routed_counters(per_layer):
+    """{"moe.held_pairs": var, "moe.load_max_over_mean": var,
+    "moe.rows_made": var} from every routed layer's (pairs computed,
+    fullest expert over the mean, rows made): the pairs summed, the
+    fullest-over-mean at its worst, the rows of sorted pairs summed
+    (whole row blocks, so no fewer than the pairs); empty without a
+    routed layer."""
+    if not per_layer:
+        return {}
+    pairs, load, made = per_layer[0]
+    for p, ld, m in per_layer[1:]:
+        pairs = layers.elementwise_add(pairs, p)
+        load = layers.elementwise_max(load, ld)
+        made = layers.elementwise_add(made, m)
+    return {"moe.held_pairs": pairs, "moe.load_max_over_mean": load,
+            "moe.rows_made": made}
+
+
+def nemotron_h_loss(cfg, seq_len, checkpoints_out=None):
+    """Next-token cross-entropy over feed vars `ids` and `labels`
+    [B, seq_len] (the caller shifts: labels[t] is the token after
+    ids[t]), the mean over all positions. Returns (loss, counters,
+    feeds): `counters` is what `routed_counters` gives of the routed
+    layers, to fetch with the loss where wanted."""
+    ids = layers.data(name="ids", shape=[seq_len], dtype="int64")
+    labels = layers.data(name="labels", shape=[seq_len], dtype="int64")
+    per_layer = []
+    hidden = nemotron_h_decoder(ids, cfg, checkpoints_out, per_layer)
+    loss = next_token_loss(hidden, labels, cfg)
+    return loss, routed_counters(per_layer), ["ids", "labels"]

@@ -1,20 +1,26 @@
-"""Ops of hybrid state-space / routed-expert decoders: RMS norm, the
-causal depthwise convolution and the chunked state-space scan of a
-Mamba-2 mixer, and a routed-expert layer in two ops (the router, and
-the experts a chip holds as one grouped matrix product).
+"""Ops of hybrid decoders whose layers are not all softmax attention
+over a dense feed-forward: RMS norm (plain or zero-centred weight) and
+L2 norm, the causal depthwise convolution, the chunked state-space scan
+of a Mamba-2 mixer, the chunked gated delta rule of a Gated DeltaNet
+mixer (a linear-attention state corrected by what it already holds), a
+partial rotary embedding, the gated (SwiGLU) activation, and a
+routed-expert layer in two ops (the router, sigmoid- or softmax-scored,
+and the experts a chip holds as grouped matrix products, plain or
+gated).
 
-Precision is part of each op, not of an AMP list: the norm's
-statistics, the scan's step sizes, decays, cumulative sums and chunk
-states, and the router's scores are float32 whatever the inputs'
-dtype; the matrix products run at the inputs' dtype with float32
-accumulation. `fp16_lists.fp32_param_slots` keeps the parameters
-behind those float32 parts (the scan's `A_log`, `dt_bias`, `D`; the
-router's matrix and bias) float32 under `decorate`.
+Precision is part of each op, not of an AMP list: the norms'
+statistics, the scans' step sizes, decays, cumulative sums, triangular
+solves and chunk states, the rotary angles and the router's scores are
+float32 whatever the inputs' dtype; the matrix products run at the
+inputs' dtype with float32 accumulation. `fp16_lists.fp32_param_slots`
+keeps the parameters behind those float32 parts (the scans' `A_log`,
+`dt_bias`, `D`; the router's matrix and bias) float32 under `decorate`.
 """
 from __future__ import annotations
 
 import functools
 import logging
+import math
 
 import jax
 import jax.numpy as jnp
@@ -31,9 +37,10 @@ _F32 = jnp.float32
 
 @register_op("rms_norm")
 def _rms_norm(ins, attrs):
-    """Y = X * rsqrt(mean(X^2) + epsilon) * Scale over the last axis,
-    or over each of `groups` equal parts of it; statistics in float32,
-    Y at X's dtype."""
+    """Y = X * rsqrt(mean(X^2) + epsilon) * (scale_offset + Scale) over
+    the last axis, or over each of `groups` equal parts of it;
+    statistics in float32, Y at X's dtype. `scale_offset` 1 is the
+    zero-centred weight `(1 + w)`, w starting at zero."""
     x = ins["X"][0]
     groups = int(attrs.get("groups", 1))
     eps = float(attrs.get("epsilon", 1e-5))
@@ -42,8 +49,53 @@ def _rms_norm(ins, attrs):
                        + eps)
     y = y.reshape(x.shape)
     if ins.get("Scale"):
-        y = y * ins["Scale"][0].astype(_F32)
+        scale = ins["Scale"][0].astype(_F32)
+        offset = float(attrs.get("scale_offset", 0.0))
+        y = y * (scale + offset if offset else scale)
     return {"Y": y.astype(x.dtype)}
+
+
+@register_op("l2_norm")
+def _l2_norm(ins, attrs):
+    """Y = X * rsqrt(sum(X^2) + epsilon) over the last axis, the sum in
+    float32, Y at X's dtype."""
+    x = ins["X"][0]
+    xf = x.astype(_F32)
+    y = xf * lax.rsqrt(jnp.sum(jnp.square(xf), axis=-1, keepdims=True)
+                       + float(attrs.get("epsilon", 1e-6)))
+    return {"Y": y.astype(x.dtype)}
+
+
+@register_op("swiglu")
+def _swiglu(ins, attrs):
+    """Out = silu(G) * U for X = [G | U] halved along the last axis:
+    the gated activation between a feed-forward's two products, the
+    gate's and the up projection's matrices laid side by side."""
+    gate, up = jnp.split(ins["X"][0], 2, axis=-1)
+    return {"Out": jax.nn.silu(gate) * up}
+
+
+@register_op("rotary_embedding")
+def _rotary_embedding(ins, attrs):
+    """Rotary position embedding on the first `rotary_dim` of X
+    [B, S, H, D]'s last axis, positions 0 .. S-1, base `theta` (both
+    attributes required), the rotate-half convention: with x = [x1 | x2] the halves of that
+    part, out = [x1 cos - x2 sin | x2 cos + x1 sin], the angle of
+    column i at position t being t * theta^(-2 i / rotary_dim). Angles
+    in float32, Out at X's dtype; the rest of the axis passes through."""
+    x = ins["X"][0]
+    s, d = x.shape[1], x.shape[-1]
+    rd = int(attrs["rotary_dim"])
+    if rd % 2 or not 0 < rd <= d:
+        raise ValueError("rotary_embedding over %d of %d" % (rd, d))
+    inv = jnp.exp(-math.log(float(attrs["theta"]))
+                  * jnp.arange(0, rd, 2, dtype=_F32) / rd)
+    angle = jnp.arange(s, dtype=_F32)[:, None] * inv[None, :]
+    cos, sin = (f(angle)[None, :, None, :] for f in (jnp.cos, jnp.sin))
+    x1, x2 = (x[..., :rd // 2].astype(_F32), x[..., rd // 2:rd].astype(_F32))
+    turned = jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+    return {"Out": jnp.concatenate([turned, x[..., rd:]], axis=-1)}
 
 
 @register_op("causal_conv1d")
@@ -198,21 +250,287 @@ def _ssd_chunk_scan(ins, attrs):
 
 
 # ---------------------------------------------------------------------------
+# The chunked gated delta rule (Gated DeltaNet: arXiv:2412.06464, section
+# 3.3; the WY form of arXiv:2406.06484, section 3)
+# ---------------------------------------------------------------------------
+
+#: positions a chunk, and the diagonal blocks its triangular system is
+#: inverted in
+_GDR_CHUNK, _GDR_BLOCK = 64, 16
+#: the most one head group's [chunk, chunk] float32 values may take
+_GDR_TILE_BYTES = 32 << 20
+_HIGHEST = lax.Precision.HIGHEST
+
+
+def _unit_lower_inverse(a):
+    """(I + a)^-1 for strictly lower triangular a [..., C, C], float32,
+    in matrix products alone. With d the diagonal `_GDR_BLOCK`-blocks of
+    a, d^16 = 0, so (I + d)^-1 = (I - d)(I + d^2)(I + d^4)(I + d^8);
+    then I + a = (I + d)(I + m) with m = (I + d)^-1 (a - d) strictly
+    block-lower, m^(C/16) = 0, and (I + m)^-1 the same product over m's
+    powers. Two short series and not one of C terms: the powers of a
+    whole chunk's matrix grow as binomials before they cancel."""
+    c = a.shape[-1]
+    eye = jnp.eye(c, dtype=a.dtype)
+    block = jnp.arange(c) // _GDR_BLOCK
+    mm = functools.partial(jnp.matmul, precision=_HIGHEST)
+
+    def series(x, nilpotent):
+        inv, power = eye - x, x
+        for _ in range(max(0, (nilpotent - 1).bit_length() - 1)):
+            power = mm(power, power)
+            inv = mm(inv, eye + power)
+        return inv
+
+    d = jnp.where(block[:, None] == block[None, :], a, 0.0)
+    d_inv = series(d, _GDR_BLOCK)
+    return mm(series(mm(d_inv, a - d), -(-c // _GDR_BLOCK)), d_inv)
+
+
+def _gdr_local(q, k, v, gc, beta):
+    """What a chunk's positions need of one another, every chunk at
+    once. q, k [B, N, H, C, dk]; v [B, N, H, R, C, dv] (R value heads a
+    key head); gc (the log-decays summed from the chunk's start) and
+    beta [B, N, H, R, C] float32. With decay_ij = exp(gc_i - gc_j) and
+    T = (I + tril(diag(beta) K K^T . decay, -1))^-1, returns
+    W = T (beta exp(gc) K), U0 = T (beta V) (a chunk's corrections are
+    U0 - W S for the state S at its start), the causal Q K^T . decay,
+    Q exp(gc), K exp(gc_C - gc) and exp(gc_C)."""
+    cd = q.dtype
+    chunk = q.shape[-2]
+    kk, qk = (jnp.einsum("bnhid,bnhjd->bnhij", x, k,
+                         preferred_element_type=_F32)[:, :, :, None]
+              for x in (k, q))
+    rows, cols = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+    decay = jnp.exp(jnp.where(
+        rows >= cols, gc[..., :, None] - gc[..., None, :], -jnp.inf))
+    t = _unit_lower_inverse(jnp.where(
+        rows > cols, beta[..., None] * decay * kk, 0.0)).astype(cd)
+    kf, grow = k.astype(_F32)[:, :, :, None], jnp.exp(gc)[..., None]
+    solve = functools.partial(jnp.einsum, "bnhrij,bnhrjd->bnhrid", t,
+                              preferred_element_type=_F32)
+    w = solve((kf * (beta[..., None] * grow)).astype(cd)).astype(cd)
+    u0 = solve((v.astype(_F32) * beta[..., None]).astype(cd))
+    last = gc[..., -1:]
+    return (w, u0, (qk * decay).astype(cd),
+            (q.astype(_F32)[:, :, :, None] * grow).astype(cd),
+            (kf * jnp.exp(last - gc)[..., None]).astype(cd),
+            jnp.exp(last[..., 0]))
+
+
+def _chunked(t, chunk, heads):
+    """[B, S, heads * R, ...] -> [B, N, heads, R, chunk, ...]"""
+    b, s = t.shape[:2]
+    t = t.reshape((b, s // chunk, chunk, heads, -1) + t.shape[3:])
+    return jnp.moveaxis(t, 2, 4)
+
+
+def _unchunked(t):
+    """[B, N, H, R, chunk, ...] -> [B, S, H * R, ...]"""
+    t = jnp.moveaxis(t, 4, 2)
+    return t.reshape((t.shape[0], t.shape[1] * t.shape[2], -1) + t.shape[5:])
+
+
+def _gdr_group_inputs(q, k, v, gc, beta):
+    hk = q.shape[2]
+    q, k = (_chunked(x, _GDR_CHUNK, hk)[:, :, :, 0] for x in (q, k))
+    return (q, k) + tuple(_chunked(x, _GDR_CHUNK, hk)
+                          for x in (v, gc, beta))
+
+
+def _chunks_first(*ts):
+    return tuple(jnp.moveaxis(t, 1, 0) for t in ts)
+
+
+def _gdr_group_fwd(q, k, v, gc, beta):
+    """One group of key heads: (out [B, S, Hv, dv], the state at every
+    chunk's start [B, N, H, R, dk, dv] float32)."""
+    cd = q.dtype
+    w, u0, aqk, qg, kd, gl = _gdr_local(*_gdr_group_inputs(q, k, v, gc, beta))
+
+    def step(state, xs):
+        w_c, u0_c, aqk_c, qg_c, kd_c, gl_c = xs
+        low = state.astype(cd)
+        u = (u0_c - jnp.einsum("bhrid,bhrde->bhrie", w_c, low,
+                               preferred_element_type=_F32)).astype(cd)
+        out = (jnp.einsum("bhrid,bhrde->bhrie", qg_c, low,
+                          preferred_element_type=_F32)
+               + jnp.einsum("bhrij,bhrje->bhrie", aqk_c, u,
+                            preferred_element_type=_F32))
+        after = gl_c[..., None, None] * state + jnp.einsum(
+            "bhrid,bhrie->bhrde", kd_c, u, preferred_element_type=_F32)
+        return after, (out.astype(cd), state)
+
+    b, _, h, r, _, dk = qg.shape
+    _, (out, starts) = lax.scan(
+        step, jnp.zeros((b, h, r, dk, v.shape[-1]), _F32),
+        _chunks_first(w, u0, aqk, qg, kd, gl))
+    return _unchunked(jnp.moveaxis(out, 0, 1)), jnp.moveaxis(starts, 0, 1)
+
+
+def _gdr_group_bwd(q, k, v, gc, beta, starts, d_out):
+    """The group's chunk-local values made again and transposed: a
+    second scan, from the last chunk to the first, carries the state's
+    cotangent; what it needs of a chunk is linear in that state."""
+    cd = q.dtype
+    ins = _gdr_group_inputs(q, k, v, gc, beta)
+    (w, u0, aqk, qg, kd, gl), local_vjp = jax.vjp(_gdr_local, *ins)
+    d_out = _chunked(d_out, _GDR_CHUNK, q.shape[2])
+    low = starts.astype(cd)
+    u = (u0 - jnp.einsum("bnhrid,bnhrde->bnhrie", w, low,
+                         preferred_element_type=_F32)).astype(cd)
+
+    def step(d_state, xs):
+        w_c, aqk_c, qg_c, kd_c, gl_c, u_c, start_c, do_c = xs
+        d_low = d_state.astype(cd)
+        d_u = (jnp.einsum("bhrij,bhrie->bhrje", aqk_c, do_c,
+                          preferred_element_type=_F32)
+               + jnp.einsum("bhrjd,bhrde->bhrje", kd_c, d_low,
+                            preferred_element_type=_F32))
+        d_kd = jnp.einsum("bhrje,bhrde->bhrjd", u_c, d_low,
+                          preferred_element_type=_F32)
+        d_gl = jnp.sum(d_state * start_c, axis=(-2, -1))
+        before = (jnp.einsum("bhrid,bhrie->bhrde", qg_c, do_c,
+                             preferred_element_type=_F32)
+                  + gl_c[..., None, None] * d_state
+                  - jnp.einsum("bhrid,bhrie->bhrde", w_c, d_u.astype(cd),
+                               preferred_element_type=_F32))
+        return before, (d_u, d_kd.astype(cd), d_gl)
+
+    _, (d_u, d_kd, d_gl) = lax.scan(
+        step, jnp.zeros(starts.shape[:1] + starts.shape[2:], _F32),
+        _chunks_first(w, aqk, qg, kd, gl, u, starts, d_out), reverse=True)
+    d_u, d_kd, d_gl = (jnp.moveaxis(t, 0, 1) for t in (d_u, d_kd, d_gl))
+    d_uc = d_u.astype(cd)
+    d_w = -jnp.einsum("bnhrie,bnhrde->bnhrid", d_uc, low,
+                      preferred_element_type=_F32)
+    d_aqk = jnp.einsum("bnhrie,bnhrje->bnhrij", d_out, u,
+                       preferred_element_type=_F32)
+    d_qg = jnp.einsum("bnhrie,bnhrde->bnhrid", d_out, low,
+                      preferred_element_type=_F32)
+    d_q, d_k, d_v, d_gc, d_beta = local_vjp(
+        (d_w.astype(cd), d_u, d_aqk.astype(cd), d_qg.astype(cd), d_kd,
+         d_gl))
+    return (_unchunked(d_q[:, :, :, None]), _unchunked(d_k[:, :, :, None]),
+            _unchunked(d_v), _unchunked(d_gc), _unchunked(d_beta))
+
+
+def _gdr_groups(b, n_chunks, hk, r):
+    """Groups of key heads the op walks one after another: the fewest
+    that keep a group's [chunk, chunk] float32 values (one per value
+    head and chunk) under `_GDR_TILE_BYTES`."""
+    tile = b * n_chunks * r * _GDR_CHUNK * _GDR_CHUNK * 4
+    return next(g for g in range(1, hk + 1)
+                if hk % g == 0 and tile * (hk // g) <= _GDR_TILE_BYTES
+                or g == hk)
+
+
+def _head_groups(groups, *ts):
+    """[B, S, H, ...] -> [groups, B, S, H / groups, ...] of each"""
+    return tuple(jnp.moveaxis(t.reshape(
+        t.shape[:2] + (groups, -1) + t.shape[3:]), 2, 0) for t in ts)
+
+
+@jax.custom_vjp
+def _gdr(q, k, v, gc, beta):
+    return _gdr_fwd(q, k, v, gc, beta)[0]
+
+
+def _gdr_fwd(q, k, v, gc, beta):
+    groups = _gdr_groups(q.shape[0], q.shape[1] // _GDR_CHUNK, q.shape[2],
+                         v.shape[2] // q.shape[2])
+    out, starts = lax.map(lambda a: _gdr_group_fwd(*a),
+                          _head_groups(groups, q, k, v, gc, beta))
+    return _join_groups(out), (q, k, v, gc, beta, starts)
+
+
+def _gdr_bwd(res, d_out):
+    *ins, starts = res
+    groups = starts.shape[0]
+    *ins, d_out = _head_groups(groups, *ins, d_out)
+    grads = lax.map(lambda a: _gdr_group_bwd(*a), (*ins, starts, d_out))
+    return tuple(_join_groups(g) for g in grads)
+
+
+_gdr.defvjp(_gdr_fwd, _gdr_bwd)
+
+
+def gated_delta_rule(q, k, v, g, beta):
+    """o_t = S_t^T q_t for the state S in R^{dk x dv} of each value
+    head, S_0 = 0: S' = exp(g_t) S_{t-1}; u_t = beta_t (v_t - S'^T k_t);
+    S_t = S' + k_t u_t^T: the state decays, is read back at the new
+    key, and takes the part of the value it did not already hold.
+    q, k [B, S, Hk, dk]; v [B, S, Hv, dv], value head j reading key
+    head j // (Hv / Hk); g (log-decay, <= 0) and beta [B, S, Hv].
+
+    Computed `_GDR_CHUNK` positions a chunk (a sequence that is no
+    whole number of chunks is padded with positions that decay nothing
+    and write nothing): inside a chunk the corrections u solve a unit
+    lower triangular system (`_gdr_local`), between chunks a scan
+    carries the state. Log-decays, their sums, the system's inverse
+    and the states are float32; the products run at the inputs' dtype
+    with float32 accumulation. The backward pass is the op's own
+    (`_gdr_group_bwd`): it keeps the inputs and the state at every
+    chunk's start, nothing [S, S]-shaped and nothing a position."""
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    if hv % hk or k.shape != q.shape or g.shape != (b, s, hv):
+        raise ValueError("gated_delta_rule: q %s k %s v %s g %s beta %s"
+                         % (q.shape, k.shape, v.shape, g.shape, beta.shape))
+    pad = -s % _GDR_CHUNK
+    g, beta = g.astype(_F32), beta.astype(_F32)
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, g, beta))
+    n = (s + pad) // _GDR_CHUNK
+    gc = jnp.cumsum(g.reshape(b, n, _GDR_CHUNK, hv), axis=2).reshape(
+        b, n * _GDR_CHUNK, hv)
+    groups = _gdr_groups(b, n, hk, hv // hk)
+    # said where the op is traced: at the build's shape inference and
+    # once a compile and layer
+    logging.getLogger(__name__).info(
+        "gated_delta_rule q, k %s v %s %s: %d positions a chunk, %d "
+        "chunks, %d head groups; kept for the backward pass %d bytes "
+        "(the inputs and %d states [%d, %d] float32)",
+        tuple(q.shape), tuple(v.shape), q.dtype.name, _GDR_CHUNK, n, groups,
+        sum(t.size * t.dtype.itemsize for t in (q, k, v, gc, beta))
+        + b * n * hv * dk * dv * 4, b * n * hv, dk, dv)
+    return _gdr(q, k, v, gc, beta)[:, :s]
+
+
+@register_op("gated_delta_rule")
+def _gated_delta_rule(ins, attrs):
+    """The gated delta rule of a Gated DeltaNet mixer
+    (`gated_delta_rule` above): Q, K [B, S, Hk, dk], V [B, S, Hv, dv];
+    A and B [B, S, Hv], the projections behind the decay and the
+    writing strength; ALog, DtBias [Hv]. beta = sigmoid(B) and
+    g = -exp(ALog) softplus(A + DtBias), both float32."""
+    g = -jnp.exp(ins["ALog"][0].astype(_F32)) * jax.nn.softplus(
+        ins["A"][0].astype(_F32) + ins["DtBias"][0].astype(_F32))
+    return {"Out": gated_delta_rule(
+        ins["Q"][0], ins["K"][0], ins["V"][0], g,
+        jax.nn.sigmoid(ins["B"][0].astype(_F32)))}
+
+
+# ---------------------------------------------------------------------------
 # Routed experts
 # ---------------------------------------------------------------------------
 
 @register_op("moe_router")
 def _moe_router(ins, attrs):
-    """Scores s = sigmoid(X W) over ALL experts in float32; the `top_k`
-    largest of s + Bias (Bias steers the choice only and gets no
-    gradient); weights s_k / sum_k s_k where `norm_topk_prob`, times
+    """Scores s = sigmoid(X W), or softmax(X W) where `score_function`
+    says so, over ALL experts in float32; the `top_k` largest of
+    s + Bias (Bias steers the choice only and gets no gradient);
+    weights s_k / sum_k s_k where `norm_topk_prob`, times
     `routed_scaling_factor`. X [..., H] -> TopkIdx [T, k] int32,
     TopkWeight [T, k] float32, T the flattened leading axes."""
     x, w = ins["X"][0], ins["W"][0]
     k = int(attrs["top_k"])
     x2 = x.reshape(-1, x.shape[-1]).astype(_F32)
-    s = jax.nn.sigmoid(jnp.dot(x2, w.astype(_F32),
-                               precision=lax.Precision.HIGHEST))
+    score = {"sigmoid": jax.nn.sigmoid, "softmax": functools.partial(
+        jax.nn.softmax, axis=-1)}[attrs.get("score_function", "sigmoid")]
+    s = score(jnp.dot(x2, w.astype(_F32), precision=lax.Precision.HIGHEST))
     pick = s
     if ins.get("Bias"):
         pick = s + lax.stop_gradient(ins["Bias"][0].astype(_F32))

@@ -404,6 +404,17 @@ def _build_nemotron_h():
     return main, st, _batch(cfg, 3), loss
 
 
+def _build_qwen3_next():
+    """The gated-delta-rule / gated-attention decoder's step: bf16 AMP,
+    every mixer and every routed layer recomputed."""
+    from paddle_tpu.models import qwen3_next
+    from test_qwen3_next import _batch, _build
+
+    cfg = qwen3_next.Qwen3NextConfig.tiny(experts_held=(0, 4))
+    main, st, loss, _ = _build(cfg, True)
+    return main, st, _batch(cfg, 3), loss
+
+
 _FLASH_S = 128
 
 
@@ -431,9 +442,12 @@ def _flash_from_its_length(monkeypatch):
 #: sha256 of the step's jaxpr (addresses scrubbed) on this container's
 #: jax. The first two as PR 26 lowered them; PR 27 (new ops, AMP's
 #: fp32-pinned parameter slots, the segment policy, grouped-query
-#: flash) left both as they were. The last as PR 28 lowered it; the
+#: flash) left both as they were. The fourth as PR 28 lowered it; the
 #: hybrid decoder's was retaken in PR 30 (`moe_experts` walks row
-#: blocks in a loop, with a gradient of its own): with these every kind
+#: blocks in a loop, with a gradient of its own) and PR 31 (a score
+#: function for the router, an offset for the norm's weight, both
+#: attributes it does not set) left all four as they were and added
+#: the fifth, its own decoder's: with these every kind
 #: of step the benchmark runs is held. A PR that means to change one of
 #: these programs replaces the digest and says so
 _STEP_DIGESTS = {
@@ -441,12 +455,14 @@ _STEP_DIGESTS = {
     "_build_resnet50": "deed87d731a3ffb9",
     "_build_nemotron_h": "9df08f21cb176523",
     "_build_scan_bert_flash": "7c1a665f24fa7e4a",
+    "_build_qwen3_next": "0844c7a3beaddb8c",
 }
 
 
 @pytest.mark.parametrize("build", [_build_scan_bert_remat,
                                    _build_resnet50, _build_nemotron_h,
-                                   _build_scan_bert_flash])
+                                   _build_scan_bert_flash,
+                                   _build_qwen3_next])
 def test_berts_and_resnets_steps_are_the_accepted_programs(
         monkeypatch, build):
     import hashlib

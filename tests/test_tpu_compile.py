@@ -190,6 +190,78 @@ def test_held_experts_grouped_products_at_the_published_widths(
     assert sum("transpose(jvp())" in line for line in in_loop) == 5
 
 
+def test_flash_at_a_head_of_256_and_what_a_trace_calls_it(one_chip, mosaic):
+    """A gated attention layer's call: 16 query heads on 2 key/value
+    heads of 256 at 16,384, causal. `block_rule` is a function of the
+    head's width and had run at 64 and 128 only: at 256 the streamed
+    block halves (4,096 keys) and the figure stays under the budget."""
+    blocks = fa.block_rule(16384, 16384, 256, jnp.bfloat16, True, False)
+    assert (blocks.block_q, blocks.block_k, blocks.sub_k) == (512, 4096, 512)
+    assert blocks.vmem_bytes <= fa._VMEM_BUDGET
+
+    def loss(q, k, v):
+        o = fa.flash_attention(q, k, v, causal=True, sm_scale=1 / 16.0)
+        return jnp.sum(jnp.sin(o.astype(jnp.float32)))
+
+    q = ((1, 16, 16384, 256), jnp.bfloat16)
+    kv = ((1, 2, 16384, 256), jnp.bfloat16)
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q,
+                        kv, kv)
+    _kernels_are_called(compiled, fa.KERNEL_NAMES)
+
+
+def test_gated_experts_grouped_products_at_the_published_widths(
+        one_chip, monkeypatch):
+    """32 held experts of 2048 x (2 x 512) and 512 x 2048 over 16,384
+    tokens routed top-10 of 512, `swiglu` between the two grouped
+    products: the same loops over row blocks, the up product twice as
+    wide as the down product's rows."""
+    hybrid = importlib.import_module("paddle_tpu.ops.hybrid_ops")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(x, idx, w, w_up, w_down):
+        out = hybrid.moe_experts(x, idx, w, w_up, w_down,
+                                 activation="swiglu", num_experts=512)[0]
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+
+    bf = jnp.bfloat16
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 2, 3, 4)), one_chip,
+        ((16384, 2048), bf), ((16384, 10), jnp.int32),
+        ((16384, 10), jnp.float32), ((32, 2048, 1024), bf),
+        ((32, 512, 2048), bf))
+    _kernels_are_called(compiled, ["moe_experts_gmm", "moe_experts_tgmm"])
+    assert hybrid.row_block(163840, 32, 512) == 13824
+
+
+def test_gated_delta_rule_at_the_published_widths(one_chip):
+    """A Gated DeltaNet mixer's rule as the long-document cell runs it:
+    16 key heads and 32 value heads of 128, one document of 16,384: 256
+    chunks walked in 4 groups of key heads. Plain `jax.numpy`: no
+    kernel is called, the two scans over chunks are loops of the
+    compiled program, and what the custom gradient keeps (the five
+    inputs and 8,192 chunk states) with the group's temporaries fits
+    well inside what the step has left."""
+    hybrid = importlib.import_module("paddle_tpu.ops.hybrid_ops")
+    assert hybrid._gdr_groups(1, 256, 16, 2) == 4
+
+    def loss(q, k, v, g, beta):
+        out = hybrid.gated_delta_rule(q, k, v, -jax.nn.softplus(g),
+                                      jax.nn.sigmoid(beta))
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((1, 16384, 16, 128), bf), ((1, 16384, 16, 128), bf),
+        ((1, 16384, 32, 128), bf), ((1, 16384, 32), f32),
+        ((1, 16384, 32), f32))]
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and text.count(" while(") >= 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
 def _rpa_shapes(seqs, q_rows, hq, hkv, d, pages, page, per_seq, dtype,
                 scales=False):
     shapes = [((seqs, q_rows, hq, d),
