@@ -287,25 +287,31 @@ def _unit_lower_inverse(a):
     return mm(series(mm(d_inv, a - d), -(-c // _GDR_BLOCK)), d_inv)
 
 
+def _gdr_inverse(kk, gc, beta, cd):
+    """(T = (I + tril(diag(beta) K K^T . decay, -1))^-1 at `cd`, decay)
+    for kk = K K^T [B, N, H, 1, C, C] float32 and decay_ij =
+    exp(gc_i - gc_j) on and under the diagonal, nought above it."""
+    chunk = gc.shape[-1]
+    rows, cols = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+    decay = jnp.exp(jnp.where(
+        rows >= cols, gc[..., :, None] - gc[..., None, :], -jnp.inf))
+    return _unit_lower_inverse(jnp.where(
+        rows > cols, beta[..., None] * decay * kk, 0.0)).astype(cd), decay
+
+
 def _gdr_local(q, k, v, gc, beta):
     """What a chunk's positions need of one another, every chunk at
     once. q, k [B, N, H, C, dk]; v [B, N, H, R, C, dv] (R value heads a
     key head); gc (the log-decays summed from the chunk's start) and
-    beta [B, N, H, R, C] float32. With decay_ij = exp(gc_i - gc_j) and
-    T = (I + tril(diag(beta) K K^T . decay, -1))^-1, returns
+    beta [B, N, H, R, C] float32. With T of `_gdr_inverse`, returns
     W = T (beta exp(gc) K), U0 = T (beta V) (a chunk's corrections are
     U0 - W S for the state S at its start), the causal Q K^T . decay,
     Q exp(gc), K exp(gc_C - gc) and exp(gc_C)."""
     cd = q.dtype
-    chunk = q.shape[-2]
     kk, qk = (jnp.einsum("bnhid,bnhjd->bnhij", x, k,
                          preferred_element_type=_F32)[:, :, :, None]
               for x in (k, q))
-    rows, cols = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
-    decay = jnp.exp(jnp.where(
-        rows >= cols, gc[..., :, None] - gc[..., None, :], -jnp.inf))
-    t = _unit_lower_inverse(jnp.where(
-        rows > cols, beta[..., None] * decay * kk, 0.0)).astype(cd)
+    t, decay = _gdr_inverse(kk, gc, beta, cd)
     kf, grow = k.astype(_F32)[:, :, :, None], jnp.exp(gc)[..., None]
     solve = functools.partial(jnp.einsum, "bnhrij,bnhrjd->bnhrid", t,
                               preferred_element_type=_F32)
@@ -342,11 +348,12 @@ def _chunks_first(*ts):
     return tuple(jnp.moveaxis(t, 1, 0) for t in ts)
 
 
-def _gdr_group_fwd(q, k, v, gc, beta):
-    """One group of key heads: (out [B, S, Hv, dv], the state at every
-    chunk's start [B, N, H, R, dk, dv] float32)."""
-    cd = q.dtype
-    w, u0, aqk, qg, kd, gl = _gdr_local(*_gdr_group_inputs(q, k, v, gc, beta))
+def _gdr_walk_fwd(w, u0, aqk, qg, kd, gl):
+    """The chunks of `_gdr_local`'s values walked first to last by a
+    `lax.scan`: (out [N, B, H, R, C, dv], the state at every chunk's
+    start [N, B, H, R, dk, dv] float32), the chunk axis first as the
+    scan stacks them."""
+    cd = w.dtype
 
     def step(state, xs):
         w_c, u0_c, aqk_c, qg_c, kd_c, gl_c = xs
@@ -362,23 +369,15 @@ def _gdr_group_fwd(q, k, v, gc, beta):
         return after, (out.astype(cd), state)
 
     b, _, h, r, _, dk = qg.shape
-    _, (out, starts) = lax.scan(
-        step, jnp.zeros((b, h, r, dk, v.shape[-1]), _F32),
-        _chunks_first(w, u0, aqk, qg, kd, gl))
-    return _unchunked(jnp.moveaxis(out, 0, 1)), jnp.moveaxis(starts, 0, 1)
+    return lax.scan(
+        step, jnp.zeros((b, h, r, dk, u0.shape[-1]), _F32),
+        _chunks_first(w, u0, aqk, qg, kd, gl))[1]
 
 
-def _gdr_group_bwd(q, k, v, gc, beta, starts, d_out):
-    """The group's chunk-local values made again and transposed: a
-    second scan, from the last chunk to the first, carries the state's
-    cotangent; what it needs of a chunk is linear in that state."""
-    cd = q.dtype
-    ins = _gdr_group_inputs(q, k, v, gc, beta)
-    (w, u0, aqk, qg, kd, gl), local_vjp = jax.vjp(_gdr_local, *ins)
-    d_out = _chunked(d_out, _GDR_CHUNK, q.shape[2])
-    low = starts.astype(cd)
-    u = (u0 - jnp.einsum("bnhrid,bnhrde->bnhrie", w, low,
-                         preferred_element_type=_F32)).astype(cd)
+def _gdr_walk_bwd(w, aqk, qg, kd, gl, u, starts, d_out):
+    """The same chunks walked last to first, the state's cotangent
+    carried: (d_u float32, d_kd, d_gl), a chunk each."""
+    cd = w.dtype
 
     def step(d_state, xs):
         w_c, aqk_c, qg_c, kd_c, gl_c, u_c, start_c, do_c = xs
@@ -397,10 +396,67 @@ def _gdr_group_bwd(q, k, v, gc, beta, starts, d_out):
                                preferred_element_type=_F32))
         return before, (d_u, d_kd.astype(cd), d_gl)
 
-    _, (d_u, d_kd, d_gl) = lax.scan(
+    _, walked = lax.scan(
         step, jnp.zeros(starts.shape[:1] + starts.shape[2:], _F32),
         _chunks_first(w, aqk, qg, kd, gl, u, starts, d_out), reverse=True)
-    d_u, d_kd, d_gl = (jnp.moveaxis(t, 0, 1) for t in (d_u, d_kd, d_gl))
+    return tuple(jnp.moveaxis(t, 0, 1) for t in walked)
+
+
+def _gdr_group_fwd(q, k, v, gc, beta):
+    """One group of key heads: (out [B, S, Hv, dv], the state at every
+    chunk's start [B, N, H, R, dk, dv] float32)."""
+    out, starts = _gdr_walk_fwd(
+        *_gdr_local(*_gdr_group_inputs(q, k, v, gc, beta)))
+    return _unchunked(jnp.moveaxis(out, 0, 1)), jnp.moveaxis(starts, 0, 1)
+
+
+def _gdr_group_fwd_kernel(into, q, k, v, gc, beta):
+    """The same through the forward kernel, which is handed the
+    triangular system's inverse (float32 products, here) and makes what
+    else a chunk's positions need of one another where it walks.
+    `into`: the stack of every group's chunk states and this group's
+    place in it, or None where no state is kept. Returns (out, stack)."""
+    from .pallas.gated_delta_rule import gated_delta_rule_fwd
+
+    q, k, v, gc, beta = _gdr_group_inputs(q, k, v, gc, beta)
+    kk = jnp.einsum("bnhid,bnhjd->bnhij", k, k,
+                    preferred_element_type=_F32)[:, :, :, None]
+    out, stack = gated_delta_rule_fwd(
+        q, k, v, _gdr_inverse(kk, gc, beta, q.dtype)[0], gc, beta, into)
+    return _unchunked(out), stack
+
+
+def _gdr_group_bwd(q, k, v, gc, beta, starts, d_out, group=None):
+    """The group's chunk-local values made again and transposed: a
+    second walk, from the last chunk to the first, carries the state's
+    cotangent; what it needs of a chunk is linear in that state. With
+    `group`, `starts` is the stack of every group's chunk states, this
+    group's at that place, and the reverse kernel walks (it reads the
+    states where they lie); without, a `lax.scan` does."""
+    cd = q.dtype
+    ins = _gdr_group_inputs(q, k, v, gc, beta)
+    (w, u0, aqk, qg, kd, gl), local_vjp = jax.vjp(_gdr_local, *ins)
+    d_out = _chunked(d_out, _GDR_CHUNK, q.shape[2])
+    stack = starts
+    if group is not None:
+        starts = lax.dynamic_index_in_dim(stack, group, keepdims=False)
+    low = starts.astype(cd)
+    u = (u0 - jnp.einsum("bnhrid,bnhrde->bnhrie", w, low,
+                         preferred_element_type=_F32)).astype(cd)
+    if group is not None:
+        from .pallas.gated_delta_rule import gated_delta_rule_bwd
+
+        # a loop stands between what is made before it and after it;
+        # a kernel's call does not, and the compiler then schedules the
+        # transposed products' [C, C] values round it: 0.2 GB more and
+        # 9 ms a step
+        w_, aqk_, qg_, kd_, gl_, u_, do_ = lax.optimization_barrier(
+            (w, aqk, qg, kd, gl, u, d_out))
+        d_u, d_kd, d_gl = lax.optimization_barrier(gated_delta_rule_bwd(
+            w_, aqk_, qg_, kd_, gl_, u_, stack, group, do_))
+    else:
+        d_u, d_kd, d_gl = _gdr_walk_bwd(w, aqk, qg, kd, gl, u, starts,
+                                        d_out)
     d_uc = d_u.astype(cd)
     d_w = -jnp.einsum("bnhrie,bnhrde->bnhrid", d_uc, low,
                       preferred_element_type=_F32)
@@ -431,31 +487,56 @@ def _head_groups(groups, *ts):
         t.shape[:2] + (groups, -1) + t.shape[3:]), 2, 0) for t in ts)
 
 
-@jax.custom_vjp
-def _gdr(q, k, v, gc, beta):
-    return _gdr_fwd(q, k, v, gc, beta)[0]
-
-
-def _gdr_fwd(q, k, v, gc, beta):
+def _gdr_grouped(q, k, v, gc, beta):
     groups = _gdr_groups(q.shape[0], q.shape[1] // _GDR_CHUNK, q.shape[2],
                          v.shape[2] // q.shape[2])
-    out, starts = lax.map(lambda a: _gdr_group_fwd(*a),
-                          _head_groups(groups, q, k, v, gc, beta))
+    return _head_groups(groups, q, k, v, gc, beta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _gdr(q, k, v, gc, beta, kernel):
+    if kernel:
+        # a kernel's output cannot be dropped where nobody reads it
+        return _join_groups(lax.map(
+            lambda a: _gdr_group_fwd_kernel(None, *a)[0],
+            _gdr_grouped(q, k, v, gc, beta)))
+    return _gdr_fwd(q, k, v, gc, beta, kernel)[0]
+
+
+def _gdr_fwd(q, k, v, gc, beta, kernel):
+    grouped = _gdr_grouped(q, k, v, gc, beta)
+    if kernel:
+        # each group's walk writes its states into the one stack
+        groups, b, s, hk, dk = grouped[0].shape
+        hv, dv = grouped[2].shape[3:]
+        starts, out = lax.scan(
+            lambda stack, a: _gdr_group_fwd_kernel((stack, a[0]), *a[1:])[
+                ::-1],
+            lax.empty((groups, b, s // _GDR_CHUNK, hk, hv // hk, dk, dv),
+                      _F32),
+            (jnp.arange(groups),) + grouped)
+    else:
+        out, starts = lax.map(lambda a: _gdr_group_fwd(*a), grouped)
     return _join_groups(out), (q, k, v, gc, beta, starts)
 
 
-def _gdr_bwd(res, d_out):
+def _gdr_bwd(kernel, res, d_out):
     *ins, starts = res
     groups = starts.shape[0]
     *ins, d_out = _head_groups(groups, *ins, d_out)
-    grads = lax.map(lambda a: _gdr_group_bwd(*a), (*ins, starts, d_out))
+    if kernel:
+        grads = lax.map(
+            lambda a: _gdr_group_bwd(*a[1:6], starts, a[6], group=a[0]),
+            (jnp.arange(groups), *ins, d_out))
+    else:
+        grads = lax.map(lambda a: _gdr_group_bwd(*a), (*ins, starts, d_out))
     return tuple(_join_groups(g) for g in grads)
 
 
 _gdr.defvjp(_gdr_fwd, _gdr_bwd)
 
 
-def gated_delta_rule(q, k, v, g, beta):
+def gated_delta_rule(q, k, v, g, beta, kernel=None):
     """o_t = S_t^T q_t for the state S in R^{dk x dv} of each value
     head, S_0 = 0: S' = exp(g_t) S_{t-1}; u_t = beta_t (v_t - S'^T k_t);
     S_t = S' + k_t u_t^T: the state decays, is read back at the new
@@ -466,12 +547,19 @@ def gated_delta_rule(q, k, v, g, beta):
     Computed `_GDR_CHUNK` positions a chunk (a sequence that is no
     whole number of chunks is padded with positions that decay nothing
     and write nothing): inside a chunk the corrections u solve a unit
-    lower triangular system (`_gdr_local`), between chunks a scan
+    lower triangular system (`_gdr_local`), between chunks a walk
     carries the state. Log-decays, their sums, the system's inverse
     and the states are float32; the products run at the inputs' dtype
     with float32 accumulation. The backward pass is the op's own
-    (`_gdr_group_bwd`): it keeps the inputs and the state at every
-    chunk's start, nothing [S, S]-shaped and nothing a position."""
+    (`_gdr_group_bwd`, a second walk from the last chunk to the
+    first): it keeps the inputs and the state at every chunk's start,
+    nothing [S, S]-shaped and nothing a position. `kernel` None has
+    the two walks made by the Pallas kernels of
+    `pallas/gated_delta_rule.py` on a TPU where dk and dv are whole
+    lane tiles (multiples of 128), and by `lax.scan` elsewhere. The
+    inverse and the backward pass's chunk-local values are `jax.numpy`
+    either way; the forward kernel makes the rest of a chunk's local
+    values itself."""
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2:]
     if hv % hk or k.shape != q.shape or g.shape != (b, s, hv):
@@ -487,16 +575,27 @@ def gated_delta_rule(q, k, v, g, beta):
     gc = jnp.cumsum(g.reshape(b, n, _GDR_CHUNK, hv), axis=2).reshape(
         b, n * _GDR_CHUNK, hv)
     groups = _gdr_groups(b, n, hk, hv // hk)
+    if kernel is None:
+        kernel = (jax.default_backend() == "tpu" and dk % 128 == 0
+                  and dv % 128 == 0)
+    walked = "jax.numpy"
+    if kernel:
+        from .pallas.gated_delta_rule import heads_a_step
+
+        heads = heads_a_step(hk // groups, hv // hk, _GDR_CHUNK, dk, dv,
+                             q.dtype.itemsize)
+        walked = "pallas (%d value heads a grid step, %d grid steps a call)" \
+            % (heads, b * (hv // groups // heads) * n)
     # said where the op is traced: at the build's shape inference and
     # once a compile and layer
     logging.getLogger(__name__).info(
         "gated_delta_rule q, k %s v %s %s: %d positions a chunk, %d "
-        "chunks, %d head groups; kept for the backward pass %d bytes "
-        "(the inputs and %d states [%d, %d] float32)",
+        "chunks, %d head groups, the chunks walked by %s; kept for the "
+        "backward pass %d bytes (the inputs and %d states [%d, %d] float32)",
         tuple(q.shape), tuple(v.shape), q.dtype.name, _GDR_CHUNK, n, groups,
-        sum(t.size * t.dtype.itemsize for t in (q, k, v, gc, beta))
+        walked, sum(t.size * t.dtype.itemsize for t in (q, k, v, gc, beta))
         + b * n * hv * dk * dv * 4, b * n * hv, dk, dv)
-    return _gdr(q, k, v, gc, beta)[:, :s]
+    return _gdr(q, k, v, gc, beta, bool(kernel))[:, :s]
 
 
 @register_op("gated_delta_rule")

@@ -4,6 +4,7 @@ CPU with seeded random weights, against the benchmark's plain reference
 (`benchmark/reference/qwen3_next.py`, which imports nothing of the
 program: the delta rule token by token, attention as the masked
 square, the experts as a loop)."""
+import functools
 import logging
 import math
 
@@ -18,6 +19,7 @@ from paddle_tpu.fluid import framework
 from paddle_tpu.fluid.contrib import mixed_precision
 from paddle_tpu.models import qwen3_next
 from paddle_tpu.ops import hybrid_ops
+from paddle_tpu.ops.pallas import gated_delta_rule as delta_kernels
 from paddle_tpu.ops.registry import run_op
 from benchmark.reference import qwen3_next as ref
 from test_nemotron_h import _lay
@@ -162,27 +164,100 @@ def _delta_args(seed, s, hk=2, r=2, dk=8, dv=16, decay=(0.5, 1.0),
     ids=["one_chunk", "three_chunks", "not_whole_chunks", "under_a_chunk",
          "decay_and_beta_near_1", "decay_and_beta_near_0",
          "four_value_heads_a_key_head"])
+@pytest.mark.parametrize("kernel", [False, True], ids=["scan", "kernels"])
 def test_gated_delta_rule_and_every_inputs_gradient_match_the_recurrence(
-        case):
+        case, kernel):
+    """The chunks walked by `lax.scan` and by the two Pallas kernels
+    (under the interpreter here), each against the recurrence."""
+    rule = functools.partial(hybrid_ops.gated_delta_rule, kernel=kernel)
     args = _delta_args(5, **case)
     want = _recurrence(*args)
-    got = hybrid_ops.gated_delta_rule(*args)
+    got = rule(*args)
     scale = float(jnp.max(jnp.abs(want)))
     assert float(jnp.max(jnp.abs(got - want))) <= 2e-5 * max(scale, 1.0)
     w = jnp.asarray(np.random.default_rng(9).normal(size=want.shape),
                     jnp.float32)
     grads = [jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
                       argnums=(0, 1, 2, 3, 4))(*args)
-             for f in (hybrid_ops.gated_delta_rule, _recurrence)]
+             for f in (rule, _recurrence)]
     for name, a, b in zip("q k v g beta".split(), *grads):
         top = float(jnp.max(jnp.abs(b)))
         assert float(jnp.max(jnp.abs(a - b))) <= 5e-5 * max(top, 1.0), name
 
 
+def _chunked_inputs(seed, r, dtype, b=2, n=3, h=2, dk=8, dv=16):
+    """Random inputs a chunk at a time, as `_gdr_group_inputs` lays
+    them: q, k [B, N, H, C, dk] (unit rows); v [B, N, H, R, C, dv]; gc
+    (decreasing inside a chunk) and beta [B, N, H, R, C] float32."""
+    rng = np.random.default_rng(seed)
+    c = hybrid_ops._GDR_CHUNK
+    q, k = (rng.normal(size=(b, n, h, c, dk)) for _ in range(2))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    gc = np.cumsum(np.log(rng.uniform(0.7, 1.0, (b, n, h, r, c))), axis=-1)
+    return [jnp.asarray(t, kind) for t, kind in (
+        (q, dtype), (k, dtype), (rng.normal(size=(b, n, h, r, c, dv)), dtype),
+        (gc, jnp.float32), (rng.uniform(0.1, 0.9, (b, n, h, r, c)),
+                            jnp.float32))]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("r", [2, 4])
+def test_each_walk_kernel_alone_gives_what_its_scan_gives(r, dtype):
+    """Forward: the kernel, handed the inverse alone, against
+    `_gdr_local` and the scan. Reverse: on values no `_gdr_local` made.
+    2 and 4 value heads a key head: 4 and 8 heads a sequence, walked in
+    one grid step; the rule takes whole key heads and only blocks that
+    divide."""
+    tol = 1e-5 if dtype == jnp.float32 else 0.02
+    close = lambda a, b: float(jnp.max(jnp.abs(  # noqa: E731
+        a.astype(jnp.float32) - b.astype(jnp.float32)))) <= tol * max(
+            1.0, float(jnp.max(jnp.abs(b.astype(jnp.float32)))))
+    q, k, v, gc, beta = ins = _chunked_inputs(3, r, dtype)
+    local = hybrid_ops._gdr_local(*ins)
+    want = [jnp.moveaxis(t, 0, 1) for t in hybrid_ops._gdr_walk_fwd(*local)]
+    kk = jnp.einsum("bnhid,bnhjd->bnhij", k, k,
+                    preferred_element_type=jnp.float32)[:, :, :, None]
+    t = hybrid_ops._gdr_inverse(kk, gc, beta, dtype)[0]
+    # the states go into group 1 of a stack of three, in place
+    stack = jnp.full((3,) + want[1].shape, 7.0, jnp.float32)
+    out, kept = delta_kernels.gated_delta_rule_fwd(q, k, v, t, gc, beta,
+                                                   (stack, 1))
+    assert out.dtype == dtype and kept.dtype == jnp.float32
+    assert close(out, want[0]) and close(kept[1], want[1])
+    assert float(jnp.min(kept[::2])) == float(jnp.max(kept[::2])) == 7.0
+    alone, none = delta_kernels.gated_delta_rule_fwd(q, k, v, t, gc, beta)
+    assert none is None and close(alone, want[0])
+    rng = np.random.default_rng(4)
+    w, _, aqk, qg, kd, gl = local
+    u, d_out = (jnp.asarray(rng.normal(size=v.shape) / 4, dtype)
+                for _ in range(2))
+    want = hybrid_ops._gdr_walk_bwd(w, aqk, qg, kd, gl, u, kept[1], d_out)
+    got = delta_kernels.gated_delta_rule_bwd(w, aqk, qg, kd, gl, u, kept, 1,
+                                             d_out)
+    assert [x.dtype for x in got] == [jnp.float32, dtype, jnp.float32]
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and close(a, b)
+    rule = delta_kernels.heads_a_step
+    assert rule(2, r, 64, 8, 16, 4) == 2 * r
+    # a head's blocks at 128 x 128 in bfloat16 take 0.22 MB: eight fit,
+    # whole key heads of them; 6 key heads of 2 go in threes (4 would
+    # not divide), 3 value heads a key head in two key heads, 16 in one
+    # key head however many that is, and a wide head with its key head
+    assert rule(4, 2, 64, 128, 128, 2) == 8
+    assert rule(6, 2, 64, 128, 128, 2) == 6
+    assert rule(4, 3, 64, 128, 128, 2) == 6
+    assert rule(4, 16, 64, 128, 128, 2) == 16
+    assert rule(4, 2, 64, 1024, 1024, 4) == 2
+
+
 def test_gated_delta_rule_walks_head_groups_and_says_what_it_keeps(
         monkeypatch, caplog):
     """A tile budget that one key head's chunks fill: the op walks its
-    key heads one after another and gives what one group gives."""
+    key heads one after another and gives what one group gives, the
+    kernels (each group's states written into, and read from, its place
+    in the one stack) as the scans."""
     args = _delta_args(6, 130, hk=4, r=2)
     whole = hybrid_ops.gated_delta_rule(*args)
     g_whole = jax.grad(lambda *a: jnp.sum(jnp.square(
@@ -191,13 +266,15 @@ def test_gated_delta_rule_walks_head_groups_and_says_what_it_keeps(
     monkeypatch.setattr(hybrid_ops, "_GDR_TILE_BYTES", 2 * 3 * 2 * 64 * 64 * 4)
     assert hybrid_ops._gdr_groups(2, 3, 4, 2) == 4
     with caplog.at_level(logging.INFO, logger=hybrid_ops.__name__):
-        walked = hybrid_ops.gated_delta_rule(*args)
-        g_walked = jax.grad(lambda *a: jnp.sum(jnp.square(
-            hybrid_ops.gated_delta_rule(*a))),
-            argnums=(0, 1, 2, 3, 4))(*args)
-    assert float(jnp.max(jnp.abs(walked - whole))) <= 1e-5
-    for a, b in zip(g_walked, g_whole):
-        assert float(jnp.max(jnp.abs(a - b))) <= 1e-4
+        for kernel in (False, True):
+            rule = functools.partial(hybrid_ops.gated_delta_rule,
+                                     kernel=kernel)
+            walked = rule(*args)
+            g_walked = jax.grad(lambda *a: jnp.sum(jnp.square(rule(*a))),
+                                argnums=(0, 1, 2, 3, 4))(*args)
+            assert float(jnp.max(jnp.abs(walked - whole))) <= 1e-5
+            for a, b in zip(g_walked, g_whole):
+                assert float(jnp.max(jnp.abs(a - b))) <= 1e-4
     said = caplog.records[0].getMessage()
     # padded to 192: q, k 2 x 192 x 4 x 8, v 2 x 192 x 8 x 16, two
     # float32 [2, 192, 8] and 2 x 3 x 8 states of 8 x 16, all float32
@@ -205,16 +282,27 @@ def test_gated_delta_rule_walks_head_groups_and_says_what_it_keeps(
                 + 2 * 3 * 8 * 8 * 16)
     assert said == (
         "gated_delta_rule q, k (2, 192, 4, 8) v (2, 192, 8, 16) float32: "
-        "64 positions a chunk, 3 chunks, 4 head groups; kept for the "
-        "backward pass %d bytes (the inputs and 48 states [8, 16] "
-        "float32)" % kept)
+        "64 positions a chunk, 3 chunks, 4 head groups, the chunks walked "
+        "by jax.numpy; kept for the backward pass %d bytes (the inputs "
+        "and 48 states [8, 16] float32)" % kept)
+    assert (" 4 head groups, the chunks walked by pallas (2 value heads a "
+            "grid step, 6 grid steps a call); kept ") in \
+        caplog.records[-1].getMessage()
 
 
-def test_gated_delta_rule_keeps_token_sized_values_and_chunk_states():
+@pytest.mark.parametrize("kernel", [False, True], ids=["scan", "kernels"])
+def test_gated_delta_rule_keeps_token_sized_values_and_chunk_states(
+        kernel, caplog):
     """The custom gradient's residuals: the five inputs and the state at
-    every chunk's start; nothing [S, S]- or [S, dk, dv]-shaped."""
+    every chunk's start; nothing [S, S]- or [S, dk, dv]-shaped, whoever
+    walks the chunks, and the log line says who does."""
     args = _delta_args(7, 192)
-    _, vjp = jax.vjp(hybrid_ops.gated_delta_rule, *args)
+    with caplog.at_level(logging.INFO, logger=hybrid_ops.__name__):
+        _, vjp = jax.vjp(functools.partial(
+            hybrid_ops.gated_delta_rule, kernel=kernel), *args)
+    assert ("1 head groups, the chunks walked by " + (
+        "pallas (4 value heads a grid step, 6 grid steps a call);"
+        if kernel else "jax.numpy;")) in caplog.records[0].getMessage()
     shapes = sorted(tuple(x.shape) for x in jax.tree_util.tree_leaves(vjp)
                     if hasattr(x, "shape") and x.size > 16)
     s = 192

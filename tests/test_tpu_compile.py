@@ -234,16 +234,27 @@ def test_gated_experts_grouped_products_at_the_published_widths(
     assert hybrid.row_block(163840, 32, 512) == 13824
 
 
-def test_gated_delta_rule_at_the_published_widths(one_chip):
+@pytest.mark.parametrize("walked_by", ["jax.numpy", "pallas"])
+def test_gated_delta_rule_at_the_published_widths(one_chip, monkeypatch,
+                                                  walked_by):
     """A Gated DeltaNet mixer's rule as the long-document cell runs it:
     16 key heads and 32 value heads of 128, one document of 16,384: 256
-    chunks walked in 4 groups of key heads. Plain `jax.numpy`: no
-    kernel is called, the two scans over chunks are loops of the
-    compiled program, and what the custom gradient keeps (the five
-    inputs and 8,192 chunk states) with the group's temporaries fits
-    well inside what the step has left."""
+    chunks walked in 4 groups of key heads. Off the TPU the two walks
+    over chunks are `lax.scan`s, loops of the compiled program inside
+    the two loops over head groups; on it (heads of whole lane tiles)
+    they are the two kernels of `pallas/gated_delta_rule.py`, compiled
+    here by Mosaic, 8 value heads a grid step, and only the loops over
+    head groups are left. Either way what the custom gradient keeps
+    (the five inputs and 8,192 chunk states) with the group's
+    temporaries fits well inside what the step has left."""
     hybrid = importlib.import_module("paddle_tpu.ops.hybrid_ops")
+    kernels = importlib.import_module(
+        "paddle_tpu.ops.pallas.gated_delta_rule")
     assert hybrid._gdr_groups(1, 256, 16, 2) == 4
+    assert kernels.heads_a_step(4, 2, 64, 128, 128, 2) == 8
+    if walked_by == "pallas":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(kernels, "_interpret_default", lambda: False)
 
     def loss(q, k, v, g, beta):
         out = hybrid.gated_delta_rule(q, k, v, -jax.nn.softplus(g),
@@ -258,7 +269,12 @@ def test_gated_delta_rule_at_the_published_widths(one_chip):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         *args).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text and text.count(" while(") >= 4
+    if walked_by == "pallas":
+        _kernels_are_called(compiled, ["gated_delta_rule_fwd",
+                                       "gated_delta_rule_bwd"])
+        assert text.count(" while(") == 2
+    else:
+        assert "tpu_custom_call" not in text and text.count(" while(") >= 4
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
 
 
