@@ -710,8 +710,11 @@ resize_nearest = image_resize
 def scaled_dot_product_attention(q, k, v, key_bias=None, causal=False,
                                  sm_scale=None, attn_dropout_prob=0.0,
                                  is_test=False, name=None):
-    """Fused attention over [B, H, S, D] q/k/v; optional [B, Sk] additive
-    key bias. Lowers to the Pallas flash-attention kernel on TPU
+    """Fused attention over q [B, H, S, D], k [B, Hkv, S, D] and
+    v [B, Hkv, S, Dv] (H a multiple of Hkv; V has a head size of its
+    own, and the output [B, H, S, Dv] takes it); optional [B, Sk]
+    additive key bias; `sm_scale` defaults to D ** -0.5. Lowers to the
+    Pallas flash-attention kernel on TPU
     (paddle_tpu/ops/pallas/); reference fuses only inference attention
     (`operators/fused/multihead_matmul_op.cu`)."""
     ins = {"Q": [q], "K": [k], "V": [v]}

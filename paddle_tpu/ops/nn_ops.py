@@ -539,10 +539,14 @@ def _pad2d(ins, attrs):
 
 @register_op("scaled_dot_product_attention", needs_rng=True)
 def _sdpa(ins, attrs):
-    """Fused attention. Q: [B, H, S, D]; K, V: [B, Hkv, S, D] with H a
-    multiple of Hkv (grouped-query attention: query head j reads
-    key/value head j // (H / Hkv); the flash kernel reads them in
-    place); optional KeyBias: [B, Sk] additive key bias. On TPU with no attention-prob dropout this lowers
+    """Fused attention. Q: [B, H, S, D]; K: [B, Hkv, S, D]; V:
+    [B, Hkv, S, Dv] with H a multiple of Hkv (grouped-query attention:
+    query head j reads key/value head j // (H / Hkv); the flash kernel
+    reads them in place). V has a head size of its own (latent
+    attention: D 192, Dv 128): Out is [B, H, S, Dv] and the scale
+    defaults to D ** -0.5 on the flash path, on `reference_attention`
+    and on the unfused path alike. Optional KeyBias: [B, Sk] additive
+    key bias. On TPU with no attention-prob dropout this lowers
     to the Pallas flash kernel (paddle_tpu/ops/pallas/flash_attention.py);
     otherwise the XLA reference path (identical semantics) runs, with
     upscale_in_train dropout on the normalized probs.

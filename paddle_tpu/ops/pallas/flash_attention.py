@@ -12,6 +12,10 @@ sequential innermost grid dimension. Backward recomputes tiles the same
 way (no O(S^2) residuals; only the per-row logsumexp is saved).
 
 Layout: q, k, v are [B, H, S, D]; internally flattened to [B*H, S, D].
+V's last axis may differ from Q's and K's (PR 33: latent attention
+scores at 192 and mixes values of 128): every block, scratch and output
+takes the width of what it holds and the VMEM figure counts both; at
+equal widths the blocks are those of one width.
 `key_bias` is an additive [B, S_k] bias on the keys (the BERT padding
 mask); it is treated as non-differentiable (its cotangent is zero), which
 matches how masks are used everywhere in the reference.
@@ -131,35 +135,39 @@ def _largest_block(n, cap):
     return max(m for m in range(_LANES, cap + 1, _LANES) if n % m == 0)
 
 
-def _vmem_bytes(resident, streamed, sub, d, itemsize, extra_tiles=0):
+def _vmem_bytes(resident, streamed, sub, d, dv, itemsize, extra_tiles=0):
     """An upper bound, over the three kernels, on what one of them takes
     of VMEM: every block twice (the pipeline's two buffers), the float32
     scratch, and the tile temporaries (scores, probabilities, dP, dS and
-    their casts; `extra_tiles` more for a causal or a dropout mask)."""
-    dp = _ceil_to(d, _LANES)
+    their casts; `extra_tiles` more for a causal or a dropout mask).
+    Of a kernel's blocks half are as wide as a query or key (`d`) and
+    half as wide as a value (`dv`); a row takes whole lane tiles."""
+    dp = _ceil_to(d, _LANES) + _ceil_to(dv, _LANES)
     column = resident * _LANES * 4      # a [rows, 1] float32 block
     line = _SUBLANES * streamed * 4     # a [1, rows] float32 block
-    blocks = (4 * resident * dp * itemsize      # two in, two out
-              + 2 * streamed * dp * itemsize
+    blocks = (2 * resident * dp * itemsize      # two in, two out
+              + streamed * dp * itemsize
               + 3 * column + 3 * line)
-    scratch = 2 * resident * dp * 4 + 2 * column
+    scratch = resident * dp * 4 + 2 * column
     tiles = (6 + extra_tiles) * resident * sub * 4
     return 2 * blocks + scratch + tiles
 
 
-def _tile(n_resident, n_streamed, d, itemsize, extra_tiles):
+def _tile(n_resident, n_streamed, d, dv, itemsize, extra_tiles):
     """(resident block, streamed block, sub-block, VMEM bytes) for
-    padded lengths: the largest that divide them under the three caps;
-    while the VMEM figure is over the budget the streamed block is
-    halved, then the tile."""
+    padded lengths: the largest that divide them under the three caps
+    (the streamed one by the wider of `d` and `dv`); while the VMEM
+    figure is over the budget the streamed block is halved, then the
+    tile."""
     res_cap, sub_cap = _RESIDENT_ROWS, _SUB_ROWS
     str_cap = max(_LANES, _STREAMED_BYTES
-                  // (_ceil_to(d, _LANES) * itemsize) // _LANES * _LANES)
+                  // (_ceil_to(max(d, dv), _LANES) * itemsize)
+                  // _LANES * _LANES)
     while True:
         res = _largest_block(n_resident, res_cap)
         streamed = _largest_block(n_streamed, str_cap)
         sub = _largest_block(streamed, sub_cap)
-        vmem = _vmem_bytes(res, streamed, sub, d, itemsize, extra_tiles)
+        vmem = _vmem_bytes(res, streamed, sub, d, dv, itemsize, extra_tiles)
         if vmem <= _VMEM_BUDGET or max(res_cap, str_cap, sub_cap) <= _LANES:
             return res, streamed, sub, vmem
         if str_cap > sub_cap:
@@ -171,26 +179,28 @@ def _tile(n_resident, n_streamed, d, itemsize, extra_tiles):
             res_cap = max(_LANES, res_cap // 2)
 
 
-def block_rule(sq, sk, d, dtype, causal=False, dropout=False):
+def block_rule(sq, sk, d, dtype, causal=False, dropout=False, dv=None):
     """The blocks the kernels step through for `sq` queries on `sk`
-    keys of `d` in `dtype`: a pure function of its arguments. Each
-    block is a multiple of 8 that divides its padded length
-    (`_padded`); `vmem_bytes` is under `_VMEM_BUDGET`."""
+    keys of `d` (values of `dv`, `d` where not given) in `dtype`: a
+    pure function of its arguments. Each block is a multiple of 8 that
+    divides its padded length (`_padded`); `vmem_bytes` is under
+    `_VMEM_BUDGET`."""
     itemsize = np.dtype(dtype).itemsize
     extra = int(bool(causal)) + int(bool(dropout))
+    dv = d if dv is None else dv
     nq, nk = _padded(sq), _padded(sk)
-    bq, bk, sub_k, vmem = _tile(nq, nk, d, itemsize, extra)
-    bk_dkv, bq_dkv, sub_q, vmem_dkv = _tile(nk, nq, d, itemsize, extra)
+    bq, bk, sub_k, vmem = _tile(nq, nk, d, dv, itemsize, extra)
+    bk_dkv, bq_dkv, sub_q, vmem_dkv = _tile(nk, nq, d, dv, itemsize, extra)
     return Blocks(bq, bk, sub_k, bq_dkv, bk_dkv, sub_q,
                   max(vmem, vmem_dkv))
 
 
-def _blocks_for(sq, sk, d, dtype, causal, dropout, block_q, block_k):
+def _blocks_for(sq, sk, d, dv, dtype, causal, dropout, block_q, block_k):
     """(blocks, padded Sq, padded Sk) of a call: the rule's, but a
     length whose block the caller gave is stepped through in that block
     by all three kernels (in sub-blocks of at most `_SUB_ROWS`) and
     padded to a multiple of it."""
-    rule = block_rule(sq, sk, d, dtype, causal, dropout)
+    rule = block_rule(sq, sk, d, dtype, causal, dropout, dv)
     if block_q is None and block_k is None:
         return rule, _padded(sq), _padded(sk)
 
@@ -206,8 +216,8 @@ def _blocks_for(sq, sk, d, dtype, causal, dropout, block_q, block_k):
     nk, bk_dkv, bk, sub_k = side(sk, block_k, rule.block_k_dkv,
                                  rule.block_k, rule.sub_k)
     itemsize, extra = np.dtype(dtype).itemsize, int(causal) + int(dropout)
-    vmem = max(_vmem_bytes(bq, bk, sub_k, d, itemsize, extra),
-               _vmem_bytes(bk_dkv, bq_dkv, sub_q, d, itemsize, extra))
+    vmem = max(_vmem_bytes(bq, bk, sub_k, d, dv, itemsize, extra),
+               _vmem_bytes(bk_dkv, bq_dkv, sub_q, d, dv, itemsize, extra))
     return Blocks(bq, bk, sub_k, bq_dkv, bk_dkv, sub_q, vmem), nq, nk
 
 
@@ -418,7 +428,7 @@ def _first_live_q(j, block_q, block_k):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, spec):
-    block_q, d = q_ref.shape[1:]
+    block_q, dv = q_ref.shape[1], v_ref.shape[2]
     block_k, sub_k = k_ref.shape[1], spec.blocks.sub_k
     n_sub = block_k // sub_k
     bh, iq, ik = (pl.program_id(a) for a in range(3))
@@ -462,7 +472,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref, lse_ref,
                     row_hash, _col_hash(seed, col0 + c, sub_k, 1),
                     spec.p_drop), p, 0.0)
             v = v_ref[0, cols, :]
-            acc_scr[...] = acc_scr[...] * _lanes(alpha, d) + lax.dot_general(
+            acc_scr[...] = acc_scr[...] * _lanes(alpha, dv) + lax.dot_general(
                 p.astype(v.dtype), v, _NN,
                 preferred_element_type=jnp.float32)
 
@@ -473,7 +483,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref, lse_ref,
         l_row = l_scr[...]
         l_safe = jnp.where(l_row == 0.0, 1.0, l_row)
         o_ref[0] = (acc_scr[...] * _lanes(
-            (1.0 / (1.0 - spec.p_drop)) / l_safe, d)).astype(o_ref.dtype)
+            (1.0 / (1.0 - spec.p_drop)) / l_safe, dv)).astype(o_ref.dtype)
         lse_ref[0] = m_scr[:, :1] + jnp.log(l_safe[:, :1])   # [bq, 1]
 
 
@@ -503,7 +513,7 @@ def _bias_spec(spec, block, index, column=False):
 
 def _fwd_call(q, k, v, key_bias, seed, spec, interpret):
     BH, S, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[2]
     block_q, block_k = spec.blocks.block_q, spec.blocks.block_k
     grid = (BH, S // block_q, Sk // block_k)
     kv = _kv_row(spec.kv_rep)
@@ -513,7 +523,7 @@ def _fwd_call(q, k, v, key_bias, seed, spec, interpret):
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, D),
                      lambda b, i, j: (kv(b), k_block(b, i, j), 0)),
-        pl.BlockSpec((1, block_k, D),
+        pl.BlockSpec((1, block_k, Dv),
                      lambda b, i, j: (kv(b), k_block(b, i, j), 0)),
     ]
     args = [q, k, v]
@@ -531,18 +541,18 @@ def _fwd_call(q, k, v, key_bias, seed, spec, interpret):
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             # [BH, S, 1]: sublane-layout so lse reads back as (bq, 1)
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
         ],
         scratch_shapes=[
             _vmem((block_q, _LANES), jnp.float32),
             _vmem((block_q, _LANES), jnp.float32),
-            _vmem((block_q, D), jnp.float32),
+            _vmem((block_q, Dv), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=_compiler_params(_VMEM_BUDGET),
@@ -693,7 +703,7 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 def _bwd_call(q, k, v, key_bias, seed, o, lse, do, spec, interpret):
     BH, S, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[2]
     kv = _kv_row(spec.kv_rep)
     # [BH, S, 1]; with dropout the kernels want it less the 1/(1-p)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -710,12 +720,21 @@ def _bwd_call(q, k, v, key_bias, seed, o, lse, do, spec, interpret):
     else:
         def q_block(b, j, i):
             return i
-    rows = pl.BlockSpec((1, block_q, D),
-                        lambda b, j, i: (b, q_block(b, j, i), 0))
+
+    def rows(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda b, j, i: (b, q_block(b, j, i), 0))
+
+    def keys(width):
+        return pl.BlockSpec((1, block_k, width),
+                            lambda b, j, i: (kv(b), j, 0))
+
+    def out(width):
+        return pl.BlockSpec((1, block_k, width), lambda b, j, i: (b, j, 0))
+
     line = pl.BlockSpec((1, 1, block_q),
                         lambda b, j, i: (b, 0, q_block(b, j, i)))
-    keys = pl.BlockSpec((1, block_k, D), lambda b, j, i: (kv(b), j, 0))
-    in_specs = [rows, rows, line, line, keys, keys]
+    in_specs = [rows(D), rows(Dv), line, line, keys(D), keys(Dv)]
     args = [q, do, lse.reshape(BH, 1, S), delta.reshape(BH, 1, S), k, v]
     if has_bias:
         in_specs.append(_bias_spec(spec, block_k, lambda b, j, i: j,
@@ -724,19 +743,18 @@ def _bwd_call(q, k, v, key_bias, seed, o, lse, do, spec, interpret):
     if has_drop:
         in_specs.append(_seed_spec())
         args.append(seed)
-    out = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
     dk, dv = pl.pallas_call(
         _with_optional(_bwd_dkv_kernel, 6, has_bias, has_drop, spec),
         grid=(BH, Sk // block_k, S // block_q),
         in_specs=in_specs,
-        out_specs=[out, out],
+        out_specs=[out(D), out(Dv)],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, Sk, D), v.dtype),
+            jax.ShapeDtypeStruct((BH, Sk, Dv), v.dtype),
         ],
         scratch_shapes=[
             _vmem((block_k, D), jnp.float32),
-            _vmem((block_k, D), jnp.float32),
+            _vmem((block_k, Dv), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=_compiler_params(_VMEM_BUDGET),
@@ -745,17 +763,22 @@ def _bwd_call(q, k, v, key_bias, seed, o, lse, do, spec, interpret):
     if spec.kv_rep > 1:
         # a key/value head's gradient is the sum over its query heads
         dk, dv = (t.astype(jnp.float32)
-                  .reshape(-1, spec.kv_rep, Sk, D).sum(1)
+                  .reshape(-1, spec.kv_rep, Sk, t.shape[2]).sum(1)
                   .astype(t.dtype) for t in (dk, dv))
 
     # dQ: queries resident, keys streamed, as in the forward kernel
     block_q, block_k = spec.blocks.block_q, spec.blocks.block_k
     k_block = _k_block(spec)
-    rows = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
+
+    def rows(width):
+        return pl.BlockSpec((1, block_q, width), lambda b, i, j: (b, i, 0))
+
+    def keys(width):
+        return pl.BlockSpec((1, block_k, width),
+                            lambda b, i, j: (kv(b), k_block(b, i, j), 0))
+
     column = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    keys = pl.BlockSpec((1, block_k, D),
-                        lambda b, i, j: (kv(b), k_block(b, i, j), 0))
-    in_specs = [rows, rows, column, column, keys, keys]
+    in_specs = [rows(D), rows(Dv), column, column, keys(D), keys(Dv)]
     args = [q, do, lse, delta, k, v]
     if has_bias:
         in_specs.append(_bias_spec(spec, block_k, k_block))
@@ -767,7 +790,7 @@ def _bwd_call(q, k, v, key_bias, seed, o, lse, do, spec, interpret):
         _with_optional(_bwd_dq_kernel, 6, has_bias, has_drop, spec),
         grid=(BH, S // block_q, Sk // block_k),
         in_specs=in_specs,
-        out_specs=rows,
+        out_specs=rows(D),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         scratch_shapes=[_vmem((block_q, D), jnp.float32)],
         interpret=interpret,
@@ -820,11 +843,12 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 _said = set()
 
 
-def _engaged(q, k, spec):
+def _engaged(q, k, v, spec):
     """The engagement record: once per distinct signature, where a call
-    is traced, the log says what the kernels were handed and how they
-    step through it."""
-    signature = (q.shape, k.shape, str(q.dtype), spec)
+    is traced, the log says what the kernels were handed (a value's
+    width beside a key's where they differ) and how they step through
+    it."""
+    signature = (q.shape, k.shape, v.shape, str(q.dtype), spec)
     if signature in _said:
         return
     _said.add(signature)
@@ -835,11 +859,13 @@ def _engaged(q, k, spec):
         seen = sum(min(_last_live_k(i, b.block_q, b.block_k) + 1, nk)
                    for i in range(nq))
         live = ", %.1f %% of them live (causal)" % (100.0 * seen / (nq * nk))
+    keys = "k/v %s" % list(k.shape) if v.shape == k.shape else \
+        "k %s, v %s" % (list(k.shape), list(v.shape))
     logging.getLogger(__name__).info(
-        "flash attention: q %s on k/v %s in %s, bias %s, dropout %g: "
+        "flash attention: q %s on %s in %s, bias %s, dropout %g: "
         "blocks %d x %d in sub-blocks of %d (dK/dV: %d keys x %d in "
         "sub-blocks of %d), %d bytes of VMEM, %d grid steps a forward "
-        "call%s", list(q.shape), list(k.shape), q.dtype,
+        "call%s", list(q.shape), keys, q.dtype,
         "yes" if spec.bias_rep else "no", spec.p_drop, b.block_q,
         b.block_k, b.sub_k, b.block_k_dkv, b.block_q_dkv, b.sub_q,
         b.vmem_bytes, q.shape[0] * nq * nk, live)
@@ -850,10 +876,15 @@ def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
                     dropout_seed=None):
     """Blockwise (flash) attention.
 
-    q: [B, H, Sq, D]; k, v: [B, Hkv, Sk, D] with H a multiple of Hkv
-    (query head j reads key/value head j // (H / Hkv), in place);
-    key_bias: optional [B, Sk] additive bias on keys (e.g. `(mask - 1) * 1e4` padding bias;
-    non-differentiable). Returns [B, H, Sq, D] in q.dtype.
+    q: [B, H, Sq, D]; k: [B, Hkv, Sk, D]; v: [B, Hkv, Sk, Dv] with H a
+    multiple of Hkv (query head j reads key/value head
+    j // (H / Hkv), in place). V has a head size of its own: Dv may
+    differ from D (latent attention scores at 192 and mixes values of
+    128), the blocks follow both, and neither is padded in HBM.
+    key_bias: optional [B, Sk] additive bias on keys (e.g.
+    `(mask - 1) * 1e4` padding bias; non-differentiable). `sm_scale`
+    defaults to D ** -0.5, the query's. Returns [B, H, Sq, Dv] in
+    q.dtype.
 
     block_q, block_k: left out, `block_rule` picks the blocks from the
     shapes and the dtype; given, all three kernels step through
@@ -866,9 +897,13 @@ def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
     int32 scalar (traced is fine), required when dropout_p > 0.
     """
     B, H, Sq, D = q.shape
-    Hkv, Sk = k.shape[1], k.shape[2]
+    Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     if H % Hkv:
         raise ValueError("%d query heads on %d key/value heads" % (H, Hkv))
+    if k.shape[3] != D or v.shape[:3] != k.shape[:3]:
+        raise ValueError("q %s, k %s, v %s: keys are as wide as queries, "
+                         "values lie on the keys' heads and positions"
+                         % (q.shape, k.shape, v.shape))
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
     dropout_p = float(dropout_p)
@@ -880,11 +915,11 @@ def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
             raise ValueError("dropout_p > 0 requires dropout_seed")
         seed = jnp.reshape(dropout_seed, (1,)).astype(jnp.int32)
 
-    blocks, nq, nk = _blocks_for(Sq, Sk, D, q.dtype, causal,
+    blocks, nq, nk = _blocks_for(Sq, Sk, D, Dv, q.dtype, causal,
                                  dropout_p > 0.0, block_q, block_k)
     qf = _pad_to(q.reshape(B * H, Sq, D), 1, nq)
     kf = _pad_to(k.reshape(B * Hkv, Sk, D), 1, nk)
-    vf = _pad_to(v.reshape(B * Hkv, Sk, D), 1, nk)
+    vf = _pad_to(v.reshape(B * Hkv, Sk, Dv), 1, nk)
 
     bias = key_bias
     if nk > Sk and bias is None:
@@ -897,14 +932,15 @@ def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
 
     spec = _Spec(float(sm_scale), bool(causal), dropout_p, H // Hkv,
                  H if bias is not None else 0, blocks)
-    _engaged(qf, kf, spec)
+    _engaged(qf, kf, vf, spec)
     o = _flash_core(qf, kf, vf, bias, seed, spec)
-    return o[:, :Sq, :].reshape(B, H, Sq, D)
+    return o[:, :Sq, :].reshape(B, H, Sq, Dv)
 
 
 def reference_attention(q, k, v, key_bias=None, causal=False,
                         sm_scale=None):
-    """Naive XLA attention with identical semantics (golden reference)."""
+    """Naive XLA attention with identical semantics (golden reference);
+    the output is as wide as V."""
     D = q.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)

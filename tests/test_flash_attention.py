@@ -469,3 +469,114 @@ def test_block_rule_on_the_two_cells():
         <= 16384
     for blocks in (s4096, nemotron):
         assert min(blocks[:6]) >= 256 and blocks.sub_k % 128 == 0
+
+
+# -- PR 33: V has a head size of its own -------------------------------------
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("h,hkv,d,dv", [(16, 16, 192, 128), (4, 2, 48, 80)],
+                         ids=["latent_192_on_128", "grouped_48_on_80"])
+def test_values_of_their_own_width_forward_and_grads(h, hkv, d, dv, dtype):
+    """Queries and keys of `d` on values of `dv`, causal: the output
+    and dV take V's width, dQ and dK the key's, forward and all three
+    gradients against the reference on the float32 upcast of the same
+    inputs (latent attention's 16 heads of 128 + 64 on 128; a narrower
+    key on a wider value with grouped heads)."""
+    rng = np.random.default_rng(33)
+    B, S = 1, 200
+    q = jnp.asarray(rng.standard_normal((B, h, S, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((B, hkv, S, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((B, hkv, S, dv)), dtype)
+    w = jnp.asarray(rng.standard_normal((B, h, S, dv)).astype("float32"))
+    got = _loss_and_grads(flash_attention, q, k, v, w, causal=True)
+    want = _loss_and_grads(
+        reference_attention, *(t.astype(jnp.float32) for t in (q, k, v)),
+        w, causal=True)
+    assert [g.shape for g in got] == [(B, h, S, dv), q.shape, k.shape,
+                                      v.shape]
+    assert all(g.dtype == q.dtype for g in got)
+    tol = 3e-2 if dtype == "bfloat16" else 5e-5
+    for g, r, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(g, np.float32), r, atol=tol,
+                                   rtol=tol, err_msg="%s mismatch" % name)
+
+
+def test_the_scale_is_the_querys_and_shapes_that_do_not_fit_raise():
+    rng = np.random.default_rng(34)
+    q = jnp.asarray(rng.standard_normal((1, 2, 64, 24)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, 2, 64, 24)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, 2, 64, 40)), jnp.float32)
+    np.testing.assert_allclose(
+        flash_attention(q, k, v),
+        reference_attention(q, k, v, sm_scale=24 ** -0.5), atol=2e-5)
+    with pytest.raises(ValueError, match="keys are as wide as queries"):
+        flash_attention(q, v, v)
+    with pytest.raises(ValueError, match="values lie on the keys'"):
+        flash_attention(q, k, v[:, :, :32])
+
+
+# what `block_rule` gave before values had a width of their own (the
+# parent's, recorded): `_CELLS` and the qwen3-next cell's D = 256 at 16k
+_RULE_BEFORE = [
+    (512, 4096, 512, 4096, 512, 512, 15990784),
+    (512, 8192, 512, 8192, 512, 512, 20971520),
+    (104, 104, 104, 104, 104, 104, 1451008),
+    (8, 256, 256, 8, 256, 8, 2426368),
+    (384, 4224, 384, 4224, 384, 384, 12607488),
+    (128, 1024, 512, 640, 512, 128, 7987200),
+    (512, 2048, 512, 2048, 512, 512, 23461888),
+    (512, 4096, 512, 4096, 512, 512, 21757952),
+]
+
+
+@pytest.mark.parametrize("cell,before", list(zip(
+    _CELLS + [(16384, 16384, 256, "bfloat16", True, False)], _RULE_BEFORE)))
+def test_equal_widths_are_stepped_through_as_before(cell, before):
+    """A call whose values are as wide as its keys gets the blocks and
+    the VMEM figure it got before, whether `dv` is left out or given."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    assert tuple(fa.block_rule(*cell)) == before
+    assert tuple(fa.block_rule(*cell, dv=cell[2])) == before
+
+
+def test_block_rule_on_the_latent_attention_cell():
+    """16,384 positions, keys of 192 (a lane row and a half: 256 in
+    VMEM) on values of 128: tiles of 512 x 512, 4,096 keys a streamed
+    block by the wider of the two (D = 128 streams 8,192), and a
+    figure under that of values widened to 192."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    args = (16384, 16384, 192, "bfloat16", True, False)
+    latent = fa.block_rule(*args, dv=128)
+    assert tuple(latent) == (512, 4096, 512, 4096, 512, 512, 18874368)
+    assert fa.block_rule(16384, 16384, 128, "bfloat16", True,
+                         False).block_k == 8192
+    assert latent.vmem_bytes < fa.block_rule(*args).vmem_bytes \
+        <= fa._VMEM_BUDGET
+
+
+def test_sdpa_op_gives_values_their_width_on_every_path():
+    """The op's three paths on the CPU (`reference_attention`; the
+    unfused path under a mask; under dropout): the output takes V's
+    last axis, the scale the query's."""
+    from paddle_tpu.ops.registry import run_op
+
+    rng = np.random.default_rng(35)
+    q = jnp.asarray(rng.standard_normal((2, 4, 24, 24)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, 2, 24, 24)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, 2, 24, 12)), jnp.float32)
+    want = reference_attention(q, k, v, causal=True)
+    assert want.shape == (2, 4, 24, 12)
+    ins = {"Q": [q], "K": [k], "V": [v]}
+    attrs = {"causal": True, "sm_scale": -1.0, "is_test": True}
+    def sdpa(ins, attrs):
+        return run_op("scaled_dot_product_attention", ins, attrs)["Out"][0]
+
+    np.testing.assert_allclose(sdpa(ins, attrs), want, atol=1e-6)
+    mask = jnp.zeros((24, 24), jnp.float32)
+    np.testing.assert_allclose(sdpa(dict(ins, Mask=[mask]), attrs), want,
+                               atol=1e-5)
+    dropped = sdpa(ins, dict(attrs, is_test=False, attn_dropout_prob=0.5,
+                             _rng_key=jax.random.PRNGKey(0)))
+    assert dropped.shape == want.shape

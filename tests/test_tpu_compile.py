@@ -210,6 +210,31 @@ def test_flash_at_a_head_of_256_and_what_a_trace_calls_it(one_chip, mosaic):
     _kernels_are_called(compiled, fa.KERNEL_NAMES)
 
 
+def test_flash_at_keys_of_192_on_values_of_128(one_chip, mosaic):
+    """A latent-attention layer's call (PR 33): 16 heads whose queries
+    and keys are 128 + 64 wide on values of 128 at 16,384, causal. A
+    key's row is a lane tile and a half; Mosaic takes the blocks as
+    they are (no padding in HBM: the arguments are q, k and v at their
+    own sizes), the forward and dQ kernels accumulate 128 and 192 wide,
+    dK/dV both."""
+    blocks = fa.block_rule(16384, 16384, 192, jnp.bfloat16, True, False,
+                           dv=128)
+    assert (blocks.block_q, blocks.block_k, blocks.sub_k) == (512, 4096, 512)
+    assert blocks.vmem_bytes <= fa._VMEM_BUDGET
+
+    def loss(q, k, v):
+        o = fa.flash_attention(q, k, v, causal=True)
+        return jnp.sum(jnp.sin(o.astype(jnp.float32)))
+
+    qk = ((1, 16, 16384, 192), jnp.bfloat16)
+    v = ((1, 16, 16384, 128), jnp.bfloat16)
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, qk,
+                        qk, v)
+    _kernels_are_called(compiled, fa.KERNEL_NAMES)
+    assert compiled.memory_analysis().argument_size_in_bytes == \
+        2 * 16 * 16384 * (192 + 192 + 128)
+
+
 def test_gated_experts_grouped_products_at_the_published_widths(
         one_chip, monkeypatch):
     """32 held experts of 2048 x (2 x 512) and 512 x 2048 over 16,384
