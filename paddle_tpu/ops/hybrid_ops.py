@@ -262,6 +262,7 @@ _GDR_TILE_BYTES = 32 << 20
 _HIGHEST = lax.Precision.HIGHEST
 
 
+@jax.custom_vjp
 def _unit_lower_inverse(a):
     """(I + a)^-1 for strictly lower triangular a [..., C, C], float32,
     in matrix products alone. With d the diagonal `_GDR_BLOCK`-blocks of
@@ -269,7 +270,11 @@ def _unit_lower_inverse(a):
     then I + a = (I + d)(I + m) with m = (I + d)^-1 (a - d) strictly
     block-lower, m^(C/16) = 0, and (I + m)^-1 the same product over m's
     powers. Two short series and not one of C terms: the powers of a
-    whole chunk's matrix grow as binomials before they cancel."""
+    whole chunk's matrix grow as binomials before they cancel.
+
+    The gradient is the inverse's own (`_unit_lower_inverse_bwd`), not
+    the two series transposed: it needs T alone, in two products where
+    the series' transpose runs twenty and keeps every power."""
     c = a.shape[-1]
     eye = jnp.eye(c, dtype=a.dtype)
     block = jnp.arange(c) // _GDR_BLOCK
@@ -285,6 +290,26 @@ def _unit_lower_inverse(a):
     d = jnp.where(block[:, None] == block[None, :], a, 0.0)
     d_inv = series(d, _GDR_BLOCK)
     return mm(series(mm(d_inv, a - d), -(-c // _GDR_BLOCK)), d_inv)
+
+
+def _unit_lower_inverse_fwd(a):
+    t = _unit_lower_inverse(a)
+    return t, t
+
+
+def _unit_lower_inverse_bwd(t, d_t):
+    """T = (I + a)^-1 has dT = -T da T, so d_a = -T^T d_T T^T: two
+    float32 products. Kept to the strict lower triangle, where a lives:
+    off it the truncated series is not the inverse, and its transpose
+    and this formula differ."""
+    d_a = -jnp.einsum(
+        "...ji,...jl->...il", t,
+        jnp.einsum("...jk,...lk->...jl", d_t, t, precision=_HIGHEST),
+        precision=_HIGHEST)
+    return (jnp.tril(d_a, -1),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
 def _gdr_inverse(kk, gc, beta, cd):
@@ -429,7 +454,11 @@ def _gdr_group_fwd_kernel(into, q, k, v, gc, beta):
 def _gdr_group_bwd(q, k, v, gc, beta, starts, d_out, group=None):
     """The group's chunk-local values made again and transposed: a
     second walk, from the last chunk to the first, carries the state's
-    cotangent; what it needs of a chunk is linear in that state. With
+    cotangent; what it needs of a chunk is linear in that state. The
+    transpose of `_gdr_local` goes through the triangular system by the
+    inverse's own gradient (`_unit_lower_inverse_bwd`): of the system
+    it keeps T alone, across the walk, and runs two [C, C] products
+    where the series were twenty. With
     `group`, `starts` is the stack of every group's chunk states, this
     group's at that place, and the reverse kernel walks (it reads the
     states where they lie); without, a `lax.scan` does."""
@@ -448,8 +477,8 @@ def _gdr_group_bwd(q, k, v, gc, beta, starts, d_out, group=None):
 
         # a loop stands between what is made before it and after it;
         # a kernel's call does not, and the compiler then schedules the
-        # transposed products' [C, C] values round it: 0.2 GB more and
-        # 9 ms a step
+        # transposed products round it: 7 ms a step (9 and 0.2 GB while
+        # the inverse's series were transposed too)
         w_, aqk_, qg_, kd_, gl_, u_, do_ = lax.optimization_barrier(
             (w, aqk, qg, kd, gl, u, d_out))
         d_u, d_kd, d_gl = lax.optimization_barrier(gated_delta_rule_bwd(
@@ -557,9 +586,10 @@ def gated_delta_rule(q, k, v, g, beta, kernel=None):
     the two walks made by the Pallas kernels of
     `pallas/gated_delta_rule.py` on a TPU where dk and dv are whole
     lane tiles (multiples of 128), and by `lax.scan` elsewhere. The
-    inverse and the backward pass's chunk-local values are `jax.numpy`
-    either way; the forward kernel makes the rest of a chunk's local
-    values itself."""
+    inverse (float32 products at `HIGHEST`, its gradient the formula
+    d_a = -T^T d_T T^T and not the series transposed) and the backward
+    pass's chunk-local values are `jax.numpy` either way; the forward
+    kernel makes the rest of a chunk's local values itself."""
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2:]
     if hv % hk or k.shape != q.shape or g.shape != (b, s, hv):

@@ -12,7 +12,9 @@ that their independent chains of products fill the MXU's latency.
 The forward walk makes a chunk's local values itself, in VMEM, from
 the chunked inputs and the inverse T of the chunk's triangular system
 (`hybrid_ops._gdr_inverse`, float32 products outside, rounded once to
-the operands' dtype as `hybrid_ops._gdr_local` rounds it): the
+the operands' dtype as `hybrid_ops._gdr_local` rounds it; the inverse
+is differentiated outside too, by its own formula, where the backward
+pass makes it again): the
 products against q and k, the decay mask and T applied to
 beta exp(gc) k and beta v never reach HBM. The reverse walk reads the
 chunk-local values `_gdr_local` stacks for its transpose,

@@ -324,6 +324,57 @@ def test_unit_lower_inverse_of_a_chunk_of_equal_keys():
     assert np.max(np.abs(got - want)) <= 1e-4 * np.max(np.abs(want))
 
 
+@pytest.mark.parametrize("case", ["equal_keys", "random"])
+def test_unit_lower_inverses_gradient_is_the_inverses_own(case):
+    """d_a = -T^T d_T T^T on the strict lower triangle, where a lives,
+    against autodiff through `jnp.linalg.inv`, on the two systems of
+    the test above."""
+    r = np.random.default_rng(0)
+    a = jnp.tril(jnp.ones((64, 64), jnp.float32) if case == "equal_keys"
+                 else jnp.asarray(r.uniform(-1, 1, (3, 64, 64)), jnp.float32),
+                 -1)
+    d_t = jnp.asarray(r.normal(size=a.shape), jnp.float32)
+    eye = jnp.eye(64, dtype=jnp.float32)
+    got, = jax.vjp(hybrid_ops._unit_lower_inverse, a)[1](d_t)
+    want, = jax.vjp(lambda x: jnp.linalg.inv(eye + x), a)[1](d_t)
+    want = np.tril(np.asarray(want), -1)
+    assert np.max(np.abs(np.asarray(got) - want)) <= 1e-4 * np.max(
+        np.abs(want))
+    assert not np.any(np.triu(np.asarray(got)))
+
+
+def _square_float32_products(jaxpr):
+    """The `dot_general`s of a jaxpr, and of every jaxpr inside it,
+    whose operands are both [..., 64, 64] float32: the products of the
+    triangular inverse and of its gradient, and no other of the op at
+    head sizes that are not 64."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            n += all(v.aval.shape[-2:] == (64, 64)
+                     and v.aval.dtype == jnp.float32 for v in eqn.invars)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _square_float32_products(sub)
+    return n
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["scan", "kernels"])
+def test_the_backward_pass_transposes_the_inverse_in_two_products(kernel):
+    """A group call of the forward pass runs the two series' ten
+    products; the backward pass makes them again and adds the two of
+    d_a = -T^T d_T T^T (twenty more when `jax.vjp` transposed the
+    series)."""
+    rule = functools.partial(hybrid_ops.gated_delta_rule, kernel=kernel)
+    args = _delta_args(7, 192)
+    out, vjp = jax.vjp(rule, *args)
+    assert _square_float32_products(jax.make_jaxpr(rule)(*args).jaxpr) == 10
+    assert _square_float32_products(
+        jax.make_jaxpr(vjp)(jnp.ones_like(out)).jaxpr) == 12
+
+
 def test_the_delta_rule_op_makes_its_decay_and_strength_in_float32():
     q, k, v, _, _ = _delta_args(8, 64)
     r = np.random.default_rng(1)

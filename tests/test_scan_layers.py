@@ -447,7 +447,8 @@ def _flash_from_its_length(monkeypatch):
 #: blocks in a loop, with a gradient of its own) and PR 31 (a score
 #: function for the router, an offset for the norm's weight, both
 #: attributes it does not set) left all four as they were and added
-#: the fifth, its own decoder's: with these every kind
+#: the fifth, its own decoder's (retaken in PR 34: the delta rule's
+#: triangular inverse has a gradient of its own): with these every kind
 #: of step the benchmark runs is held. A PR that means to change one of
 #: these programs replaces the digest and says so
 _STEP_DIGESTS = {
@@ -455,7 +456,7 @@ _STEP_DIGESTS = {
     "_build_resnet50": "deed87d731a3ffb9",
     "_build_nemotron_h": "9df08f21cb176523",
     "_build_scan_bert_flash": "7c1a665f24fa7e4a",
-    "_build_qwen3_next": "0844c7a3beaddb8c",
+    "_build_qwen3_next": "3c544571148cfcdf",
 }
 
 
