@@ -49,6 +49,9 @@ file to read because `jax.profiler.ProfileData` hands out an event's
 own stats and not its metadata's, where the scope path lives.
 `perf_analysis.py --stragglers --xplane-dir D` blames a *layer*, not
 just a phase; the benchmark's `device_*_ms` metrics read the regions.
+An op whose code names its parts (`part_scope`: `pt[<part>]` scopes
+under the op's own marker) is folded one level further, by op type,
+part and region (`by_op_part`, `op_part_table`).
 """
 from __future__ import annotations
 
@@ -62,20 +65,23 @@ from ..core.errors import ResourceExhaustedError
 
 __all__ = [
     "enabled", "op_marker", "op_scope", "marker_scope", "bucket_marker",
-    "grad_sync_marker", "gather_marker", "amp_marker", "parse_marker",
-    "provenance_of", "layer_of", "stablehlo_debug_asm",
+    "grad_sync_marker", "gather_marker", "amp_marker", "part_scope",
+    "parse_marker", "provenance_of", "part_of", "layer_of",
+    "stablehlo_debug_asm",
     "collective_provenance", "hlo_activation_provenance",
     "optimizer_state_vars", "classify_state_var", "build_report",
     "cross_check_donation", "static_breakdown", "budget_bytes",
     "HbmBudgetExceeded", "is_resource_exhausted",
     "record_oom_forensics", "load_trace_events", "device_op_rows",
-    "region_of", "time_attribution", "REGIONS",
+    "region_of", "time_attribution", "op_part_table", "REGIONS",
 ]
 
 #: marker grammar: `pp[<field>;<field>;...]` — `;` and `]` never occur
 #: in fluid var names, and every other marker character survives XLA's
 #: op_name metadata verbatim (only `@` is truncated — see _sanitize)
 _MARKER_RE = re.compile(r"pp\[([^\[\]]+)\]")
+#: a part of one op, named where the op's code makes it: `pt[<part>]`
+_PART_RE = re.compile(r"pt\[([a-z0-9_]+)\]")
 
 _AT_ESCAPE = "!"  # '@' truncates HLO op_name metadata; '!' survives
 
@@ -143,6 +149,14 @@ def op_scope(op, op_idx):
     return marker_scope(op_marker(op, op_idx))
 
 
+def part_scope(name):
+    """A part of the op being traced, named by the op's own code: the
+    scope `pt[<name>]` (`name` a short lower-case word, `[a-z0-9_]+`)
+    under the op's `pp[...]` marker, a no-op context when provenance is
+    off. `part_of` reads it back; `time_attribution` folds by it."""
+    return marker_scope("pt[%s]" % name)
+
+
 # ---------------------------------------------------------------------------
 # marker recovery from HLO text
 # ---------------------------------------------------------------------------
@@ -187,6 +201,20 @@ def provenance_of(path) -> Optional[dict]:
     if not hits:
         return None
     return parse_marker(hits[-1])
+
+
+def part_of(path) -> Optional[str]:
+    """The part (`part_scope`) a scope path lies in: the LAST `pt[...]`
+    after the path's last `pp[...]` marker, else None. A part belongs
+    to the innermost fluid op (a sub-block's ops carry their own marker
+    inside their parent's, and a part before it is the parent's), and
+    of two parts the inner one wins: jax keeps both through `jvp(`,
+    `transpose(` and a `custom_vjp`'s rules
+    (`.../pp[...]/jvp(pt[local])/pt[inverse]/dot_general`)."""
+    path = path or ""
+    marker = path.rfind("pp[")
+    hits = _PART_RE.findall(path, marker) if marker >= 0 else None
+    return hits[-1] if hits else None
 
 
 def layer_of(var) -> str:
@@ -1006,18 +1034,27 @@ def region_of(path, prov, differentiated=True) -> str:
 def time_attribution(events) -> dict:
     """Fold a profile's device time back through the provenance markers
     and the name stack: {"steps", "devices", "by_region": {region: us},
-    "by_op_type": {fluid op type: us}, "by_op": {key: us}, "by_layer":
-    {layer: us}, "by_bucket": {bucket_id: us}, "matched_us" (under a
-    marker), "unmatched_us", "unattributed_us" (no scope path at all),
-    "total_us"}, each of self time (`device_op_rows`) over the traced
-    executions of the step's module, in microseconds of ONE device.
-    The per-layer view is the straggler answer one level deeper than
-    PR 7's phase blame (WHICH layer's ops ate the step); the regions
-    say where in the step: forward, recompute, backward, update."""
+    "by_op_type": {fluid op type: us}, "by_op_type_region": {op type:
+    {region: us}}, "by_op_part": {op type: {part or "": {region: us}}},
+    "by_op": {key: us}, "by_layer": {layer: us}, "by_bucket":
+    {bucket_id: us}, "matched_us" (under a marker), "unmatched_us",
+    "unattributed_us" (no scope path at all), "total_us"}, each of self
+    time (`device_op_rows`) over the traced executions of the step's
+    module, in microseconds of ONE device. The per-layer view is the
+    straggler answer one level deeper than PR 7's phase blame (WHICH
+    layer's ops ate the step); the regions say where in the step:
+    forward, recompute, backward, update; the two crossed keys say
+    where in the step an op type ran and in which of the parts its own
+    code names (`part_of`; "" under the op's marker and under no
+    part), and for every op type each sums to `by_op_type`'s entry. A
+    fusion's self time goes to the one scope path the fusion carries,
+    so a part reads what XLA left under its name."""
     got = device_op_rows(events)
     scale = 1.0 / max(got["devices"], 1)
     by_region = dict.fromkeys(REGIONS, 0.0)
     by_op_type: Dict[str, float] = {}
+    by_op_type_region: Dict[str, Dict[str, float]] = {}
+    by_op_part: Dict[str, Dict[str, Dict[str, float]]] = {}
     by_op: Dict[str, float] = {}
     by_layer: Dict[str, float] = {}
     by_bucket: Dict[int, float] = {}
@@ -1028,7 +1065,8 @@ def time_attribution(events) -> dict:
         us *= scale
         total += us
         prov = provenance_of(path)
-        by_region[region_of(path, prov, differentiated)] += us
+        region = region_of(path, prov, differentiated)
+        by_region[region] += us
         if prov is None:
             unmatched += us
             continue
@@ -1037,6 +1075,10 @@ def time_attribution(events) -> dict:
         by_op[key] = by_op.get(key, 0.0) + us
         kind = prov["op_type"] if prov["kind"] == "op" else prov["kind"]
         by_op_type[kind] = by_op_type.get(kind, 0.0) + us
+        for crossed in (by_op_type_region.setdefault(kind, {}),
+                        by_op_part.setdefault(kind, {}).setdefault(
+                            part_of(path) or "", {})):
+            crossed[region] = crossed.get(region, 0.0) + us
         if prov["kind"] == "bucket":
             b = int(prov["bucket"])
             by_bucket[b] = by_bucket.get(b, 0.0) + us
@@ -1048,12 +1090,56 @@ def time_attribution(events) -> dict:
     def by_time(d):
         return dict(sorted(d.items(), key=lambda kv: -kv[1]))
 
+    def in_order(row):
+        return {r: row[r] for r in REGIONS if r in row}
+
+    by_op_type = by_time(by_op_type)
     return {
         "steps": got["steps"], "devices": got["devices"],
-        "by_region": by_region, "by_op_type": by_time(by_op_type),
+        "by_region": by_region, "by_op_type": by_op_type,
+        "by_op_type_region": {kind: in_order(by_op_type_region[kind])
+                              for kind in by_op_type},
+        "by_op_part": {kind: {part: in_order(row) for part, row in sorted(
+            by_op_part[kind].items(), key=lambda kv: -sum(kv[1].values()))}
+            for kind in by_op_type},
         "by_op": by_time(by_op), "by_layer": by_time(by_layer),
         "by_bucket": dict(sorted(by_bucket.items())),
         "matched_us": matched, "unmatched_us": unmatched,
         "unattributed_us": by_region["unattributed"],
         "total_us": total,
     }
+
+
+def op_part_table(t, top=12) -> List[str]:
+    """The lines an operator reads of `time_attribution`'s crossed
+    keys: the `top` op types by device time, a column a region that
+    ran anything, in ms a step of one device; under an op type whose
+    code names its parts one line a part (`(no part)` for what lies
+    under the op's marker alone) and the share of its time a part
+    carries. [] where the fold has no crossed keys or no time."""
+    by_part = t.get("by_op_part")
+    if not by_part or not t.get("total_us"):
+        return []
+    per = 1e3 * max(t["steps"], 1)
+    regions = [r for r in REGIONS
+               if any(r in row for row in t["by_op_type_region"].values())]
+    fmt = "  %-32s" + " %10s" * (len(regions) + 1)
+
+    def line(label, row):
+        return fmt % ((label, "%.3f" % (sum(row.values()) / per)) + tuple(
+            "%.3f" % (row[r] / per) if r in row else "-" for r in regions))
+
+    out = ["device time by fluid op type and region (ms a step, self "
+           "times):", fmt % (("op type / part", "all") + tuple(regions))]
+    for kind, row in list(t["by_op_type_region"].items())[:top]:
+        out.append(line(kind, row))
+        parts = by_part[kind]
+        if set(parts) == {""}:
+            continue
+        out.extend(line("  pt[%s]" % part if part else "  (no part)", prow)
+                   for part, prow in parts.items())
+        whole = sum(row.values())
+        parted = whole - sum(parts.get("", {}).values())
+        out.append("    %.2f %% of %s under a part"
+                   % (100.0 * parted / whole if whole else 0.0, kind))
+    return out

@@ -31,6 +31,15 @@ from .registry import get_op, register_op
 _F32 = jnp.float32
 
 
+def _part(name):
+    """A part of the op being traced, named for the fold of device time
+    (`observability/attribution.py`: `part_scope`, `part_of`): a scope
+    at trace time, nothing at run time."""
+    from ..observability import attribution
+
+    return attribution.part_scope(name)
+
+
 # ---------------------------------------------------------------------------
 # RMS norm, causal depthwise convolution
 # ---------------------------------------------------------------------------
@@ -127,38 +136,40 @@ def _ssd_group(xdt, cs, bm, cm, chunk):
     `dt * A` inside each chunk). Inside a chunk a masked,
     decay-weighted (C B^T) product; between chunks a scan over the
     chunk states [Hg, P, N], kept float32."""
-    b, s, hg, p = xdt.shape
-    n = bm.shape[-1]
-    nc, cd = s // chunk, xdt.dtype
-    x5 = jnp.transpose(xdt.reshape(b, nc, chunk, hg, p), (0, 1, 3, 2, 4))
-    b4, c4 = bm.reshape(b, nc, chunk, n), cm.reshape(b, nc, chunk, n)
-    cs4 = jnp.transpose(cs.reshape(b, nc, chunk, hg), (0, 1, 3, 2))
-    cb = jnp.einsum("bcln,bcsn->bcls", c4, b4,
-                    preferred_element_type=_F32)
-    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-    decay = jnp.exp(jnp.where(
-        causal, cs4[..., :, None] - cs4[..., None, :], -jnp.inf))
-    y = jnp.einsum("bchls,bchsp->bchlp",
-                   (cb[:, :, None] * decay).astype(cd), x5,
-                   preferred_element_type=_F32)
-    last = cs4[..., -1:]                                 # [B, nc, Hg, 1]
-    xw = (x5.astype(_F32) * jnp.exp(last - cs4)[..., None]).astype(cd)
-    states = jnp.einsum("bcsn,bchsp->bchpn", b4, xw,
+    with _part("local"):
+        b, s, hg, p = xdt.shape
+        n = bm.shape[-1]
+        nc, cd = s // chunk, xdt.dtype
+        x5 = jnp.transpose(xdt.reshape(b, nc, chunk, hg, p), (0, 1, 3, 2, 4))
+        b4, c4 = bm.reshape(b, nc, chunk, n), cm.reshape(b, nc, chunk, n)
+        cs4 = jnp.transpose(cs.reshape(b, nc, chunk, hg), (0, 1, 3, 2))
+        cb = jnp.einsum("bcln,bcsn->bcls", c4, b4,
                         preferred_element_type=_F32)
+        causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+        decay = jnp.exp(jnp.where(
+            causal, cs4[..., :, None] - cs4[..., None, :], -jnp.inf))
+        y = jnp.einsum("bchls,bchsp->bchlp",
+                       (cb[:, :, None] * decay).astype(cd), x5,
+                       preferred_element_type=_F32)
+        last = cs4[..., -1:]                             # [B, nc, Hg, 1]
+        xw = (x5.astype(_F32) * jnp.exp(last - cs4)[..., None]).astype(cd)
+        states = jnp.einsum("bcsn,bchsp->bchpn", b4, xw,
+                            preferred_element_type=_F32)
 
-    def step(prev, inp):
-        st, dec = inp
-        return prev * dec[..., None, None] + st, prev
+        def step(prev, inp):
+            st, dec = inp
+            return prev * dec[..., None, None] + st, prev
 
-    _, before = lax.scan(
-        step, jnp.zeros((b, hg, p, n), _F32),
-        (jnp.moveaxis(states, 1, 0), jnp.moveaxis(jnp.exp(last[..., 0]),
-                                                  1, 0)))
-    before = jnp.moveaxis(before, 0, 1)                  # [B, nc, Hg, P, N]
-    y = y + jnp.exp(cs4)[..., None] * jnp.einsum(
-        "bcln,bchpn->bchlp", c4, before.astype(cd),
-        preferred_element_type=_F32)
-    return jnp.transpose(y, (0, 1, 3, 2, 4)).reshape(b, s, hg, p).astype(cd)
+        _, before = lax.scan(
+            step, jnp.zeros((b, hg, p, n), _F32),
+            (jnp.moveaxis(states, 1, 0), jnp.moveaxis(jnp.exp(last[..., 0]),
+                                                      1, 0)))
+        before = jnp.moveaxis(before, 0, 1)              # [B, nc, Hg, P, N]
+        y = y + jnp.exp(cs4)[..., None] * jnp.einsum(
+            "bcln,bchpn->bchlp", c4, before.astype(cd),
+            preferred_element_type=_F32)
+        return jnp.transpose(y, (0, 1, 3, 2, 4)).reshape(
+            b, s, hg, p).astype(cd)
 
 
 def _by_group(fn, bm, cm, *by_head):
@@ -166,17 +177,19 @@ def _by_group(fn, bm, cm, *by_head):
     (`lax.map`: one group's [L, L] intermediates live at a time);
     `by_head` are [B, S, H, ...], `bm`/`cm` [B, S, G, N]. Every result
     comes back with the group axis first."""
-    g = bm.shape[2]
-    split = lambda t: jnp.moveaxis(  # noqa: E731
-        t.reshape(t.shape[:2] + (g, -1) + t.shape[3:]), 2, 0)
-    return lax.map(lambda a: fn(*a), tuple(map(split, by_head)) + (
-        jnp.moveaxis(bm, 2, 0), jnp.moveaxis(cm, 2, 0)))
+    with _part("groups"):
+        g = bm.shape[2]
+        split = lambda t: jnp.moveaxis(  # noqa: E731
+            t.reshape(t.shape[:2] + (g, -1) + t.shape[3:]), 2, 0)
+        return lax.map(lambda a: fn(*a), tuple(map(split, by_head)) + (
+            jnp.moveaxis(bm, 2, 0), jnp.moveaxis(cm, 2, 0)))
 
 
 def _join_groups(t):
     """[G, B, S, Hg, ...] -> [B, S, G * Hg, ...]"""
-    t = jnp.moveaxis(t, 0, 2)
-    return t.reshape(t.shape[:2] + (-1,) + t.shape[4:])
+    with _part("groups"):
+        t = jnp.moveaxis(t, 0, 2)
+        return t.reshape(t.shape[:2] + (-1,) + t.shape[4:])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -184,7 +197,8 @@ def _ssd(xdt, bm, cm, cs, chunk, kernel):
     if kernel:
         from .pallas.ssd_scan import ssd_chunk_scan_fwd
 
-        return ssd_chunk_scan_fwd(xdt, bm, cm, cs, chunk)
+        with _part("kernel"):
+            return ssd_chunk_scan_fwd(xdt, bm, cm, cs, chunk)
     return _join_groups(_by_group(
         functools.partial(_ssd_group, chunk=chunk), bm, cm, xdt, cs))
 
@@ -203,8 +217,9 @@ def _ssd_bwd(chunk, kernel, res, dy):
                        x_g, cs_g, b_g, c_g)[1](dy_g)
 
     dx, dcs, db, dc = _by_group(one, bm, cm, xdt, cs, dy)
-    return (_join_groups(dx), jnp.moveaxis(db, 0, 2),
-            jnp.moveaxis(dc, 0, 2), _join_groups(dcs))
+    with _part("groups"):
+        return (_join_groups(dx), jnp.moveaxis(db, 0, 2),
+                jnp.moveaxis(dc, 0, 2), _join_groups(dcs))
 
 
 _ssd.defvjp(_ssd_fwd, _ssd_bwd)
@@ -220,21 +235,23 @@ def ssd_chunk_scan(x, dt, dt_bias, a_log, bm, cm, d, chunk, kernel=None):
     b, s, h, p = x.shape
     if kernel is None:
         kernel = jax.default_backend() == "tpu"
-    step = jax.nn.softplus(dt.astype(_F32) + dt_bias.astype(_F32))
-    a = -jnp.exp(a_log.astype(_F32))
-    xdt = (x.astype(_F32) * step[..., None]).astype(x.dtype)
-    pad = -s % chunk
-    if pad:
-        # a padded position has a step of nought: it decays nothing
-        # and adds nothing
-        xdt, bm, cm, step = (
-            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-            for t in (xdt, bm, cm, step))
-    da = (step * a).reshape(b, -1, chunk, h)
-    cs = jnp.cumsum(da, axis=2).reshape(b, -1, h)
+    with _part("groups"):
+        step = jax.nn.softplus(dt.astype(_F32) + dt_bias.astype(_F32))
+        a = -jnp.exp(a_log.astype(_F32))
+        xdt = (x.astype(_F32) * step[..., None]).astype(x.dtype)
+        pad = -s % chunk
+        if pad:
+            # a padded position has a step of nought: it decays nothing
+            # and adds nothing
+            xdt, bm, cm, step = (
+                jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                for t in (xdt, bm, cm, step))
+        da = (step * a).reshape(b, -1, chunk, h)
+        cs = jnp.cumsum(da, axis=2).reshape(b, -1, h)
     y = _ssd(xdt, bm, cm, cs, chunk, bool(kernel))[:, :s]
-    return (y.astype(_F32)
-            + d.astype(_F32)[:, None] * x.astype(_F32)).astype(x.dtype)
+    with _part("groups"):
+        return (y.astype(_F32)
+                + d.astype(_F32)[:, None] * x.astype(_F32)).astype(x.dtype)
 
 
 @register_op("ssd_chunk_scan")
@@ -275,21 +292,22 @@ def _unit_lower_inverse(a):
     The gradient is the inverse's own (`_unit_lower_inverse_bwd`), not
     the two series transposed: it needs T alone, in two products where
     the series' transpose runs twenty and keeps every power."""
-    c = a.shape[-1]
-    eye = jnp.eye(c, dtype=a.dtype)
-    block = jnp.arange(c) // _GDR_BLOCK
-    mm = functools.partial(jnp.matmul, precision=_HIGHEST)
+    with _part("inverse"):
+        c = a.shape[-1]
+        eye = jnp.eye(c, dtype=a.dtype)
+        block = jnp.arange(c) // _GDR_BLOCK
+        mm = functools.partial(jnp.matmul, precision=_HIGHEST)
 
-    def series(x, nilpotent):
-        inv, power = eye - x, x
-        for _ in range(max(0, (nilpotent - 1).bit_length() - 1)):
-            power = mm(power, power)
-            inv = mm(inv, eye + power)
-        return inv
+        def series(x, nilpotent):
+            inv, power = eye - x, x
+            for _ in range(max(0, (nilpotent - 1).bit_length() - 1)):
+                power = mm(power, power)
+                inv = mm(inv, eye + power)
+            return inv
 
-    d = jnp.where(block[:, None] == block[None, :], a, 0.0)
-    d_inv = series(d, _GDR_BLOCK)
-    return mm(series(mm(d_inv, a - d), -(-c // _GDR_BLOCK)), d_inv)
+        d = jnp.where(block[:, None] == block[None, :], a, 0.0)
+        d_inv = series(d, _GDR_BLOCK)
+        return mm(series(mm(d_inv, a - d), -(-c // _GDR_BLOCK)), d_inv)
 
 
 def _unit_lower_inverse_fwd(a):
@@ -302,11 +320,12 @@ def _unit_lower_inverse_bwd(t, d_t):
     float32 products. Kept to the strict lower triangle, where a lives:
     off it the truncated series is not the inverse, and its transpose
     and this formula differ."""
-    d_a = -jnp.einsum(
-        "...ji,...jl->...il", t,
-        jnp.einsum("...jk,...lk->...jl", d_t, t, precision=_HIGHEST),
-        precision=_HIGHEST)
-    return (jnp.tril(d_a, -1),)
+    with _part("inverse"):
+        d_a = -jnp.einsum(
+            "...ji,...jl->...il", t,
+            jnp.einsum("...jk,...lk->...jl", d_t, t, precision=_HIGHEST),
+            precision=_HIGHEST)
+        return (jnp.tril(d_a, -1),)
 
 
 _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
@@ -316,12 +335,13 @@ def _gdr_inverse(kk, gc, beta, cd):
     """(T = (I + tril(diag(beta) K K^T . decay, -1))^-1 at `cd`, decay)
     for kk = K K^T [B, N, H, 1, C, C] float32 and decay_ij =
     exp(gc_i - gc_j) on and under the diagonal, nought above it."""
-    chunk = gc.shape[-1]
-    rows, cols = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
-    decay = jnp.exp(jnp.where(
-        rows >= cols, gc[..., :, None] - gc[..., None, :], -jnp.inf))
-    return _unit_lower_inverse(jnp.where(
-        rows > cols, beta[..., None] * decay * kk, 0.0)).astype(cd), decay
+    with _part("inverse"):
+        chunk = gc.shape[-1]
+        rows, cols = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+        decay = jnp.exp(jnp.where(
+            rows >= cols, gc[..., :, None] - gc[..., None, :], -jnp.inf))
+        return _unit_lower_inverse(jnp.where(
+            rows > cols, beta[..., None] * decay * kk, 0.0)).astype(cd), decay
 
 
 def _gdr_local(q, k, v, gc, beta):
@@ -332,41 +352,46 @@ def _gdr_local(q, k, v, gc, beta):
     W = T (beta exp(gc) K), U0 = T (beta V) (a chunk's corrections are
     U0 - W S for the state S at its start), the causal Q K^T . decay,
     Q exp(gc), K exp(gc_C - gc) and exp(gc_C)."""
-    cd = q.dtype
-    kk, qk = (jnp.einsum("bnhid,bnhjd->bnhij", x, k,
-                         preferred_element_type=_F32)[:, :, :, None]
-              for x in (k, q))
-    t, decay = _gdr_inverse(kk, gc, beta, cd)
-    kf, grow = k.astype(_F32)[:, :, :, None], jnp.exp(gc)[..., None]
-    solve = functools.partial(jnp.einsum, "bnhrij,bnhrjd->bnhrid", t,
-                              preferred_element_type=_F32)
-    w = solve((kf * (beta[..., None] * grow)).astype(cd)).astype(cd)
-    u0 = solve((v.astype(_F32) * beta[..., None]).astype(cd))
-    last = gc[..., -1:]
-    return (w, u0, (qk * decay).astype(cd),
-            (q.astype(_F32)[:, :, :, None] * grow).astype(cd),
-            (kf * jnp.exp(last - gc)[..., None]).astype(cd),
-            jnp.exp(last[..., 0]))
+    with _part("local"):
+        cd = q.dtype
+        kk, qk = (jnp.einsum("bnhid,bnhjd->bnhij", x, k,
+                             preferred_element_type=_F32)[:, :, :, None]
+                  for x in (k, q))
+        t, decay = _gdr_inverse(kk, gc, beta, cd)
+        kf, grow = k.astype(_F32)[:, :, :, None], jnp.exp(gc)[..., None]
+        solve = functools.partial(jnp.einsum, "bnhrij,bnhrjd->bnhrid", t,
+                                  preferred_element_type=_F32)
+        w = solve((kf * (beta[..., None] * grow)).astype(cd)).astype(cd)
+        u0 = solve((v.astype(_F32) * beta[..., None]).astype(cd))
+        last = gc[..., -1:]
+        return (w, u0, (qk * decay).astype(cd),
+                (q.astype(_F32)[:, :, :, None] * grow).astype(cd),
+                (kf * jnp.exp(last - gc)[..., None]).astype(cd),
+                jnp.exp(last[..., 0]))
 
 
 def _chunked(t, chunk, heads):
     """[B, S, heads * R, ...] -> [B, N, heads, R, chunk, ...]"""
-    b, s = t.shape[:2]
-    t = t.reshape((b, s // chunk, chunk, heads, -1) + t.shape[3:])
-    return jnp.moveaxis(t, 2, 4)
+    with _part("groups"):
+        b, s = t.shape[:2]
+        t = t.reshape((b, s // chunk, chunk, heads, -1) + t.shape[3:])
+        return jnp.moveaxis(t, 2, 4)
 
 
 def _unchunked(t):
     """[B, N, H, R, chunk, ...] -> [B, S, H * R, ...]"""
-    t = jnp.moveaxis(t, 4, 2)
-    return t.reshape((t.shape[0], t.shape[1] * t.shape[2], -1) + t.shape[5:])
+    with _part("groups"):
+        t = jnp.moveaxis(t, 4, 2)
+        return t.reshape(
+            (t.shape[0], t.shape[1] * t.shape[2], -1) + t.shape[5:])
 
 
 def _gdr_group_inputs(q, k, v, gc, beta):
-    hk = q.shape[2]
-    q, k = (_chunked(x, _GDR_CHUNK, hk)[:, :, :, 0] for x in (q, k))
-    return (q, k) + tuple(_chunked(x, _GDR_CHUNK, hk)
-                          for x in (v, gc, beta))
+    with _part("groups"):
+        hk = q.shape[2]
+        q, k = (_chunked(x, _GDR_CHUNK, hk)[:, :, :, 0] for x in (q, k))
+        return (q, k) + tuple(_chunked(x, _GDR_CHUNK, hk)
+                              for x in (v, gc, beta))
 
 
 def _chunks_first(*ts):
@@ -378,53 +403,55 @@ def _gdr_walk_fwd(w, u0, aqk, qg, kd, gl):
     `lax.scan`: (out [N, B, H, R, C, dv], the state at every chunk's
     start [N, B, H, R, dk, dv] float32), the chunk axis first as the
     scan stacks them."""
-    cd = w.dtype
+    with _part("walk"):
+        cd = w.dtype
 
-    def step(state, xs):
-        w_c, u0_c, aqk_c, qg_c, kd_c, gl_c = xs
-        low = state.astype(cd)
-        u = (u0_c - jnp.einsum("bhrid,bhrde->bhrie", w_c, low,
-                               preferred_element_type=_F32)).astype(cd)
-        out = (jnp.einsum("bhrid,bhrde->bhrie", qg_c, low,
-                          preferred_element_type=_F32)
-               + jnp.einsum("bhrij,bhrje->bhrie", aqk_c, u,
-                            preferred_element_type=_F32))
-        after = gl_c[..., None, None] * state + jnp.einsum(
-            "bhrid,bhrie->bhrde", kd_c, u, preferred_element_type=_F32)
-        return after, (out.astype(cd), state)
+        def step(state, xs):
+            w_c, u0_c, aqk_c, qg_c, kd_c, gl_c = xs
+            low = state.astype(cd)
+            u = (u0_c - jnp.einsum("bhrid,bhrde->bhrie", w_c, low,
+                                   preferred_element_type=_F32)).astype(cd)
+            out = (jnp.einsum("bhrid,bhrde->bhrie", qg_c, low,
+                              preferred_element_type=_F32)
+                   + jnp.einsum("bhrij,bhrje->bhrie", aqk_c, u,
+                                preferred_element_type=_F32))
+            after = gl_c[..., None, None] * state + jnp.einsum(
+                "bhrid,bhrie->bhrde", kd_c, u, preferred_element_type=_F32)
+            return after, (out.astype(cd), state)
 
-    b, _, h, r, _, dk = qg.shape
-    return lax.scan(
-        step, jnp.zeros((b, h, r, dk, u0.shape[-1]), _F32),
-        _chunks_first(w, u0, aqk, qg, kd, gl))[1]
+        b, _, h, r, _, dk = qg.shape
+        return lax.scan(
+            step, jnp.zeros((b, h, r, dk, u0.shape[-1]), _F32),
+            _chunks_first(w, u0, aqk, qg, kd, gl))[1]
 
 
 def _gdr_walk_bwd(w, aqk, qg, kd, gl, u, starts, d_out):
     """The same chunks walked last to first, the state's cotangent
     carried: (d_u float32, d_kd, d_gl), a chunk each."""
-    cd = w.dtype
+    with _part("walk"):
+        cd = w.dtype
 
-    def step(d_state, xs):
-        w_c, aqk_c, qg_c, kd_c, gl_c, u_c, start_c, do_c = xs
-        d_low = d_state.astype(cd)
-        d_u = (jnp.einsum("bhrij,bhrie->bhrje", aqk_c, do_c,
-                          preferred_element_type=_F32)
-               + jnp.einsum("bhrjd,bhrde->bhrje", kd_c, d_low,
-                            preferred_element_type=_F32))
-        d_kd = jnp.einsum("bhrje,bhrde->bhrjd", u_c, d_low,
-                          preferred_element_type=_F32)
-        d_gl = jnp.sum(d_state * start_c, axis=(-2, -1))
-        before = (jnp.einsum("bhrid,bhrie->bhrde", qg_c, do_c,
-                             preferred_element_type=_F32)
-                  + gl_c[..., None, None] * d_state
-                  - jnp.einsum("bhrid,bhrie->bhrde", w_c, d_u.astype(cd),
-                               preferred_element_type=_F32))
-        return before, (d_u, d_kd.astype(cd), d_gl)
+        def step(d_state, xs):
+            w_c, aqk_c, qg_c, kd_c, gl_c, u_c, start_c, do_c = xs
+            d_low = d_state.astype(cd)
+            d_u = (jnp.einsum("bhrij,bhrie->bhrje", aqk_c, do_c,
+                              preferred_element_type=_F32)
+                   + jnp.einsum("bhrjd,bhrde->bhrje", kd_c, d_low,
+                                preferred_element_type=_F32))
+            d_kd = jnp.einsum("bhrje,bhrde->bhrjd", u_c, d_low,
+                              preferred_element_type=_F32)
+            d_gl = jnp.sum(d_state * start_c, axis=(-2, -1))
+            before = (jnp.einsum("bhrid,bhrie->bhrde", qg_c, do_c,
+                                 preferred_element_type=_F32)
+                      + gl_c[..., None, None] * d_state
+                      - jnp.einsum("bhrid,bhrie->bhrde", w_c, d_u.astype(cd),
+                                   preferred_element_type=_F32))
+            return before, (d_u, d_kd.astype(cd), d_gl)
 
-    _, walked = lax.scan(
-        step, jnp.zeros(starts.shape[:1] + starts.shape[2:], _F32),
-        _chunks_first(w, aqk, qg, kd, gl, u, starts, d_out), reverse=True)
-    return tuple(jnp.moveaxis(t, 0, 1) for t in walked)
+        _, walked = lax.scan(
+            step, jnp.zeros(starts.shape[:1] + starts.shape[2:], _F32),
+            _chunks_first(w, aqk, qg, kd, gl, u, starts, d_out), reverse=True)
+        return tuple(jnp.moveaxis(t, 0, 1) for t in walked)
 
 
 def _gdr_group_fwd(q, k, v, gc, beta):
@@ -432,7 +459,8 @@ def _gdr_group_fwd(q, k, v, gc, beta):
     chunk's start [B, N, H, R, dk, dv] float32)."""
     out, starts = _gdr_walk_fwd(
         *_gdr_local(*_gdr_group_inputs(q, k, v, gc, beta)))
-    return _unchunked(jnp.moveaxis(out, 0, 1)), jnp.moveaxis(starts, 0, 1)
+    with _part("groups"):
+        return _unchunked(jnp.moveaxis(out, 0, 1)), jnp.moveaxis(starts, 0, 1)
 
 
 def _gdr_group_fwd_kernel(into, q, k, v, gc, beta):
@@ -444,10 +472,12 @@ def _gdr_group_fwd_kernel(into, q, k, v, gc, beta):
     from .pallas.gated_delta_rule import gated_delta_rule_fwd
 
     q, k, v, gc, beta = _gdr_group_inputs(q, k, v, gc, beta)
-    kk = jnp.einsum("bnhid,bnhjd->bnhij", k, k,
-                    preferred_element_type=_F32)[:, :, :, None]
-    out, stack = gated_delta_rule_fwd(
-        q, k, v, _gdr_inverse(kk, gc, beta, q.dtype)[0], gc, beta, into)
+    with _part("local"):
+        kk = jnp.einsum("bnhid,bnhjd->bnhij", k, k,
+                        preferred_element_type=_F32)[:, :, :, None]
+    with _part("walk"):
+        out, stack = gated_delta_rule_fwd(
+            q, k, v, _gdr_inverse(kk, gc, beta, q.dtype)[0], gc, beta, into)
     return _unchunked(out), stack
 
 
@@ -467,35 +497,39 @@ def _gdr_group_bwd(q, k, v, gc, beta, starts, d_out, group=None):
     (w, u0, aqk, qg, kd, gl), local_vjp = jax.vjp(_gdr_local, *ins)
     d_out = _chunked(d_out, _GDR_CHUNK, q.shape[2])
     stack = starts
-    if group is not None:
-        starts = lax.dynamic_index_in_dim(stack, group, keepdims=False)
-    low = starts.astype(cd)
-    u = (u0 - jnp.einsum("bnhrid,bnhrde->bnhrie", w, low,
-                         preferred_element_type=_F32)).astype(cd)
-    if group is not None:
-        from .pallas.gated_delta_rule import gated_delta_rule_bwd
+    with _part("groups"):
+        if group is not None:
+            starts = lax.dynamic_index_in_dim(stack, group, keepdims=False)
+    with _part("local"):
+        low = starts.astype(cd)
+        u = (u0 - jnp.einsum("bnhrid,bnhrde->bnhrie", w, low,
+                             preferred_element_type=_F32)).astype(cd)
+    with _part("walk"):
+        if group is not None:
+            from .pallas.gated_delta_rule import gated_delta_rule_bwd
 
-        # a loop stands between what is made before it and after it;
-        # a kernel's call does not, and the compiler then schedules the
-        # transposed products round it: 7 ms a step (9 and 0.2 GB while
-        # the inverse's series were transposed too)
-        w_, aqk_, qg_, kd_, gl_, u_, do_ = lax.optimization_barrier(
-            (w, aqk, qg, kd, gl, u, d_out))
-        d_u, d_kd, d_gl = lax.optimization_barrier(gated_delta_rule_bwd(
-            w_, aqk_, qg_, kd_, gl_, u_, stack, group, do_))
-    else:
-        d_u, d_kd, d_gl = _gdr_walk_bwd(w, aqk, qg, kd, gl, u, starts,
-                                        d_out)
-    d_uc = d_u.astype(cd)
-    d_w = -jnp.einsum("bnhrie,bnhrde->bnhrid", d_uc, low,
-                      preferred_element_type=_F32)
-    d_aqk = jnp.einsum("bnhrie,bnhrje->bnhrij", d_out, u,
-                       preferred_element_type=_F32)
-    d_qg = jnp.einsum("bnhrie,bnhrde->bnhrid", d_out, low,
-                      preferred_element_type=_F32)
-    d_q, d_k, d_v, d_gc, d_beta = local_vjp(
-        (d_w.astype(cd), d_u, d_aqk.astype(cd), d_qg.astype(cd), d_kd,
-         d_gl))
+            # a loop stands between what is made before it and after it;
+            # a kernel's call does not, and the compiler then schedules the
+            # transposed products round it: 7 ms a step (9 and 0.2 GB while
+            # the inverse's series were transposed too)
+            w_, aqk_, qg_, kd_, gl_, u_, do_ = lax.optimization_barrier(
+                (w, aqk, qg, kd, gl, u, d_out))
+            d_u, d_kd, d_gl = lax.optimization_barrier(gated_delta_rule_bwd(
+                w_, aqk_, qg_, kd_, gl_, u_, stack, group, do_))
+        else:
+            d_u, d_kd, d_gl = _gdr_walk_bwd(w, aqk, qg, kd, gl, u, starts,
+                                            d_out)
+    with _part("local"):
+        d_uc = d_u.astype(cd)
+        d_w = -jnp.einsum("bnhrie,bnhrde->bnhrid", d_uc, low,
+                          preferred_element_type=_F32)
+        d_aqk = jnp.einsum("bnhrie,bnhrje->bnhrij", d_out, u,
+                           preferred_element_type=_F32)
+        d_qg = jnp.einsum("bnhrie,bnhrde->bnhrid", d_out, low,
+                          preferred_element_type=_F32)
+        d_q, d_k, d_v, d_gc, d_beta = local_vjp(
+            (d_w.astype(cd), d_u, d_aqk.astype(cd), d_qg.astype(cd), d_kd,
+             d_gl))
     return (_unchunked(d_q[:, :, :, None]), _unchunked(d_k[:, :, :, None]),
             _unchunked(d_v), _unchunked(d_gc), _unchunked(d_beta))
 
@@ -512,8 +546,9 @@ def _gdr_groups(b, n_chunks, hk, r):
 
 def _head_groups(groups, *ts):
     """[B, S, H, ...] -> [groups, B, S, H / groups, ...] of each"""
-    return tuple(jnp.moveaxis(t.reshape(
-        t.shape[:2] + (groups, -1) + t.shape[3:]), 2, 0) for t in ts)
+    with _part("groups"):
+        return tuple(jnp.moveaxis(t.reshape(
+            t.shape[:2] + (groups, -1) + t.shape[3:]), 2, 0) for t in ts)
 
 
 def _gdr_grouped(q, k, v, gc, beta):
@@ -526,26 +561,28 @@ def _gdr_grouped(q, k, v, gc, beta):
 def _gdr(q, k, v, gc, beta, kernel):
     if kernel:
         # a kernel's output cannot be dropped where nobody reads it
-        return _join_groups(lax.map(
-            lambda a: _gdr_group_fwd_kernel(None, *a)[0],
-            _gdr_grouped(q, k, v, gc, beta)))
+        with _part("groups"):
+            return _join_groups(lax.map(
+                lambda a: _gdr_group_fwd_kernel(None, *a)[0],
+                _gdr_grouped(q, k, v, gc, beta)))
     return _gdr_fwd(q, k, v, gc, beta, kernel)[0]
 
 
 def _gdr_fwd(q, k, v, gc, beta, kernel):
     grouped = _gdr_grouped(q, k, v, gc, beta)
-    if kernel:
-        # each group's walk writes its states into the one stack
-        groups, b, s, hk, dk = grouped[0].shape
-        hv, dv = grouped[2].shape[3:]
-        starts, out = lax.scan(
-            lambda stack, a: _gdr_group_fwd_kernel((stack, a[0]), *a[1:])[
-                ::-1],
-            lax.empty((groups, b, s // _GDR_CHUNK, hk, hv // hk, dk, dv),
-                      _F32),
-            (jnp.arange(groups),) + grouped)
-    else:
-        out, starts = lax.map(lambda a: _gdr_group_fwd(*a), grouped)
+    with _part("groups"):
+        if kernel:
+            # each group's walk writes its states into the one stack
+            groups, b, s, hk, dk = grouped[0].shape
+            hv, dv = grouped[2].shape[3:]
+            starts, out = lax.scan(
+                lambda stack, a: _gdr_group_fwd_kernel((stack, a[0]), *a[1:])[
+                    ::-1],
+                lax.empty((groups, b, s // _GDR_CHUNK, hk, hv // hk, dk, dv),
+                          _F32),
+                (jnp.arange(groups),) + grouped)
+        else:
+            out, starts = lax.map(lambda a: _gdr_group_fwd(*a), grouped)
     return _join_groups(out), (q, k, v, gc, beta, starts)
 
 
@@ -553,12 +590,14 @@ def _gdr_bwd(kernel, res, d_out):
     *ins, starts = res
     groups = starts.shape[0]
     *ins, d_out = _head_groups(groups, *ins, d_out)
-    if kernel:
-        grads = lax.map(
-            lambda a: _gdr_group_bwd(*a[1:6], starts, a[6], group=a[0]),
-            (jnp.arange(groups), *ins, d_out))
-    else:
-        grads = lax.map(lambda a: _gdr_group_bwd(*a), (*ins, starts, d_out))
+    with _part("groups"):
+        if kernel:
+            grads = lax.map(
+                lambda a: _gdr_group_bwd(*a[1:6], starts, a[6], group=a[0]),
+                (jnp.arange(groups), *ins, d_out))
+        else:
+            grads = lax.map(lambda a: _gdr_group_bwd(*a),
+                            (*ins, starts, d_out))
     return tuple(_join_groups(g) for g in grads)
 
 
@@ -748,13 +787,14 @@ def _window(i, order, sizes, block, k):
     """Row block i of the sorted pairs: (the pairs, their tokens, the
     share of each held expert's group that lies in the block, which
     rows lie before the last held pair)."""
-    lo = i * block
-    pairs = lax.dynamic_slice(order, (lo,), (block,))
-    ends = jnp.cumsum(sizes)
-    part = (jnp.clip(ends, lo, lo + block)
-            - jnp.clip(ends - sizes, lo, lo + block))
-    live = (lo + jnp.arange(block) < ends[-1])[:, None]
-    return pairs, pairs // k, part, live
+    with _part("sort"):
+        lo = i * block
+        pairs = lax.dynamic_slice(order, (lo,), (block,))
+        ends = jnp.cumsum(sizes)
+        part = (jnp.clip(ends, lo, lo + block)
+                - jnp.clip(ends - sizes, lo, lo + block))
+        live = (lo + jnp.arange(block) < ends[-1])[:, None]
+        return pairs, pairs // k, part, live
 
 
 def _act(activation, x):
@@ -762,7 +802,8 @@ def _act(activation, x):
 
 
 def _trips(sizes, block):
-    return -(-jnp.sum(sizes) // block)
+    with _part("sort"):
+        return -(-jnp.sum(sizes) // block)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
@@ -775,14 +816,18 @@ def _routed_rows(x, weight, w_up, w_down, order, sizes, block, activation):
 
     def trip(i, out):
         pairs, tokens, part, live = _window(i, order, sizes, block, k)
-        rows = jnp.take(x, tokens, axis=0)
-        mid = _act(activation, _grouped_product(rows, w_up, part))
-        made = _grouped_product(mid, w_down, part).astype(_F32)
-        made = made * jnp.take(scale, pairs)[:, None]
-        return out.at[tokens].add(jnp.where(live, made, 0.0))
+        with _part("gather"):
+            rows = jnp.take(x, tokens, axis=0)
+        with _part("products"):
+            mid = _act(activation, _grouped_product(rows, w_up, part))
+            made = _grouped_product(mid, w_down, part).astype(_F32)
+        with _part("scatter"):
+            made = made * jnp.take(scale, pairs)[:, None]
+            return out.at[tokens].add(jnp.where(live, made, 0.0))
 
-    return lax.fori_loop(0, _trips(sizes, block), trip,
-                         jnp.zeros(x.shape, _F32)).astype(x.dtype)
+    with _part("scatter"):
+        return lax.fori_loop(0, _trips(sizes, block), trip,
+                             jnp.zeros(x.shape, _F32)).astype(x.dtype)
 
 
 def _routed_rows_fwd(x, weight, w_up, w_down, order, sizes, block,
@@ -806,30 +851,41 @@ def _routed_rows_bwd(block, activation, res, ct):
     def trip(i, carry):
         d_x, d_scale, d_up, d_down = carry
         pairs, tokens, part, live = _window(i, order, sizes, block, k)
-        rows = jnp.take(x, tokens, axis=0)
-        mid, act_vjp = jax.vjp(functools.partial(_act, activation),
-                               _grouped_product(rows, w_up, part))
-        ct_rows = jnp.take(ct, tokens, axis=0)
-        ct_mid = _grouped_product(ct_rows, w_down, part, True).astype(_F32)
-        d_scale = d_scale.at[pairs].add(jnp.sum(jnp.where(
-            live, mid.astype(_F32) * ct_mid, 0.0), axis=1))
-        by_pair = jnp.take(scale, pairs)[:, None]
-        d_down = _grouped_outer_product(
-            d_down, mid, (ct_rows * by_pair).astype(x.dtype), part)
-        ct_pre, = act_vjp((ct_mid * by_pair).astype(x.dtype))
-        d_up = _grouped_outer_product(d_up, rows, ct_pre, part)
-        d_rows = _grouped_product(ct_pre, w_up, part, True)
-        d_x = d_x.at[tokens].add(jnp.where(live, d_rows, 0).astype(_F32))
+        with _part("gather"):
+            rows = jnp.take(x, tokens, axis=0)
+        with _part("products"):
+            mid, act_vjp = jax.vjp(functools.partial(_act, activation),
+                                   _grouped_product(rows, w_up, part))
+        with _part("gather"):
+            ct_rows = jnp.take(ct, tokens, axis=0)
+        with _part("products"):
+            ct_mid = _grouped_product(ct_rows, w_down, part,
+                                      True).astype(_F32)
+        with _part("scatter"):
+            d_scale = d_scale.at[pairs].add(jnp.sum(jnp.where(
+                live, mid.astype(_F32) * ct_mid, 0.0), axis=1))
+        with _part("gather"):
+            by_pair = jnp.take(scale, pairs)[:, None]
+        with _part("products"):
+            d_down = _grouped_outer_product(
+                d_down, mid, (ct_rows * by_pair).astype(x.dtype), part)
+            ct_pre, = act_vjp((ct_mid * by_pair).astype(x.dtype))
+            d_up = _grouped_outer_product(d_up, rows, ct_pre, part)
+            d_rows = _grouped_product(ct_pre, w_up, part, True)
+        with _part("scatter"):
+            d_x = d_x.at[tokens].add(
+                jnp.where(live, d_rows, 0).astype(_F32))
         return d_x, d_scale, d_up, d_down
 
     d_x, d_scale, d_up, d_down = lax.fori_loop(
         0, _trips(sizes, block), trip,
         (jnp.zeros(x.shape, _F32), jnp.zeros(scale.shape, _F32),
          jnp.zeros(w_up.shape, _F32), jnp.zeros(w_down.shape, _F32)))
-    return (d_x.astype(x.dtype),
-            d_scale.reshape(weight.shape).astype(weight.dtype),
-            d_up.astype(w_up.dtype), d_down.astype(w_down.dtype), None,
-            None)
+    with _part("scatter"):
+        return (d_x.astype(x.dtype),
+                d_scale.reshape(weight.shape).astype(weight.dtype),
+                d_up.astype(w_up.dtype), d_down.astype(w_down.dtype), None,
+                None)
 
 
 _routed_rows.defvjp(_routed_rows_fwd, _routed_rows_bwd)
@@ -861,12 +917,13 @@ def moe_experts(x, idx, weight, w_up, w_down, held_start=0,
         "trip, %d trips if every pair is held here", held_start,
         held_start + n_held, of, idx.shape[-1], block,
         -(-idx.size // block))
-    local = idx.reshape(-1) - held_start                 # [T * k]
-    key = jnp.where((local >= 0) & (local < n_held), local, n_held)
-    sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
-                    axis=0).astype(jnp.int32)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    order = jnp.pad(order, (0, -order.shape[0] % block))
+    with _part("sort"):
+        local = idx.reshape(-1) - held_start             # [T * k]
+        key = jnp.where((local >= 0) & (local < n_held), local, n_held)
+        sizes = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
+                        axis=0).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        order = jnp.pad(order, (0, -order.shape[0] % block))
     out = _routed_rows(x, weight, w_up, w_down, order, sizes, block,
                        activation)
     return out, sizes, _trips(sizes, block) * block

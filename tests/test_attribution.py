@@ -13,6 +13,7 @@ counter lane, and model_stats' static estimate now has a ground-truth
 cross-check."""
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -590,6 +591,181 @@ def test_region_fold_counts_device_self_time_once():
     assert rows["steps"] == 2 and len(rows["rows"]) == 9
     assert ("while.1", "jit(fn)/jvp(pp[b0;o8;scan;])/while", 10.0) \
         in rows["rows"]
+
+
+_GDR = "pp[b0;o5;gated_delta_rule;mix.tmp_3]"
+
+
+@pytest.mark.parametrize("path,part", [
+    # a part belongs to the innermost fluid op: one that stands before
+    # the last marker is the enclosing op's (a `scan` over a sub-block)
+    ("jit(fn)/jvp(pp[b0;o8;scan;])/pt[walk]/while/body/closed_call/"
+     "pp[b1;o2;matmul;enc.tmp_0]/dot_general", None),
+    # of two parts the inner one wins
+    ("jit(fn)/jvp(" + _GDR + ")/pt[local]/pt[inverse]/dot_general",
+     "inverse"),
+    # jax keeps both through a `custom_vjp`'s backward rule, where the
+    # marker stands twice, and under `jax.checkpoint`
+    ("jit(fn)/transpose(jvp(" + _GDR + "))/pt[local]/transpose(" + _GDR
+     + ")/jvp(pt[local])/pt[inverse]/jit(tril)/select_n", "inverse"),
+    ("jit(fn)/transpose(jvp())/checkpoint/rematted_computation/" + _GDR
+     + "/jvp(pt[local])/mul", "local"),
+    ("jit(fn)/jvp(" + _GDR + ")/while/body/pt[walk]/while/body/dot_general",
+     "walk"),
+    # no part: under the op's marker alone, under no marker, no path
+    ("jit(fn)/jvp(" + _GDR + ")/reshape", None),
+    ("jit(fn)/pt[local]/mul", None),
+    ("", None), (None, None),
+    # a name outside `[a-z0-9_]+` is no part
+    ("jit(fn)/jvp(" + _GDR + ")/pt[Local]/mul", None),
+], ids=["outer_ops_part", "inner_of_two", "custom_vjp_backward_rule",
+        "recompute", "inside_two_loops", "marker_alone", "no_marker",
+        "empty", "none", "not_a_part_name"])
+def test_part_of_reads_the_innermost_ops_innermost_part(path, part):
+    assert attr.part_of(path) == part
+
+
+def test_part_scope_follows_the_provenance_flag():
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+
+    def names():
+        def fn(x):
+            with attr.marker_scope(_GDR), attr.part_scope("local"):
+                with attr.part_scope("inverse"):
+                    return jnp.sin(x) @ x
+        text = jax.jit(jax.grad(lambda x: jnp.sum(fn(x)))).lower(
+            jnp.ones((4, 4))).compile().as_text()
+        return set(re.findall(r'op_name="([^"]*)"', text))
+
+    set_flags({"FLAGS_tpu_op_provenance": True})
+    assert not isinstance(attr.part_scope("local"), contextlib.nullcontext)
+    on = names()
+    assert {attr.part_of(n) for n in on if _GDR in n} == {"inverse"}
+    assert any("transpose(" in n and "pt[inverse]" in n for n in on)
+    set_flags({"FLAGS_tpu_op_provenance": False})
+    assert isinstance(attr.part_scope("local"), contextlib.nullcontext)
+    assert not any("pt[" in n or "pp[" in n for n in names())
+
+
+_OLD_KEYS = ("steps", "devices", "by_region", "by_op_type", "by_op",
+             "by_layer", "by_bucket", "matched_us", "unmatched_us",
+             "unattributed_us", "total_us")
+
+
+def test_the_fold_crosses_op_type_with_region_and_part():
+    """`by_op_type_region` and `by_op_part` each sum to `by_op_type`,
+    op type by op type, and every key the fold had reads what it read
+    with the parts' scopes taken out of the paths."""
+    fwd = "jit(fn)/jvp(" + _GDR + ")/"
+    bwd = "jit(fn)/transpose(jvp(" + _GDR + "))/"
+    again = "jit(fn)/transpose(jvp())/checkpoint/rematted_computation/" \
+        + _GDR + "/"
+    module = "jit_fn(1)"
+    ops = [
+        ("fusion.1", 10.0, 8.0, fwd + "pt[groups]/reshape"),
+        ("while.1", 18.0, 30.0, fwd + "while"),
+        ("fusion.2", 19.0, 12.0, fwd + "while/body/pt[local]/dot_general"),
+        ("fusion.3", 31.0, 9.0,
+         fwd + "while/body/pt[local]/pt[inverse]/dot_general"),
+        ("kernel.1", 40.0, 7.0, fwd + "while/body/pt[walk]/pallas_call"),
+        ("fusion.4", 50.0, 11.0, again + "jvp(pt[local])/pt[inverse]/mul"),
+        ("fusion.5", 61.0, 6.0, again + "jvp(pt[local])/mul"),
+        ("fusion.6", 70.0, 20.0, bwd + "pt[local]/transpose(" + _GDR
+         + ")/jvp(pt[local])/pt[inverse]/dot_general"),
+        ("fusion.7", 90.0, 5.0, bwd + "pt[walk]/optimization_barrier"),
+        ("fusion.8", 95.0, 4.0, bwd + "add_any"),
+        # another op type, with no part anywhere
+        ("fusion.9", 100.0, 10.0,
+         "jit(fn)/jvp(pp[b0;o6;matmul;mix.tmp_4])/dot_general"),
+        ("fusion.10", 110.0, 3.0, "jit(fn)/pp[b0;o80;cast;nsp.b]/mul"),
+        ("copy-done.1", 113.0, 2.0, None),
+    ]
+    modules = [(module, 10.0, 110.0, None)]
+    t = attr.time_attribution(_device_trace(ops, modules))
+    assert t["by_op_type"] == pytest.approx({
+        "gated_delta_rule": 84.0, "matmul": 10.0, "cast": 3.0})
+    assert t["by_op_type_region"] == {
+        "gated_delta_rule": pytest.approx({
+            "forward": 38.0, "recompute": 17.0, "backward": 29.0}),
+        "matmul": {"forward": 10.0}, "cast": {"update": 3.0}}
+    assert t["by_op_part"]["gated_delta_rule"] == {
+        "inverse": pytest.approx({
+            "forward": 9.0, "recompute": 11.0, "backward": 20.0}),
+        "local": pytest.approx({"forward": 12.0, "recompute": 6.0}),
+        "walk": pytest.approx({"forward": 7.0, "backward": 5.0}),
+        "groups": {"forward": 8.0},
+        # the loop's own time and the cotangents' sum: under no part
+        "": pytest.approx({"forward": 2.0, "backward": 4.0})}
+    assert t["by_op_part"]["matmul"] == {"": {"forward": 10.0}}
+    # parts and op types by time, regions in REGIONS' order
+    assert list(t["by_op_part"]["gated_delta_rule"]) == [
+        "inverse", "local", "walk", "groups", ""]
+    assert list(t["by_op_type_region"]) == list(t["by_op_type"])
+    assert list(t["by_op_type_region"]["gated_delta_rule"]) == [
+        "forward", "recompute", "backward"]
+    for kind, us in t["by_op_type"].items():
+        assert sum(t["by_op_type_region"][kind].values()) \
+            == pytest.approx(us)
+        assert sum(sum(row.values())
+                   for row in t["by_op_part"][kind].values()) \
+            == pytest.approx(us)
+    # what the fold read before it knew of parts, it reads still
+    bare = [(name, ts, dur, path and re.sub(r"pt\[\w+\]", "", path))
+            for name, ts, dur, path in ops]
+    was = attr.time_attribution(_device_trace(bare, modules))
+    for key in _OLD_KEYS:
+        assert t[key] == was[key], key
+    assert set(t) == set(_OLD_KEYS) | {"by_op_type_region", "by_op_part"}
+    assert all(set(row) == {""} for row in was["by_op_part"].values())
+    # the table an operator reads: a line an op type, one a part
+    table = attr.op_part_table(t)
+    assert table[1].split() == ["op", "type", "/", "part", "all",
+                                "forward", "recompute", "backward",
+                                "update"]
+    assert table[2].split() == ["gated_delta_rule", "0.084", "0.038",
+                                "0.017", "0.029", "-"]
+    assert table[3].split() == ["pt[inverse]", "0.040", "0.009", "0.011",
+                                "0.020", "-"]
+    assert table[7].split()[:3] == ["(no", "part)", "0.006"]
+    assert table[8].strip() == "92.86 % of gated_delta_rule under a part"
+    assert table[9].split()[0] == "matmul" and len(table) == 11
+    assert attr.op_part_table({"total_us": 0.0}) == []
+
+
+def test_xplane_blame_prints_the_folds_own_table(tmp_path, capsys,
+                                                 monkeypatch):
+    """`perf_analysis.py --stragglers --xplane-dir` shows an operator
+    the op types by region and a line a part, as the fold formats it."""
+    import gzip
+
+    fwd = "jit(fn)/jvp(" + _GDR + ")/"
+    ops = [("fusion.1", 0.0, 30.0, fwd + "pt[local]/pt[inverse]/dot_general"),
+           ("fusion.2", 30.0, 10.0, fwd + "pt[groups]/transpose"),
+           ("fusion.3", 40.0, 10.0, "jit(fn)/transpose(jvp(" + _GDR
+            + "))/pt[walk]/pallas_call")]
+    d = tmp_path / "plugins" / "profile" / "run1"
+    d.mkdir(parents=True)
+    with gzip.open(d / "host.trace.json.gz", "wt") as f:
+        json.dump({"traceEvents": _device_trace(
+            ops, [("jit_fn(1)", 0.0, 50.0, None)])}, f)
+    monkeypatch.syspath_prepend(os.path.join(_REPO, "tools"))
+    import perf_analysis
+
+    t = perf_analysis.xplane_blame(str(tmp_path))
+    said = capsys.readouterr().out.splitlines()
+    assert t["by_op_part"]["gated_delta_rule"]["inverse"] == {
+        "forward": 30.0}
+    table = ["  " + line for line in attr.op_part_table(t)]
+    at = said.index(table[0])
+    assert said[at:at + len(table)] == table
+    assert any(line.split()[:2] == ["pt[inverse]", "0.030"]
+               for line in said)
+    # below the op-type lines it had
+    assert at > max(i for i, line in enumerate(said)
+                    if line.startswith("  op type "))
 
 
 @pytest.mark.parametrize("policy", [True, False])

@@ -187,6 +187,84 @@ def test_ssd_chunk_scan_forward_and_backward_match_the_recurrence(
             jnp.max(jnp.abs(b))) + 1e-6
 
 
+def _parts_by_region(op_type, fn, *args):
+    """{region: the parts named there} of the compiled gradient of
+    `fn` under the op's marker and `jax.checkpoint` (its output read
+    inside the checkpoint, as a layer's next op reads it), and the
+    gradient itself: what a trace's fold would read of the op."""
+    import re
+
+    from paddle_tpu.observability import attribution as attr
+
+    marker = "pp[b0;o3;%s;mix.tmp_0]" % op_type
+
+    def layer(*a):
+        with attr.marker_scope(marker):
+            y = fn(*a)
+        return jnp.sin(y)
+
+    grad = jax.jit(jax.grad(lambda *a: jnp.sum(jax.checkpoint(layer)(*a)),
+                            argnums=tuple(range(len(args)))))
+    got = {}
+    for name in set(re.findall(r'op_name="([^"]*)"',
+                               grad.lower(*args).compile().as_text())):
+        if marker in name:
+            got.setdefault(attr.region_of(name, attr.provenance_of(name)),
+                           set()).add(attr.part_of(name))
+    return got, grad(*args)
+
+
+def _stamps_change_no_number(op_type, fn, args, parts):
+    """With `FLAGS_tpu_op_provenance` on, every one of `parts`
+    ({region: names}) stands in an `op_name` of that region; off, none
+    does; the gradients are equal to the bit."""
+    from paddle_tpu.utils.flags import get_flag, set_flags
+
+    was = get_flag("FLAGS_tpu_op_provenance")
+    try:
+        set_flags({"FLAGS_tpu_op_provenance": True})
+        (on, g_on), y_on = _parts_by_region(op_type, fn, *args), fn(*args)
+        set_flags({"FLAGS_tpu_op_provenance": False})
+        (off, g_off), y_off = _parts_by_region(op_type, fn, *args), fn(*args)
+    finally:
+        set_flags({"FLAGS_tpu_op_provenance": was})
+    assert off == {}
+    assert set(on) == {"recompute", "backward"}
+    for region, names in parts.items():
+        assert set(names) <= on[region], (region, on[region])
+    for a, b in zip(g_on + (y_on,), g_off + (y_off,)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["jnp", "pallas"])
+def test_ssd_chunk_scan_names_its_parts_where_a_trace_reads_them(kernel):
+    """`pt[kernel]` is the Pallas forward (made again in the recompute),
+    `pt[local]` the `jax.numpy` group wherever it runs, the backward
+    pass among it, `pt[groups]` the layouts round them."""
+    made = "kernel" if kernel else "local"
+    _stamps_change_no_number(
+        "ssd_chunk_scan", lambda *a: hybrid_ops.ssd_chunk_scan(
+            *a, chunk=16, kernel=kernel), _scan_args(40),
+        {"recompute": {made, "groups"}, "backward": {"local", "groups"}})
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["relu2", "swiglu"])
+def test_moe_experts_names_its_parts_where_a_trace_reads_them(gated):
+    x, w_r, w_up, w_down = _moe_inputs()
+    if gated:
+        w_up = jnp.concatenate([w_up, w_up[:, :, ::-1]], axis=-1)
+    score = jax.nn.sigmoid(x @ w_r)
+    _, idx = jax.lax.top_k(score, 3)
+    weight = jnp.take_along_axis(score, idx, axis=1)
+    every = {"sort", "gather", "products", "scatter"}
+    _stamps_change_no_number(
+        "moe_experts", lambda x, weight, w_up, w_down: hybrid_ops.moe_experts(
+            x, idx, weight, w_up, w_down, held_start=4,
+            activation="swiglu" if gated else "relu2", num_experts=16)[0],
+        (x, weight, w_up[4:8], w_down[4:8]),
+        {"recompute": every, "backward": every})
+
+
 def _moe_inputs(tokens=48, hidden=16, experts=16, width=24, seed=5):
     r = np.random.default_rng(seed)
     x = jnp.asarray(r.normal(size=(tokens, hidden)), jnp.float32)

@@ -22,7 +22,7 @@ from paddle_tpu.ops import hybrid_ops
 from paddle_tpu.ops.pallas import gated_delta_rule as delta_kernels
 from paddle_tpu.ops.registry import run_op
 from benchmark.reference import qwen3_next as ref
-from test_nemotron_h import _lay
+from test_nemotron_h import _lay, _stamps_change_no_number
 
 _B, _S = 2, 80          # 80 positions: two chunks of 64, the last padded
 
@@ -183,6 +183,19 @@ def test_gated_delta_rule_and_every_inputs_gradient_match_the_recurrence(
     for name, a, b in zip("q k v g beta".split(), *grads):
         top = float(jnp.max(jnp.abs(b)))
         assert float(jnp.max(jnp.abs(a - b))) <= 5e-5 * max(top, 1.0), name
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["scan", "kernels"])
+def test_gated_delta_rule_names_its_parts_where_a_trace_reads_them(kernel):
+    """`pt[inverse]` (inside `pt[local]`, and the inner part wins),
+    `pt[local]`, `pt[walk]` and `pt[groups]` each stand in an `op_name`
+    of the recompute and of the backward pass, whoever walks the
+    chunks; the stamps change no number."""
+    every = {"inverse", "local", "walk", "groups"}
+    _stamps_change_no_number(
+        "gated_delta_rule", functools.partial(
+            hybrid_ops.gated_delta_rule, kernel=kernel),
+        _delta_args(5, s=130), {"recompute": every, "backward": every})
 
 
 def _chunked_inputs(seed, r, dtype, b=2, n=3, h=2, dk=8, dv=16):

@@ -182,10 +182,11 @@ def test_held_experts_grouped_products_at_the_published_widths(
         ((16384, 6), jnp.float32), ((8, 2688, 1856), bf),
         ((8, 1856, 2688), bf))
     _kernels_are_called(compiled, ["moe_experts_gmm", "moe_experts_tgmm"])
-    # two products in the forward's loop, five in the backward's
+    # two products in the forward's loop, five in the backward's, each
+    # under the part the op's code names round it
     in_loop = [line for line in compiled.as_text().splitlines()
-               if "tpu_custom_call" in line and "/while/body/moe_experts_"
-               in line]
+               if "tpu_custom_call" in line
+               and "/while/body/pt[products]/moe_experts_" in line]
     assert len(in_loop) == 7
     assert sum("transpose(jvp())" in line for line in in_loop) == 5
 

@@ -1095,8 +1095,10 @@ def xplane_blame(xplane_dir):
     """Fold a capture window's device time (self times of the `XLA Ops`
     thread, over the traced executions of the step's module) through
     the provenance markers: where in the step it went (forward /
-    recompute / backward / update / collective), by fluid op type, and
-    the per-layer / per-bucket blame (--stragglers --xplane-dir).
+    recompute / backward / update / collective), by fluid op type, by
+    op type crossed with region and with the parts an op's code names
+    (`attribution.op_part_table`), and the per-layer / per-bucket blame
+    (--stragglers --xplane-dir).
     Returns the attribution dict."""
     from paddle_tpu.observability import attribution as attr
 
@@ -1121,6 +1123,8 @@ def xplane_blame(xplane_dir):
                   % (region, us / steps, 100.0 * us / t["total_us"]))
     for op_type, us in list(t["by_op_type"].items())[:12]:
         print("  op type %-26s %10.1f us/step" % (op_type, us / steps))
+    for line in attr.op_part_table(t):
+        print("  " + line)
     for layer, us in list(t["by_layer"].items())[:10]:
         print("  layer %-28s %10.1f us" % (layer, us))
     for b, us in t["by_bucket"].items():
