@@ -70,8 +70,9 @@ class Scan:
     RecomputeOptimizer's checkpoint segments). Held across the n
     iterations are the boundary (the carry) and the few values of the
     body that cost less to keep than to make again — every dropout's
-    boolean keep mask and the output of a matmul narrower than what it
-    contracts (``ops/remat_names.py``) — instead of O(n *
+    boolean keep mask, the output of a matmul narrower than what it
+    contracts, and the output and row statistics of an attention the
+    flash kernels ran (``ops/remat_names.py``) — instead of O(n *
     body-internals); ``Executor.remat_saved`` says what a traced
     program keeps, in bytes.
 

@@ -479,8 +479,10 @@ def _exec_scan(op, env, key0, op_idx, amp_lists):
     segment machinery. What the forward scan stacks for the backward
     is then the carry and the few values the ops named as cheaper to
     keep than to make again (ops/remat_names.py: dropout keep masks,
-    narrow matmul products); everything else in the body is computed a
-    second time. `program._remat_saved` holds what was kept, per scan
+    narrow matmul products, the flash kernels' output and row
+    statistics, which leave their forward kernel out of the second
+    pass); everything else in the body is computed a second time.
+    `program._remat_saved` holds what was kept, per scan
     op (Executor.remat_saved reads it). Reverse-mode grads fall out of
     the ordinary jax.vjp over lax.scan (no recurrent_grad op —
     contrast reference recurrent_op.cc's scope-mutation step loop)."""
@@ -1184,7 +1186,9 @@ def build_block_fn(program, block, feed_names, fetch_names,
                 else:
                     # the scan's policy on an unrolled stack: a segment
                     # hands on its outputs and the few values its ops
-                    # named as cheaper to keep (ops/remat_names.py)
+                    # named as cheaper to keep (ops/remat_names.py:
+                    # masks, narrow products, what the flash kernels'
+                    # forward call wrote)
                     from ..observability import attribution as _attr
                     from ..ops import remat_names
 

@@ -9,7 +9,13 @@ recipe) that keeps the [S, S] score matrix out of HBM entirely — scores
 live tile-by-tile in VMEM, the MXU does the two matmuls per tile, and the
 running (m, l, acc) statistics are carried in VMEM scratch across the
 sequential innermost grid dimension. Backward recomputes tiles the same
-way (no O(S^2) residuals; only the per-row logsumexp is saved).
+way (no O(S^2) residuals: besides its inputs the gradient holds the
+output and the per-row logsumexp). Under a per-layer checkpoint
+(`fluid/lowering.py`'s remat scan and recompute segments) those two
+are what the checkpoint keeps (`ops/remat_names.FLASH_RESIDUAL`), the
+logsumexp as S floats a head and not as the column the kernel writes,
+whose every row pads to 128 lanes in HBM: the recompute then makes q,
+k and v again and the forward kernel runs once a layer and step.
 
 Layout: q, k, v are [B, H, S, D]; internally flattened to [B*H, S, D].
 V's last axis may differ from Q's and K's (PR 33: latent attention
@@ -53,8 +59,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .. import remat_names
 
 _NEG_INF = -1e30
 _LANES = 128  # VREG lane count: scratch stats are replicated across lanes
@@ -230,6 +239,10 @@ class _Spec(NamedTuple):
     kv_rep: int      # query heads on one key/value head
     bias_rep: int    # programs (batch * head) on one row of the key bias
     blocks: Blocks
+    #: the call is traced in a checkpointed body, whose policy keeps o
+    #: and the row statistics (decided where `flash_attention` is
+    #: called and carried here, so that it is part of every cache key)
+    kept: bool = False
 
 
 # ---------------------------------------------------------------------------
@@ -826,11 +839,21 @@ def _flash_core(q, k, v, key_bias, seed, spec):
 
 def _flash_core_fwd(q, k, v, key_bias, seed, spec):
     o, lse = _fwd_call(q, k, v, key_bias, seed, spec, _interpret_default())
+    if spec.kept:
+        # the backward rule reads these two and nothing else of the
+        # call, so the checkpoint that keeps them recomputes no kernel;
+        # the statistics as [BH, S]: the kernel's [BH, S, 1] column
+        # takes 128 lanes a row in HBM
+        o = checkpoint_name(o, remat_names.FLASH_RESIDUAL)
+        lse = checkpoint_name(lse.reshape(lse.shape[:2]),
+                              remat_names.FLASH_RESIDUAL)
     return o, (q, k, v, key_bias, seed, o, lse)
 
 
 def _flash_core_bwd(spec, res, do):
     q, k, v, key_bias, seed, o, lse = res
+    if spec.kept:
+        lse = lse.reshape(*lse.shape, 1)
     dq, dk, dv = _bwd_call(q, k, v, key_bias, seed, o, lse, do, spec,
                            _interpret_default())
     dbias = None if key_bias is None else jnp.zeros_like(key_bias)
@@ -933,6 +956,13 @@ def flash_attention(q, k, v, key_bias=None, causal=False, sm_scale=None,
     spec = _Spec(float(sm_scale), bool(causal), dropout_p, H // Hkv,
                  H if bias is not None else 0, blocks)
     _engaged(qf, kf, vf, spec)
+    # in a checkpointed body: its record takes the two rows here, where
+    # the body is traced (the forward rule is traced later)
+    if remat_names.note(remat_names.FLASH_RESIDUAL, (B * H, nq, Dv),
+                        q.dtype):
+        remat_names.note(remat_names.FLASH_RESIDUAL, (B * H, nq),
+                         jnp.dtype(jnp.float32))
+        spec = spec._replace(kept=True)
     o = _flash_core(qf, kf, vf, bias, seed, spec)
     return o[:, :Sq, :].reshape(B, H, Sq, Dv)
 

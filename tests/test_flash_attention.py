@@ -580,3 +580,129 @@ def test_sdpa_op_gives_values_their_width_on_every_path():
     dropped = sdpa(ins, dict(attrs, is_test=False, attn_dropout_prob=0.5,
                              _rng_key=jax.random.PRNGKey(0)))
     assert dropped.shape == want.shape
+
+
+# -- PR 36: a checkpoint keeps the output and the row statistics -------------
+
+def _kernel_calls(jaxpr):
+    """{kernel's name: `pallas_call` equations of that name in `jaxpr`
+    and every jaxpr below it}."""
+    import collections
+
+    from test_scan_layers import _walk
+
+    return dict(collections.Counter(
+        eqn.params["name"] for _, eqn in _walk(jaxpr)
+        if eqn.primitive.name == "pallas_call"))
+
+
+def _checkpointed_grads(kept, q, k, v, w, **kw):
+    """(the jaxpr of value and gradients, their values) of one
+    attention layer under the lowering's checkpoint, its body traced
+    inside `collecting(kept)`: a list as in a `remat` scan or a
+    recompute segment, None as anywhere else."""
+    from paddle_tpu.ops import remat_names
+
+    def loss(q, k, v):
+        # a new function a trace, as the lowering's bodies are
+        body = jax.checkpoint(
+            lambda q, k, v: flash_attention(q * 1.5, k, v, **kw),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *remat_names.KEPT))
+        with remat_names.collecting(kept):
+            return jnp.sum(body(q, k, v).astype(jnp.float32) * w)
+
+    f = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    return jax.make_jaxpr(f)(q, k, v), f(q, k, v)
+
+
+@pytest.mark.parametrize("h,hkv,d,dv,dtype,kw", [
+    (2, 2, 32, 32, "float32", dict(causal=True)),
+    (4, 2, 32, 32, "bfloat16", dict(causal=True)),
+    (2, 2, 48, 24, "float32", dict(causal=True)),
+    (2, 2, 32, 32, "float32", dict(dropout_p=0.25,
+                                   dropout_seed=jnp.int32(77))),
+], ids=["equal_widths", "grouped_query", "values_of_their_own_width",
+        "dropout_in_the_kernel"])
+def test_a_checkpoint_keeps_the_output_and_the_row_statistics(
+        h, hkv, d, dv, dtype, kw):
+    """Traced in a checkpointed body the call's output and compact
+    logsumexp are the checkpoint's to keep: the gradient's program
+    holds the forward kernel once where the parent's (nothing named)
+    holds it twice, the gradients are equal to the bit, and the body's
+    list says what is kept."""
+    import importlib
+
+    from paddle_tpu.ops import remat_names
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    rng = np.random.default_rng(36)
+    B, S = 2, 72
+    q = jnp.asarray(rng.standard_normal((B, h, S, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((B, hkv, S, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((B, hkv, S, dv)), dtype)
+    w = jnp.asarray(rng.standard_normal((B, h, S, dv)).astype("float32"))
+    fwd, dkv, dq = fa.KERNEL_NAMES
+
+    plain_jaxpr, (plain_loss, plain) = _checkpointed_grads(
+        None, q, k, v, w, **kw)
+    assert _kernel_calls(plain_jaxpr.jaxpr) == {fwd: 2, dkv: 1, dq: 1}
+    kept = []
+    kept_jaxpr, (kept_loss, got) = _checkpointed_grads(
+        kept, q, k, v, w, **kw)
+    assert _kernel_calls(kept_jaxpr.jaxpr) == {fwd: 1, dkv: 1, dq: 1}
+    assert "name=%s" % remat_names.FLASH_RESIDUAL in str(kept_jaxpr)
+    assert remat_names.FLASH_RESIDUAL not in str(plain_jaxpr)
+
+    assert float(kept_loss) == float(plain_loss)
+    for a, b, name in zip(got, plain, ("dq", "dk", "dv")):
+        assert a.dtype == b.dtype and np.array_equal(
+            np.asarray(a, np.float32), np.asarray(b, np.float32)), name
+    # S = 72 is one block of 72 rows: the output at its own dtype and S
+    # floats a head; the body was traced twice (the jaxpr, the values)
+    rows = [(remat_names.FLASH_RESIDUAL, (B * h, S, dv), jnp.dtype(dtype)),
+            (remat_names.FLASH_RESIDUAL, (B * h, S), jnp.dtype("float32"))]
+    assert kept == rows + rows
+    assert sum(int(np.prod(shape)) * np.dtype(dt).itemsize
+               for _, shape, dt in rows) == \
+        B * h * S * (dv * jnp.dtype(dtype).itemsize + 4)
+
+
+def test_the_same_functions_traced_outside_and_inside_are_two_programs():
+    """Whether the two values are named is decided where
+    `flash_attention` is called and carried in the `custom_vjp`'s
+    static argument: a forward rule that read the context itself would
+    be in no cache key, and the same function objects traced first
+    outside a checkpointed body would hand that program back inside
+    one."""
+    import importlib
+
+    from paddle_tpu.ops import remat_names
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    rng = np.random.default_rng(37)
+    q, k, v = _rand_qkv(rng, 1, 2, 64, 64, 16)
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *remat_names.KEPT)
+
+    def attend(q, k, v):      # one function object for every trace below
+        return fa._flash_core(q, k, v, None, None, attend.spec)
+
+    def program(kept):
+        def loss(q, k, v):
+            with remat_names.collecting(kept):
+                attend.spec = base._replace(kept=remat_names.note(
+                    remat_names.FLASH_RESIDUAL, (2, 64, 16), q.dtype))
+                return jnp.sum(jax.checkpoint(
+                    lambda *a: attend(*a), policy=policy)(q, k, v))
+        return jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+            q.reshape(2, 64, 16), k.reshape(2, 64, 16),
+            v.reshape(2, 64, 16))
+
+    base = fa._Spec(0.25, False, 0.0, 1, 0,
+                    fa.block_rule(64, 64, 16, "float32"))
+    assert base.kept is False
+    outside, inside, outside_again = program(None), program([]), program(None)
+    fwd = fa.KERNEL_NAMES[0]
+    assert _kernel_calls(outside.jaxpr)[fwd] == 2
+    assert _kernel_calls(inside.jaxpr)[fwd] == 1
+    assert _kernel_calls(outside_again.jaxpr)[fwd] == 2
+    assert str(outside) == str(outside_again) != str(inside)

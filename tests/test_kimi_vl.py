@@ -358,3 +358,48 @@ def test_layer_kinds_follow_first_k_dense_replace():
         kimi_vl.KimiVLConfig.tiny()))}
     assert "l0.gate_up" in names and "l0.router" not in names
     assert "l1.router" in names and "l1.gate_up" not in names
+
+
+def test_a_mixers_segment_keeps_the_flash_kernels_output_and_statistics(
+        monkeypatch):
+    """The segment path of PR 36: with attention in the flash kernels
+    (as on the chip at the cell's length) every mixer's segment keeps
+    the kernels' output `[B heads, S, v_head_dim]` and S floats a head
+    of row statistics beside its narrow products, and the whole step
+    holds one forward kernel a layer where it held two."""
+    from paddle_tpu.ops.pallas.flash_attention import KERNEL_NAMES
+    from paddle_tpu.utils import flags
+    from test_scan_layers import (_flash_from_its_length, _step_jaxpr,
+                                  _walk)
+
+    _flash_from_its_length(monkeypatch)
+    monkeypatch.setitem(flags._FLAGS, "FLAGS_flash_attention_min_seq", _S)
+    cfg = kimi_vl.KimiVLConfig.tiny(experts_held=(0, 4))
+    main, startup, loss, _ = _build(cfg, True)
+    jaxpr = _step_jaxpr(main, startup, _batch(cfg, 3), loss)
+    calls = [e.params["name"] for _, e in _walk(jaxpr)
+             if e.primitive.name == "pallas_call"
+             and e.params["name"] in KERNEL_NAMES]
+    layers_n = cfg.num_hidden_layers
+    assert {n: calls.count(n) for n in KERNEL_NAMES} == dict.fromkeys(
+        KERNEL_NAMES, layers_n)
+
+    saved = fluid.Executor(fluid.CPUPlace()).remat_saved(main)
+    segs = [saved[k] for k in sorted(
+        saved, key=lambda k: int(k.rsplit("seg", 1)[1]))]
+    heads, dv = cfg.num_attention_heads, cfg.v_head_dim
+    out, stats = _B * heads * _S * dv * 2, _B * heads * _S * 4
+    for mixer in segs[0:2 * layers_n:2]:
+        residual = [(r["shape"], r["dtype"], r["bytes"])
+                    for r in mixer["kept"]
+                    if r["name"] == "flash_attention_residual"]
+        assert residual == [([_B * heads, _S, dv], "bfloat16", out),
+                            ([_B * heads, _S], "float32", stats)]
+        # besides the two narrow products it kept before
+        assert len(mixer["kept"]) == 4 and mixer["n"] == 1
+        assert mixer["bytes_per_layer"] == out + stats + sum(
+            r["bytes"] for r in mixer["kept"]
+            if r["name"] == "narrow_matmul_product")
+    for other in segs[1:2 * layers_n:2] + segs[2 * layers_n:]:
+        assert not any(r["name"] == "flash_attention_residual"
+                       for r in other["kept"])

@@ -449,13 +449,17 @@ def _flash_from_its_length(monkeypatch):
 #: attributes it does not set) left all four as they were and added
 #: the fifth, its own decoder's (retaken in PR 34: the delta rule's
 #: triangular inverse has a gradient of its own): with these every kind
-#: of step the benchmark runs is held. A PR that means to change one of
-#: these programs replaces the digest and says so
+#: of step the benchmark runs is held. The fourth was retaken in PR 36:
+#: a flash call traced in a checkpointed body names its output and its
+#: compact row statistics for the checkpoint to keep, so the scan stacks
+#: the two and its recompute holds no forward kernel; the other four
+#: (no flash kernel at these lengths) stood. A PR that means to change
+#: one of these programs replaces the digest and says so
 _STEP_DIGESTS = {
     "_build_scan_bert_remat": "f6d6b541724972c8",
     "_build_resnet50": "deed87d731a3ffb9",
     "_build_nemotron_h": "9df08f21cb176523",
-    "_build_scan_bert_flash": "7c1a665f24fa7e4a",
+    "_build_scan_bert_flash": "b097f3d589655a72",
     "_build_qwen3_next": "3c544571148cfcdf",
 }
 
@@ -478,6 +482,66 @@ def test_berts_and_resnets_steps_are_the_accepted_programs(
     text = re.sub(r"0x[0-9a-f]+", "0x", str(_step_jaxpr(*build())))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         _STEP_DIGESTS[build.__name__]
+
+
+def test_remat_saved_record_lists_the_flash_residual(monkeypatch, caplog):
+    """At a length the flash kernels take, the scan keeps their output
+    and S floats a head of row statistics beside the two hidden masks
+    and the narrow product (the attention mask is made in the kernel),
+    stacks exactly those, and its recompute holds no forward kernel."""
+    import logging
+
+    from paddle_tpu.ops.pallas.flash_attention import KERNEL_NAMES
+
+    _flash_from_its_length(monkeypatch)
+    cfg, main, st, total, feed = _build_scan_bert(seq=_FLASH_S)
+    L, h, heads = (cfg.num_hidden_layers, cfg.hidden_size,
+                   cfg.num_attention_heads)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(st)
+    with caplog.at_level(logging.INFO, logger="paddle_tpu.fluid.lowering"):
+        for _ in range(2):
+            exe.run(main, feed=feed, fetch_list=[total])
+    (_, rec), = exe.remat_saved(main).items()
+    out = _B * heads * _FLASH_S * (h // heads) * 2      # bfloat16
+    stats = _B * heads * _FLASH_S * 4                   # float32, compact
+    hidden_mask = _B * _FLASH_S * h
+    product = _B * _FLASH_S * h * 2
+    assert [(r["name"], r["shape"], r["dtype"], r["bytes"])
+            for r in rec["kept"]] == [
+        ("flash_attention_residual", [_B * heads, _FLASH_S, h // heads],
+         "bfloat16", out),
+        ("flash_attention_residual", [_B * heads, _FLASH_S], "float32",
+         stats),
+        ("dropout_keep_mask", [_B, _FLASH_S, h], "bool", hidden_mask),
+        ("narrow_matmul_product", [_B, _FLASH_S, h], "bfloat16", product),
+        ("dropout_keep_mask", [_B, _FLASH_S, h], "bool", hidden_mask)]
+    assert rec["bytes_per_layer"] == out + stats + 2 * hidden_mask + product
+    assert rec["bytes_over_scan"] == L * rec["bytes_per_layer"]
+    said = [r for r in caplog.records if "keeps across" in r.getMessage()]
+    assert len(said) == 1 and "flash_attention_residual float32[%d, %d]" % (
+        _B * heads, _FLASH_S) in said[0].getMessage()
+
+    fwd, bwd = _layer_scans(_step_jaxpr(main, st, feed, total), L)
+    stacked = sorted((str(v.aval.dtype), tuple(v.aval.shape))
+                     for v in fwd.outvars[fwd.params["num_carry"]:])
+    assert stacked == sorted([
+        ("bfloat16", (L, _B * heads, _FLASH_S, h // heads)),
+        ("float32", (L, _B * heads, _FLASH_S)),
+        # the layer's index: the recompute folds it into the key again
+        # for the seed the backward kernels hash
+        ("int32", (L,)),
+        ("bool", (L, _B, _FLASH_S, h)), ("bool", (L, _B, _FLASH_S, h)),
+        ("bfloat16", (L, _B, _FLASH_S, h)),
+        ("float32", (L, _B, _FLASH_S, h))])
+
+    def kernels(scan_eqn):
+        return sorted(e.params["name"]
+                      for _, e in _walk(scan_eqn.params["jaxpr"].jaxpr)
+                      if e.primitive.name == "pallas_call")
+
+    assert kernels(fwd) == [KERNEL_NAMES[0]]
+    assert kernels(bwd) == sorted(KERNEL_NAMES[1:])
 
 
 def test_remat_saved_record_matches_hand_arithmetic(caplog):
