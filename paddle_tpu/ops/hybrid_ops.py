@@ -310,8 +310,8 @@ _GDR_TILE_BYTES = 32 << 20
 _HIGHEST = lax.Precision.HIGHEST
 
 
-@jax.custom_vjp
-def _unit_lower_inverse(a):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _unit_lower_inverse(a, kernel=False):
     """(I + a)^-1 for strictly lower triangular a [..., C, C], float32,
     in matrix products alone. With d the diagonal `_GDR_BLOCK`-blocks of
     a, d^16 = 0, so (I + d)^-1 = (I - d)(I + d^2)(I + d^4)(I + d^8);
@@ -319,11 +319,18 @@ def _unit_lower_inverse(a):
     block-lower, m^(C/16) = 0, and (I + m)^-1 the same product over m's
     powers. Two short series and not one of C terms: the powers of a
     whole chunk's matrix grow as binomials before they cancel.
+    `kernel`: the same two series in one Pallas call
+    (`pallas/gated_delta_rule.unit_lower_inverse`), its products kept in
+    VMEM where these ten each go through HBM.
 
     The gradient is the inverse's own (`_unit_lower_inverse_bwd`), not
     the two series transposed: it needs T alone, in two products where
     the series' transpose runs twenty and keeps every power."""
     with _part("inverse"):
+        if kernel:
+            from .pallas.gated_delta_rule import unit_lower_inverse
+
+            return unit_lower_inverse(a, _GDR_BLOCK)
         c = a.shape[-1]
         eye = jnp.eye(c, dtype=a.dtype)
         block = jnp.arange(c) // _GDR_BLOCK
@@ -341,12 +348,12 @@ def _unit_lower_inverse(a):
         return mm(series(mm(d_inv, a - d), -(-c // _GDR_BLOCK)), d_inv)
 
 
-def _unit_lower_inverse_fwd(a):
-    t = _unit_lower_inverse(a)
+def _unit_lower_inverse_fwd(a, kernel):
+    t = _unit_lower_inverse(a, kernel)
     return t, t
 
 
-def _unit_lower_inverse_bwd(t, d_t):
+def _unit_lower_inverse_bwd(kernel, t, d_t):
     """T = (I + a)^-1 has dT = -T da T, so d_a = -T^T d_T T^T: two
     float32 products. Kept to the strict lower triangle, where a lives:
     off it the truncated series is not the inverse, and its transpose
@@ -362,24 +369,27 @@ def _unit_lower_inverse_bwd(t, d_t):
 _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-def _gdr_inverse(kk, gc, beta, cd):
+def _gdr_inverse(kk, gc, beta, cd, kernel=False):
     """(T = (I + tril(diag(beta) K K^T . decay, -1))^-1 at `cd`, decay)
     for kk = K K^T [B, N, H, 1, C, C] float32 and decay_ij =
-    exp(gc_i - gc_j) on and under the diagonal, nought above it."""
+    exp(gc_i - gc_j) on and under the diagonal, nought above it;
+    `kernel`: T from the Pallas kernel."""
     with _part("inverse"):
         chunk = gc.shape[-1]
         rows, cols = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
         decay = jnp.exp(jnp.where(
             rows >= cols, gc[..., :, None] - gc[..., None, :], -jnp.inf))
         return _unit_lower_inverse(jnp.where(
-            rows > cols, beta[..., None] * decay * kk, 0.0)).astype(cd), decay
+            rows > cols, beta[..., None] * decay * kk, 0.0),
+            kernel).astype(cd), decay
 
 
-def _gdr_local(q, k, v, gc, beta):
+def _gdr_local(q, k, v, gc, beta, kernel=False):
     """What a chunk's positions need of one another, every chunk at
     once. q, k [B, N, H, C, dk]; v [B, N, H, R, C, dv] (R value heads a
     key head); gc (the log-decays summed from the chunk's start) and
-    beta [B, N, H, R, C] float32. With T of `_gdr_inverse`, returns
+    beta [B, N, H, R, C] float32. With T of `_gdr_inverse` (`kernel`:
+    made by the Pallas kernel), returns
     W = T (beta exp(gc) K), U0 = T (beta V) (a chunk's corrections are
     U0 - W S for the state S at its start), the causal Q K^T . decay,
     Q exp(gc), K exp(gc_C - gc) and exp(gc_C)."""
@@ -388,7 +398,7 @@ def _gdr_local(q, k, v, gc, beta):
         kk, qk = (jnp.einsum("bnhid,bnhjd->bnhij", x, k,
                              preferred_element_type=_F32)[:, :, :, None]
                   for x in (k, q))
-        t, decay = _gdr_inverse(kk, gc, beta, cd)
+        t, decay = _gdr_inverse(kk, gc, beta, cd, kernel)
         kf, grow = k.astype(_F32)[:, :, :, None], jnp.exp(gc)[..., None]
         solve = functools.partial(jnp.einsum, "bnhrij,bnhrjd->bnhrid", t,
                                   preferred_element_type=_F32)
@@ -496,7 +506,7 @@ def _gdr_group_fwd(q, k, v, gc, beta):
 
 def _gdr_group_fwd_kernel(into, q, k, v, gc, beta):
     """The same through the forward kernel, which is handed the
-    triangular system's inverse (float32 products, here) and makes what
+    triangular system's inverse (the inverse kernel's) and makes what
     else a chunk's positions need of one another where it walks.
     `into`: the stack of every group's chunk states and this group's
     place in it, or None where no state is kept. Returns (out, stack)."""
@@ -508,7 +518,8 @@ def _gdr_group_fwd_kernel(into, q, k, v, gc, beta):
                         preferred_element_type=_F32)[:, :, :, None]
     with _part("walk"):
         out, stack = gated_delta_rule_fwd(
-            q, k, v, _gdr_inverse(kk, gc, beta, q.dtype)[0], gc, beta, into)
+            q, k, v, _gdr_inverse(kk, gc, beta, q.dtype, True)[0], gc, beta,
+            into)
     return _unchunked(out), stack
 
 
@@ -521,11 +532,13 @@ def _gdr_group_bwd(q, k, v, gc, beta, starts, d_out, group=None):
     it keeps T alone, across the walk, and runs two [C, C] products
     where the series were twenty. With
     `group`, `starts` is the stack of every group's chunk states, this
-    group's at that place, and the reverse kernel walks (it reads the
-    states where they lie); without, a `lax.scan` does."""
+    group's at that place, and the kernels make T again and walk (the
+    reverse one reads the states where they lie); without, the
+    `jax.numpy` series and a `lax.scan` do."""
     cd = q.dtype
     ins = _gdr_group_inputs(q, k, v, gc, beta)
-    (w, u0, aqk, qg, kd, gl), local_vjp = jax.vjp(_gdr_local, *ins)
+    (w, u0, aqk, qg, kd, gl), local_vjp = jax.vjp(
+        functools.partial(_gdr_local, kernel=group is not None), *ins)
     d_out = _chunked(d_out, _GDR_CHUNK, q.shape[2])
     stack = starts
     with _part("groups"):
@@ -655,11 +668,14 @@ def gated_delta_rule(q, k, v, g, beta, kernel=None):
     nothing [S, S]-shaped and nothing a position. `kernel` None has
     the two walks made by the Pallas kernels of
     `pallas/gated_delta_rule.py` on a TPU where dk and dv are whole
-    lane tiles (multiples of 128), and by `lax.scan` elsewhere. The
-    inverse (float32 products at `HIGHEST`, its gradient the formula
-    d_a = -T^T d_T T^T and not the series transposed) and the backward
-    pass's chunk-local values are `jax.numpy` either way; the forward
-    kernel makes the rest of a chunk's local values itself."""
+    lane tiles (multiples of 128), and by `lax.scan` elsewhere. There
+    the triangular system's inverse (float32 products at `HIGHEST`) is
+    made by the Pallas kernel too, wherever the forward pass, its
+    recompute or the backward pass needs it, and by the `jax.numpy`
+    series elsewhere; its gradient is the formula d_a = -T^T d_T T^T,
+    not the series transposed, either way. The backward pass's other
+    chunk-local values are `jax.numpy`; the forward kernel makes the
+    rest of a chunk's local values itself."""
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2:]
     if hv % hk or k.shape != q.shape or g.shape != (b, s, hv):
@@ -678,22 +694,28 @@ def gated_delta_rule(q, k, v, g, beta, kernel=None):
     if kernel is None:
         kernel = (jax.default_backend() == "tpu" and dk % 128 == 0
                   and dv % 128 == 0)
-    walked = "jax.numpy"
+    walked = inverted = "jax.numpy"
     if kernel:
-        from .pallas.gated_delta_rule import heads_a_step
+        from .pallas.gated_delta_rule import heads_a_step, systems_a_step
 
         heads = heads_a_step(hk // groups, hv // hk, _GDR_CHUNK, dk, dv,
                              q.dtype.itemsize)
         walked = "pallas (%d value heads a grid step, %d grid steps a call)" \
             % (heads, b * (hv // groups // heads) * n)
+        systems = b * n * hv // groups
+        step = systems_a_step(systems, _GDR_CHUNK)
+        inverted = "pallas (%d systems a grid step, %d grid steps a call)" \
+            % (step, -(-systems // step))
     # said where the op is traced: at the build's shape inference and
     # once a compile and layer
     logging.getLogger(__name__).info(
         "gated_delta_rule q, k %s v %s %s: %d positions a chunk, %d "
-        "chunks, %d head groups, the chunks walked by %s; kept for the "
-        "backward pass %d bytes (the inputs and %d states [%d, %d] float32)",
+        "chunks, %d head groups, the chunks walked by %s, the inverse by "
+        "%s; kept for the backward pass %d bytes (the inputs and %d states "
+        "[%d, %d] float32)",
         tuple(q.shape), tuple(v.shape), q.dtype.name, _GDR_CHUNK, n, groups,
-        walked, sum(t.size * t.dtype.itemsize for t in (q, k, v, gc, beta))
+        walked, inverted,
+        sum(t.size * t.dtype.itemsize for t in (q, k, v, gc, beta))
         + b * n * hv * dk * dv * 4, b * n * hv, dk, dv)
     return _gdr(q, k, v, gc, beta, bool(kernel))[:, :s]
 

@@ -1,6 +1,7 @@
 """The two walks over a sequence's chunks of the gated delta rule
 (`ops/hybrid_ops.gated_delta_rule`) as Pallas TPU kernels: what is
-sequential in the chunked algorithm, forward and reverse.
+sequential in the chunked algorithm, forward and reverse; and the
+inverse of each chunk's triangular system.
 
 One grid step is one chunk of a block of value heads of one sequence,
 the chunk axis innermost and sequential. Each head's state [dk, dv]
@@ -11,10 +12,10 @@ that their independent chains of products fill the MXU's latency.
 
 The forward walk makes a chunk's local values itself, in VMEM, from
 the chunked inputs and the inverse T of the chunk's triangular system
-(`hybrid_ops._gdr_inverse`, float32 products outside, rounded once to
-the operands' dtype as `hybrid_ops._gdr_local` rounds it; the inverse
-is differentiated outside too, by its own formula, where the backward
-pass makes it again): the
+(`hybrid_ops._gdr_inverse`, made by `unit_lower_inverse` below and
+rounded once to the operands' dtype as `hybrid_ops._gdr_local` rounds
+it; the inverse is differentiated outside, by its own formula, where
+the backward pass makes it again): the
 products against q and k, the decay mask and T applied to
 beta exp(gc) k and beta v never reach HBM. The reverse walk reads the
 chunk-local values `_gdr_local` stacks for its transpose,
@@ -23,6 +24,20 @@ run at the operands' dtype with float32 accumulation; what the two
 compute is what `hybrid_ops._gdr_local` with `_gdr_walk_fwd`, and
 `_gdr_walk_bwd`, compute, which remain what runs off the TPU and at
 widths that are no whole lane tiles.
+
+The inverse T = (I + a)^-1 of a strictly lower triangular a [64, 64]
+float32 runs the two short series of `hybrid_ops._unit_lower_inverse`
+in VMEM, every product float32 at `HIGHEST`: a grid step reads a block
+of systems once and writes their T once, where the `jax.numpy` series
+takes each of its ten [.., 64, 64] products through HBM (a 64-wide
+float32 row padded to 128 lanes there). Two systems go side by side in
+the 128 lanes, [a1 a2], and a product of the pair against one matrix of
+each is [x1 x2] @ [[y1, 0], [0, y2]]: one MXU pass for two. The series
+over the diagonal 16-blocks d takes the blocks alone, [B1 .. B8] in 16
+rows against their block diagonal. The second series runs over
+z = (a - d) d^-1 where the `jax.numpy` one runs over d^-1 (a - d), the
+same matrix seen from d^-1's other side: T = d^-1 (I - z)(I + z^2),
+and the last power and the running product share one right-hand side.
 """
 from __future__ import annotations
 
@@ -255,3 +270,114 @@ def gated_delta_rule_bwd(w, aqk, qg, kd, gl, u, stack, group, d_out,
     return (d_u.reshape(lead + d_u.shape[3:]),
             d_kd.reshape(lead + d_kd.shape[3:]),
             jnp.sum(d_gl, axis=-1).reshape(lead))
+
+
+#: pairs of systems a trip of the inverse kernel's loop takes at once,
+#: independent chains of products that fill one another's latency
+_PAIRS = 8
+
+
+def systems_a_step(n, chunk):
+    """Triangular systems [chunk, chunk] float32 a grid step of the
+    inverse kernel takes: the most, in whole trips of `_PAIRS` pairs,
+    whose blocks in and out (a row padded to whole lane tiles) fit
+    `_VMEM_BLOCK_BYTES` twice over, and no more than `n` need."""
+    trip = 2 * _PAIRS
+    each = 2 * 2 * chunk * -(-chunk // 128) * 128 * 4
+    return min(max(1, _VMEM_BLOCK_BYTES // (each * trip)), -(-n // trip)) * trip
+
+
+def _inverse_kernel(a_ref, t_ref, *, block):
+    c = a_ref.shape[-1]
+    wide = 2 * c
+    hi = functools.partial(jnp.dot, precision=lax.Precision.HIGHEST,
+                           preferred_element_type=_F32)
+
+    def iota(shape, axis):
+        return lax.broadcasted_iota(jnp.int32, shape, axis)
+
+    col = iota((c, wide), 1) % c
+    on_diag = iota((c, wide), 0) // block == col // block
+    left = iota((c, wide), 1) < c
+    short_block = iota((block, wide), 1) % c // block
+    eye_short = jnp.where(iota((block, wide), 0) == iota((block, wide), 1)
+                          % block, 1.0, 0.0).astype(_F32)
+    on_wide_diag = iota((wide, wide), 0) // block == iota((wide, wide), 1) \
+        // block
+
+    def short(x):
+        """a pair's diagonal blocks [c, 2c] as [B1 .. B8], [block, 2c]"""
+        out = jnp.where(short_block == 0, x[:block], 0.0)
+        for b in range(1, c // block):
+            out = out + jnp.where(short_block == b,
+                                  x[b * block:(b + 1) * block], 0.0)
+        return out
+
+    def blocks_diag(s):
+        """[B1 .. B8] -> their block diagonal [2c, 2c]"""
+        return jnp.where(on_wide_diag, jnp.concatenate([s] * (wide // block)),
+                         0.0)
+
+    def pair_diag(p):
+        """[p1 p2] -> [[p1, 0], [0, p2]]"""
+        return jnp.concatenate([jnp.where(left, p, 0.0),
+                                jnp.where(left, 0.0, p)])
+
+    def series(r, p, diag, factors):
+        """r (I + p)(I + p^2)(I + p^4) ..., `factors` of them: each
+        power and the running product times it share `diag(power)`."""
+        rows = p.shape[0]
+        for _ in range(factors - 1):
+            both = hi(jnp.concatenate([p, r]), diag(p))
+            p, r = both[:rows], r + both[rows:]
+        return r + hi(r, diag(p))
+
+    def body(trip, carry):
+        for j in range(_PAIRS):
+            at = (trip * _PAIRS + j) * 2
+            pair = jnp.concatenate([a_ref[at], a_ref[at + 1]], 1)
+            d = jnp.where(on_diag, pair, 0.0)
+            x = short(d)
+            # (I + d)^-1 = (I - x)(I + x^2)(I + x^4)(I + x^8), x^16 = 0
+            u = series(eye_short - x, hi(x, blocks_diag(x)), blocks_diag,
+                       (block - 1).bit_length() - 1)
+            u_pair = jnp.where(on_diag, jnp.concatenate([u] * (c // block)),
+                               0.0)
+            # z = (a - d) u is strictly block lower, z^(c / block) = 0:
+            # T = u (I - z)(I + z^2) ..., u z beside z^2
+            z = hi(pair - d, blocks_diag(u))
+            both = hi(jnp.concatenate([z, u_pair]), pair_diag(z))
+            t = series(u_pair - both[c:], both[:c], pair_diag,
+                       (c // block - 1).bit_length() - 1)
+            t_ref[at], t_ref[at + 1] = t[:, :c], t[:, c:]
+        return carry
+
+    lax.fori_loop(0, a_ref.shape[0] // (2 * _PAIRS), body, 0)
+
+
+def unit_lower_inverse(a, block, interpret=None):
+    """(I + a)^-1 for strictly lower triangular a [..., 64, 64] float32,
+    its diagonal `block`-blocks (16, the op's) inverted first, in one
+    Pallas call; float32 [..., 64, 64]. An odd count of systems is made
+    even by a zero system, which no pair then mixes with one of the
+    others."""
+    lead, c = a.shape[:-2], a.shape[-1]
+    if a.shape[-2:] != (64, 64) or block != 16:
+        raise ValueError("unit_lower_inverse: a %s, blocks of %d"
+                         % (a.shape, block))
+    a = a.reshape(-1, c, c)
+    n = a.shape[0]
+    if n % 2:
+        a = jnp.pad(a, ((0, 1), (0, 0), (0, 0)))
+    m = systems_a_step(a.shape[0], c)
+    spec = pl.BlockSpec((m, c, c), lambda i: (i, 0, 0))
+    t = pl.pallas_call(
+        functools.partial(_inverse_kernel, block=block),
+        grid=(pl.cdiv(a.shape[0], m),), in_specs=[spec], out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(a.shape, _F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=_interpret_default() if interpret is None else interpret,
+        name="gated_delta_rule_inverse",
+    )(a)
+    return t[:n].reshape(lead + (c, c))

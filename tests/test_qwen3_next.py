@@ -296,10 +296,12 @@ def test_gated_delta_rule_walks_head_groups_and_says_what_it_keeps(
     assert said == (
         "gated_delta_rule q, k (2, 192, 4, 8) v (2, 192, 8, 16) float32: "
         "64 positions a chunk, 3 chunks, 4 head groups, the chunks walked "
-        "by jax.numpy; kept for the backward pass %d bytes (the inputs "
-        "and 48 states [8, 16] float32)" % kept)
+        "by jax.numpy, the inverse by jax.numpy; kept for the backward "
+        "pass %d bytes (the inputs and 48 states [8, 16] float32)" % kept)
+    # a group call's 2 x 3 x 2 systems, in one block of 16
     assert (" 4 head groups, the chunks walked by pallas (2 value heads a "
-            "grid step, 6 grid steps a call); kept ") in \
+            "grid step, 6 grid steps a call), the inverse by pallas (16 "
+            "systems a grid step, 1 grid steps a call); kept ") in \
         caplog.records[-1].getMessage()
 
 
@@ -314,8 +316,10 @@ def test_gated_delta_rule_keeps_token_sized_values_and_chunk_states(
         _, vjp = jax.vjp(functools.partial(
             hybrid_ops.gated_delta_rule, kernel=kernel), *args)
     assert ("1 head groups, the chunks walked by " + (
-        "pallas (4 value heads a grid step, 6 grid steps a call);"
-        if kernel else "jax.numpy;")) in caplog.records[0].getMessage()
+        "pallas (4 value heads a grid step, 6 grid steps a call), the "
+        "inverse by pallas (32 systems a grid step, 1 grid steps a call);"
+        if kernel else "jax.numpy, the inverse by jax.numpy;")) in \
+        caplog.records[0].getMessage()
     shapes = sorted(tuple(x.shape) for x in jax.tree_util.tree_leaves(vjp)
                     if hasattr(x, "shape") and x.size > 16)
     s = 192
@@ -337,6 +341,54 @@ def test_unit_lower_inverse_of_a_chunk_of_equal_keys():
     assert np.max(np.abs(got - want)) <= 1e-4 * np.max(np.abs(want))
 
 
+def _systems(case):
+    """Strictly lower triangular float32 systems [..., 64, 64]: the
+    equal keys' one in front of random ones (a count of 70 leaves the
+    second block of 64 six), an odd count, the op's leading shape."""
+    r = np.random.default_rng(1)
+    shape = {"equal_keys_and_random": (3,), "a_block_and_six": (70,),
+             "odd": (17,), "the_ops_shape": (2, 3, 2, 2)}[case]
+    a = np.tril(r.uniform(-1, 1, shape + (64, 64)), -1)
+    a.reshape(-1, 64, 64)[0] = np.tril(np.ones((64, 64)), -1)
+    return jnp.asarray(a, jnp.float32)
+
+
+@pytest.mark.parametrize("case", ["equal_keys_and_random", "a_block_and_six",
+                                  "odd", "the_ops_shape"])
+def test_the_inverse_kernel_gives_the_series_inverse(case):
+    """The Pallas inverse (under the interpreter here) against the
+    inverse in float64, with the `jax.numpy` series' tolerance, and
+    against the series itself to float32 rounding of its scale."""
+    a = _systems(case)
+    got = delta_kernels.unit_lower_inverse(a, hybrid_ops._GDR_BLOCK)
+    assert got.shape == a.shape and got.dtype == jnp.float32
+    eye = np.eye(64)
+    want = np.linalg.inv(eye + np.asarray(a, np.float64))
+    got = np.asarray(got, np.float64)
+    assert np.max(np.abs(got - want)) <= 1e-4 * np.max(np.abs(want))
+    series = np.asarray(hybrid_ops._unit_lower_inverse(a), np.float64)
+    assert np.max(np.abs(got - series)) <= 1e-6 * np.max(np.abs(series))
+    # through the op's switch, the gradient the formula's either way
+    d_t = jnp.asarray(np.random.default_rng(2).normal(size=a.shape),
+                      jnp.float32)
+    grads = [jax.vjp(lambda x, k=k: hybrid_ops._unit_lower_inverse(x, k),
+                     a)[1](d_t)[0] for k in (True, False)]
+    assert float(jnp.max(jnp.abs(grads[0] - grads[1]))) <= 1e-5 * float(
+        jnp.max(jnp.abs(grads[1])))
+
+
+def test_the_inverse_kernels_block_of_systems():
+    """Whole loop trips of 8 pairs, up to 64 systems of [64, 64] (in
+    and out, padded to 128 lanes and double buffered, 8 MiB), never
+    more trips than the count needs."""
+    rule = delta_kernels.systems_a_step
+    assert rule(2048, 64) == 64 and rule(70, 64) == 64
+    assert rule(18, 64) == 32 and rule(2, 64) == 16
+    for shape, block in (((2, 32, 32), 16), ((2, 64, 64), 8)):
+        with pytest.raises(ValueError):
+            delta_kernels.unit_lower_inverse(jnp.zeros(shape), block)
+
+
 @pytest.mark.parametrize("case", ["equal_keys", "random"])
 def test_unit_lower_inverses_gradient_is_the_inverses_own(case):
     """d_a = -T^T d_T T^T on the strict lower triangle, where a lives,
@@ -356,22 +408,36 @@ def test_unit_lower_inverses_gradient_is_the_inverses_own(case):
     assert not np.any(np.triu(np.asarray(got)))
 
 
-def _square_float32_products(jaxpr):
-    """The `dot_general`s of a jaxpr, and of every jaxpr inside it,
-    whose operands are both [..., 64, 64] float32: the products of the
-    triangular inverse and of its gradient, and no other of the op at
-    head sizes that are not 64."""
+def _inside(jaxpr, count):
+    """`count(eqn)` summed over the equations of a jaxpr and of every
+    jaxpr inside it but a Pallas kernel's body."""
     n = 0
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
-            n += all(v.aval.shape[-2:] == (64, 64)
-                     and v.aval.dtype == jnp.float32 for v in eqn.invars)
+        n += count(eqn)
+        if eqn.primitive.name == "pallas_call":
+            continue
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else [v]):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    n += _square_float32_products(sub)
+                    n += _inside(sub, count)
     return n
+
+
+def _square_float32_products(jaxpr):
+    """The `dot_general`s outside the Pallas kernels whose operands are
+    both [..., 64, 64] float32: the products of the `jax.numpy`
+    triangular inverse and of its gradient, and no other of the op at
+    head sizes that are not 64."""
+    return _inside(jaxpr, lambda eqn: eqn.primitive.name == "dot_general"
+                   and all(v.aval.shape[-2:] == (64, 64)
+                           and v.aval.dtype == jnp.float32
+                           for v in eqn.invars))
+
+
+def _inverse_kernel_calls(jaxpr):
+    return _inside(jaxpr, lambda eqn: eqn.primitive.name == "pallas_call"
+                   and eqn.params["name"] == "gated_delta_rule_inverse")
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["scan", "kernels"])
@@ -379,13 +445,17 @@ def test_the_backward_pass_transposes_the_inverse_in_two_products(kernel):
     """A group call of the forward pass runs the two series' ten
     products; the backward pass makes them again and adds the two of
     d_a = -T^T d_T T^T (twenty more when `jax.vjp` transposed the
-    series)."""
+    series). With the kernels, the inverse kernel's call stands where
+    the ten stood, in both."""
     rule = functools.partial(hybrid_ops.gated_delta_rule, kernel=kernel)
     args = _delta_args(7, 192)
     out, vjp = jax.vjp(rule, *args)
-    assert _square_float32_products(jax.make_jaxpr(rule)(*args).jaxpr) == 10
-    assert _square_float32_products(
-        jax.make_jaxpr(vjp)(jnp.ones_like(out)).jaxpr) == 12
+    forward = jax.make_jaxpr(rule)(*args).jaxpr
+    backward = jax.make_jaxpr(vjp)(jnp.ones_like(out)).jaxpr
+    assert _square_float32_products(forward) == (0 if kernel else 10)
+    assert _square_float32_products(backward) == (2 if kernel else 12)
+    assert _inverse_kernel_calls(forward) == _inverse_kernel_calls(
+        backward) == (1 if kernel else 0)
 
 
 def test_the_delta_rule_op_makes_its_decay_and_strength_in_float32():

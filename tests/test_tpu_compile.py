@@ -270,14 +270,19 @@ def test_gated_delta_rule_at_the_published_widths(one_chip, monkeypatch,
     the two loops over head groups; on it (heads of whole lane tiles)
     they are the two kernels of `pallas/gated_delta_rule.py`, compiled
     here by Mosaic, 8 value heads a grid step, and only the loops over
-    head groups are left. Either way what the custom gradient keeps
-    (the five inputs and 8,192 chunk states) with the group's
-    temporaries fits well inside what the step has left."""
+    head groups are left; there the triangular systems' inverse is that
+    file's third kernel, 64 systems a grid step, in the forward pass
+    and again in the backward pass. Either way what the custom gradient
+    keeps (the five inputs and 8,192 chunk states) with the group's
+    temporaries fits well inside what the step has left, and the
+    inverse kernel needs no more of it than the `jax.numpy` series does
+    (temporaries 2,368,144,384 bytes against 2,368,370,176)."""
     hybrid = importlib.import_module("paddle_tpu.ops.hybrid_ops")
     kernels = importlib.import_module(
         "paddle_tpu.ops.pallas.gated_delta_rule")
     assert hybrid._gdr_groups(1, 256, 16, 2) == 4
     assert kernels.heads_a_step(4, 2, 64, 128, 128, 2) == 8
+    assert kernels.systems_a_step(2048, 64) == 64
     if walked_by == "pallas":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         monkeypatch.setattr(kernels, "_interpret_default", lambda: False)
@@ -292,16 +297,28 @@ def test_gated_delta_rule_at_the_published_widths(one_chip, monkeypatch,
         ((1, 16384, 16, 128), bf), ((1, 16384, 16, 128), bf),
         ((1, 16384, 32, 128), bf), ((1, 16384, 32), f32),
         ((1, 16384, 32), f32))]
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        *args).compile()
+
+    def compile_grad():
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *args).compile()
+
+    compiled = compile_grad()
     text = compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
     if walked_by == "pallas":
         _kernels_are_called(compiled, ["gated_delta_rule_fwd",
-                                       "gated_delta_rule_bwd"])
+                                       "gated_delta_rule_bwd",
+                                       "gated_delta_rule_inverse"])
         assert text.count(" while(") == 2
+        # the walks' kernels beside the `jax.numpy` series
+        monkeypatch.setattr(kernels, "unit_lower_inverse",
+                            lambda a, block: hybrid._unit_lower_inverse(a))
+        series = compile_grad()
+        assert "gated_delta_rule_inverse" not in series.as_text()
+        assert temp <= series.memory_analysis().temp_size_in_bytes
     else:
         assert "tpu_custom_call" not in text and text.count(" while(") >= 4
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+    assert temp < 3 << 30
 
 
 def _rpa_shapes(seqs, q_rows, hq, hkv, d, pages, page, per_seq, dtype,
