@@ -103,7 +103,10 @@ def gated_delta_rule(q, k, v, a, b, a_log, dt_bias):
     chunks: q, k [B, S, Hk, dk] (normalised and scaled by the caller),
     v [B, S, Hv, dv], a and b [B, S, Hv] (the decay is
     exp(-exp(a_log) softplus(a + dt_bias)), the writing strength
-    sigmoid(b)), a_log, dt_bias [Hv] -> [B, S, Hv, dv]."""
+    sigmoid(b)), a_log, dt_bias [Hv] -> [B, S, Hv, dv]. With a of
+    [B, S, Hv, dk] and dt_bias [Hv * dk] each key channel decays on its
+    own (Kimi Delta Attention), a_log [Hv] a head's factor over its
+    channels."""
     return apply_op(
         "gated_delta_rule", "gated_delta_rule",
         {"Q": [q], "K": [k], "V": [v], "A": [a], "B": [b],

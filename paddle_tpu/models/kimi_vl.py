@@ -54,7 +54,7 @@ class KimiVLConfig:
                  n_shared_experts=2, num_experts_per_tok=6,
                  routed_scaling_factor=2.446, norm_topk_prob=True,
                  rms_norm_eps=1e-5, initializer_range=0.02,
-                 experts_held=None):
+                 experts_held=None, mla_use_nope=False):
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.num_hidden_layers = num_hidden_layers
@@ -75,6 +75,10 @@ class KimiVLConfig:
         self.rms_norm_eps = rms_norm_eps
         self.initializer_range = initializer_range
         self.experts_held = tuple(experts_held or (0, n_routed_experts))
+        #: no rotary: the `qk_rope_head_dim` parts pass unturned
+        #: (Kimi-Linear's latent attention; positions come from its
+        #: delta-rule layers)
+        self.mla_use_nope = mla_use_nope
 
     def is_dense(self, i):
         return i < self.first_k_dense_replace
@@ -105,15 +109,21 @@ def latent_attention_mixer(x, cfg, name):
     """`q_proj` gives every head `q_nope | q_rope`; `kv_a_proj` the
     latent `c` and the one rotary key head `k_r`; `c` passes a norm of
     its own and `kv_b_proj` decompresses it into every head's
-    `k_nope | v`. `q_rope` and `k_r` are turned by the rotary embedding;
-    a head's key is `k_nope | k_r`, `k_r` the same for all heads.
+    `k_nope | v`. `q_rope` and `k_r` are turned by the rotary embedding
+    (left as they are where `cfg.mla_use_nope`); a head's key is
+    `k_nope | k_r`, `k_r` the same for all heads.
     Causal attention through `scaled_dot_product_attention`, scaled by
     (nope + rope) ** -0.5, values and output of `v_head_dim`; the
     output projection."""
     h, nq = cfg.hidden_size, cfg.num_attention_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     rank = cfg.kv_lora_rank
-    rotary = {"rotary_dim": dr, "theta": cfg.rope_theta}
+
+    def turn(t):
+        if cfg.mla_use_nope:
+            return t
+        return layers.rotary_embedding(t, rotary_dim=dr, theta=cfg.rope_theta)
+
     w_q = _par(name + ".q_proj", [h, nq * (dn + dr)], cfg)
     w_kv_a = _par(name + ".kv_a_proj", [h, rank + dr], cfg)
     w_kv_b = _par(name + ".kv_b_proj", [rank, nq * (dn + dv)], cfg)
@@ -128,10 +138,8 @@ def latent_attention_mixer(x, cfg, name):
             layers.matmul(_norm(latent, name + ".kv_a_norm", cfg), w_kv_b),
             [0, 0, nq, dn + dv]),
         3, [dn, dv])
-    q = layers.concat(
-        [q_nope, layers.rotary_embedding(q_rope, **rotary)], axis=3)
-    k_rope = layers.rotary_embedding(
-        layers.reshape(k_rope, [0, 0, 1, dr]), **rotary)
+    q = layers.concat([q_nope, turn(q_rope)], axis=3)
+    k_rope = turn(layers.reshape(k_rope, [0, 0, 1, dr]))
     k = layers.concat(
         [k_nope, layers.expand(k_rope, [1, 1, nq, 1])], axis=3)
     ctx = layers.scaled_dot_product_attention(

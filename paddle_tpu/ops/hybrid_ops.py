@@ -384,6 +384,81 @@ def _gdr_inverse(kk, gc, beta, cd, kernel=False):
             kernel).astype(cd), decay
 
 
+def _gdr_decayed(x, k, gc):
+    """sum_d x_id k_jd exp(gc_id - gc_jd) for j <= i, nought above, of
+    a decay per key channel: x, k [B, N, H, C, dk], gc [B, N, H, R, C,
+    dk] float32 -> [B, N, H, R, C, C] float32. No exponent taken is
+    positive, whatever the decays (x exp(gc) times k exp(-gc) overflows
+    float32 once a channel's decay over the chunk passes 88). Each
+    `_GDR_BLOCK` of rows is anchored at its first position r: below its
+    diagonal block, (x_i exp(gc_i - gc_r)) . (k_j exp(gc_r - gc_j)), one
+    product at x's dtype for the block's rows against every earlier
+    column; on it, the sum over channels in float32. The block is the
+    inverse's, 16: the diagonal blocks' work a chunk is C x 16 x dk
+    exponentials, a quarter of a whole [C, C, dk]'s, and the products
+    below them stay four [16, dk] x [dk, C] a chunk."""
+    with _part("decay"):
+        c, dk = gc.shape[-2:]
+        nb, blk = c // _GDR_BLOCK, _GDR_BLOCK
+        lead = gc.shape[:-2]
+        xf, kf = (t.astype(_F32)[:, :, :, None] for t in (x, k))
+        g = gc.reshape(lead + (nb, blk, dk))
+        anchor = g[..., :1, :]
+        left = (xf.reshape(xf.shape[:-2] + (nb, blk, dk))
+                * jnp.exp(g - anchor)).astype(x.dtype)
+        earlier = (jnp.arange(c)[None, :]
+                   < (jnp.arange(nb) * blk)[:, None])[..., None]
+        right = (kf[..., None, :, :] * jnp.exp(jnp.where(
+            earlier, anchor - gc[..., None, :, :], -jnp.inf))).astype(x.dtype)
+        below = jnp.einsum("...nid,...njd->...nij", left, right,
+                           preferred_element_type=_F32)
+        seen = (jnp.arange(blk)[:, None] >= jnp.arange(blk)[None, :])[
+            ..., None]
+        inside = jnp.sum(
+            xf.reshape(xf.shape[:-2] + (nb, blk, 1, dk))
+            * kf.reshape(kf.shape[:-2] + (nb, 1, blk, dk))
+            * jnp.exp(jnp.where(seen, g[..., :, None, :] - g[..., None, :, :],
+                                -jnp.inf)), axis=-1)
+        on = jnp.eye(nb, dtype=_F32)[:, None, :, None]
+        return below.reshape(lead + (c, c)) + (
+            inside[..., :, :, None, :] * on).reshape(lead + (c, c))
+
+
+def _gdr_system_channels(q, k, gc, beta, kernel=False):
+    """(T = (I + tril(diag(beta) A, -1))^-1, the causal Q K^T decayed)
+    at q's dtype for A the decayed K K^T of a decay per key channel
+    (`_gdr_decayed`); `kernel`: T from the Pallas kernel."""
+    c = gc.shape[-2]
+    with _part("inverse"):
+        t = _unit_lower_inverse(jnp.where(
+            jnp.arange(c)[:, None] > jnp.arange(c)[None, :],
+            beta[..., None] * _gdr_decayed(k, k, gc), 0.0),
+            kernel).astype(q.dtype)
+    with _part("decay"):
+        return t, _gdr_decayed(q, k, gc).astype(q.dtype)
+
+
+def _gdr_local_channels(q, k, v, gc, beta, kernel=False):
+    """`_gdr_local` of a decay per key channel: gc [B, N, H, R, C, dk].
+    The same six values, the decay's products anchored
+    (`_gdr_decayed`), gl = exp(gc_C) [B, N, H, R, dk] a factor a row of
+    the state."""
+    with _part("local"):
+        cd = q.dtype
+        t, aqk = _gdr_system_channels(q, k, gc, beta, kernel)
+        with _part("decay"):
+            kf, grow = k.astype(_F32)[:, :, :, None], jnp.exp(gc)
+            last = gc[..., -1:, :]
+            kg = (kf * (beta[..., None] * grow)).astype(cd)
+            qg = (q.astype(_F32)[:, :, :, None] * grow).astype(cd)
+            kd = (kf * jnp.exp(last - gc)).astype(cd)
+        solve = functools.partial(jnp.einsum, "bnhrij,bnhrjd->bnhrid", t,
+                                  preferred_element_type=_F32)
+        return (solve(kg).astype(cd),
+                solve((v.astype(_F32) * beta[..., None]).astype(cd)),
+                aqk, qg, kd, jnp.exp(last[..., 0, :]))
+
+
 def _gdr_local(q, k, v, gc, beta, kernel=False):
     """What a chunk's positions need of one another, every chunk at
     once. q, k [B, N, H, C, dk]; v [B, N, H, R, C, dv] (R value heads a
@@ -392,7 +467,10 @@ def _gdr_local(q, k, v, gc, beta, kernel=False):
     made by the Pallas kernel), returns
     W = T (beta exp(gc) K), U0 = T (beta V) (a chunk's corrections are
     U0 - W S for the state S at its start), the causal Q K^T . decay,
-    Q exp(gc), K exp(gc_C - gc) and exp(gc_C)."""
+    Q exp(gc), K exp(gc_C - gc) and exp(gc_C). A gc of [B, N, H, R, C,
+    dk] is a decay per key channel: `_gdr_local_channels`."""
+    if gc.ndim == q.ndim + 1:
+        return _gdr_local_channels(q, k, v, gc, beta, kernel)
     with _part("local"):
         cd = q.dtype
         kk, qk = (jnp.einsum("bnhid,bnhjd->bnhij", x, k,
@@ -439,6 +517,14 @@ def _chunks_first(*ts):
     return tuple(jnp.moveaxis(t, 1, 0) for t in ts)
 
 
+def _decays(gl, state):
+    """The state [B, H, R, dk, dv] decayed by a chunk's gl: one factor a
+    head [B, H, R], or one a row, a key channel, [B, H, R, dk]."""
+    if gl.ndim == 4:
+        return gl[..., None] * state
+    return gl[..., None, None] * state
+
+
 def _gdr_walk_fwd(w, u0, aqk, qg, kd, gl):
     """The chunks of `_gdr_local`'s values walked first to last by a
     `lax.scan`: (out [N, B, H, R, C, dv], the state at every chunk's
@@ -456,7 +542,7 @@ def _gdr_walk_fwd(w, u0, aqk, qg, kd, gl):
                               preferred_element_type=_F32)
                    + jnp.einsum("bhrij,bhrje->bhrie", aqk_c, u,
                                 preferred_element_type=_F32))
-            after = gl_c[..., None, None] * state + jnp.einsum(
+            after = _decays(gl_c, state) + jnp.einsum(
                 "bhrid,bhrie->bhrde", kd_c, u, preferred_element_type=_F32)
             return after, (out.astype(cd), state)
 
@@ -481,10 +567,11 @@ def _gdr_walk_bwd(w, aqk, qg, kd, gl, u, starts, d_out):
                                 preferred_element_type=_F32))
             d_kd = jnp.einsum("bhrje,bhrde->bhrjd", u_c, d_low,
                               preferred_element_type=_F32)
-            d_gl = jnp.sum(d_state * start_c, axis=(-2, -1))
+            d_gl = jnp.sum(d_state * start_c,
+                           axis=(-1,) if gl_c.ndim == 4 else (-2, -1))
             before = (jnp.einsum("bhrid,bhrie->bhrde", qg_c, do_c,
                                  preferred_element_type=_F32)
-                      + gl_c[..., None, None] * d_state
+                      + _decays(gl_c, d_state)
                       - jnp.einsum("bhrid,bhrie->bhrde", w_c, d_u.astype(cd),
                                    preferred_element_type=_F32))
             return before, (d_u, d_kd.astype(cd), d_gl)
@@ -513,6 +600,14 @@ def _gdr_group_fwd_kernel(into, q, k, v, gc, beta):
     from .pallas.gated_delta_rule import gated_delta_rule_fwd
 
     q, k, v, gc, beta = _gdr_group_inputs(q, k, v, gc, beta)
+    if gc.ndim == q.ndim + 1:
+        # a decay per key channel: the decayed Q K^T is handed in too
+        with _part("local"):
+            t, aqk = _gdr_system_channels(q, k, gc, beta, True)
+        with _part("walk"):
+            out, stack = gated_delta_rule_fwd(q, k, v, t, gc, beta, into,
+                                              aqk=aqk)
+        return _unchunked(out), stack
     with _part("local"):
         kk = jnp.einsum("bnhid,bnhjd->bnhij", k, k,
                         preferred_element_type=_F32)[:, :, :, None]
@@ -654,7 +749,11 @@ def gated_delta_rule(q, k, v, g, beta, kernel=None):
     S_t = S' + k_t u_t^T: the state decays, is read back at the new
     key, and takes the part of the value it did not already hold.
     q, k [B, S, Hk, dk]; v [B, S, Hv, dv], value head j reading key
-    head j // (Hv / Hk); g (log-decay, <= 0) and beta [B, S, Hv].
+    head j // (Hv / Hk); g (log-decay, <= 0) and beta [B, S, Hv]. A g
+    of [B, S, Hv, dk] decays each key channel on its own (Kimi Delta
+    Attention, arXiv:2510.26692): S' = Diag(exp(g_t)) S_{t-1}, the
+    chunk-local products anchored so that no exponent is positive
+    (`_gdr_decayed`).
 
     Computed `_GDR_CHUNK` positions a chunk (a sequence that is no
     whole number of chunks is padded with positions that decay nothing
@@ -678,7 +777,8 @@ def gated_delta_rule(q, k, v, g, beta, kernel=None):
     rest of a chunk's local values itself."""
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2:]
-    if hv % hk or k.shape != q.shape or g.shape != (b, s, hv):
+    if hv % hk or k.shape != q.shape or g.shape not in ((b, s, hv),
+                                                         (b, s, hv, dk)):
         raise ValueError("gated_delta_rule: q %s k %s v %s g %s beta %s"
                          % (q.shape, k.shape, v.shape, g.shape, beta.shape))
     pad = -s % _GDR_CHUNK
@@ -688,8 +788,8 @@ def gated_delta_rule(q, k, v, g, beta, kernel=None):
             jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             for t in (q, k, v, g, beta))
     n = (s + pad) // _GDR_CHUNK
-    gc = jnp.cumsum(g.reshape(b, n, _GDR_CHUNK, hv), axis=2).reshape(
-        b, n * _GDR_CHUNK, hv)
+    gc = jnp.cumsum(g.reshape((b, n, _GDR_CHUNK) + g.shape[2:]),
+                    axis=2).reshape((b, n * _GDR_CHUNK) + g.shape[2:])
     groups = _gdr_groups(b, n, hk, hv // hk)
     if kernel is None:
         kernel = (jax.default_backend() == "tpu" and dk % 128 == 0
@@ -709,11 +809,13 @@ def gated_delta_rule(q, k, v, g, beta, kernel=None):
     # said where the op is traced: at the build's shape inference and
     # once a compile and layer
     logging.getLogger(__name__).info(
-        "gated_delta_rule q, k %s v %s %s: %d positions a chunk, %d "
+        "gated_delta_rule q, k %s v %s %s%s: %d positions a chunk, %d "
         "chunks, %d head groups, the chunks walked by %s, the inverse by "
         "%s; kept for the backward pass %d bytes (the inputs and %d states "
         "[%d, %d] float32)",
-        tuple(q.shape), tuple(v.shape), q.dtype.name, _GDR_CHUNK, n, groups,
+        tuple(q.shape), tuple(v.shape), q.dtype.name,
+        ", a decay per key channel" if g.ndim == 4 else "", _GDR_CHUNK, n,
+        groups,
         walked, inverted,
         sum(t.size * t.dtype.itemsize for t in (q, k, v, gc, beta))
         + b * n * hv * dk * dv * 4, b * n * hv, dk, dv)
@@ -726,9 +828,16 @@ def _gated_delta_rule(ins, attrs):
     (`gated_delta_rule` above): Q, K [B, S, Hk, dk], V [B, S, Hv, dv];
     A and B [B, S, Hv], the projections behind the decay and the
     writing strength; ALog, DtBias [Hv]. beta = sigmoid(B) and
-    g = -exp(ALog) softplus(A + DtBias), both float32."""
-    g = -jnp.exp(ins["ALog"][0].astype(_F32)) * jax.nn.softplus(
-        ins["A"][0].astype(_F32) + ins["DtBias"][0].astype(_F32))
+    g = -exp(ALog) softplus(A + DtBias), both float32. A of
+    [B, S, Hv, dk] with DtBias [Hv * dk] is a decay per key channel,
+    ALog's a head's factor over its channels."""
+    if ins["A"][0].ndim == 4:
+        a = ins["A"][0].astype(_F32)
+        g = -jnp.exp(ins["ALog"][0].astype(_F32))[:, None] * jax.nn.softplus(
+            a + ins["DtBias"][0].astype(_F32).reshape(a.shape[2:]))
+    else:
+        g = -jnp.exp(ins["ALog"][0].astype(_F32)) * jax.nn.softplus(
+            ins["A"][0].astype(_F32) + ins["DtBias"][0].astype(_F32))
     return {"Out": gated_delta_rule(
         ins["Q"][0], ins["K"][0], ins["V"][0], g,
         jax.nn.sigmoid(ins["B"][0].astype(_F32)))}

@@ -128,8 +128,61 @@ def _fwd_kernel(_, q_ref, k_ref, v_ref, t_ref, gc_ref, gct_ref, bt_ref, *rest,
             (kf * jnp.exp(last - gc)).astype(cd), u)
 
 
+def _flip(v):
+    """[1, d] <-> [d, 1]: the vector laid on the diagonal of a [d, d]
+    and summed across the other axis (a row of lanes becomes a column
+    of sublanes, and back)"""
+    d = max(v.shape)
+    eye = lax.broadcasted_iota(jnp.int32, (d, d), 0) == \
+        lax.broadcasted_iota(jnp.int32, (d, d), 1)
+    return jnp.sum(jnp.where(eye, jnp.broadcast_to(v, (d, d)), 0.0),
+                   axis=1 if v.shape[0] == 1 else 0, keepdims=True)
+
+
+def _fwd_kernel_channels(_, q_ref, k_ref, v_ref, t_ref, gc_ref, bt_ref,
+                         aqk_ref, *rest, heads, r):
+    """`_fwd_kernel` of a decay per key channel: a head's log-decays
+    [C, dk] as its block, the decayed q k^T handed in (made outside,
+    anchored: `hybrid_ops._gdr_decayed`), and the state's rows each
+    decayed by its own channel's factor."""
+    if len(rest) == 2:
+        (out_ref, state), stack_ref = rest, None
+    else:
+        _, out_ref, stack_ref, state = rest
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        state[:] = jnp.zeros_like(state)
+
+    cd, chunk = q_ref.dtype, q_ref.shape[-2]
+    for h in range(heads):
+        if h % r == 0:
+            qf = q_ref[0, 0, h // r].astype(_F32)
+            kf = k_ref[0, 0, h // r].astype(_F32)
+        gc = gc_ref[0, 0, h]                             # [C, dk] float32
+        beta = bt_ref[0, 0, 0, :, h:h + 1]
+        grow, last = jnp.exp(gc), gc[chunk - 1:chunk, :]
+        t = t_ref[0, 0, h]
+        w = jnp.dot(t, (kf * (beta * grow)).astype(cd),
+                    preferred_element_type=_F32).astype(cd)
+        u0 = jnp.dot(t, (v_ref[0, 0, h].astype(_F32) * beta).astype(cd),
+                     preferred_element_type=_F32)
+        before = state[h]
+        if stack_ref is not None:
+            stack_ref[0, 0, 0, h] = before
+        low = before.astype(cd)
+        u = (u0 - jnp.dot(w, low, preferred_element_type=_F32)).astype(cd)
+        out = (jnp.dot((qf * grow).astype(cd), low,
+                       preferred_element_type=_F32)
+               + jnp.dot(aqk_ref[0, 0, h], u, preferred_element_type=_F32))
+        out_ref[0, 0, h] = out.astype(out_ref.dtype)
+        state[h] = _flip(jnp.exp(last)) * before + _tn(
+            (kf * jnp.exp(last - gc)).astype(cd), u)
+
+
 def _bwd_kernel(_, w_ref, aqk_ref, qg_ref, kd_ref, gl_ref, u_ref, start_ref,
-                do_ref, du_ref, dkd_ref, dgl_ref, d_state, *, heads):
+                do_ref, du_ref, dkd_ref, dgl_ref, d_state, *, heads,
+                channels=False):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         d_state[:] = jnp.zeros_like(d_state)
@@ -143,11 +196,19 @@ def _bwd_kernel(_, w_ref, aqk_ref, qg_ref, kd_ref, gl_ref, u_ref, start_ref,
                + jnp.dot(kd_ref[0, 0, h], d_low, preferred_element_type=_F32))
         du_ref[0, 0, h] = d_u
         dkd_ref[0, 0, h] = _nt(u_ref[0, 0, h], d_low).astype(dkd_ref.dtype)
-        # the sum over dk here, the sum over dv outside
-        dgl_ref[0, 0, 0, h:h + 1] = jnp.sum(
-            after * start_ref[0, 0, 0, h], axis=0, keepdims=True)
+        if channels:
+            # a factor a row: the sum over dv, a row's, as a row of dk
+            dgl_ref[0, 0, 0, h:h + 1] = _flip(jnp.sum(
+                after * start_ref[0, 0, 0, h], axis=1, keepdims=True))
+            gl = _flip(gl_ref[0, 0, 0, h:h + 1])
+        else:
+            # the sum over dk here, the sum over dv outside
+            dgl_ref[0, 0, 0, h:h + 1] = jnp.sum(
+                after * start_ref[0, 0, 0, h], axis=0, keepdims=True)
+            # read where the product needs it, as this kernel always has
+            gl = gl_ref.at[0, 0, 0, h:h + 1]
         d_state[h] = (_tn(qg_ref[0, 0, h], do)
-                      + gl_ref[0, 0, 0, h:h + 1] * after
+                      + gl[...] * after
                       - _tn(w_ref[0, 0, h], d_u.astype(cd)))
 
 
@@ -214,7 +275,8 @@ def _stacked(stack):
     return stack.reshape(stack.shape[:3] + (-1,) + stack.shape[5:])
 
 
-def gated_delta_rule_fwd(q, k, v, t, gc, beta, into=None, interpret=None):
+def gated_delta_rule_fwd(q, k, v, t, gc, beta, into=None, interpret=None,
+                         aqk=None):
     """The forward walk over chunks, each chunk's local values made on
     the way: W = T (beta exp(gc) k), U0 = T (beta v), then
     u = U0 - W S, out = q exp(gc) S + (q k^T . decay) u,
@@ -225,19 +287,27 @@ def gated_delta_rule_fwd(q, k, v, t, gc, beta, into=None, interpret=None):
     the state at every chunk's start is written into that group of the
     stack, in place. Returns (out [B, N, H, R, C, dv] at q's dtype, the
     stack, or None without `into`: the pass whose gradient nobody takes
-    writes no state)."""
+    writes no state). A decay per key channel: gc [B, N, H, R, C, dk]
+    with `aqk`, the decayed q k^T [B, N, H, R, C, C] at q's dtype, and
+    S <- Diag(exp(gc_C)) S + (k exp(gc_C - gc))^T u."""
     lead, chunk, dk, dv = v.shape[:4], v.shape[4], q.shape[-1], v.shape[-1]
     hv, r = lead[2] * lead[3], lead[3]
     heads = heads_a_step(lead[2], r, chunk, dk, dv, q.dtype.itemsize)
-    # a head's log-decays as a row (for the mask's columns) and, with
-    # its writing strengths, as a column of its block's lanes
-    ins = [q, k, _by_head(v), _by_head(t), _rows(gc, heads)] + [
-        jnp.swapaxes(_rows(x, heads), -1, -2) for x in (gc, beta)]
+    if aqk is None:
+        # a head's log-decays as a row (for the mask's columns) and, with
+        # its writing strengths, as a column of its block's lanes
+        ins = [q, k, _by_head(v), _by_head(t), _rows(gc, heads)] + [
+            jnp.swapaxes(_rows(x, heads), -1, -2) for x in (gc, beta)]
+        kernel = _fwd_kernel
+    else:
+        ins = [q, k, _by_head(v), _by_head(t), _by_head(gc),
+               jnp.swapaxes(_rows(beta, heads), -1, -2), _by_head(aqk)]
+        kernel = _fwd_kernel_channels
     outs = [jax.ShapeDtypeStruct(ins[2].shape, q.dtype)]
     stack, group = (None, 0) if into is None else into
     if stack is not None:
         outs.append(jax.ShapeDtypeStruct(_stacked(stack).shape, _F32))
-    got = _call(functools.partial(_fwd_kernel, heads=heads, r=r),
+    got = _call(functools.partial(kernel, heads=heads, r=r),
                 "gated_delta_rule_fwd", hv, heads, group, ins, outs, dk, dv,
                 False, interpret, None if stack is None else _stacked(stack))
     out = got[0].reshape(lead + got[0].shape[3:])
@@ -254,22 +324,28 @@ def gated_delta_rule_bwd(w, aqk, qg, kd, gl, u, stack, group, d_out,
     [B, N, H, R, C, dv]; the chunk-start states are group `group` of
     `stack` [G, B, N, H, R, dk, dv] float32, read where they lie.
     Returns (d_u float32, d_kd at w's dtype [B, N, H, R, C, dk], d_gl
-    [B, N, H, R] float32)."""
+    [B, N, H, R] float32). A gl of [B, N, H, R, dk] decays each row of
+    the state by its own factor: D <- qg^T d_out + Diag(gl) D - w^T d_u
+    and d_gl [B, N, H, R, dk] the sum over dv of D . start."""
     lead, dk, dv = w.shape[:4], w.shape[-1], u.shape[-1]
     hv = lead[2] * lead[3]
     heads = heads_a_step(lead[2], lead[3], w.shape[4], dk, dv,
                          w.dtype.itemsize)
+    channels = gl.ndim == len(lead) + 1
     ins = [_by_head(t) for t in (w, aqk, qg, kd)] + [
-        _lanes(gl, heads, dv), _by_head(u), _stacked(stack), _by_head(d_out)]
+        _rows(gl, heads) if channels else _lanes(gl, heads, dv), _by_head(u),
+        _stacked(stack), _by_head(d_out)]
     outs = [jax.ShapeDtypeStruct(ins[5].shape, _F32),
             jax.ShapeDtypeStruct(ins[0].shape, w.dtype),
             jax.ShapeDtypeStruct(ins[4].shape, _F32)]
-    d_u, d_kd, d_gl = _call(functools.partial(_bwd_kernel, heads=heads),
-                            "gated_delta_rule_bwd", hv, heads, group, ins,
-                            outs, dk, dv, True, interpret)
+    d_u, d_kd, d_gl = _call(
+        functools.partial(_bwd_kernel, heads=heads, channels=channels),
+        "gated_delta_rule_bwd", hv, heads, group, ins, outs, dk, dv, True,
+        interpret)
     return (d_u.reshape(lead + d_u.shape[3:]),
             d_kd.reshape(lead + d_kd.shape[3:]),
-            jnp.sum(d_gl, axis=-1).reshape(lead))
+            d_gl.reshape(lead + (dk,)) if channels
+            else jnp.sum(d_gl, axis=-1).reshape(lead))
 
 
 #: pairs of systems a trip of the inverse kernel's loop takes at once,

@@ -252,6 +252,24 @@ def test_each_walk_kernel_alone_gives_what_its_scan_gives(r, dtype):
     assert [x.dtype for x in got] == [jnp.float32, dtype, jnp.float32]
     for a, b in zip(got, want):
         assert a.shape == b.shape and close(a, b)
+    # a decay per key channel: the kernels take a head's log-decays as a
+    # [C, dk] block and the decayed q k^T made outside, and decay the
+    # state's rows each by its own channel's factor, a [dk] column
+    gcc = jnp.asarray(np.cumsum(np.log(rng.uniform(
+        0.7, 1.0, gc.shape + (q.shape[-1],))), axis=-2), jnp.float32)
+    local = hybrid_ops._gdr_local(q, k, v, gcc, beta)
+    want = [jnp.moveaxis(t, 0, 1) for t in hybrid_ops._gdr_walk_fwd(*local)]
+    t, aqk = hybrid_ops._gdr_system_channels(q, k, gcc, beta)
+    out, kept = delta_kernels.gated_delta_rule_fwd(q, k, v, t, gcc, beta,
+                                                   (stack, 1), aqk=aqk)
+    assert close(out, want[0]) and close(kept[1], want[1])
+    w, _, aqk, qg, kd, gl = local
+    assert gl.shape == gcc.shape[:-2] + gcc.shape[-1:]
+    want = hybrid_ops._gdr_walk_bwd(w, aqk, qg, kd, gl, u, kept[1], d_out)
+    got = delta_kernels.gated_delta_rule_bwd(w, aqk, qg, kd, gl, u, kept, 1,
+                                             d_out)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and close(a, b)
     rule = delta_kernels.heads_a_step
     assert rule(2, r, 64, 8, 16, 4) == 2 * r
     # a head's blocks at 128 x 128 in bfloat16 take 0.22 MB: eight fit,

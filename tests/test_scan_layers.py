@@ -415,6 +415,28 @@ def _build_qwen3_next():
     return main, st, _batch(cfg, 3), loss
 
 
+def _build_kimi_vl():
+    """The latent-attention decoder's step: bf16 AMP, every mixer and
+    every feed-forward recomputed, the rotary parts turned."""
+    from paddle_tpu.models import kimi_vl
+    from test_kimi_vl import _batch, _build
+
+    cfg = kimi_vl.KimiVLConfig.tiny(experts_held=(0, 4))
+    main, st, loss, _ = _build(cfg, True)
+    return main, st, _batch(cfg, 3), loss
+
+
+def _build_kimi_linear():
+    """The per-channel delta-rule / unturned latent-attention decoder's
+    step: bf16 AMP, every mixer and every feed-forward recomputed."""
+    from paddle_tpu.models import kimi_linear
+    from test_kimi_linear import _batch, _build
+
+    cfg = kimi_linear.KimiLinearConfig.tiny(experts_held=(0, 4))
+    main, st, loss, _ = _build(cfg, True)
+    return main, st, _batch(cfg, 3), loss
+
+
 _FLASH_S = 128
 
 
@@ -453,21 +475,28 @@ def _flash_from_its_length(monkeypatch):
 #: a flash call traced in a checkpointed body names its output and its
 #: compact row statistics for the checkpoint to keep, so the scan stacks
 #: the two and its recompute holds no forward kernel; the other four
-#: (no flash kernel at these lengths) stood. A PR that means to change
-#: one of these programs replaces the digest and says so
+#: (no flash kernel at these lengths) stood. PR 39 added the sixth,
+#: kimi-vl's decoder, as its parent lowered it (before latent attention
+#: could leave its rotary parts unturned), and the seventh, Kimi-Linear's
+#: (the delta rule with a decay per key channel); the five before stood.
+#: A PR that means to change one of these programs replaces the digest
+#: and says so
 _STEP_DIGESTS = {
     "_build_scan_bert_remat": "f6d6b541724972c8",
     "_build_resnet50": "deed87d731a3ffb9",
     "_build_nemotron_h": "9df08f21cb176523",
     "_build_scan_bert_flash": "b097f3d589655a72",
     "_build_qwen3_next": "3c544571148cfcdf",
+    "_build_kimi_vl": "18928f1fecfa9164",
+    "_build_kimi_linear": "8d2453fbb8bc1e73",
 }
 
 
 @pytest.mark.parametrize("build", [_build_scan_bert_remat,
                                    _build_resnet50, _build_nemotron_h,
                                    _build_scan_bert_flash,
-                                   _build_qwen3_next])
+                                   _build_qwen3_next, _build_kimi_vl,
+                                   _build_kimi_linear])
 def test_berts_and_resnets_steps_are_the_accepted_programs(
         monkeypatch, build):
     import hashlib

@@ -321,6 +321,48 @@ def test_gated_delta_rule_at_the_published_widths(one_chip, monkeypatch,
     assert temp < 3 << 30
 
 
+@pytest.mark.parametrize("walked_by", ["jax.numpy", "pallas"])
+def test_a_decay_per_channel_at_the_published_widths(one_chip, monkeypatch,
+                                                     walked_by):
+    """Kimi Delta Attention's rule as the kimi-linear cell runs it: 32
+    heads of 128 (a key head a value head), g a key channel, one
+    document of 8,192: 128 chunks walked in 2 groups of heads. On the
+    TPU the walks are the two kernels with their per-channel blocks (a
+    head's log-decays [64, 128] float32, the decayed q k^T handed in,
+    the state's rows decayed by a [128] column) and the inverse the
+    third kernel; the anchored products and the diagonal blocks'
+    per-channel sums are the compiled program's. Either way the
+    gradient's temporaries stay under 2.6 GB (2,538,583,552 bytes with
+    the kernels, 2,606,063,104 without)."""
+    hybrid = importlib.import_module("paddle_tpu.ops.hybrid_ops")
+    kernels = importlib.import_module(
+        "paddle_tpu.ops.pallas.gated_delta_rule")
+    assert hybrid._gdr_groups(1, 128, 32, 1) == 2
+    assert kernels.heads_a_step(16, 1, 64, 128, 128, 2) == 8
+    if walked_by == "pallas":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(kernels, "_interpret_default", lambda: False)
+
+    def loss(q, k, v, g, beta):
+        out = hybrid.gated_delta_rule(q, k, v, -jax.nn.softplus(g),
+                                      jax.nn.sigmoid(beta))
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*[
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+            ((1, 8192, 32, 128), bf), ((1, 8192, 32, 128), bf),
+            ((1, 8192, 32, 128), bf), ((1, 8192, 32, 128), f32),
+            ((1, 8192, 32), f32))]).compile()
+    if walked_by == "pallas":
+        _kernels_are_called(compiled, ["gated_delta_rule_fwd",
+                                       "gated_delta_rule_bwd",
+                                       "gated_delta_rule_inverse"])
+    else:
+        assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.7e9
+
+
 def _rpa_shapes(seqs, q_rows, hq, hkv, d, pages, page, per_seq, dtype,
                 scales=False):
     shapes = [((seqs, q_rows, hq, d),
