@@ -424,16 +424,48 @@ def _gdr_decayed(x, k, gc):
             inside[..., :, :, None, :] * on).reshape(lead + (c, c))
 
 
+@jax.custom_vjp
+def _gdr_decayed_kernel(q, k, gc):
+    """`_gdr_decayed`'s two products of a chunk in one Pallas call
+    (`pallas/gated_delta_rule.gated_delta_rule_decay`): (K K^T decayed,
+    strictly lower, float32; Q K^T decayed, causal, at q's dtype), the
+    exponentials kept in VMEM. Its transpose is the reverse kernel's,
+    which makes them again from q, k and gc."""
+    from .pallas.gated_delta_rule import gated_delta_rule_decay
+
+    with _part("decay"):
+        return gated_delta_rule_decay(q, k, gc, _GDR_BLOCK)
+
+
+def _gdr_decayed_kernel_fwd(q, k, gc):
+    return _gdr_decayed_kernel(q, k, gc), (q, k, gc)
+
+
+def _gdr_decayed_kernel_bwd(res, d):
+    from .pallas.gated_delta_rule import gated_delta_rule_decay_bwd
+
+    with _part("decay"):
+        return gated_delta_rule_decay_bwd(*res, *d, _GDR_BLOCK)
+
+
+_gdr_decayed_kernel.defvjp(_gdr_decayed_kernel_fwd, _gdr_decayed_kernel_bwd)
+
+
 def _gdr_system_channels(q, k, gc, beta, kernel=False):
     """(T = (I + tril(diag(beta) A, -1))^-1, the causal Q K^T decayed)
     at q's dtype for A the decayed K K^T of a decay per key channel
-    (`_gdr_decayed`); `kernel`: T from the Pallas kernel."""
+    (`_gdr_decayed`); `kernel`: both products from the Pallas pair
+    (`_gdr_decayed_kernel`) and T from the inverse kernel."""
     c = gc.shape[-2]
+    if kernel:
+        kk, aqk = _gdr_decayed_kernel(q, k, gc)
     with _part("inverse"):
         t = _unit_lower_inverse(jnp.where(
             jnp.arange(c)[:, None] > jnp.arange(c)[None, :],
-            beta[..., None] * _gdr_decayed(k, k, gc), 0.0),
-            kernel).astype(q.dtype)
+            beta[..., None] * (kk if kernel else _gdr_decayed(k, k, gc)),
+            0.0), kernel).astype(q.dtype)
+    if kernel:
+        return t, aqk
     with _part("decay"):
         return t, _gdr_decayed(q, k, gc).astype(q.dtype)
 
@@ -782,19 +814,20 @@ def gated_delta_rule(q, k, v, g, beta, kernel=None):
         raise ValueError("gated_delta_rule: q %s k %s v %s g %s beta %s"
                          % (q.shape, k.shape, v.shape, g.shape, beta.shape))
     pad = -s % _GDR_CHUNK
-    g, beta = g.astype(_F32), beta.astype(_F32)
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-            for t in (q, k, v, g, beta))
     n = (s + pad) // _GDR_CHUNK
-    gc = jnp.cumsum(g.reshape((b, n, _GDR_CHUNK) + g.shape[2:]),
-                    axis=2).reshape((b, n * _GDR_CHUNK) + g.shape[2:])
+    with _part("head"):
+        g, beta = g.astype(_F32), beta.astype(_F32)
+        if pad:
+            q, k, v, g, beta = (
+                jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                for t in (q, k, v, g, beta))
+        gc = jnp.cumsum(g.reshape((b, n, _GDR_CHUNK) + g.shape[2:]),
+                        axis=2).reshape((b, n * _GDR_CHUNK) + g.shape[2:])
     groups = _gdr_groups(b, n, hk, hv // hk)
     if kernel is None:
         kernel = (jax.default_backend() == "tpu" and dk % 128 == 0
                   and dv % 128 == 0)
-    walked = inverted = "jax.numpy"
+    walked = inverted = decayed = "jax.numpy"
     if kernel:
         from .pallas.gated_delta_rule import heads_a_step, systems_a_step
 
@@ -806,17 +839,22 @@ def gated_delta_rule(q, k, v, g, beta, kernel=None):
         step = systems_a_step(systems, _GDR_CHUNK)
         inverted = "pallas (%d systems a grid step, %d grid steps a call)" \
             % (step, -(-systems // step))
+        heads = heads_a_step(hk // groups, hv // hk, _GDR_CHUNK, dk, dk,
+                             q.dtype.itemsize)
+        decayed = "pallas (%d value heads a grid step, %d grid steps a " \
+            "call)" % (heads, b * (hv // groups // heads) * n)
     # said where the op is traced: at the build's shape inference and
     # once a compile and layer
     logging.getLogger(__name__).info(
         "gated_delta_rule q, k %s v %s %s%s: %d positions a chunk, %d "
         "chunks, %d head groups, the chunks walked by %s, the inverse by "
-        "%s; kept for the backward pass %d bytes (the inputs and %d states "
-        "[%d, %d] float32)",
+        "%s%s; kept for the backward pass %d bytes (the inputs and %d "
+        "states [%d, %d] float32)",
         tuple(q.shape), tuple(v.shape), q.dtype.name,
         ", a decay per key channel" if g.ndim == 4 else "", _GDR_CHUNK, n,
         groups,
         walked, inverted,
+        ", the decayed products by " + decayed if g.ndim == 4 else "",
         sum(t.size * t.dtype.itemsize for t in (q, k, v, gc, beta))
         + b * n * hv * dk * dv * 4, b * n * hv, dk, dv)
     return _gdr(q, k, v, gc, beta, bool(kernel))[:, :s]
@@ -831,16 +869,18 @@ def _gated_delta_rule(ins, attrs):
     g = -exp(ALog) softplus(A + DtBias), both float32. A of
     [B, S, Hv, dk] with DtBias [Hv * dk] is a decay per key channel,
     ALog's a head's factor over its channels."""
-    if ins["A"][0].ndim == 4:
-        a = ins["A"][0].astype(_F32)
-        g = -jnp.exp(ins["ALog"][0].astype(_F32))[:, None] * jax.nn.softplus(
-            a + ins["DtBias"][0].astype(_F32).reshape(a.shape[2:]))
-    else:
-        g = -jnp.exp(ins["ALog"][0].astype(_F32)) * jax.nn.softplus(
-            ins["A"][0].astype(_F32) + ins["DtBias"][0].astype(_F32))
+    with _part("head"):
+        if ins["A"][0].ndim == 4:
+            a = ins["A"][0].astype(_F32)
+            g = -jnp.exp(ins["ALog"][0].astype(_F32))[:, None] \
+                * jax.nn.softplus(a + ins["DtBias"][0].astype(_F32).reshape(
+                    a.shape[2:]))
+        else:
+            g = -jnp.exp(ins["ALog"][0].astype(_F32)) * jax.nn.softplus(
+                ins["A"][0].astype(_F32) + ins["DtBias"][0].astype(_F32))
+        beta = jax.nn.sigmoid(ins["B"][0].astype(_F32))
     return {"Out": gated_delta_rule(
-        ins["Q"][0], ins["K"][0], ins["V"][0], g,
-        jax.nn.sigmoid(ins["B"][0].astype(_F32)))}
+        ins["Q"][0], ins["K"][0], ins["V"][0], g, beta)}
 
 
 # ---------------------------------------------------------------------------

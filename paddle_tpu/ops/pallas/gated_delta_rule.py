@@ -1,7 +1,8 @@
 """The two walks over a sequence's chunks of the gated delta rule
 (`ops/hybrid_ops.gated_delta_rule`) as Pallas TPU kernels: what is
-sequential in the chunked algorithm, forward and reverse; and the
-inverse of each chunk's triangular system.
+sequential in the chunked algorithm, forward and reverse; the inverse
+of each chunk's triangular system; and, for a decay per key channel, a
+chunk's two decayed products and their transpose.
 
 One grid step is one chunk of a block of value heads of one sequence,
 the chunk axis innermost and sequential. Each head's state [dk, dv]
@@ -38,6 +39,22 @@ rows against their block diagonal. The second series runs over
 z = (a - d) d^-1 where the `jax.numpy` one runs over d^-1 (a - d), the
 same matrix seen from d^-1's other side: T = d^-1 (I - z)(I + z^2),
 and the last power and the running product share one right-hand side.
+
+A decay per key channel makes a chunk's K K^T and Q K^T
+sum_d x_id k_jd exp(gc_id - gc_jd) (`hybrid_ops._gdr_decayed`, the
+same anchoring): below the diagonal 16-blocks each row block is
+anchored at its first row, one product at the operands' dtype against
+every earlier column; on them the exponentials are a key channel's
+own, summed over the channels in float32 (a lane sum, on the XLU,
+which bounds the forward kernel). `gated_delta_rule_decay` makes both
+products of a block of heads of one chunk from q, k and gc read once;
+`gated_delta_rule_decay_bwd` makes the exponentials again where the
+`jax.numpy` products' transpose kept them in HBM. Both loop over the
+grid step's heads, two a trip, with every row block of a trip's heads
+in one array: each `pallas_call` site is traced and lowered on every
+start of a process, compile cache or not, so a kernel's body is kept
+a loop and not unrolled code (`tests/test_tpu_compile.py` holds its
+size).
 """
 from __future__ import annotations
 
@@ -457,3 +474,254 @@ def unit_lower_inverse(a, block, interpret=None):
         name="gated_delta_rule_inverse",
     )(a)
     return t[:n].reshape(lead + (c, c))
+
+
+#: rows of a diagonal block taken at a time: a vreg's sublanes
+_ROWS = 8
+
+
+def _decay_parts(q_ref, k_ref, gc_ref, h, hp, r, block):
+    """What both decay kernels make of the `hp` value heads from `h`
+    before their diagonal blocks, every `block` of a chunk's rows at
+    once (nb of them, a head's after another's) and each anchored at its
+    first row r: (q, k, gc [nb, block, dk] float32; grow = exp(gc_i -
+    gc_r) [nb, block, dk]; fall = exp(gc_r - gc_j) for the columns j
+    before the block, nought from it on, [nb, C, dk]; the left factors
+    k grow and q grow, [nb, 2 block, dk], and the right one k fall,
+    [nb, C, dk], at k's dtype). No exponent is positive. The heads share
+    one key head, or have one each where `r` is 1."""
+    c, dk = gc_ref.shape[-2:]
+    nb, cd = hp * c // block, k_ref.dtype
+    gc = gc_ref[0, 0, pl.ds(h, hp)]                      # [hp, C, dk]
+    qf, kf = (jnp.broadcast_to(
+        (x[0, 0, pl.ds(h, hp)] if r == 1 else x[0, 0, h // r]).astype(_F32),
+        (hp, c, dk)) for x in (q_ref, k_ref))
+    gb, qb, kb = (x.reshape(nb, block, dk) for x in (gc, qf, kf))
+    anchor = gb[:, :1]                                   # [nb, 1, dk]
+    grow = jnp.exp(gb - anchor)
+
+    def by_block(x):
+        """[hp, C, dk] -> [nb, C, dk], each head's once a block"""
+        return jnp.broadcast_to(x[:, None], (hp, nb // hp, c, dk)).reshape(
+            nb, c, dk)
+
+    earlier = (lax.broadcasted_iota(jnp.int32, (nb, c, 1), 1) < block * (
+        lax.broadcasted_iota(jnp.int32, (nb, c, 1), 0) % (c // block)))
+    fall = jnp.exp(jnp.where(earlier, anchor - by_block(gc), _NEG_INF))
+    return (qb, kb, gb, grow, fall,
+            jnp.concatenate([kb * grow, qb * grow], 1).astype(cd),
+            (by_block(kf) * fall).astype(cd))
+
+
+def _diagonal(gb, kb, block):
+    """The diagonal blocks' exponentials, every block at once, in parts
+    of `_ROWS` rows from `top` (a vreg's sublanes) and the columns j up
+    to the part's last row: (top, e = exp(gc_i - gc_j) [nb, j, _ROWS,
+    dk], nought where i < j, and e k_j). No exponent is positive."""
+    for top in range(0, block, _ROWS):
+        n = top + _ROWS
+        shape = (1, n, _ROWS, 1)
+        seen = (lax.broadcasted_iota(jnp.int32, shape, 1)
+                <= lax.broadcasted_iota(jnp.int32, shape, 2) + top)
+        e = jnp.exp(jnp.where(
+            seen, gb[:, top:n][:, None] - gb[:, :n][:, :, None], _NEG_INF))
+        yield top, e, e * kb[:, :n][:, :, None]
+
+
+def _heads_a_trip(heads, r):
+    """Value heads a trip of a decay kernel's loop takes together: two
+    where they pair off, their independent chains filling one another's
+    latency, and share a key head or have one each"""
+    return 2 if heads % 2 == 0 and (r == 1 or r % 2 == 0) else 1
+
+
+def _decay_kernel(q_ref, k_ref, gc_ref, a_ref, aqk_ref, *, r, block):
+    heads, c, dk = gc_ref.shape[2:]
+    hp = _heads_a_trip(heads, r)
+    nb = hp * c // block
+    strict = (lax.broadcasted_iota(jnp.int32, (c, c), 0)
+              > lax.broadcasted_iota(jnp.int32, (c, c), 1))
+    # a lane's column within its row's diagonal block (negative or past
+    # the block elsewhere)
+    shape = (nb, _ROWS, c)
+    column = lax.broadcasted_iota(jnp.int32, shape, 2) - block * (
+        lax.broadcasted_iota(jnp.int32, shape, 0) % (c // block))
+
+    def laid(sums, lo, hi):
+        """the sums of columns lo to hi [nb, j, _ROWS, 1] laid into
+        their lanes by a tree of selects (a chain would wait on each
+        lane sum in turn)"""
+        if hi - lo == 1:
+            return sums[:, lo]
+        mid = (lo + hi) // 2
+        return jnp.where(column < mid, laid(sums, lo, mid),
+                         laid(sums, mid, hi))
+
+    def trip(i, carry):
+        h = i * hp
+        qb, kb, gb, _, _, left, right = _decay_parts(q_ref, k_ref, gc_ref, h,
+                                                     hp, r, block)
+        # every block's rows of both products against its earlier columns
+        below = jnp.einsum("bid,bjd->bij", left, right,
+                           preferred_element_type=_F32)  # [nb, 2 block, C]
+        # its diagonal block, `_ROWS` rows at a time, summed over the
+        # channels in float32
+        parts = ([], [])
+        for top, _, ke in _diagonal(gb, kb, block):
+            for x, part in zip((kb, qb), parts):
+                part.append(jnp.where(
+                    (column >= 0) & (column < top + _ROWS), laid(jnp.sum(
+                        x[:, top:top + _ROWS][:, None] * ke, axis=-1,
+                        keepdims=True), 0, top + _ROWS), 0.0))
+        sk, sq = ((below[:, at:at + block] + jnp.concatenate(part, 1)
+                   ).reshape(hp, c, c) for part, at in zip(parts, (0, block)))
+        a_ref[0, 0, pl.ds(h, hp)] = jnp.where(strict, sk, 0.0)
+        aqk_ref[0, 0, pl.ds(h, hp)] = sq.astype(aqk_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, heads // hp, trip, 0)
+
+
+def _decay_bwd_kernel(q_ref, k_ref, gc_ref, da_ref, daqk_ref, dq_ref, dk_ref,
+                      dgc_ref, dq_acc, dk_acc, *, r, block):
+    heads, c, dk = gc_ref.shape[2:]
+    hp = _heads_a_trip(heads, r)
+    nb, cd = hp * c // block, k_ref.dtype
+    rows = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    on_block = rows // block == cols // block
+    dq_acc[:] = jnp.zeros_like(dq_acc)
+    dk_acc[:] = jnp.zeros_like(dk_acc)
+
+    def blocks(d_p):
+        """[hp, C, C] -> the transposed diagonal blocks [nb, j, i]"""
+        d_p = jnp.where(on_block, jnp.swapaxes(d_p, 1, 2), 0.0)
+        return sum(d_p[..., b * block:(b + 1) * block]
+                   for b in range(c // block)).reshape(nb, block, block)
+
+    def into(acc, h, x):
+        """[hp, C, dk] of the value heads from `h` into their key heads'
+        sums"""
+        if r == 1:
+            acc[pl.ds(h, hp)] += x
+        else:
+            acc[h // r] += jnp.sum(x, axis=0)
+
+    def trip(i, carry):
+        h = i * hp
+        qb, kb, gb, grow, fall, left, right = _decay_parts(
+            q_ref, k_ref, gc_ref, h, hp, r, block)
+        d_a = jnp.where(rows > cols, da_ref[0, 0, pl.ds(h, hp)], 0.0)
+        d_aqk = jnp.where(rows >= cols,
+                          daqk_ref[0, 0, pl.ds(h, hp)].astype(_F32), 0.0)
+        # below the diagonal blocks (the right factor is nought from a
+        # block's first column on, so the cotangents need no mask there)
+        d_below = jnp.concatenate([d_a.reshape(nb, block, c),
+                                   d_aqk.reshape(nb, block, c)], 1).astype(cd)
+        d_left = jnp.einsum("bij,bjd->bid", d_below, right,
+                            preferred_element_type=_F32)
+        d_right = jnp.einsum("bij,bid->bjd", d_below, left,
+                             preferred_element_type=_F32)
+        dk_row = [(d_left[:, t:t + _ROWS] * grow[:, t:t + _ROWS])
+                  for t in range(0, block, _ROWS)]
+        d_q = [(d_left[:, block + t:block + t + _ROWS] * grow[:, t:t + _ROWS])
+               for t in range(0, block, _ROWS)]
+        # the diagonal blocks, `_ROWS` rows at a time: a column's
+        # cotangents across the channels, and the exponentials' share of
+        # the column's k summed over its rows
+        ck_all, cq_all = blocks(d_a), blocks(d_aqk)
+        by_col = 0.0
+        for top, e, ke in _diagonal(gb, kb, block):
+            n, part = top // _ROWS, slice(top, top + _ROWS)
+            ck, cq = (t[:, :top + _ROWS, part][..., None]
+                      for t in (ck_all, cq_all))         # [nb, j, _ROWS, 1]
+            dk_row[n] = dk_row[n] + jnp.sum(ck * ke, axis=1)
+            d_q[n] = d_q[n] + jnp.sum(cq * ke, axis=1)
+            col = jnp.sum((ck * kb[:, part][:, None]
+                           + cq * qb[:, part][:, None]) * e, axis=2)
+            if top + _ROWS < block:
+                col = jnp.concatenate(
+                    [col, jnp.zeros((nb, block - top - _ROWS, dk), _F32)], 1)
+            by_col = by_col + col
+        dk_col = (jnp.sum((d_right * fall).reshape(hp, -1, c, dk), axis=1)
+                  + by_col.reshape(hp, c, dk))
+        dk_row, d_q = (jnp.concatenate(x, 1).reshape(hp, c, dk)
+                       for x in (dk_row, d_q))
+        # d P / d gc_i = x_i d x_i as a row, -k_j d k_j as a column
+        dgc_ref[0, 0, pl.ds(h, hp)] = (qb.reshape(hp, c, dk) * d_q
+                                       + kb.reshape(hp, c, dk)
+                                       * (dk_row - dk_col))
+        into(dq_acc, h, d_q)
+        into(dk_acc, h, dk_row + dk_col)
+        return carry
+
+    lax.fori_loop(0, heads // hp, trip, 0)
+    dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
+    dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+
+
+def _decay_call(kernel, name, block, q, gc, ins, outs, scratch, interpret):
+    """One grid step a chunk and a block of value heads, as many as the
+    walks take (`heads_a_step` with dv = dk: the reverse kernel's
+    blocks a head, q, k, gc, the two [C, C] cotangents and three
+    gradients, are about a reverse walk's): `ins` and `outs` [B, N, X,
+    rows, cols] with X the value heads or their key heads; `scratch`
+    float32 [C, dk] a key head, that many times."""
+    b, n, h, c, dk = q.shape
+    if c % block or block % _ROWS:
+        raise ValueError("%s: chunks of %d in blocks of %d" % (name, c, block))
+    hv, r = h * gc.shape[3], gc.shape[3]
+    heads = heads_a_step(h, r, c, dk, dk, q.dtype.itemsize)
+
+    def spec(t):
+        return pl.BlockSpec((1, 1, t.shape[2] * heads // hv) + t.shape[3:],
+                            lambda i, j, k: (i, j, k, 0, 0))
+
+    return pl.pallas_call(
+        functools.partial(kernel, r=r, block=block),
+        grid=(b, n, hv // heads),
+        in_specs=[spec(t) for t in ins], out_specs=[spec(t) for t in outs],
+        out_shape=outs,
+        scratch_shapes=[pltpu.VMEM((heads // r, c, dk), _F32)] * scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        interpret=_interpret_default() if interpret is None else interpret,
+        name=name,
+    )(*ins)
+
+
+def gated_delta_rule_decay(q, k, gc, block, interpret=None):
+    """The decayed products of a chunk of a decay per key channel,
+    `hybrid_ops._gdr_decayed`'s anchored form in VMEM: A = sum_d
+    k_id k_jd exp(gc_id - gc_jd) for j < i, float32, and Aqk the same
+    of q against k for j <= i, at q's dtype; both nought above. q, k
+    [B, N, H, C, dk]; gc [B, N, H, R, C, dk] float32. Returns A and
+    Aqk [B, N, H, R, C, C]. A block of 16 rows anchored at its first
+    row r: under its diagonal block one product at q's dtype of
+    x exp(gc - gc_r) against k exp(gc_r - gc), on it the sum over
+    channels in float32."""
+    lead, c = gc.shape[:4], gc.shape[4]
+    g = _by_head(gc)
+    shape = g.shape[:3] + (c, c)
+    a, aqk = _decay_call(
+        _decay_kernel, "gated_delta_rule_decay", block, q, gc, [q, k, g],
+        [jax.ShapeDtypeStruct(shape, _F32),
+         jax.ShapeDtypeStruct(shape, q.dtype)], 0, interpret)
+    return a.reshape(lead + (c, c)), aqk.reshape(lead + (c, c))
+
+
+def gated_delta_rule_decay_bwd(q, k, gc, d_a, d_aqk, block, interpret=None):
+    """The transpose of `gated_delta_rule_decay`: for P_ij = sum_d
+    x_id k_jd exp(gc_id - gc_jd), d x_id = sum_j dP_ij k_jd exp(gc_id -
+    gc_jd) and d k_jd = sum_i dP_ij x_id exp(gc_id - gc_jd), anchored
+    as the forward kernel anchors them, and d gc = x d x - k d k summed
+    over both products (in A, x is k). The cotangents are read where A
+    and Aqk are not nought alone. Returns (d q, d k at q's dtype,
+    summed over a key head's value heads; d gc float32)."""
+    lead, c = gc.shape[:4], gc.shape[4]
+    ins = [q, k, _by_head(gc), _by_head(d_a), _by_head(d_aqk)]
+    d_q, d_k, d_gc = _decay_call(
+        _decay_bwd_kernel, "gated_delta_rule_decay_bwd", block, q, gc, ins,
+        [jax.ShapeDtypeStruct(q.shape, q.dtype)] * 2
+        + [jax.ShapeDtypeStruct(ins[2].shape, _F32)], 2, interpret)
+    return d_q, d_k, d_gc.reshape(gc.shape)

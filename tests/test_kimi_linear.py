@@ -327,11 +327,11 @@ def test_a_decay_equal_across_channels_is_the_scalar_rule(kernel):
 def test_a_decay_per_channel_names_its_parts_where_a_trace_reads_them(
         kernel):
     """`pt[decay]` (the anchored products, q and k scaled by their
-    decays) stands beside the scalar rule's four parts in an `op_name`
+    decays) stands beside the scalar rule's five parts in an `op_name`
     of the recompute and of the backward pass (the forward kernel makes
     every other local value itself: no `pt[local]` is left beside it);
     the stamps change no number."""
-    every = {"decay", "inverse", "local", "walk", "groups"}
+    every = {"head", "decay", "inverse", "local", "walk", "groups"}
     _stamps_change_no_number(
         "gated_delta_rule", functools.partial(
             hybrid_ops.gated_delta_rule, kernel=kernel),
@@ -359,3 +359,119 @@ def test_the_op_makes_a_channels_decay_from_its_projection_in_float32():
                        jax.nn.sigmoid(b))
     assert float(jnp.max(jnp.abs(got - want))) <= 2e-5 * max(
         1.0, float(jnp.max(jnp.abs(want))))
+
+
+# -- the decay's kernel pair -------------------------------------------------
+
+def _decay_inputs(h, r, dk=16, n=2, seed=21):
+    """q and k of `h` key heads, and a log-decay a channel for their `r`
+    value heads each, summed over a chunk of 64 as the op sums it: A of
+    1, 4, 9 and 16 (the head's factor, as `ALog` gives it) on a channel
+    falling by up to 1.5 a position, channel 0 by 1.5 exactly. Unanchored,
+    exp(gc) of a chunk's last position reaches e^-1536 and exp(-gc)
+    e^1536: both far past float32."""
+    rng = np.random.default_rng(seed)
+    q, k = (jnp.asarray(rng.normal(size=(1, n, h, 64, dk)), jnp.float32)
+            for _ in range(2))
+    fall = rng.uniform(0.0, 1.5, size=(1, n, h, r, 64, dk))
+    fall[..., 0] = 1.5
+    a = np.array([1.0, 4.0, 9.0, 16.0])[np.arange(h * r) % 4].reshape(h, r)
+    gc = np.cumsum(-a[:, :, None, None] * fall, axis=-2)
+    return q, k, jnp.asarray(gc, jnp.float32)
+
+
+def _anchored_products(q, k, gc):
+    """What the kernel pair computes, from `_gdr_decayed`: (K K^T
+    decayed, strictly lower; Q K^T decayed, causal)"""
+    strict = np.tril(np.ones((64, 64), bool), -1)
+    return (jnp.where(strict, hybrid_ops._gdr_decayed(k, k, gc), 0.0),
+            hybrid_ops._gdr_decayed(q, k, gc))
+
+
+_DECAY_CASES = {"two_heads_a_step": (4, 1, 2),
+                "every_head_a_step": (4, 1, None),
+                "two_value_heads_a_key_head": (2, 2, 2),
+                "three_heads_one_a_trip": (3, 1, None)}
+
+
+@pytest.mark.parametrize("case", list(_DECAY_CASES))
+def test_the_decay_kernel_makes_both_anchored_products(monkeypatch, case):
+    """`gated_delta_rule_decay` (under the interpreter) against
+    `_gdr_decayed`, both products, at decays no unanchored form
+    survives: every output finite and equal to float32 rounding. The
+    grid steps take two value heads or every head of the call; their
+    loop takes two at a time where they pair off."""
+    from paddle_tpu.ops.pallas import gated_delta_rule as kernels
+
+    h, r, heads = _DECAY_CASES[case]
+    if heads:
+        monkeypatch.setattr(kernels, "heads_a_step", lambda *a: heads)
+    q, k, gc = _decay_inputs(h, r)
+    got = kernels.gated_delta_rule_decay(q, k, gc, 16, interpret=True)
+    for a, b in zip(got, _anchored_products(q, k, gc)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert bool(jnp.all(jnp.isfinite(a)))
+        assert float(jnp.max(jnp.abs(a - b))) <= 2e-6 * max(
+            1.0, float(jnp.max(jnp.abs(b))))
+
+
+@pytest.mark.parametrize("case", list(_DECAY_CASES))
+def test_the_reverse_decay_kernel_is_the_products_transpose(monkeypatch,
+                                                             case):
+    """`gated_delta_rule_decay_bwd` against `jax.vjp` of `_gdr_decayed`'s
+    two products: d q, d k (summed over a key head's value heads) and
+    d gc = x d x - k d k, with cotangents on both products, on K K^T
+    alone (x is k: k takes both terms) and on Q K^T alone. Cotangents
+    where a product is nought by construction are not read."""
+    from paddle_tpu.ops.pallas import gated_delta_rule as kernels
+
+    h, r, heads = _DECAY_CASES[case]
+    if heads:
+        monkeypatch.setattr(kernels, "heads_a_step", lambda *a: heads)
+    q, k, gc = _decay_inputs(h, r)
+    rng = np.random.default_rng(5)
+    d_a, d_aqk = (jnp.asarray(rng.normal(size=gc.shape[:-1] + (64,)),
+                              jnp.float32) for _ in range(2))
+    _, vjp = jax.vjp(_anchored_products, q, k, gc)
+    zero = jnp.zeros_like(d_a)
+    for cot in ((d_a, d_aqk), (d_a, zero), (zero, d_aqk)):
+        got = kernels.gated_delta_rule_decay_bwd(q, k, gc, *cot, 16,
+                                                 interpret=True)
+        # what lies above either product's triangle changes nothing
+        junk = [c + jnp.triu(jnp.full((64, 64), 7.0), 0 if i == 0 else 1)
+                for i, c in enumerate(cot)]
+        again = kernels.gated_delta_rule_decay_bwd(q, k, gc, *junk, 16,
+                                                   interpret=True)
+        for name, a, b, c in zip(("d_q", "d_k", "d_gc"), got, vjp(cot),
+                                 again):
+            assert a.shape == b.shape and a.dtype == b.dtype, name
+            assert bool(jnp.all(jnp.isfinite(a))), name
+            assert float(jnp.max(jnp.abs(a - b))) <= 2e-6 * max(
+                1.0, float(jnp.max(jnp.abs(b)))), name
+            assert bool(jnp.all(a == c)), name
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["scan", "kernels"])
+def test_the_op_says_who_forms_the_decayed_products(kernel, caplog):
+    """Where the op is traced its log line has a clause for the decay's
+    two products of a decay per key channel (the kernel pair, its value
+    heads a grid step and its grid steps a call, or `jax.numpy`), and
+    none for a decay a head; the gradient keeps the inputs and the chunk
+    states either way, nothing of the products."""
+    import logging
+
+    args = _channel_args(5, s=130)
+    rule = functools.partial(hybrid_ops.gated_delta_rule, kernel=kernel)
+    with caplog.at_level(logging.INFO, logger=hybrid_ops.__name__):
+        _, vjp = jax.vjp(rule, *args)
+        rule(*args[:3], args[3][..., 0], args[4])
+    said = [r.getMessage() for r in caplog.records]
+    assert (", the decayed products by " + (
+        "pallas (4 value heads a grid step, 6 grid steps a call)"
+        if kernel else "jax.numpy") + "; kept ") in said[0]
+    assert "decayed products" not in said[-1]
+    shapes = sorted(tuple(x.shape) for x in jax.tree_util.tree_leaves(vjp)
+                    if hasattr(x, "shape") and x.size > 16)
+    assert shapes == sorted([(2, 192, 2, 8), (2, 192, 2, 8), (2, 192, 4, 16),
+                             (2, 192, 4, 8), (2, 192, 4),
+                             (1, 2, 3, 2, 2, 8, 16)])

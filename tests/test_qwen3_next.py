@@ -188,10 +188,11 @@ def test_gated_delta_rule_and_every_inputs_gradient_match_the_recurrence(
 @pytest.mark.parametrize("kernel", [False, True], ids=["scan", "kernels"])
 def test_gated_delta_rule_names_its_parts_where_a_trace_reads_them(kernel):
     """`pt[inverse]` (inside `pt[local]`, and the inner part wins),
-    `pt[local]`, `pt[walk]` and `pt[groups]` each stand in an `op_name`
-    of the recompute and of the backward pass, whoever walks the
-    chunks; the stamps change no number."""
-    every = {"inverse", "local", "walk", "groups"}
+    `pt[local]`, `pt[walk]`, `pt[groups]` and `pt[head]` (g and beta
+    made float32, the pad, the log-decays summed over each chunk) each
+    stand in an `op_name` of the recompute and of the backward pass,
+    whoever walks the chunks; the stamps change no number."""
+    every = {"head", "inverse", "local", "walk", "groups"}
     _stamps_change_no_number(
         "gated_delta_rule", functools.partial(
             hybrid_ops.gated_delta_rule, kernel=kernel),

@@ -10,6 +10,7 @@ at import, in a skipif or in parametrize), every compile happens in
 this process, and all of these tests live in this ONE file.
 """
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -321,6 +322,55 @@ def test_gated_delta_rule_at_the_published_widths(one_chip, monkeypatch,
     assert temp < 3 << 30
 
 
+def _kda_rule_args(one_chip):
+    """The kimi-linear cell's KDA rule: 32 heads of 128 (a key head a
+    value head), g a key channel, one document of 8,192"""
+    bf, f32 = jnp.bfloat16, jnp.float32
+    return [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((1, 8192, 32, 128), bf), ((1, 8192, 32, 128), bf),
+        ((1, 8192, 32, 128), bf), ((1, 8192, 32, 128), f32),
+        ((1, 8192, 32), f32))]
+
+
+def _rule_grad():
+    hybrid = importlib.import_module("paddle_tpu.ops.hybrid_ops")
+
+    def loss(q, k, v, g, beta):
+        out = hybrid.gated_delta_rule(q, k, v, -jax.nn.softplus(g),
+                                      jax.nn.sigmoid(beta))
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+
+
+def _on_the_kernels(monkeypatch):
+    kernels = importlib.import_module(
+        "paddle_tpu.ops.pallas.gated_delta_rule")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "_interpret_default", lambda: False)
+    return kernels
+
+
+def _lowered_without_locations(fn, args):
+    """`fn` lowered with no source locations in the text (a kernel's
+    serialised body carries its call's, which move with any edit of
+    the files it was called from)"""
+    was = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        return fn.lower(*args).as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", was)
+
+
+def _kernel_bodies(text):
+    """[(kernel name, bytes of its serialised Mosaic body)] of each
+    custom call in a lowered text"""
+    return [(re.search(r'kernel_name = "([^"]+)"', line).group(1),
+             len(re.search(r'body\\22: \\22([^\\]+)', line).group(1)))
+            for line in text.splitlines() if "tpu_custom_call" in line]
+
+
 @pytest.mark.parametrize("walked_by", ["jax.numpy", "pallas"])
 def test_a_decay_per_channel_at_the_published_widths(one_chip, monkeypatch,
                                                      walked_by):
@@ -329,38 +379,84 @@ def test_a_decay_per_channel_at_the_published_widths(one_chip, monkeypatch,
     document of 8,192: 128 chunks walked in 2 groups of heads. On the
     TPU the walks are the two kernels with their per-channel blocks (a
     head's log-decays [64, 128] float32, the decayed q k^T handed in,
-    the state's rows decayed by a [128] column) and the inverse the
-    third kernel; the anchored products and the diagonal blocks'
-    per-channel sums are the compiled program's. Either way the
-    gradient's temporaries stay under 2.6 GB (2,538,583,552 bytes with
-    the kernels, 2,606,063,104 without)."""
+    the state's rows decayed by a [128] column), the inverse the third
+    kernel, and the anchored products with the diagonal blocks'
+    per-channel sums the decay's pair, `gated_delta_rule_decay` and its
+    reverse kernel, in the forward pass and again in the backward
+    pass's remade local values. The pair keeps the diagonal blocks'
+    float32 exponentials and their cotangents out of HBM: the
+    gradient's temporaries are 1,926,612,992 bytes with the kernels
+    (2,538,583,552 while the products were the compiled program's),
+    2,606,063,104 without."""
     hybrid = importlib.import_module("paddle_tpu.ops.hybrid_ops")
     kernels = importlib.import_module(
         "paddle_tpu.ops.pallas.gated_delta_rule")
     assert hybrid._gdr_groups(1, 128, 32, 1) == 2
     assert kernels.heads_a_step(16, 1, 64, 128, 128, 2) == 8
     if walked_by == "pallas":
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(kernels, "_interpret_default", lambda: False)
-
-    def loss(q, k, v, g, beta):
-        out = hybrid.gated_delta_rule(q, k, v, -jax.nn.softplus(g),
-                                      jax.nn.sigmoid(beta))
-        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
-
-    bf, f32 = jnp.bfloat16, jnp.float32
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*[
-        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
-            ((1, 8192, 32, 128), bf), ((1, 8192, 32, 128), bf),
-            ((1, 8192, 32, 128), bf), ((1, 8192, 32, 128), f32),
-            ((1, 8192, 32), f32))]).compile()
+        _on_the_kernels(monkeypatch)
+    compiled = _rule_grad().lower(*_kda_rule_args(one_chip)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
     if walked_by == "pallas":
-        _kernels_are_called(compiled, ["gated_delta_rule_fwd",
-                                       "gated_delta_rule_bwd",
-                                       "gated_delta_rule_inverse"])
+        _kernels_are_called(compiled, [
+            "gated_delta_rule_fwd", "gated_delta_rule_bwd",
+            "gated_delta_rule_inverse", "gated_delta_rule_decay",
+            "gated_delta_rule_decay_bwd"])
+        assert temp < 2.0e9
     else:
         assert "tpu_custom_call" not in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.7e9
+        assert temp < 2.7e9
+
+
+#: bytes of the decay kernels' serialised Mosaic bodies at the cell's
+#: widths (their loops over heads and rows are loops, not unrolled
+#: code: a body that unrolled them read 85,612 and 112,896)
+_DECAY_BODY_BYTES = {"gated_delta_rule_decay": 12756,
+                     "gated_delta_rule_decay_bwd": 10992}
+
+
+def test_the_decay_kernels_lower_to_loops_and_not_unrolled_code(
+        one_chip, monkeypatch):
+    """Each site of a `pallas_call` is traced and lowered on every start
+    of a process, the persistent compile cache's hits included: the
+    kimi-linear cell's step holds the decay's pair at 16 sites. Lowered
+    for the chip at the cell's widths, each of their bodies stays under
+    twice the bytes of its looped form, so that an edit that unrolls
+    a loop fails here and not in the cell's set-up time."""
+    _on_the_kernels(monkeypatch)
+    bodies = _kernel_bodies(_lowered_without_locations(
+        _rule_grad(), _kda_rule_args(one_chip)))
+    sizes = {name: [n for k, n in bodies if k == name]
+             for name in _DECAY_BODY_BYTES}
+    # the forward kernel in the forward pass and in the backward pass's
+    # remade local values, the reverse one in the backward pass
+    assert [len(sizes[k]) for k in _DECAY_BODY_BYTES] == [2, 1]
+    for name, most in _DECAY_BODY_BYTES.items():
+        assert max(sizes[name]) < 2 * most, (name, sizes[name])
+
+
+def test_the_scalar_rule_lowers_to_the_programs_it_did(one_chip,
+                                                       monkeypatch):
+    """A decay a head (qwen3-next's rule at its widths: 16 key and 32
+    value heads of 128, one document of 16,384) never reaches the
+    decay's kernel pair: its gradient calls the walks and the inverse
+    alone, and its lowered text, source locations aside, is the one
+    the rule had before the pair existed."""
+    import hashlib
+
+    _on_the_kernels(monkeypatch)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((1, 16384, 16, 128), bf), ((1, 16384, 16, 128), bf),
+        ((1, 16384, 32, 128), bf), ((1, 16384, 32), f32),
+        ((1, 16384, 32), f32))]
+    text = _lowered_without_locations(_rule_grad(), args)
+    assert sorted(k for k, _ in _kernel_bodies(text)) == [
+        "gated_delta_rule_bwd", "gated_delta_rule_fwd",
+        "gated_delta_rule_inverse", "gated_delta_rule_inverse"]
+    if jax.__version__ == "0.9.0":
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+            "124f0b7c305da6d8"
 
 
 def _rpa_shapes(seqs, q_rows, hq, hkv, d, pages, page, per_seq, dtype,
