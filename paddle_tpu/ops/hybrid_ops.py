@@ -138,23 +138,77 @@ def _rotary_embedding(ins, attrs):
     return {"Out": jnp.concatenate([turned, x[..., rd:]], axis=-1)}
 
 
-@register_op("causal_conv1d")
-def _causal_conv1d(ins, attrs):
-    """Depthwise causal convolution along a sequence: X [B, S, C],
-    Filter [C, K]; Out[t] = sum_k Filter[:, k] X[t - (K-1) + k] + Bias,
-    positions before the sequence's start reading zero. `activation`
-    `silu` is applied where named. Summed in float32."""
-    x, w = ins["X"][0], ins["Filter"][0]
-    k = w.shape[1]
-    s = x.shape[1]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _conv_kernel(x, w, b, silu):
+    """`causal_conv1d` by the Pallas pair of `pallas/causal_conv1d.py`:
+    each pass reads X (and dOut) once, and the transpose makes the
+    pre-activation again from X, so only the op's inputs are kept."""
+    from .pallas.causal_conv1d import causal_conv1d_fwd
+
+    return causal_conv1d_fwd(x, w, b, silu)
+
+
+def _conv_kernel_fwd(x, w, b, silu):
+    return _conv_kernel(x, w, b, silu), (x, w, b)
+
+
+def _conv_kernel_bwd(silu, res, dy):
+    from .pallas.causal_conv1d import causal_conv1d_bwd
+
+    return causal_conv1d_bwd(*res, dy, silu)
+
+
+_conv_kernel.defvjp(_conv_kernel_fwd, _conv_kernel_bwd)
+
+
+def causal_conv1d(x, w, b=None, silu=False, kernel=None):
+    """Depthwise causal convolution along a sequence: x [B, S, C], w
+    [C, K]; Out[t] = sum_k w[:, k] x[t - (K-1) + k] + b, positions
+    before the sequence's start reading zero, then `silu` where asked.
+    Summed in float32, Out at x's dtype. `kernel` None takes the Pallas
+    pair on a TPU where C is whole lane tiles (a multiple of 128) and
+    the filter has at most 9 taps, and `jax.numpy` elsewhere."""
+    k, (n, s, c) = w.shape[1], x.shape
+    if kernel is None:
+        kernel = jax.default_backend() == "tpu" and c % 128 == 0 and k <= 9
+    by = "jax.numpy"
+    if kernel:
+        from .pallas.causal_conv1d import conv_blocks
+
+        steps = []
+        for arrays in (2, 3):
+            bs, bc = conv_blocks(s, c, x.dtype.itemsize, arrays)
+            steps.append("%d x %d a grid step, %d steps" % (
+                bs, bc, n * -(-s // bs) * (c // bc)))
+        by = "pallas (positions x channels: forward %s; reverse %s)" \
+            % tuple(steps)
+    # said where the op is traced: at the build's shape inference and
+    # once a compile and layer
+    logging.getLogger(__name__).info(
+        "causal_conv1d x %s %s, %d taps%s%s: convolved by %s",
+        tuple(x.shape), x.dtype.name, k, ", a bias" if b is not None else "",
+        ", silu" if silu else "", by)
+    if kernel:
+        return _conv_kernel(x, w, b, bool(silu))
     xp = jnp.pad(x.astype(_F32), ((0, 0), (k - 1, 0), (0, 0)))
     wf = w.astype(_F32)
     out = sum(xp[:, i:i + s, :] * wf[:, i] for i in range(k))
-    if ins.get("Bias"):
-        out = out + ins["Bias"][0].astype(_F32)
-    if attrs.get("activation", "") == "silu":
+    if b is not None:
+        out = out + b.astype(_F32)
+    if silu:
         out = jax.nn.silu(out)
-    return {"Out": out.astype(x.dtype)}
+    return out.astype(x.dtype)
+
+
+@register_op("causal_conv1d")
+def _causal_conv1d(ins, attrs):
+    """Depthwise causal convolution along a sequence (`causal_conv1d`
+    above): X [B, S, C], Filter [C, K], Bias [C] where given;
+    `activation` `silu` is applied where named."""
+    return {"Out": causal_conv1d(
+        ins["X"][0], ins["Filter"][0],
+        ins["Bias"][0] if ins.get("Bias") else None,
+        attrs.get("activation", "") == "silu")}
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,9 @@
 """Pallas TPU kernels for the hot ops: flash attention
 (`flash_attention.py`), ragged paged attention, the forward pass of the
-chunked state-space scan (`ssd_scan.py`) and the two walks over chunks,
-forward and reverse, of the gated delta rule (`gated_delta_rule.py`).
+chunked state-space scan (`ssd_scan.py`), the two walks over chunks,
+forward and reverse, of the gated delta rule (`gated_delta_rule.py`)
+and the causal depthwise convolution with its transpose
+(`causal_conv1d.py`).
 
 These are the hand-scheduled kernels sitting below the XLA-lowered op
 registry — the TPU-native counterpart of the reference's hand-written

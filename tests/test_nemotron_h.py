@@ -481,9 +481,13 @@ def test_the_unrolled_stack_is_recomputed_a_block_at_a_time(caplog):
     assert {r["name"] for v in saved.values() for r in v["kept"]} == {
         "narrow_matmul_product"}
     said = {r.getMessage() for r in caplog.records}
-    # at the build's stand-in batch and at the fed one (144 pairs)
+    # at the build's stand-in batch and at the fed one (144 pairs); the
+    # Mamba blocks' convolutions say who convolves, off the TPU jax.numpy
     head = "moe_experts holds experts [4, 6) of 8, top-3: "
-    assert all(m.startswith(head) for m in said)
+    conv = {m for m in said if m.startswith("causal_conv1d ")}
+    assert conv and all(m.endswith(", a bias, silu: convolved by jax.numpy")
+                        for m in conv)
+    assert all(m.startswith(head) for m in said - conv)
     assert head + ("512 rows a trip, 1 trips if every pair is held "
                    "here") in said
     # the same loss without recompute

@@ -459,6 +459,53 @@ def test_the_scalar_rule_lowers_to_the_programs_it_did(one_chip,
             "124f0b7c305da6d8"
 
 
+#: bytes of the convolution's serialised Mosaic bodies at the cells'
+#: widths: loops over lanes and rows, not unrolled code
+_CONV_BODY_BYTES = {"causal_conv1d_fwd": 6392, "causal_conv1d_bwd": 12116}
+
+
+@pytest.mark.parametrize("shape,bias", [
+    ((2, 8192, 6144), True), ((1, 16384, 8192), False),
+    ((1, 8192, 4096), False)], ids=["nemotron", "qwen3-next", "kimi-linear"])
+def test_causal_conv1d_lowers_once_to_loops_at_the_cells_widths(
+        one_chip, monkeypatch, shape, bias):
+    """The causal convolution's pair at the three cells' widths (a Mamba
+    layer's with a bias, the concatenated q|k|v of a Gated DeltaNet
+    layer, one of a KDA layer's three), called three times with one
+    signature as a KDA layer calls it: each kernel is lowered once in
+    the program (`jax.jit` round each call), its body stays under twice
+    the bytes of its looped form, both compile for the chip, and the
+    gradient's temporaries are no more than the `jax.numpy` form's."""
+    hybrid = importlib.import_module("paddle_tpu.ops.hybrid_ops")
+    kernels = importlib.import_module("paddle_tpu.ops.pallas.causal_conv1d")
+    monkeypatch.setattr(kernels, "_interpret_default", lambda: False)
+
+    def grad(kernel):
+        def loss(x, w, b):
+            y = sum(hybrid.causal_conv1d(x * (i + 1), w, b, True, kernel)
+                    for i in range(3))
+            return jnp.sum(jnp.sin(y.astype(jnp.float32)))
+
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2) if bias else (0, 1)))
+
+    bf = jnp.bfloat16
+    c = shape[-1]
+    args = [jax.ShapeDtypeStruct(shape, bf, sharding=one_chip),
+            jax.ShapeDtypeStruct((c, 4), bf, sharding=one_chip),
+            jax.ShapeDtypeStruct((c,), jnp.float32, sharding=one_chip)
+            if bias else None]
+    bodies = _kernel_bodies(_lowered_without_locations(grad(True), args))
+    assert sorted(k for k, _ in bodies) == sorted(_CONV_BODY_BYTES)
+    for name, size in bodies:
+        assert size < 2 * _CONV_BODY_BYTES[name], (name, size)
+    compiled = grad(True).lower(*args).compile()
+    _kernels_are_called(compiled, list(_CONV_BODY_BYTES))
+    plain = grad(False).lower(*args).compile()
+    assert "tpu_custom_call" not in plain.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes <= \
+        plain.memory_analysis().temp_size_in_bytes
+
+
 def _rpa_shapes(seqs, q_rows, hq, hkv, d, pages, page, per_seq, dtype,
                 scales=False):
     shapes = [((seqs, q_rows, hq, d),
