@@ -7,35 +7,27 @@ in `linear_attn_config`) mixes by Kimi Delta Attention where it is in
 `kda_layers` and by `models/kimi_vl.py`'s latent attention, unturned
 (`mla_use_nope`), where it is in `full_attn_layers`. The first
 `first_k_dense_replace` layers have a dense SwiGLU feed-forward, the
-others kimi-vl's routed layer (sigmoid router over all experts, the top
-few renormalised and scaled, ungated shared experts). A last norm, an
-untied head and next-token cross-entropy. Trained like
-`models/kimi_vl.py`: an optimizer's `minimize` under
-`mixed_precision.decorate`, `Executor.run` a step.
+others routed experts. A last norm, an untied head and next-token
+cross-entropy.
 
 Kimi Delta Attention (`kda_mixer`) is the gated delta rule with a decay
 per key channel: `gated_delta_rule` handed a of [B, S, H, dk] and a
 dt_bias of [H * dk].
 
-Per-layer recompute: the layers are of several kinds, so the stack is
-unrolled and every mixer's and every feed-forward's output is a
-checkpoint (`kimi_linear_loss(..., checkpoints_out=[])` hands them to
-`RecomputeOptimizer`).
-
-Expert parallelism: `experts_held=(first, count)` builds the chip's
-share of every routed layer (`parallel.planner.experts_held`; the
-router scores all `num_experts`). The share's output is the layer's
-partial sum; nothing stands in for the other chips.
+Its own: the configuration's published names, the KDA mixer and the
+plan. From `models/kimi_vl.py`: the configuration's class and the
+latent attention. From `models/decoder.py`: the norm, the dense
+feed-forward, the routed layer (kimi-vl's: sigmoid router over all
+experts, the top few renormalised and scaled, `swiglu` experts, ungated
+shared experts), the skeleton and the loss, with what it says of
+recompute and expert parallelism.
 """
 from __future__ import annotations
 
-import numpy as np
-
 from ..fluid import initializer, layers
-from ..fluid.param_attr import ParamAttr
-from .kimi_vl import (KimiVLConfig, _norm, dense_layer,
-                      latent_attention_mixer, routed_layer)
-from .nemotron_h import _par, next_token_loss, routed_counters
+from .decoder import (a_log_init, causal_lm_loss, dense_layer, param,
+                      routed_layer)
+from .kimi_vl import KimiVLConfig, latent_attention_mixer
 
 KDA, MLA = "kda", "mla"
 
@@ -43,8 +35,8 @@ KDA, MLA = "kda", "mla"
 class KimiLinearConfig(KimiVLConfig):
     """The published `config.json` keys the model reads (defaults:
     Kimi-Linear-48B-A3B-Instruct), held under `KimiVLConfig`'s names
-    where kimi-vl's latent attention, dense layer and routed layer read
-    them. `linear_attn_config` lists the layers of each kind, counted
+    where the latent attention and the shared blocks read them.
+    `linear_attn_config` lists the layers of each kind, counted
     from 1; it may list more layers than `num_hidden_layers`: the first
     that many are built. `experts_held` is (first expert, how many) of
     the `num_experts` this chip holds; None holds them all."""
@@ -109,13 +101,6 @@ class KimiLinearConfig(KimiVLConfig):
         return KimiLinearConfig(**kw)
 
 
-def _a_log_init(heads):
-    """A spread over [1, 16], A_log its logarithm: the family's draw's
-    range (flash-linear-attention's `KimiDeltaAttention`)."""
-    return initializer.NumpyArrayInitializer(
-        np.log(np.linspace(1.0, 16.0, heads)).astype("float32"))
-
-
 def kda_mixer(x, cfg, name):
     """Kimi Delta Attention: `q_proj`, `k_proj`, `v_proj` each pass a
     causal depthwise convolution of its own and silu; q and k are
@@ -127,22 +112,21 @@ def kda_mixer(x, cfg, name):
     lin = cfg.linear_attn_config
     h, heads, d = cfg.hidden_size, lin["num_heads"], lin["head_dim"]
     wide, taps = heads * d, lin["short_conv_kernel_size"]
-    w_q, w_k, w_v = (_par(name + "." + p, [h, wide], cfg)
+    w_q, w_k, w_v = (param(name + "." + p, [h, wide], cfg)
                      for p in ("q_proj", "k_proj", "v_proj"))
-    convs = [_par(name + "." + p, [wide, taps], cfg,
-                  initializer.Uniform(-0.5, 0.5))
+    convs = [param(name + "." + p, [wide, taps], cfg,
+                   initializer.Uniform(-0.5, 0.5))
              for p in ("q_conv", "k_conv", "v_conv")]
-    w_fa = _par(name + ".f_a_proj", [h, d], cfg)
-    w_fb = _par(name + ".f_b_proj", [d, wide], cfg)
-    w_b = _par(name + ".b_proj", [h, heads], cfg)
-    a_log = _par(name + ".A_log", [heads], cfg, _a_log_init(heads))
-    dt_bias = _par(name + ".dt_bias", [wide], cfg,
-                   initializer.Constant(0.0))
-    w_ga = _par(name + ".g_a_proj", [h, d], cfg)
-    w_gb = _par(name + ".g_b_proj", [d, wide], cfg)
-    b_g = _par(name + ".g_b_bias", [wide], cfg, initializer.Constant(0.0))
-    norm_w = _par(name + ".o_norm", [d], cfg, initializer.Constant(1.0))
-    w_o = _par(name + ".o_proj", [wide, h], cfg)
+    w_fa = param(name + ".f_a_proj", [h, d], cfg)
+    w_fb = param(name + ".f_b_proj", [d, wide], cfg)
+    w_b = param(name + ".b_proj", [h, heads], cfg)
+    a_log = param(name + ".A_log", [heads], cfg, a_log_init(heads))
+    dt_bias = param(name + ".dt_bias", [wide], cfg, 0.0)
+    w_ga = param(name + ".g_a_proj", [h, d], cfg)
+    w_gb = param(name + ".g_b_proj", [d, wide], cfg)
+    b_g = param(name + ".g_b_bias", [wide], cfg, 0.0)
+    norm_w = param(name + ".o_norm", [d], cfg, 1.0)
+    w_o = param(name + ".o_proj", [wide, h], cfg)
 
     q, k, v = (layers.reshape(layers.causal_conv1d(
         layers.matmul(x, w), conv, activation="silu"), [0, 0, heads, d])
@@ -161,37 +145,16 @@ def kda_mixer(x, cfg, name):
     return layers.matmul(layers.reshape(out, [0, 0, wide]), w_o)
 
 
-def kimi_linear_decoder(ids, cfg, checkpoints_out=None, counters=None):
-    """ids [B, S] -> hidden states [B, S, H] after the final norm."""
-    h = layers.embedding(ids, size=[cfg.vocab_size, cfg.hidden_size],
-                         param_attr=ParamAttr(
-                             name="embed", initializer=initializer
-                             .TruncatedNormal(0.0, cfg.initializer_range)))
-    for i in range(cfg.num_hidden_layers):
-        name = "l%d" % i
-        mixer = kda_mixer if cfg.layer_kinds[i] == KDA else \
-            latent_attention_mixer
-        feed_forward = (dense_layer, {}) if cfg.is_dense(i) else \
-            (routed_layer, {"counters": counters})
-        for norm, fn, extra in (
-                (".input_norm", mixer, {}),
-                (".post_attention_norm",) + feed_forward):
-            h = layers.elementwise_add(
-                h, fn(_norm(h, name + norm, cfg), cfg, name, **extra))
-            if checkpoints_out is not None:
-                checkpoints_out.append(h)
-    return _norm(h, "final_norm", cfg)
+def plan(cfg):
+    """Layer i: KDA or latent attention behind `.input_norm`, then the
+    dense or the routed feed-forward behind `.post_attention_norm`."""
+    return [[(".input_norm", kda_mixer if cfg.layer_kinds[i] == KDA
+              else latent_attention_mixer),
+             (".post_attention_norm",
+              dense_layer if cfg.is_dense(i) else routed_layer)]
+            for i in range(cfg.num_hidden_layers)]
 
 
 def kimi_linear_loss(cfg, seq_len, checkpoints_out=None):
-    """Next-token cross-entropy over feed vars `ids` and `labels`
-    [B, seq_len] (the caller shifts: labels[t] is the token after
-    ids[t]), the mean over all positions. Returns (loss, counters,
-    feeds): `counters` is what `nemotron_h.routed_counters` gives of the
-    routed layers, to fetch with the loss where wanted."""
-    ids = layers.data(name="ids", shape=[seq_len], dtype="int64")
-    labels = layers.data(name="labels", shape=[seq_len], dtype="int64")
-    per_layer = []
-    hidden = kimi_linear_decoder(ids, cfg, checkpoints_out, per_layer)
-    loss = next_token_loss(hidden, labels, cfg)
-    return loss, routed_counters(per_layer), ["ids", "labels"]
+    """`decoder.causal_lm_loss` of the stack `plan` lays out."""
+    return causal_lm_loss(cfg, seq_len, plan(cfg), checkpoints_out)

@@ -5,9 +5,7 @@ bias) in the fluid static graph API. Every layer is
 `h <- h + Attn_i(N(h))`, then `h <- h + FFN_i(N(h))`, `N` the plain RMS
 norm; the first `first_k_dense_replace` layers have a dense SwiGLU
 feed-forward, the others routed experts and ungated shared ones; a last
-norm, an untied head and next-token cross-entropy. Trained like
-`models/qwen3_next.py`: an optimizer's `minimize` under
-`mixed_precision.decorate`, `Executor.run` a step.
+norm, an untied head and next-token cross-entropy.
 
 Latent attention, as the architecture's paper gives it for training:
 keys and values are DECOMPRESSED from the latent (`kv_b_proj`) and the
@@ -18,32 +16,30 @@ reads; it is joined to each head's unrotated part in HBM (the flash
 kernels read K `[B, heads, S, 192]`). The text model alone: no vision
 tower, no projector.
 
-Per-layer recompute: the layers are of two kinds, so the stack is
-unrolled and every mixer's and every feed-forward's output is a
-checkpoint (`kimi_vl_loss(..., checkpoints_out=[])` hands them to
-`RecomputeOptimizer`).
-
-Expert parallelism: `experts_held=(first, count)` builds the chip's
-share of every routed layer (`parallel.planner.experts_held`): the
-router scores all `n_routed_experts`, the layer computes its own
-experts' part for the tokens routed to them plus the shared experts,
-and that partial sum is the layer's output. Nothing stands in for the
-other chips.
+Its own: the configuration, the latent attention mixer and the plan.
+From `models/decoder.py`: the norm, the dense feed-forward, the routed
+layer (sigmoid router with a selection bias, `swiglu` experts, ungated
+shared experts of `n_shared_experts` times the expert width), the
+skeleton and the loss, with what it says of recompute and expert
+parallelism.
 """
 from __future__ import annotations
 
 import math
 
-from ..fluid import initializer, layers
-from ..fluid.param_attr import ParamAttr
-from .nemotron_h import _par, next_token_loss, routed_counters
-from .qwen3_next import _cut
+from ..fluid import layers
+from .decoder import (causal_lm_loss, cut, dense_layer, param,
+                      rms_norm_block, routed_layer)
 
 
 class KimiVLConfig:
     """The published `config.json` keys the text model reads (defaults:
     Kimi-VL-A3B-Instruct). `experts_held` is (first expert, how many)
     of the `n_routed_experts` this chip holds; None holds them all."""
+
+    ZERO_CENTRED_NORM = False
+    ROUTER_SCORE, EXPERT_ACTIVATION = "sigmoid", "swiglu"
+    SHARED_EXPERT_GATED = False
 
     def __init__(self, vocab_size=163840, hidden_size=2048,
                  num_hidden_layers=27, first_k_dense_replace=1,
@@ -83,6 +79,11 @@ class KimiVLConfig:
     def is_dense(self, i):
         return i < self.first_k_dense_replace
 
+    # the names `models/decoder.py`'s routed layer reads
+    num_experts = property(lambda self: self.n_routed_experts)
+    shared_expert_intermediate_size = property(
+        lambda self: self.n_shared_experts * self.moe_intermediate_size)
+
     @staticmethod
     def tiny(**over):
         """Both kinds of layer, unequal head sizes and a one-head
@@ -95,14 +96,6 @@ class KimiVLConfig:
                   n_shared_experts=2, num_experts_per_tok=3)
         kw.update(over)
         return KimiVLConfig(**kw)
-
-
-def _norm(x, name, cfg):
-    """The plain RMS norm over the last axis, its own weight."""
-    return layers.rms_norm(
-        x, scale=_par(name, [int(x.shape[-1])], cfg,
-                      initializer.Constant(1.0)),
-        epsilon=cfg.rms_norm_eps)
 
 
 def latent_attention_mixer(x, cfg, name):
@@ -124,20 +117,18 @@ def latent_attention_mixer(x, cfg, name):
             return t
         return layers.rotary_embedding(t, rotary_dim=dr, theta=cfg.rope_theta)
 
-    w_q = _par(name + ".q_proj", [h, nq * (dn + dr)], cfg)
-    w_kv_a = _par(name + ".kv_a_proj", [h, rank + dr], cfg)
-    w_kv_b = _par(name + ".kv_b_proj", [rank, nq * (dn + dv)], cfg)
-    w_o = _par(name + ".o_proj", [nq * dv, h], cfg)
+    w_q = param(name + ".q_proj", [h, nq * (dn + dr)], cfg)
+    w_kv_a = param(name + ".kv_a_proj", [h, rank + dr], cfg)
+    w_kv_b = param(name + ".kv_b_proj", [rank, nq * (dn + dv)], cfg)
+    w_o = param(name + ".o_proj", [nq * dv, h], cfg)
 
-    q_nope, q_rope = _cut(
+    q_nope, q_rope = cut(
         layers.reshape(layers.matmul(x, w_q), [0, 0, nq, dn + dr]),
         3, [dn, dr])
-    latent, k_rope = _cut(layers.matmul(x, w_kv_a), 2, [rank, dr])
-    k_nope, v = _cut(
-        layers.reshape(
-            layers.matmul(_norm(latent, name + ".kv_a_norm", cfg), w_kv_b),
-            [0, 0, nq, dn + dv]),
-        3, [dn, dv])
+    latent, k_rope = cut(layers.matmul(x, w_kv_a), 2, [rank, dr])
+    latent = rms_norm_block(latent, name + ".kv_a_norm", cfg)
+    k_nope, v = cut(layers.reshape(layers.matmul(latent, w_kv_b),
+                                   [0, 0, nq, dn + dv]), 3, [dn, dv])
     q = layers.concat([q_nope, turn(q_rope)], axis=3)
     k_rope = turn(layers.reshape(k_rope, [0, 0, 1, dr]))
     k = layers.concat(
@@ -150,76 +141,15 @@ def latent_attention_mixer(x, cfg, name):
     return layers.matmul(ctx, w_o)
 
 
-def _gated_mlp(x, w_gate_up, w_down):
-    return layers.matmul(layers.swiglu(layers.matmul(x, w_gate_up)), w_down)
-
-
-def dense_layer(x, cfg, name):
-    """A leading layer's feed-forward: one SwiGLU of
-    `intermediate_size`."""
-    h, f = cfg.hidden_size, cfg.intermediate_size
-    return _gated_mlp(x, _par(name + ".gate_up", [h, 2 * f], cfg),
-                      _par(name + ".down", [f, h], cfg))
-
-
-def routed_layer(x, cfg, name, counters=None):
-    """The shared experts (one SwiGLU of `n_shared_experts` times the
-    expert width, added ungated) for every token plus this chip's share
-    of the routed experts: sigmoid scores over all experts, the top few
-    of score + bias (the bias steers the choice only), renormalised and
-    scaled. `counters` collects the layer's (pairs computed, fullest
-    expert over the mean, rows made)."""
-    h, f = cfg.hidden_size, cfg.moe_intermediate_size
-    fs = cfg.n_shared_experts * f
-    first, count = cfg.experts_held
-    w_r = _par(name + ".router", [h, cfg.n_routed_experts], cfg)
-    b_r = _par(name + ".router_bias", [cfg.n_routed_experts], cfg,
-               initializer.Constant(0.0), trainable=False)
-    w_sgu = _par(name + ".shared_gate_up", [h, 2 * fs], cfg)
-    w_sd = _par(name + ".shared_down", [fs, h], cfg)
-    w_gu = _par(name + ".experts_gate_up", [count, h, 2 * f], cfg)
-    w_down = _par(name + ".experts_down", [count, f, h], cfg)
-    idx, weight = layers.moe_router(
-        x, w_r, b_r, top_k=cfg.num_experts_per_tok,
-        norm_topk_prob=cfg.norm_topk_prob,
-        routed_scaling_factor=cfg.routed_scaling_factor)
-    routed, *counted = layers.moe_experts(
-        x, idx, weight, w_gu, w_down, held_start=first,
-        num_experts=cfg.n_routed_experts, activation="swiglu")
-    if counters is not None:
-        counters.append(counted)
-    return layers.elementwise_add(_gated_mlp(x, w_sgu, w_sd), routed)
-
-
-def kimi_vl_decoder(ids, cfg, checkpoints_out=None, counters=None):
-    """ids [B, S] -> hidden states [B, S, H] after the final norm."""
-    h = layers.embedding(ids, size=[cfg.vocab_size, cfg.hidden_size],
-                         param_attr=ParamAttr(
-                             name="embed", initializer=initializer
-                             .TruncatedNormal(0.0, cfg.initializer_range)))
-    for i in range(cfg.num_hidden_layers):
-        name = "l%d" % i
-        feed_forward = (dense_layer, {}) if cfg.is_dense(i) else \
-            (routed_layer, {"counters": counters})
-        for norm, fn, extra in (
-                (".input_norm", latent_attention_mixer, {}),
-                (".post_attention_norm",) + feed_forward):
-            h = layers.elementwise_add(
-                h, fn(_norm(h, name + norm, cfg), cfg, name, **extra))
-            if checkpoints_out is not None:
-                checkpoints_out.append(h)
-    return _norm(h, "final_norm", cfg)
+def plan(cfg):
+    """Layer i: latent attention behind `.input_norm`, then the dense or
+    the routed feed-forward behind `.post_attention_norm`."""
+    return [[(".input_norm", latent_attention_mixer),
+             (".post_attention_norm",
+              dense_layer if cfg.is_dense(i) else routed_layer)]
+            for i in range(cfg.num_hidden_layers)]
 
 
 def kimi_vl_loss(cfg, seq_len, checkpoints_out=None):
-    """Next-token cross-entropy over feed vars `ids` and `labels`
-    [B, seq_len] (the caller shifts: labels[t] is the token after
-    ids[t]), the mean over all positions. Returns (loss, counters,
-    feeds): `counters` is what `nemotron_h.routed_counters` gives of the
-    routed layers, to fetch with the loss where wanted."""
-    ids = layers.data(name="ids", shape=[seq_len], dtype="int64")
-    labels = layers.data(name="labels", shape=[seq_len], dtype="int64")
-    per_layer = []
-    hidden = kimi_vl_decoder(ids, cfg, checkpoints_out, per_layer)
-    loss = next_token_loss(hidden, labels, cfg)
-    return loss, routed_counters(per_layer), ["ids", "labels"]
+    """`decoder.causal_lm_loss` of the stack `plan` lays out."""
+    return causal_lm_loss(cfg, seq_len, plan(cfg), checkpoints_out)

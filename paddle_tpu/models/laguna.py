@@ -8,35 +8,28 @@ of a full layer's head, the default on all of a window layer's) and its
 layers their own count of query heads (`num_attention_heads_per_layer`)
 on the same key/value heads, and every head's output is scaled by a
 sigmoid gate of its own (one scalar a head and token). The layers in
-`mlp_only_layers` have a dense SwiGLU feed-forward, the others the
-routed layer of `models/qwen3_next.py` (softmax router, top-k
-renormalised and scaled, a sigmoid-gated shared expert). A last norm,
-an untied head and next-token cross-entropy. Trained like
-`models/kimi_vl.py`: an optimizer's `minimize` under
-`mixed_precision.decorate`, `Executor.run` a step.
+`mlp_only_layers` have a dense SwiGLU feed-forward, the others routed
+experts. A last norm, an untied head and next-token cross-entropy.
 
-Per-layer recompute: the layers are of several kinds, so the stack is
-unrolled and every mixer's and every feed-forward's output is a
-checkpoint (`laguna_loss(..., checkpoints_out=[])` hands them to
-`RecomputeOptimizer`).
+Its own: the configuration, the attention mixer and the plan. From
+`models/decoder.py`: the norm, the dense feed-forward, the routed layer
+(softmax router, top-k renormalised and scaled, `swiglu` experts, a
+sigmoid-gated shared expert), the skeleton and the loss, with what it
+says of recompute and expert parallelism.
 
-A chip's share of a deployment: `experts_held=(first, count)` builds
-its share of every routed layer (`parallel.planner.experts_held`; the
-router scores all `num_experts`), and a configuration whose head counts
-are the chip's share of a tensor-parallel pair (half of the query heads
-with their gates and `o_proj` rows, half of the key/value heads) builds
-that share of every attention layer. Each share's output is the layer's
-partial sum; nothing stands in for the other chips.
+A chip's share of a deployment: besides its share of the experts, a
+configuration whose head counts are the chip's share of a
+tensor-parallel pair (half of the query heads with their gates and
+`o_proj` rows, half of the key/value heads) builds that share of every
+attention layer, whose output is then the layer's partial sum.
 """
 from __future__ import annotations
 
+import functools
 import math
 
-from ..fluid import initializer, layers
-from ..fluid.param_attr import ParamAttr
-from .kimi_vl import _norm, dense_layer
-from .nemotron_h import _par, next_token_loss, routed_counters
-from .qwen3_next import routed_layer
+from ..fluid import layers
+from .decoder import causal_lm_loss, dense_layer, param, routed_layer
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 
@@ -47,6 +40,10 @@ class LagunaConfig:
     may be longer than `num_hidden_layers`: the first that many are
     built. `experts_held` is (first expert, how many) of the
     `num_experts` this chip holds; None holds them all."""
+
+    ZERO_CENTRED_NORM = False
+    ROUTER_SCORE, EXPERT_ACTIVATION = "softmax", "swiglu"
+    SHARED_EXPERT_GATED = True
 
     def __init__(self, vocab_size=100352, hidden_size=3072,
                  intermediate_size=12288, num_hidden_layers=48,
@@ -90,7 +87,7 @@ class LagunaConfig:
                 [num_attention_heads if t == FULL
                  else num_attention_heads * 3 // 2
                  for t in self.layer_types]))
-        # the routed layer (`qwen3_next.routed_layer`) reads it so
+        # `decoder.routed_layer` reads it so
         self.routed_scaling_factor = moe_routed_scaling_factor
         self.initializer_range = initializer_range
         self.experts_held = tuple(experts_held or (0, num_experts))
@@ -152,11 +149,11 @@ def attention_mixer(x, cfg, name, layer):
     rope = cfg.rope_parameters[cfg.layer_types[layer]]
     rotary = {"rotary_dim": int(d * rope.get("partial_rotary_factor", 1)),
               "theta": rope["rope_theta"], "scaling": rope}
-    w_q = _par(name + ".q_proj", [h, nq * d], cfg)
-    w_k = _par(name + ".k_proj", [h, nkv * d], cfg)
-    w_v = _par(name + ".v_proj", [h, nkv * d], cfg)
-    w_g = _par(name + ".g_proj", [h, nq], cfg)
-    w_o = _par(name + ".o_proj", [nq * d, h], cfg)
+    w_q = param(name + ".q_proj", [h, nq * d], cfg)
+    w_k = param(name + ".k_proj", [h, nkv * d], cfg)
+    w_v = param(name + ".v_proj", [h, nkv * d], cfg)
+    w_g = param(name + ".g_proj", [h, nq], cfg)
+    w_o = param(name + ".o_proj", [nq * d, h], cfg)
 
     q = layers.rotary_embedding(
         layers.reshape(layers.matmul(x, w_q), [0, 0, nq, d]), **rotary)
@@ -174,35 +171,15 @@ def attention_mixer(x, cfg, name, layer):
     return layers.matmul(layers.reshape(ctx, [0, 0, nq * d]), w_o)
 
 
-def laguna_decoder(ids, cfg, checkpoints_out=None, counters=None):
-    """ids [B, S] -> hidden states [B, S, H] after the final norm."""
-    h = layers.embedding(ids, size=[cfg.vocab_size, cfg.hidden_size],
-                         param_attr=ParamAttr(
-                             name="embed", initializer=initializer
-                             .TruncatedNormal(0.0, cfg.initializer_range)))
-    for i in range(cfg.num_hidden_layers):
-        name = "l%d" % i
-        feed_forward = (dense_layer, {}) if cfg.is_dense(i) else \
-            (routed_layer, {"counters": counters})
-        for norm, fn, extra in (
-                (".input_norm", attention_mixer, {"layer": i}),
-                (".post_attention_norm",) + feed_forward):
-            h = layers.elementwise_add(
-                h, fn(_norm(h, name + norm, cfg), cfg, name, **extra))
-            if checkpoints_out is not None:
-                checkpoints_out.append(h)
-    return _norm(h, "final_norm", cfg)
+def plan(cfg):
+    """Layer i: its attention behind `.input_norm`, then the dense or the
+    routed feed-forward behind `.post_attention_norm`."""
+    return [[(".input_norm", functools.partial(attention_mixer, layer=i)),
+             (".post_attention_norm",
+              dense_layer if cfg.is_dense(i) else routed_layer)]
+            for i in range(cfg.num_hidden_layers)]
 
 
 def laguna_loss(cfg, seq_len, checkpoints_out=None):
-    """Next-token cross-entropy over feed vars `ids` and `labels`
-    [B, seq_len] (the caller shifts: labels[t] is the token after
-    ids[t]), the mean over all positions. Returns (loss, counters,
-    feeds): `counters` is what `nemotron_h.routed_counters` gives of the
-    routed layers, to fetch with the loss where wanted."""
-    ids = layers.data(name="ids", shape=[seq_len], dtype="int64")
-    labels = layers.data(name="labels", shape=[seq_len], dtype="int64")
-    per_layer = []
-    hidden = laguna_decoder(ids, cfg, checkpoints_out, per_layer)
-    loss = next_token_loss(hidden, labels, cfg)
-    return loss, routed_counters(per_layer), ["ids", "labels"]
+    """`decoder.causal_lm_loss` of the stack `plan` lays out."""
+    return causal_lm_loss(cfg, seq_len, plan(cfg), checkpoints_out)

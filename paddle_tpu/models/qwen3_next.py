@@ -6,20 +6,13 @@ gated softmax attention where `(i + 1) % full_attention_interval == 0`
 and by a Gated DeltaNet (a linear-attention state updated by the gated
 delta rule) otherwise; a last norm, an untied head and next-token
 cross-entropy. `N(x) = x rsqrt(mean(x^2) + eps) (1 + w)`, w starting
-at zero. Trained like `models/nemotron_h.py`: an optimizer's
-`minimize` under `mixed_precision.decorate`, `Executor.run` a step.
+at zero.
 
-Per-layer recompute: the layers are of two kinds, so the stack is
-unrolled and every mixer's and every routed layer's output is a
-checkpoint (`qwen3_next_loss(..., checkpoints_out=[])` hands them to
-`RecomputeOptimizer`).
-
-Expert parallelism: `experts_held=(first, count)` builds the chip's
-share of every routed layer (`parallel.planner.experts_held`): the
-router scores all `num_experts`, the layer computes its own experts'
-part for the tokens routed to them and the shared expert, and that
-partial sum is the layer's output. Nothing stands in for the other
-chips.
+Its own: the configuration, the Gated DeltaNet and gated attention
+mixers and the plan. From `models/decoder.py`: the zero-centred norm,
+the routed layer (softmax router, `swiglu` experts, a sigmoid-gated
+shared expert), the skeleton and the loss, with what it says of
+recompute and expert parallelism.
 """
 from __future__ import annotations
 
@@ -28,14 +21,17 @@ import math
 import numpy as np
 
 from ..fluid import initializer, layers
-from ..fluid.param_attr import ParamAttr
-from .nemotron_h import next_token_loss, routed_counters
+from .decoder import causal_lm_loss, cut, param, rms_norm_block, routed_layer
 
 
 class Qwen3NextConfig:
     """The published `config.json` keys the model reads (defaults:
     Qwen3-Next-80B-A3B-Instruct). `experts_held` is (first expert, how
     many) of the `num_experts` this chip holds; None holds them all."""
+
+    ZERO_CENTRED_NORM = True
+    ROUTER_SCORE, EXPERT_ACTIVATION = "softmax", "swiglu"
+    SHARED_EXPERT_GATED = True
 
     def __init__(self, vocab_size=151936, hidden_size=2048,
                  num_hidden_layers=48, full_attention_interval=4,
@@ -72,8 +68,8 @@ class Qwen3NextConfig:
         self.rms_norm_eps = rms_norm_eps
         self.initializer_range = initializer_range
         self.experts_held = tuple(experts_held or (0, num_experts))
-        #: the routed weights' factor: the published config has none
-        #: (`models/laguna.py` sets its own on the same routed layer)
+        #: the routed weights' factor (`decoder.routed_layer` reads it):
+        #: the published config has none
         self.routed_scaling_factor = 1.0
         if linear_num_value_heads % linear_num_key_heads:
             raise ValueError("%d value heads on %d key heads"
@@ -97,31 +93,6 @@ class Qwen3NextConfig:
                   shared_expert_intermediate_size=24)
         kw.update(over)
         return Qwen3NextConfig(**kw)
-
-
-def _par(name, shape, cfg, init=None):
-    init = init or initializer.TruncatedNormal(0.0, cfg.initializer_range)
-    return layers.create_parameter(
-        shape=shape, dtype="float32", name=name,
-        attr=ParamAttr(name=name, initializer=init))
-
-
-def _const(value):
-    return initializer.Constant(float(value))
-
-
-def _norm(x, name, cfg):
-    """The zero-centred RMS norm over the last axis, its own weight."""
-    return layers.rms_norm(
-        x, scale=_par(name, [int(x.shape[-1])], cfg, _const(0.0)),
-        epsilon=cfg.rms_norm_eps, zero_centered=True)
-
-
-def _cut(x, axis, sizes):
-    """`x` cut along `axis` into consecutive parts of `sizes`."""
-    edges = np.cumsum([0] + list(sizes))
-    return [layers.slice(x, axes=[axis], starts=[int(lo)], ends=[int(hi)])
-            for lo, hi in zip(edges, edges[1:])]
 
 
 def _dt_bias_init(heads):
@@ -148,28 +119,28 @@ def gated_delta_net_mixer(x, cfg, name):
     hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv, r = cfg.linear_key_head_dim, cfg.linear_value_head_dim, hv // hk
     key_dim, value_dim = hk * dk, hv * dv
-    w_qkvz = _par(name + ".in_proj_qkvz", [h, 2 * key_dim + 2 * value_dim],
-                  cfg)
-    w_ba = _par(name + ".in_proj_ba", [h, 2 * hv], cfg)
-    conv_w = _par(name + ".conv.w",
-                  [2 * key_dim + value_dim, cfg.linear_conv_kernel_dim], cfg,
-                  initializer.Uniform(-0.5, 0.5))
-    a_log = _par(name + ".A_log", [hv], cfg, _a_log_init(hv))
-    dt_bias = _par(name + ".dt_bias", [hv], cfg, _dt_bias_init(hv))
-    norm_w = _par(name + ".gate_norm", [dv], cfg, _const(1.0))
-    w_out = _par(name + ".out_proj", [value_dim, h], cfg)
+    w_qkvz = param(name + ".in_proj_qkvz", [h, 2 * key_dim + 2 * value_dim],
+                   cfg)
+    w_ba = param(name + ".in_proj_ba", [h, 2 * hv], cfg)
+    conv_w = param(name + ".conv.w",
+                   [2 * key_dim + value_dim, cfg.linear_conv_kernel_dim], cfg,
+                   initializer.Uniform(-0.5, 0.5))
+    a_log = param(name + ".A_log", [hv], cfg, _a_log_init(hv))
+    dt_bias = param(name + ".dt_bias", [hv], cfg, _dt_bias_init(hv))
+    norm_w = param(name + ".gate_norm", [dv], cfg, 1.0)
+    w_out = param(name + ".out_proj", [value_dim, h], cfg)
 
     qkvz = layers.reshape(layers.matmul(x, w_qkvz),
                           [0, 0, hk, 2 * dk + 2 * r * dv])
-    q, k, v, z = _cut(qkvz, 3, [dk, dk, r * dv, r * dv])
-    b, a = _cut(layers.reshape(layers.matmul(x, w_ba), [0, 0, hk, 2 * r]),
-                3, [r, r])
+    q, k, v, z = cut(qkvz, 3, [dk, dk, r * dv, r * dv])
+    b, a = cut(layers.reshape(layers.matmul(x, w_ba), [0, 0, hk, 2 * r]),
+               3, [r, r])
     mixed = layers.causal_conv1d(
         layers.concat([layers.reshape(q, [0, 0, key_dim]),
                        layers.reshape(k, [0, 0, key_dim]),
                        layers.reshape(v, [0, 0, value_dim])], axis=2),
         conv_w, activation="silu")
-    q, k, v = _cut(mixed, 2, [key_dim, key_dim, value_dim])
+    q, k, v = cut(mixed, 2, [key_dim, key_dim, value_dim])
     q = layers.scale(layers.l2_norm(layers.reshape(q, [0, 0, hk, dk])),
                      scale=dk ** -0.5)
     k = layers.l2_norm(layers.reshape(k, [0, 0, hk, dk]))
@@ -194,17 +165,19 @@ def gated_attention_mixer(x, cfg, name):
     nq, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
     rotary = {"rotary_dim": int(d * cfg.partial_rotary_factor),
               "theta": cfg.rope_theta}
-    w_q = _par(name + ".q_proj", [h, nq * 2 * d], cfg)
-    w_k = _par(name + ".k_proj", [h, nkv * d], cfg)
-    w_v = _par(name + ".v_proj", [h, nkv * d], cfg)
-    w_o = _par(name + ".o_proj", [nq * d, h], cfg)
+    w_q = param(name + ".q_proj", [h, nq * 2 * d], cfg)
+    w_k = param(name + ".k_proj", [h, nkv * d], cfg)
+    w_v = param(name + ".v_proj", [h, nkv * d], cfg)
+    w_o = param(name + ".o_proj", [nq * d, h], cfg)
 
-    q, gate = _cut(layers.reshape(layers.matmul(x, w_q), [0, 0, nq, 2 * d]),
-                   3, [d, d])
+    q, gate = cut(layers.reshape(layers.matmul(x, w_q), [0, 0, nq, 2 * d]),
+                  3, [d, d])
     k = layers.reshape(layers.matmul(x, w_k), [0, 0, nkv, d])
     v = layers.reshape(layers.matmul(x, w_v), [0, 0, nkv, d])
-    q = layers.rotary_embedding(_norm(q, name + ".q_norm", cfg), **rotary)
-    k = layers.rotary_embedding(_norm(k, name + ".k_norm", cfg), **rotary)
+    q = layers.rotary_embedding(rms_norm_block(q, name + ".q_norm", cfg),
+                                **rotary)
+    k = layers.rotary_embedding(rms_norm_block(k, name + ".k_norm", cfg),
+                                **rotary)
     ctx = layers.scaled_dot_product_attention(
         *(layers.transpose(t, [0, 2, 1, 3]) for t in (q, k, v)),
         causal=True, sm_scale=1.0 / math.sqrt(d), is_test=True)
@@ -214,67 +187,15 @@ def gated_attention_mixer(x, cfg, name):
     return layers.matmul(ctx, w_o)
 
 
-def routed_layer(x, cfg, name, counters=None):
-    """The shared expert (times its own sigmoid gate) for every token
-    plus this chip's share of the routed experts, all gated
-    (`swiglu`): softmax scores over all experts, the top few
-    renormalised and times `cfg.routed_scaling_factor`. `counters`
-    collects the layer's (pairs computed, fullest expert over the mean,
-    rows made)."""
-    h, f = cfg.hidden_size, cfg.moe_intermediate_size
-    fs = cfg.shared_expert_intermediate_size
-    first, count = cfg.experts_held
-    w_r = _par(name + ".router", [h, cfg.num_experts], cfg)
-    w_sgu = _par(name + ".shared_gate_up", [h, 2 * fs], cfg)
-    w_sd = _par(name + ".shared_down", [fs, h], cfg)
-    w_sg = _par(name + ".shared_gate", [h, 1], cfg)
-    w_gu = _par(name + ".experts_gate_up", [count, h, 2 * f], cfg)
-    w_down = _par(name + ".experts_down", [count, f, h], cfg)
-    idx, weight = layers.moe_router(
-        x, w_r, top_k=cfg.num_experts_per_tok,
-        norm_topk_prob=cfg.norm_topk_prob,
-        routed_scaling_factor=cfg.routed_scaling_factor,
-        score_function="softmax")
-    routed, *counted = layers.moe_experts(
-        x, idx, weight, w_gu, w_down, held_start=first,
-        num_experts=cfg.num_experts, activation="swiglu")
-    if counters is not None:
-        counters.append(counted)
-    shared = layers.matmul(layers.swiglu(layers.matmul(x, w_sgu)), w_sd)
-    shared = layers.elementwise_mul(
-        shared, layers.sigmoid(layers.matmul(x, w_sg)))
-    return layers.elementwise_add(shared, routed)
-
-
-def qwen3_next_decoder(ids, cfg, checkpoints_out=None, counters=None):
-    """ids [B, S] -> hidden states [B, S, H] after the final norm."""
-    h = layers.embedding(ids, size=[cfg.vocab_size, cfg.hidden_size],
-                         param_attr=ParamAttr(
-                             name="embed", initializer=initializer
-                             .TruncatedNormal(0.0, cfg.initializer_range)))
-    for i in range(cfg.num_hidden_layers):
-        name = "l%d" % i
-        mixer = (gated_attention_mixer if cfg.is_attention(i)
-                 else gated_delta_net_mixer)
-        for norm, fn, extra in (
-                (".input_norm", mixer, {}),
-                (".post_mixer_norm", routed_layer, {"counters": counters})):
-            h = layers.elementwise_add(
-                h, fn(_norm(h, name + norm, cfg), cfg, name, **extra))
-            if checkpoints_out is not None:
-                checkpoints_out.append(h)
-    return _norm(h, "final_norm", cfg)
+def plan(cfg):
+    """Layer i: gated attention or a Gated DeltaNet behind
+    `.input_norm`, then the routed layer behind `.post_mixer_norm`."""
+    return [[(".input_norm", gated_attention_mixer if cfg.is_attention(i)
+              else gated_delta_net_mixer),
+             (".post_mixer_norm", routed_layer)]
+            for i in range(cfg.num_hidden_layers)]
 
 
 def qwen3_next_loss(cfg, seq_len, checkpoints_out=None):
-    """Next-token cross-entropy over feed vars `ids` and `labels`
-    [B, seq_len] (the caller shifts: labels[t] is the token after
-    ids[t]), the mean over all positions. Returns (loss, counters,
-    feeds): `counters` is what `nemotron_h.routed_counters` gives of the
-    routed layers, to fetch with the loss where wanted."""
-    ids = layers.data(name="ids", shape=[seq_len], dtype="int64")
-    labels = layers.data(name="labels", shape=[seq_len], dtype="int64")
-    per_layer = []
-    hidden = qwen3_next_decoder(ids, cfg, checkpoints_out, per_layer)
-    loss = next_token_loss(hidden, labels, cfg)
-    return loss, routed_counters(per_layer), ["ids", "labels"]
+    """`decoder.causal_lm_loss` of the stack `plan` lays out."""
+    return causal_lm_loss(cfg, seq_len, plan(cfg), checkpoints_out)

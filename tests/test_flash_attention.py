@@ -589,10 +589,10 @@ def _kernel_calls(jaxpr):
     and every jaxpr below it}."""
     import collections
 
-    from test_scan_layers import _walk
+    from decoder_case import walk
 
     return dict(collections.Counter(
-        eqn.params["name"] for _, eqn in _walk(jaxpr)
+        eqn.params["name"] for _, eqn in walk(jaxpr)
         if eqn.primitive.name == "pallas_call"))
 
 
@@ -773,14 +773,14 @@ def test_window_in_bfloat16_and_sub_blocks_of_a_longer_block(monkeypatch):
 
 def _grids(q, k, v, **kw):
     """{kernel's name: its grid} of one forward and backward call."""
-    from test_scan_layers import _walk
+    from decoder_case import walk
 
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, **kw).astype(jnp.float32))
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     return {e.params["name"]: tuple(e.params["grid_mapping"].grid)
-            for _, e in _walk(jaxpr.jaxpr)
+            for _, e in walk(jaxpr.jaxpr)
             if e.primitive.name == "pallas_call"}
 
 

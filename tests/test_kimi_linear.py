@@ -14,14 +14,13 @@ import pytest
 import paddle_tpu.fluid as fluid
 from paddle_tpu.core.scope import Scope
 from paddle_tpu.fluid import framework
-from paddle_tpu.fluid.contrib import mixed_precision
 from paddle_tpu.models import kimi_linear
 from paddle_tpu.ops import hybrid_ops
 from paddle_tpu.ops.registry import run_op
 from benchmark.reference import kimi_linear as ref
-from test_nemotron_h import _lay, _stamps_change_no_number
+from decoder_case import KIMI_LINEAR as CASE, stamps_change_no_number
 
-_B, _S = 2, 80          # 80 positions: two chunks of 64, the last padded
+_B, _S = CASE.b, CASE.s
 
 _KEYS = ("vocab_size", "hidden_size", "num_hidden_layers",
          "first_k_dense_replace", "num_attention_heads", "qk_nope_head_dim",
@@ -58,33 +57,6 @@ def _weights(cfg, seed, std=0.25):
             for k, v in ref.spread_decay_init(out).items()}
 
 
-def _batch(cfg, seed):
-    r = np.random.default_rng(seed)
-    ids = r.integers(0, cfg.vocab_size, (_B, _S + 1))
-    return {"ids": ids[:, :-1].astype(np.int64),
-            "labels": ids[:, 1:].astype(np.int64)}
-
-
-def _build(cfg, amp, remat=True, lr=1.0):
-    main, startup = framework.Program(), framework.Program()
-    main.random_seed = startup.random_seed = 3
-    with framework.program_guard(main, startup):
-        with framework.unique_name_guard():
-            ckpts = []
-            loss, counters, _ = kimi_linear.kimi_linear_loss(
-                cfg, _S, checkpoints_out=ckpts)
-            opt = fluid.optimizer.SGDOptimizer(learning_rate=lr)
-            if amp:
-                opt = mixed_precision.decorate(
-                    opt, use_dynamic_loss_scaling=False,
-                    amp_dtype="bfloat16")
-            if remat:
-                opt = fluid.optimizer.RecomputeOptimizer(opt)
-                opt._set_checkpoints(ckpts)
-            opt.minimize(loss)
-    return main, startup, loss, counters
-
-
 @pytest.mark.parametrize("amp,std,loss_tol,grad_tol", [
     (False, 0.25, 2e-5, 2e-3), (True, 0.05, 2e-2, 0.5)],
     ids=["float32", "bfloat16_amp"])
@@ -98,20 +70,12 @@ def test_loss_and_every_leafs_gradient_match_the_reference(
     bits of mantissa through five layers, where one routing that flips at
     a near-tie moves a leaf's gradient by a tenth."""
     cfg = kimi_linear.KimiLinearConfig.tiny(experts_held=(2, 4))
-    weights, batch = _weights(cfg, 11, std), _batch(cfg, 12)
-    main, startup, loss, _ = _build(cfg, amp)
-    scope = Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    masters = _lay(main, scope, weights)
-    value = exe.run(main, feed=batch, fetch_list=[loss], scope=scope)[0]
-    grads = {k: w - np.asarray(scope.find_var(masters.get(k, k)), np.float32)
-             for k, w in weights.items()}
+    weights, batch = _weights(cfg, 11, std), CASE.batch(cfg, 12)
+    loss, grads, _, _ = CASE.loss_and_grads(cfg, amp, weights, batch)
     want_loss, want = ref.loss_and_grad(
         {k: jnp.asarray(v) for k, v in weights.items()}, batch,
         _ref_cfg(cfg))
-    got = float(np.asarray(value).reshape(()))
-    assert abs(got - float(want_loss)) <= loss_tol * abs(float(want_loss))
+    assert abs(loss - float(want_loss)) <= loss_tol * abs(float(want_loss))
     assert set(grads) == set(want)
     worst = {}
     for k, g in want.items():
@@ -136,7 +100,7 @@ def test_layer_kinds_follow_linear_attn_config():
     cfg = kimi_linear.KimiLinearConfig.tiny()
     assert cfg.layer_kinds == ["kda", "kda", "kda", "mla", "kda"]
     assert [cfg.is_dense(i) for i in range(5)] == [True] + [False] * 4
-    main = _build(cfg, False)[0]
+    main = CASE.build(cfg, False)[0]
     types = [op.type for op in main.global_block().ops]
     assert types.count("gated_delta_rule") == 4
     assert types.count("scaled_dot_product_attention") == 1
@@ -332,7 +296,7 @@ def test_a_decay_per_channel_names_its_parts_where_a_trace_reads_them(
     every other local value itself: no `pt[local]` is left beside it);
     the stamps change no number."""
     every = {"head", "decay", "inverse", "local", "walk", "groups"}
-    _stamps_change_no_number(
+    stamps_change_no_number(
         "gated_delta_rule", functools.partial(
             hybrid_ops.gated_delta_rule, kernel=kernel),
         _channel_args(5, s=130),

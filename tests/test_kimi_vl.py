@@ -14,14 +14,13 @@ import pytest
 import paddle_tpu.fluid as fluid
 from paddle_tpu.core.scope import Scope
 from paddle_tpu.fluid import framework
-from paddle_tpu.fluid.contrib import mixed_precision
 from paddle_tpu.models import kimi_vl
 from paddle_tpu.ops import hybrid_ops
 from paddle_tpu.ops.registry import run_op
 from benchmark.reference import kimi_vl as ref
-from test_nemotron_h import _lay
+from decoder_case import KIMI_VL as CASE
 
-_B, _S = 2, 40
+_B, _S = CASE.b, CASE.s
 
 _KEYS = ("vocab_size", "hidden_size", "num_hidden_layers",
          "first_k_dense_replace", "num_attention_heads", "qk_nope_head_dim",
@@ -49,47 +48,6 @@ def _weights(cfg, seed, std=0.25):
     return {k: np.asarray(v, np.float32) for k, v in out.items()}
 
 
-def _batch(cfg, seed):
-    r = np.random.default_rng(seed)
-    ids = r.integers(0, cfg.vocab_size, (_B, _S + 1))
-    return {"ids": ids[:, :-1].astype(np.int64),
-            "labels": ids[:, 1:].astype(np.int64)}
-
-
-def _build(cfg, amp, remat=True, lr=1.0):
-    main, startup = framework.Program(), framework.Program()
-    main.random_seed = startup.random_seed = 3
-    with framework.program_guard(main, startup):
-        with framework.unique_name_guard():
-            ckpts = []
-            loss, counters, _ = kimi_vl.kimi_vl_loss(
-                cfg, _S, checkpoints_out=ckpts)
-            opt = fluid.optimizer.SGDOptimizer(learning_rate=lr)
-            if amp:
-                opt = mixed_precision.decorate(
-                    opt, use_dynamic_loss_scaling=False,
-                    amp_dtype="bfloat16")
-            if remat:
-                opt = fluid.optimizer.RecomputeOptimizer(opt)
-                opt._set_checkpoints(ckpts)
-            opt.minimize(loss)
-    return main, startup, loss, counters
-
-
-def _program_loss_and_grads(cfg, amp, weights, batch, remat=True):
-    """One SGD step at rate 1: the parameters' change is the gradient."""
-    main, startup, loss, _ = _build(cfg, amp, remat)
-    scope = Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    masters = _lay(main, scope, weights)
-    value = exe.run(main, feed=batch, fetch_list=[loss], scope=scope)[0]
-    grads = {k: w - np.asarray(scope.find_var(masters.get(k, k)),
-                               np.float32)
-             for k, w in weights.items()}
-    return float(np.asarray(value).reshape(())), grads, main, exe
-
-
 @pytest.mark.parametrize("amp,std,loss_tol,grad_tol", [
     (False, 0.25, 2e-5, 2e-3), (True, 0.05, 2e-2, 0.5)],
     ids=["float32", "bfloat16_amp"])
@@ -103,8 +61,8 @@ def test_loss_and_every_leafs_gradient_match_the_reference(
     where one routing that flips at a near-tie moves a leaf's gradient
     by a tenth (the qwen3-next test's band, for its reason)."""
     cfg = kimi_vl.KimiVLConfig.tiny(experts_held=(2, 4))
-    weights, batch = _weights(cfg, 11, std), _batch(cfg, 12)
-    loss, grads, _, _ = _program_loss_and_grads(cfg, amp, weights, batch)
+    weights, batch = _weights(cfg, 11, std), CASE.batch(cfg, 12)
+    loss, grads, _, _ = CASE.loss_and_grads(cfg, amp, weights, batch)
     want_loss, want = ref.loss_and_grad(
         {k: jnp.asarray(v) for k, v in weights.items()}, batch,
         _ref_cfg(cfg))
@@ -287,7 +245,7 @@ def test_the_router_against_a_hand_computation():
 
 def test_the_router_and_its_bias_stay_float32_under_decorate():
     cfg = kimi_vl.KimiVLConfig.tiny()
-    main = _build(cfg, True)[0]
+    main = CASE.build(cfg, True)[0]
     masters = main._amp_master_of
     block = main.global_block()
     pinned = ["l1.router", "l1.router_bias"]
@@ -311,9 +269,9 @@ def test_the_unrolled_stack_of_two_layer_kinds_is_recomputed_a_part_at_a_time(
     product 48 -> 32 is narrow; a routed layer keeps none). The routed
     layers say what they hold when they are traced."""
     cfg = kimi_vl.KimiVLConfig.tiny(experts_held=(4, 2))
-    weights, batch = _weights(cfg, 1), _batch(cfg, 2)
+    weights, batch = _weights(cfg, 1), CASE.batch(cfg, 2)
     with caplog.at_level(logging.INFO, logger=hybrid_ops.__name__):
-        loss, _, main, exe = _program_loss_and_grads(cfg, False, weights,
+        loss, _, main, exe = CASE.loss_and_grads(cfg, False, weights,
                                                      batch)
     saved = exe.remat_saved(main)
     assert len(saved) == 2 * cfg.num_hidden_layers + 1
@@ -328,18 +286,18 @@ def test_the_unrolled_stack_of_two_layer_kinds_is_recomputed_a_part_at_a_time(
     assert any(m.startswith("moe_experts holds experts [4, 6) of 8, top-3")
                for m in said)
     # the same loss without recompute
-    plain, _, _, _ = _program_loss_and_grads(cfg, False, weights, batch,
+    plain, _, _, _ = CASE.loss_and_grads(cfg, False, weights, batch,
                                              remat=False)
     assert abs(plain - loss) <= 1e-6
 
 
 def test_the_counters_come_with_the_loss():
     cfg = kimi_vl.KimiVLConfig.tiny(experts_held=(0, 4))
-    main, startup, loss, counters = _build(cfg, True)
+    main, startup, loss, counters = CASE.build(cfg, True)
     scope = Scope()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup, scope=scope)
-    got = exe.run(main, feed=_batch(cfg, 3), scope=scope, fetch_list=[
+    got = exe.run(main, feed=CASE.batch(cfg, 3), scope=scope, fetch_list=[
         loss, counters["moe.held_pairs"],
         counters["moe.load_max_over_mean"], counters["moe.rows_made"]])
     pairs, load, made = (float(np.asarray(v).reshape(())) for v in got[1:])
@@ -369,15 +327,14 @@ def test_a_mixers_segment_keeps_the_flash_kernels_output_and_statistics(
     holds one forward kernel a layer where it held two."""
     from paddle_tpu.ops.pallas.flash_attention import KERNEL_NAMES
     from paddle_tpu.utils import flags
-    from test_scan_layers import (_flash_from_its_length, _step_jaxpr,
-                                  _walk)
+    from decoder_case import flash_from_its_length, step_jaxpr, walk
 
-    _flash_from_its_length(monkeypatch)
+    flash_from_its_length(monkeypatch)
     monkeypatch.setitem(flags._FLAGS, "FLAGS_flash_attention_min_seq", _S)
     cfg = kimi_vl.KimiVLConfig.tiny(experts_held=(0, 4))
-    main, startup, loss, _ = _build(cfg, True)
-    jaxpr = _step_jaxpr(main, startup, _batch(cfg, 3), loss)
-    calls = [e.params["name"] for _, e in _walk(jaxpr)
+    main, startup, loss, _ = CASE.build(cfg, True)
+    jaxpr = step_jaxpr(main, startup, CASE.batch(cfg, 3), loss)
+    calls = [e.params["name"] for _, e in walk(jaxpr)
              if e.primitive.name == "pallas_call"
              and e.params["name"] in KERNEL_NAMES]
     layers_n = cfg.num_hidden_layers

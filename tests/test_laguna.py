@@ -14,13 +14,12 @@ import pytest
 import paddle_tpu.fluid as fluid
 from paddle_tpu.core.scope import Scope
 from paddle_tpu.fluid import framework, layers
-from paddle_tpu.fluid.contrib import mixed_precision
 from paddle_tpu.models import laguna
 from paddle_tpu.ops.registry import run_op
 from benchmark.reference import laguna as ref
-from test_nemotron_h import _lay
+from decoder_case import LAGUNA as CASE
 
-_B, _S = 2, 40
+_B, _S = CASE.b, CASE.s
 
 _KEYS = ("vocab_size", "hidden_size", "intermediate_size",
          "num_hidden_layers", "num_key_value_heads", "head_dim",
@@ -50,47 +49,6 @@ def _weights(cfg, seed, std=0.25):
     return {k: np.asarray(v, np.float32) for k, v in out.items()}
 
 
-def _batch(cfg, seed):
-    r = np.random.default_rng(seed)
-    ids = r.integers(0, cfg.vocab_size, (_B, _S + 1))
-    return {"ids": ids[:, :-1].astype(np.int64),
-            "labels": ids[:, 1:].astype(np.int64)}
-
-
-def _build(cfg, amp, remat=True):
-    main, startup = framework.Program(), framework.Program()
-    main.random_seed = startup.random_seed = 3
-    with framework.program_guard(main, startup):
-        with framework.unique_name_guard():
-            ckpts = []
-            loss, counters, _ = laguna.laguna_loss(
-                cfg, _S, checkpoints_out=ckpts)
-            opt = fluid.optimizer.SGDOptimizer(learning_rate=1.0)
-            if amp:
-                opt = mixed_precision.decorate(
-                    opt, use_dynamic_loss_scaling=False,
-                    amp_dtype="bfloat16")
-            if remat:
-                opt = fluid.optimizer.RecomputeOptimizer(opt)
-                opt._set_checkpoints(ckpts)
-            opt.minimize(loss)
-    return main, startup, loss, counters
-
-
-def _program_loss_and_grads(cfg, amp, weights, batch, remat=True):
-    """One SGD step at rate 1: the parameters' change is the gradient."""
-    main, startup, loss, _ = _build(cfg, amp, remat)
-    scope = Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    masters = _lay(main, scope, weights)
-    value = exe.run(main, feed=batch, fetch_list=[loss], scope=scope)[0]
-    grads = {k: w - np.asarray(scope.find_var(masters.get(k, k)),
-                               np.float32)
-             for k, w in weights.items()}
-    return float(np.asarray(value).reshape(())), grads, main, exe
-
-
 @pytest.mark.parametrize("amp,std,loss_tol,grad_tol", [
     (False, 0.25, 2e-5, 2e-3), (True, 0.05, 2e-2, 0.5)],
     ids=["float32", "bfloat16_amp"])
@@ -102,8 +60,8 @@ def test_loss_and_every_leafs_gradient_match_the_reference(
     experts. Under bfloat16 AMP the band is the other routed decoders'
     (a routing that flips at a near-tie moves a leaf by a tenth)."""
     cfg = laguna.LagunaConfig.tiny(experts_held=(2, 4))
-    weights, batch = _weights(cfg, 11, std), _batch(cfg, 12)
-    loss, grads, _, _ = _program_loss_and_grads(cfg, amp, weights, batch)
+    weights, batch = _weights(cfg, 11, std), CASE.batch(cfg, 12)
+    loss, grads, _, _ = CASE.loss_and_grads(cfg, amp, weights, batch)
     want_loss, want = ref.loss_and_grad(
         {k: jnp.asarray(v) for k, v in weights.items()}, batch,
         _ref_cfg(cfg))
@@ -353,7 +311,7 @@ def test_layer_kinds_heads_and_windows_follow_the_config():
 
 def test_the_router_stays_float32_under_decorate():
     cfg = laguna.LagunaConfig.tiny()
-    main = _build(cfg, True)[0]
+    main = CASE.build(cfg, True)[0]
     masters = main._amp_master_of
     block = main.global_block()
     assert "l1.router" not in masters
@@ -366,22 +324,22 @@ def test_the_router_stays_float32_under_decorate():
 
 def test_the_counters_come_with_the_loss_and_recompute_changes_nothing():
     cfg = laguna.LagunaConfig.tiny(experts_held=(0, 4))
-    main, startup, loss, counters = _build(cfg, True)
+    main, startup, loss, counters = CASE.build(cfg, True)
     scope = Scope()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup, scope=scope)
-    got = exe.run(main, feed=_batch(cfg, 3), scope=scope, fetch_list=[
+    got = exe.run(main, feed=CASE.batch(cfg, 3), scope=scope, fetch_list=[
         loss, counters["moe.held_pairs"],
         counters["moe.load_max_over_mean"], counters["moe.rows_made"]])
     pairs, load, made = (float(np.asarray(v).reshape(())) for v in got[1:])
     assert 0 < pairs <= 2 * _B * _S * 3           # two routed layers
     assert 1.0 <= load <= 4.0
     assert made == 2 * 512 >= pairs
-    weights, batch = _weights(cfg, 1), _batch(cfg, 2)
-    with_remat, _, main, exe = _program_loss_and_grads(cfg, False, weights,
+    weights, batch = _weights(cfg, 1), CASE.batch(cfg, 2)
+    with_remat, _, main, exe = CASE.loss_and_grads(cfg, False, weights,
                                                        batch)
     assert len(exe.remat_saved(main)) == 2 * cfg.num_hidden_layers + 1
-    plain, _, _, _ = _program_loss_and_grads(cfg, False, weights, batch,
+    plain, _, _, _ = CASE.loss_and_grads(cfg, False, weights, batch,
                                              remat=False)
     assert abs(plain - with_remat) <= 1e-6
 
@@ -395,15 +353,14 @@ def test_the_window_layer_runs_the_window_kernels_once_a_layer(monkeypatch):
     from paddle_tpu.ops.pallas.flash_attention import (KERNEL_NAMES,
                                                        WINDOW_KERNEL_NAMES)
     from paddle_tpu.utils import flags
-    from test_scan_layers import (_flash_from_its_length, _step_jaxpr,
-                                  _walk)
+    from decoder_case import flash_from_its_length, step_jaxpr, walk
 
-    _flash_from_its_length(monkeypatch)
+    flash_from_its_length(monkeypatch)
     monkeypatch.setitem(flags._FLAGS, "FLAGS_flash_attention_min_seq", _S)
     cfg = laguna.LagunaConfig.tiny(experts_held=(0, 4))
-    main, startup, loss, _ = _build(cfg, True)
-    jaxpr = _step_jaxpr(main, startup, _batch(cfg, 3), loss)
-    calls = [e.params["name"] for _, e in _walk(jaxpr)
+    main, startup, loss, _ = CASE.build(cfg, True)
+    jaxpr = step_jaxpr(main, startup, CASE.batch(cfg, 3), loss)
+    calls = [e.params["name"] for _, e in walk(jaxpr)
              if e.primitive.name == "pallas_call"
              and "scaled_dot_product_attention" in (e.params["name"] or "")]
     counted = {n: calls.count(n) for n in set(calls)}

@@ -14,15 +14,14 @@ import pytest
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.core.scope import Scope
-from paddle_tpu.fluid import framework
-from paddle_tpu.fluid.contrib import mixed_precision
 from paddle_tpu.models import nemotron_h
 from paddle_tpu.ops import hybrid_ops
 from paddle_tpu.ops.registry import run_op
 from benchmark.reference import nemotron_h as ref
+from decoder_case import NEMOTRON_H as CASE, lay, stamps_change_no_number
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_B, _S = 2, 24          # 24 positions: three chunks of 8
+_B, _S = CASE.b, CASE.s
 
 
 def _ref_cfg(cfg):
@@ -56,58 +55,6 @@ def _weights(cfg, seed):
             for k, v in ref.spread_ssm_init(out).items()}
 
 
-def _batch(cfg, seed):
-    r = np.random.default_rng(seed)
-    ids = r.integers(0, cfg.vocab_size, (_B, _S + 1))
-    return {"ids": ids[:, :-1].astype(np.int64),
-            "labels": ids[:, 1:].astype(np.int64)}
-
-
-def _build(cfg, amp, remat=True, lr=1.0):
-    main, startup = framework.Program(), framework.Program()
-    main.random_seed = startup.random_seed = 3
-    with framework.program_guard(main, startup):
-        with framework.unique_name_guard():
-            ckpts = []
-            loss, counters, _ = nemotron_h.nemotron_h_loss(
-                cfg, _S, checkpoints_out=ckpts)
-            opt = fluid.optimizer.SGDOptimizer(learning_rate=lr)
-            if amp:
-                opt = mixed_precision.decorate(
-                    opt, use_dynamic_loss_scaling=False,
-                    amp_dtype="bfloat16")
-            if remat:
-                opt = fluid.optimizer.RecomputeOptimizer(opt)
-                opt._set_checkpoints(ckpts)
-            opt.minimize(loss)
-    return main, startup, loss, counters
-
-
-def _lay(main, scope, weights):
-    masters = dict(getattr(main, "_amp_master_of", {}))
-    for name, w in weights.items():
-        live = scope.find_var(name)
-        assert tuple(live.shape) == w.shape, (name, live.shape, w.shape)
-        if name in masters:
-            scope.set_var(masters[name], jnp.asarray(w))
-        scope.set_var(name, jnp.asarray(w).astype(live.dtype))
-    return masters
-
-
-def _program_loss_and_grads(cfg, amp, weights, batch):
-    """One SGD step at rate 1: the parameters' change is the gradient."""
-    main, startup, loss, _ = _build(cfg, amp)
-    scope = Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    masters = _lay(main, scope, weights)
-    value = exe.run(main, feed=batch, fetch_list=[loss], scope=scope)[0]
-    grads = {k: w - np.asarray(scope.find_var(masters.get(k, k)),
-                               np.float32)
-             for k, w in weights.items()}
-    return float(np.asarray(value).reshape(())), grads, main, exe
-
-
 @pytest.mark.parametrize("amp,loss_tol,grad_tol", [
     (False, 2e-5, 2e-3), (True, 2e-2, 0.2)],
     ids=["float32", "bfloat16_amp"])
@@ -120,8 +67,8 @@ def test_loss_and_every_leafs_gradient_match_the_reference(
     reads 0.13 and experts 0.11: a routing that flips at a near-tie
     between the bfloat16 and the float32 input moves a whole token)."""
     cfg = nemotron_h.NemotronHConfig.tiny(experts_held=(2, 4))
-    weights, batch = _weights(cfg, 11), _batch(cfg, 12)
-    loss, grads, _, _ = _program_loss_and_grads(cfg, amp, weights, batch)
+    weights, batch = _weights(cfg, 11), CASE.batch(cfg, 12)
+    loss, grads, _, _ = CASE.loss_and_grads(cfg, amp, weights, batch)
     want_loss, want = ref.loss_and_grad(
         {k: jnp.asarray(v) for k, v in weights.items()}, batch,
         _ref_cfg(cfg))
@@ -187,62 +134,13 @@ def test_ssd_chunk_scan_forward_and_backward_match_the_recurrence(
             jnp.max(jnp.abs(b))) + 1e-6
 
 
-def _parts_by_region(op_type, fn, *args):
-    """{region: the parts named there} of the compiled gradient of
-    `fn` under the op's marker and `jax.checkpoint` (its output read
-    inside the checkpoint, as a layer's next op reads it), and the
-    gradient itself: what a trace's fold would read of the op."""
-    import re
-
-    from paddle_tpu.observability import attribution as attr
-
-    marker = "pp[b0;o3;%s;mix.tmp_0]" % op_type
-
-    def layer(*a):
-        with attr.marker_scope(marker):
-            y = fn(*a)
-        return jnp.sin(y)
-
-    grad = jax.jit(jax.grad(lambda *a: jnp.sum(jax.checkpoint(layer)(*a)),
-                            argnums=tuple(range(len(args)))))
-    got = {}
-    for name in set(re.findall(r'op_name="([^"]*)"',
-                               grad.lower(*args).compile().as_text())):
-        if marker in name:
-            got.setdefault(attr.region_of(name, attr.provenance_of(name)),
-                           set()).add(attr.part_of(name))
-    return got, grad(*args)
-
-
-def _stamps_change_no_number(op_type, fn, args, parts):
-    """With `FLAGS_tpu_op_provenance` on, every one of `parts`
-    ({region: names}) stands in an `op_name` of that region; off, none
-    does; the gradients are equal to the bit."""
-    from paddle_tpu.utils.flags import get_flag, set_flags
-
-    was = get_flag("FLAGS_tpu_op_provenance")
-    try:
-        set_flags({"FLAGS_tpu_op_provenance": True})
-        (on, g_on), y_on = _parts_by_region(op_type, fn, *args), fn(*args)
-        set_flags({"FLAGS_tpu_op_provenance": False})
-        (off, g_off), y_off = _parts_by_region(op_type, fn, *args), fn(*args)
-    finally:
-        set_flags({"FLAGS_tpu_op_provenance": was})
-    assert off == {}
-    assert set(on) == {"recompute", "backward"}
-    for region, names in parts.items():
-        assert set(names) <= on[region], (region, on[region])
-    for a, b in zip(g_on + (y_on,), g_off + (y_off,)):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-
-
 @pytest.mark.parametrize("kernel", [False, True], ids=["jnp", "pallas"])
 def test_ssd_chunk_scan_names_its_parts_where_a_trace_reads_them(kernel):
     """`pt[kernel]` is the Pallas forward (made again in the recompute),
     `pt[local]` the `jax.numpy` group wherever it runs, the backward
     pass among it, `pt[groups]` the layouts round them."""
     made = "kernel" if kernel else "local"
-    _stamps_change_no_number(
+    stamps_change_no_number(
         "ssd_chunk_scan", lambda *a: hybrid_ops.ssd_chunk_scan(
             *a, chunk=16, kernel=kernel), _scan_args(40),
         {"recompute": {made, "groups"}, "backward": {"local", "groups"}})
@@ -257,7 +155,7 @@ def test_moe_experts_names_its_parts_where_a_trace_reads_them(gated):
     _, idx = jax.lax.top_k(score, 3)
     weight = jnp.take_along_axis(score, idx, axis=1)
     every = {"sort", "gather", "products", "scatter"}
-    _stamps_change_no_number(
+    stamps_change_no_number(
         "moe_experts", lambda x, weight, w_up, w_down: hybrid_ops.moe_experts(
             x, idx, weight, w_up, w_down, held_start=4,
             activation="swiglu" if gated else "relu2", num_experts=16)[0],
@@ -436,8 +334,8 @@ def test_the_row_blocks_a_routing_fills_give_the_dense_loops_layer(
 
 def test_the_router_and_the_decay_stay_float32_under_decorate():
     cfg = nemotron_h.NemotronHConfig.tiny()
-    weights, batch = _weights(cfg, 1), _batch(cfg, 2)
-    _, _, main, exe = _program_loss_and_grads(cfg, True, weights, batch)
+    weights, batch = _weights(cfg, 1), CASE.batch(cfg, 2)
+    _, _, main, exe = CASE.loss_and_grads(cfg, True, weights, batch)
     masters = main._amp_master_of
     block = main.global_block()
     pinned = [n for n in weights if n.rsplit(".", 1)[-1] in (
@@ -467,9 +365,9 @@ def test_the_unrolled_stack_is_recomputed_a_block_at_a_time(caplog):
     a block and the head, each with the narrow products it keeps, and
     the routed layers say what they hold when they are traced."""
     cfg = nemotron_h.NemotronHConfig.tiny(experts_held=(4, 2))
-    weights, batch = _weights(cfg, 1), _batch(cfg, 2)
+    weights, batch = _weights(cfg, 1), CASE.batch(cfg, 2)
     with caplog.at_level(logging.INFO, logger=hybrid_ops.__name__):
-        loss, _, main, exe = _program_loss_and_grads(cfg, False, weights,
+        loss, _, main, exe = CASE.loss_and_grads(cfg, False, weights,
                                                      batch)
     saved = exe.remat_saved(main)
     assert len(saved) == len(cfg.hybrid_override_pattern) + 1
@@ -491,22 +389,22 @@ def test_the_unrolled_stack_is_recomputed_a_block_at_a_time(caplog):
     assert head + ("512 rows a trip, 1 trips if every pair is held "
                    "here") in said
     # the same loss without recompute
-    main2, startup2, loss2, _ = _build(cfg, False, remat=False)
+    main2, startup2, loss2, _ = CASE.build(cfg, False, remat=False)
     scope = Scope()
     exe2 = fluid.Executor(fluid.CPUPlace())
     exe2.run(startup2, scope=scope)
-    _lay(main2, scope, weights)
+    lay(main2, scope, weights)
     plain = exe2.run(main2, feed=batch, fetch_list=[loss2], scope=scope)[0]
     assert abs(float(np.asarray(plain).reshape(())) - loss) <= 1e-6
 
 
 def test_the_counters_come_with_the_loss():
     cfg = nemotron_h.NemotronHConfig.tiny(experts_held=(0, 4))
-    main, startup, loss, counters = _build(cfg, True)
+    main, startup, loss, counters = CASE.build(cfg, True)
     scope = Scope()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup, scope=scope)
-    got = exe.run(main, feed=_batch(cfg, 3), scope=scope, fetch_list=[
+    got = exe.run(main, feed=CASE.batch(cfg, 3), scope=scope, fetch_list=[
         loss, counters["moe.held_pairs"],
         counters["moe.load_max_over_mean"], counters["moe.rows_made"]])
     pairs, load, made = (float(np.asarray(v).reshape(())) for v in got[1:])

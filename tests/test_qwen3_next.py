@@ -16,15 +16,14 @@ import pytest
 import paddle_tpu.fluid as fluid
 from paddle_tpu.core.scope import Scope
 from paddle_tpu.fluid import framework
-from paddle_tpu.fluid.contrib import mixed_precision
 from paddle_tpu.models import qwen3_next
 from paddle_tpu.ops import hybrid_ops
 from paddle_tpu.ops.pallas import gated_delta_rule as delta_kernels
 from paddle_tpu.ops.registry import run_op
 from benchmark.reference import qwen3_next as ref
-from test_nemotron_h import _lay, _stamps_change_no_number
+from decoder_case import QWEN3_NEXT as CASE, stamps_change_no_number
 
-_B, _S = 2, 80          # 80 positions: two chunks of 64, the last padded
+_B, _S = CASE.b, CASE.s
 
 _KEYS = ("vocab_size", "hidden_size", "num_hidden_layers",
          "full_attention_interval", "num_attention_heads",
@@ -61,47 +60,6 @@ def _weights(cfg, seed, std=0.25):
             for k, v in ref.spread_decay_init(out).items()}
 
 
-def _batch(cfg, seed):
-    r = np.random.default_rng(seed)
-    ids = r.integers(0, cfg.vocab_size, (_B, _S + 1))
-    return {"ids": ids[:, :-1].astype(np.int64),
-            "labels": ids[:, 1:].astype(np.int64)}
-
-
-def _build(cfg, amp, remat=True, lr=1.0):
-    main, startup = framework.Program(), framework.Program()
-    main.random_seed = startup.random_seed = 3
-    with framework.program_guard(main, startup):
-        with framework.unique_name_guard():
-            ckpts = []
-            loss, counters, _ = qwen3_next.qwen3_next_loss(
-                cfg, _S, checkpoints_out=ckpts)
-            opt = fluid.optimizer.SGDOptimizer(learning_rate=lr)
-            if amp:
-                opt = mixed_precision.decorate(
-                    opt, use_dynamic_loss_scaling=False,
-                    amp_dtype="bfloat16")
-            if remat:
-                opt = fluid.optimizer.RecomputeOptimizer(opt)
-                opt._set_checkpoints(ckpts)
-            opt.minimize(loss)
-    return main, startup, loss, counters
-
-
-def _program_loss_and_grads(cfg, amp, weights, batch, remat=True):
-    """One SGD step at rate 1: the parameters' change is the gradient."""
-    main, startup, loss, _ = _build(cfg, amp, remat)
-    scope = Scope()
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(startup, scope=scope)
-    masters = _lay(main, scope, weights)
-    value = exe.run(main, feed=batch, fetch_list=[loss], scope=scope)[0]
-    grads = {k: w - np.asarray(scope.find_var(masters.get(k, k)),
-                               np.float32)
-             for k, w in weights.items()}
-    return float(np.asarray(value).reshape(())), grads, main, exe
-
-
 @pytest.mark.parametrize("amp,std,loss_tol,grad_tol", [
     (False, 0.25, 2e-5, 2e-3), (True, 0.05, 2e-2, 0.5)],
     ids=["float32", "bfloat16_amp"])
@@ -116,8 +74,8 @@ def test_loss_and_every_leafs_gradient_match_the_reference(
     three seeds at weights of 0.05: the worst leaf 0.30, the median
     0.05-0.09; at 0.25 the worst leaf reads 0.56-1.04)."""
     cfg = qwen3_next.Qwen3NextConfig.tiny(experts_held=(2, 4))
-    weights, batch = _weights(cfg, 11, std), _batch(cfg, 12)
-    loss, grads, _, _ = _program_loss_and_grads(cfg, amp, weights, batch)
+    weights, batch = _weights(cfg, 11, std), CASE.batch(cfg, 12)
+    loss, grads, _, _ = CASE.loss_and_grads(cfg, amp, weights, batch)
     want_loss, want = ref.loss_and_grad(
         {k: jnp.asarray(v) for k, v in weights.items()}, batch,
         _ref_cfg(cfg))
@@ -193,7 +151,7 @@ def test_gated_delta_rule_names_its_parts_where_a_trace_reads_them(kernel):
     stand in an `op_name` of the recompute and of the backward pass,
     whoever walks the chunks; the stamps change no number."""
     every = {"head", "inverse", "local", "walk", "groups"}
-    _stamps_change_no_number(
+    stamps_change_no_number(
         "gated_delta_rule", functools.partial(
             hybrid_ops.gated_delta_rule, kernel=kernel),
         _delta_args(5, s=130), {"recompute": every, "backward": every})
@@ -738,7 +696,7 @@ def test_gated_attention_mixer_matches_the_reference():
 
 def test_the_router_and_the_decay_stay_float32_under_decorate():
     cfg = qwen3_next.Qwen3NextConfig.tiny()
-    main = _build(cfg, True)[0]
+    main = CASE.build(cfg, True)[0]
     masters = main._amp_master_of
     block = main.global_block()
     pinned = [n for n, _, _, _ in ref.param_spec(_ref_cfg(cfg))
@@ -760,9 +718,9 @@ def test_the_unrolled_stack_is_recomputed_a_part_at_a_time(caplog):
     narrow products it keeps, and the delta rule and the routed layers
     say what they hold when they are traced."""
     cfg = qwen3_next.Qwen3NextConfig.tiny(experts_held=(4, 2))
-    weights, batch = _weights(cfg, 1), _batch(cfg, 2)
+    weights, batch = _weights(cfg, 1), CASE.batch(cfg, 2)
     with caplog.at_level(logging.INFO, logger=hybrid_ops.__name__):
-        loss, _, main, exe = _program_loss_and_grads(cfg, False, weights,
+        loss, _, main, exe = CASE.loss_and_grads(cfg, False, weights,
                                                      batch)
     saved = exe.remat_saved(main)
     assert len(saved) == 2 * cfg.num_hidden_layers + 1
@@ -780,18 +738,18 @@ def test_the_unrolled_stack_is_recomputed_a_part_at_a_time(caplog):
     assert any(m.startswith("moe_experts holds experts [4, 6) of 8, top-3")
                for m in said)
     # the same loss and gradients without recompute
-    plain, g_plain, _, _ = _program_loss_and_grads(cfg, False, weights,
+    plain, g_plain, _, _ = CASE.loss_and_grads(cfg, False, weights,
                                                    batch, remat=False)
     assert abs(plain - loss) <= 1e-6
 
 
 def test_the_counters_come_with_the_loss():
     cfg = qwen3_next.Qwen3NextConfig.tiny(experts_held=(0, 4))
-    main, startup, loss, counters = _build(cfg, True)
+    main, startup, loss, counters = CASE.build(cfg, True)
     scope = Scope()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup, scope=scope)
-    got = exe.run(main, feed=_batch(cfg, 3), scope=scope, fetch_list=[
+    got = exe.run(main, feed=CASE.batch(cfg, 3), scope=scope, fetch_list=[
         loss, counters["moe.held_pairs"],
         counters["moe.load_max_over_mean"], counters["moe.rows_made"]])
     pairs, load, made = (float(np.asarray(v).reshape(())) for v in got[1:])

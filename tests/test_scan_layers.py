@@ -12,8 +12,11 @@ import pytest
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import framework
 from paddle_tpu.core.scope import global_scope
-from paddle_tpu.models import bert
+from paddle_tpu.models import (bert, kimi_linear, kimi_vl, laguna,
+                               nemotron_h, qwen3_next)
 from __graft_entry__ import _bert_feed
+import decoder_case
+from decoder_case import FLASH_S, flash_from_its_length, step_jaxpr, walk
 
 
 def _run(main, st, feed, fetch):
@@ -218,45 +221,15 @@ def _build_scan_bert(remat=True, amp=True, seed=9, seq=_S):
     return cfg, main, st, total, _bert_feed(cfg, _B, seq, max_pred=2)
 
 
-def _step_jaxpr(main, st, feed, fetch):
-    """The jaxpr of the whole train step, traced and not compiled."""
-    import jax
-    from paddle_tpu.fluid import lowering
-
-    exe = fluid.Executor(fluid.CPUPlace())
-    exe.run(st)
-    block = main.global_block()
-    feeds = exe._prepare_feed(block, feed)
-    state_in, _ = lowering.analyze_block(block, list(feeds), [fetch.name])
-    specs = {n: global_scope().find_var(n) for n in state_in}
-    entry = lowering.compile_block(main, block, feeds, [fetch.name], specs)
-    return entry.jitted.trace(
-        {n: exe._aval_of(a) for n, a in feeds.items()},
-        {n: exe._aval_of(specs[n]) for n in entry.state_mut_names},
-        {n: exe._aval_of(specs[n]) for n in entry.state_ro_names},
-        jax.ShapeDtypeStruct((), np.uint32)).jaxpr.jaxpr
-
-
-def _walk(jaxpr, path=()):
-    """(names of the enclosing equations, equation), depth first."""
-    for eqn in jaxpr.eqns:
-        yield path, eqn
-        for v in eqn.params.values():
-            for sub in (v if isinstance(v, (list, tuple)) else [v]):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    yield from _walk(sub, path + (eqn.primitive.name,))
-
-
 def _layer_scans(jaxpr, n_layers):
     """(forward, backward) scan equations of the encoder stack: the
     forward draws the masks, the backward holds the recompute."""
     fwd = bwd = None
-    for path, eqn in _walk(jaxpr):
+    for path, eqn in walk(jaxpr):
         if eqn.primitive.name != "scan" or path \
                 or eqn.params["length"] != n_layers:
             continue
-        inner = {e.primitive.name for _, e in _walk(eqn.params["jaxpr"].jaxpr)}
+        inner = {e.primitive.name for _, e in walk(eqn.params["jaxpr"].jaxpr)}
         if "random_bits" in inner and fwd is None:
             fwd = eqn
         elif "remat2" in inner or "checkpoint" in inner:
@@ -269,7 +242,7 @@ def _dots(scan_eqn):
     """(lhs shape, rhs shape, contracted rhs dim) of every dot_general
     under a scan equation."""
     out = []
-    for _, e in _walk(scan_eqn.params["jaxpr"].jaxpr):
+    for _, e in walk(scan_eqn.params["jaxpr"].jaxpr):
         if e.primitive.name == "dot_general":
             (_, rc), _ = e.params["dimension_numbers"]
             out.append((tuple(e.invars[0].aval.shape),
@@ -285,10 +258,10 @@ def test_remat_scan_draws_each_mask_once_with_the_policy(
     if not policy:
         _bare_checkpoint(monkeypatch)
     cfg, main, st, total, feed = _build_scan_bert()
-    jaxpr = _step_jaxpr(main, st, feed, total)
+    jaxpr = step_jaxpr(main, st, feed, total)
     heads = cfg.num_attention_heads
     per_shape = {(_B, heads, _S, _S): 0, (_B, _S, cfg.hidden_size): 0}
-    for path, eqn in _walk(jaxpr):
+    for path, eqn in walk(jaxpr):
         if eqn.primitive.name == "random_bits" and "scan" in path:
             per_shape[tuple(eqn.outvars[0].aval.shape)] += 1
     # one attention mask and two hidden masks a layer
@@ -299,7 +272,7 @@ def test_remat_scan_draws_each_mask_once_with_the_policy(
 def test_remat_scan_recompute_drops_only_the_narrow_product():
     cfg, main, st, total, feed = _build_scan_bert()
     h, f = cfg.hidden_size, cfg.intermediate_size
-    fwd, bwd = _layer_scans(_step_jaxpr(main, st, feed, total),
+    fwd, bwd = _layer_scans(step_jaxpr(main, st, feed, total),
                             cfg.num_hidden_layers)
     ffn_out = ((_B, _S, f), (f, h), (0,))
     still = [((_B, _S, h), (h, 3 * h), (0,)),      # q/k/v
@@ -318,7 +291,7 @@ def test_remat_scan_stacks_carry_masks_and_narrow_product():
     no other activation."""
     cfg, main, st, total, feed = _build_scan_bert()
     L, h = cfg.num_hidden_layers, cfg.hidden_size
-    fwd, _ = _layer_scans(_step_jaxpr(main, st, feed, total), L)
+    fwd, _ = _layer_scans(step_jaxpr(main, st, feed, total), L)
     n_carry = fwd.params["num_carry"]
     stacked = sorted((str(v.aval.dtype), tuple(v.aval.shape))
                      for v in fwd.outvars[n_carry:])
@@ -383,10 +356,10 @@ def test_programs_without_remat_lower_as_before(monkeypatch, build):
     ops gave before they could name anything (`keep` the identity)."""
     from paddle_tpu.ops import remat_names
 
-    jaxpr = _step_jaxpr(*build())
-    assert not any(e.primitive.name == "name" for _, e in _walk(jaxpr))
+    jaxpr = step_jaxpr(*build())
+    assert not any(e.primitive.name == "name" for _, e in walk(jaxpr))
     monkeypatch.setattr(remat_names, "keep", lambda x, name: x)
-    assert str(_step_jaxpr(*build())) == str(jaxpr)
+    assert str(step_jaxpr(*build())) == str(jaxpr)
 
 
 def _build_scan_bert_remat():
@@ -394,71 +367,92 @@ def _build_scan_bert_remat():
     return main, st, feed, total
 
 
-def _build_nemotron_h():
-    """The hybrid decoder's step: bf16 AMP, every block recomputed."""
-    from paddle_tpu.models import nemotron_h
-    from test_nemotron_h import _batch, _build
+def _decoder_step(case, config):
+    """A decoder's step as its test file builds it: bf16 AMP, every
+    checkpoint recomputed, experts 0-3 of 8 held."""
+    cfg = config.tiny(experts_held=(0, 4))
+    main, st, loss, _ = case.build(cfg, True)
+    return main, st, case.batch(cfg, 3), loss
 
-    cfg = nemotron_h.NemotronHConfig.tiny(experts_held=(0, 4))
-    main, st, loss, _ = _build(cfg, True)
-    return main, st, _batch(cfg, 3), loss
+
+def _build_nemotron_h():
+    """The hybrid decoder's step: every block recomputed."""
+    return _decoder_step(decoder_case.NEMOTRON_H, nemotron_h.NemotronHConfig)
 
 
 def _build_qwen3_next():
-    """The gated-delta-rule / gated-attention decoder's step: bf16 AMP,
-    every mixer and every routed layer recomputed."""
-    from paddle_tpu.models import qwen3_next
-    from test_qwen3_next import _batch, _build
-
-    cfg = qwen3_next.Qwen3NextConfig.tiny(experts_held=(0, 4))
-    main, st, loss, _ = _build(cfg, True)
-    return main, st, _batch(cfg, 3), loss
+    """The gated-delta-rule / gated-attention decoder's step: every
+    mixer and every routed layer recomputed."""
+    return _decoder_step(decoder_case.QWEN3_NEXT, qwen3_next.Qwen3NextConfig)
 
 
 def _build_kimi_vl():
-    """The latent-attention decoder's step: bf16 AMP, every mixer and
-    every feed-forward recomputed, the rotary parts turned."""
-    from paddle_tpu.models import kimi_vl
-    from test_kimi_vl import _batch, _build
+    """The latent-attention decoder's step: every mixer and every
+    feed-forward recomputed, the rotary parts turned."""
+    return _decoder_step(decoder_case.KIMI_VL, kimi_vl.KimiVLConfig)
 
-    cfg = kimi_vl.KimiVLConfig.tiny(experts_held=(0, 4))
-    main, st, loss, _ = _build(cfg, True)
-    return main, st, _batch(cfg, 3), loss
+
+def _build_laguna():
+    """The window/full-attention decoder's step: every mixer and every
+    feed-forward recomputed."""
+    return _decoder_step(decoder_case.LAGUNA, laguna.LagunaConfig)
 
 
 def _build_kimi_linear():
     """The per-channel delta-rule / unturned latent-attention decoder's
-    step: bf16 AMP, every mixer and every feed-forward recomputed."""
-    from paddle_tpu.models import kimi_linear
-    from test_kimi_linear import _batch, _build
-
-    cfg = kimi_linear.KimiLinearConfig.tiny(experts_held=(0, 4))
-    main, st, loss, _ = _build(cfg, True)
-    return main, st, _batch(cfg, 3), loss
+    step: every mixer and every feed-forward recomputed."""
+    return _decoder_step(decoder_case.KIMI_LINEAR,
+                         kimi_linear.KimiLinearConfig)
 
 
-_FLASH_S = 128
+_DECODERS = [_build_nemotron_h, _build_qwen3_next, _build_kimi_vl,
+             _build_laguna, _build_kimi_linear]
+
+
+def _program_digest(main, startup):
+    """sha256 of both programs as the model code built them: every op's
+    type, its variables by slot and its attributes sorted by key, and
+    every parameter's name, shape, dtype and trainable flag (each
+    initializer is an op of the startup program, its attributes its
+    fields). An op's creation stack is left out."""
+    import hashlib
+    import re
+
+    parts = []
+    for prog in (main, startup):
+        for block in prog.blocks:
+            parts += [repr((op.type, sorted(op.input_names.items()),
+                            sorted(op.output_names.items()),
+                            sorted((k, repr(v)) for k, v in op.attrs.items())))
+                      for op in block.ops]
+        parts += [repr((p.name, p.shape, p.dtype, p.trainable))
+                  for p in prog.all_parameters()]
+    text = re.sub(r"0x[0-9a-f]+", "0x", "\n".join(parts))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+#: the digests of `_program_digest` as the five decoders' model files
+#: built them before `models/decoder.py` held their shared blocks: a
+#: change of a model file that moves an op, a name or an initializer
+#: moves its decoder's digest
+_PROGRAM_DIGESTS = {
+    "_build_nemotron_h": "770830fac7d9f5fb",
+    "_build_qwen3_next": "e8eada1579f94087",
+    "_build_kimi_vl": "0fd879cb0c57646f",
+    "_build_laguna": "aac192278bf82a17",
+    "_build_kimi_linear": "0811ed0f54e92755",
+}
+
+
+@pytest.mark.parametrize("build", _DECODERS)
+def test_decoders_build_the_accepted_programs(build):
+    main, startup, _, _ = build()
+    assert _program_digest(main, startup) == _PROGRAM_DIGESTS[build.__name__]
 
 
 def _build_scan_bert_flash():
-    _, main, st, total, feed = _build_scan_bert(seq=_FLASH_S)
+    _, main, st, total, feed = _build_scan_bert(seq=FLASH_S)
     return main, st, feed, total
-
-
-def _flash_from_its_length(monkeypatch):
-    """Attention of `_FLASH_S` keys goes to the flash kernels, as on
-    the chip (tests/test_sdpa_dispatch.py does the same)."""
-    import importlib
-
-    import jax
-    from paddle_tpu.utils import flags
-
-    # the package's attribute of that name is the function
-    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
-    monkeypatch.setattr(fa, "_interpret_default", lambda: True)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setitem(flags._FLAGS, "FLAGS_flash_attention_min_seq",
-                        _FLASH_S)
 
 
 #: sha256 of the step's jaxpr (addresses scrubbed) on this container's
@@ -479,8 +473,9 @@ def _flash_from_its_length(monkeypatch):
 #: kimi-vl's decoder, as its parent lowered it (before latent attention
 #: could leave its rotary parts unturned), and the seventh, Kimi-Linear's
 #: (the delta rule with a decay per key channel); the five before stood.
-#: A PR that means to change one of these programs replaces the digest
-#: and says so
+#: Laguna's, the eighth, was taken on its parent before
+#: `models/decoder.py` held the decoders' shared blocks. A PR that means
+#: to change one of these programs replaces the digest and says so
 _STEP_DIGESTS = {
     "_build_scan_bert_remat": "f6d6b541724972c8",
     "_build_resnet50": "deed87d731a3ffb9",
@@ -489,6 +484,7 @@ _STEP_DIGESTS = {
     "_build_qwen3_next": "3c544571148cfcdf",
     "_build_kimi_vl": "18928f1fecfa9164",
     "_build_kimi_linear": "8d2453fbb8bc1e73",
+    "_build_laguna": "49843b4432dd342b",
 }
 
 
@@ -496,7 +492,7 @@ _STEP_DIGESTS = {
                                    _build_resnet50, _build_nemotron_h,
                                    _build_scan_bert_flash,
                                    _build_qwen3_next, _build_kimi_vl,
-                                   _build_kimi_linear])
+                                   _build_kimi_linear, _build_laguna])
 def test_berts_and_resnets_steps_are_the_accepted_programs(
         monkeypatch, build):
     import hashlib
@@ -507,8 +503,8 @@ def test_berts_and_resnets_steps_are_the_accepted_programs(
     if jax.__version__ != "0.9.0":
         pytest.skip("the digests were taken under jax 0.9.0")
     if build is _build_scan_bert_flash:
-        _flash_from_its_length(monkeypatch)
-    text = re.sub(r"0x[0-9a-f]+", "0x", str(_step_jaxpr(*build())))
+        flash_from_its_length(monkeypatch)
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(step_jaxpr(*build())))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         _STEP_DIGESTS[build.__name__]
 
@@ -522,8 +518,8 @@ def test_remat_saved_record_lists_the_flash_residual(monkeypatch, caplog):
 
     from paddle_tpu.ops.pallas.flash_attention import KERNEL_NAMES
 
-    _flash_from_its_length(monkeypatch)
-    cfg, main, st, total, feed = _build_scan_bert(seq=_FLASH_S)
+    flash_from_its_length(monkeypatch)
+    cfg, main, st, total, feed = _build_scan_bert(seq=FLASH_S)
     L, h, heads = (cfg.num_hidden_layers, cfg.hidden_size,
                    cfg.num_attention_heads)
     exe = fluid.Executor(fluid.CPUPlace())
@@ -532,41 +528,41 @@ def test_remat_saved_record_lists_the_flash_residual(monkeypatch, caplog):
         for _ in range(2):
             exe.run(main, feed=feed, fetch_list=[total])
     (_, rec), = exe.remat_saved(main).items()
-    out = _B * heads * _FLASH_S * (h // heads) * 2      # bfloat16
-    stats = _B * heads * _FLASH_S * 4                   # float32, compact
-    hidden_mask = _B * _FLASH_S * h
-    product = _B * _FLASH_S * h * 2
+    out = _B * heads * FLASH_S * (h // heads) * 2      # bfloat16
+    stats = _B * heads * FLASH_S * 4                   # float32, compact
+    hidden_mask = _B * FLASH_S * h
+    product = _B * FLASH_S * h * 2
     assert [(r["name"], r["shape"], r["dtype"], r["bytes"])
             for r in rec["kept"]] == [
-        ("flash_attention_residual", [_B * heads, _FLASH_S, h // heads],
+        ("flash_attention_residual", [_B * heads, FLASH_S, h // heads],
          "bfloat16", out),
-        ("flash_attention_residual", [_B * heads, _FLASH_S], "float32",
+        ("flash_attention_residual", [_B * heads, FLASH_S], "float32",
          stats),
-        ("dropout_keep_mask", [_B, _FLASH_S, h], "bool", hidden_mask),
-        ("narrow_matmul_product", [_B, _FLASH_S, h], "bfloat16", product),
-        ("dropout_keep_mask", [_B, _FLASH_S, h], "bool", hidden_mask)]
+        ("dropout_keep_mask", [_B, FLASH_S, h], "bool", hidden_mask),
+        ("narrow_matmul_product", [_B, FLASH_S, h], "bfloat16", product),
+        ("dropout_keep_mask", [_B, FLASH_S, h], "bool", hidden_mask)]
     assert rec["bytes_per_layer"] == out + stats + 2 * hidden_mask + product
     assert rec["bytes_over_scan"] == L * rec["bytes_per_layer"]
     said = [r for r in caplog.records if "keeps across" in r.getMessage()]
     assert len(said) == 1 and "flash_attention_residual float32[%d, %d]" % (
-        _B * heads, _FLASH_S) in said[0].getMessage()
+        _B * heads, FLASH_S) in said[0].getMessage()
 
-    fwd, bwd = _layer_scans(_step_jaxpr(main, st, feed, total), L)
+    fwd, bwd = _layer_scans(step_jaxpr(main, st, feed, total), L)
     stacked = sorted((str(v.aval.dtype), tuple(v.aval.shape))
                      for v in fwd.outvars[fwd.params["num_carry"]:])
     assert stacked == sorted([
-        ("bfloat16", (L, _B * heads, _FLASH_S, h // heads)),
-        ("float32", (L, _B * heads, _FLASH_S)),
+        ("bfloat16", (L, _B * heads, FLASH_S, h // heads)),
+        ("float32", (L, _B * heads, FLASH_S)),
         # the layer's index: the recompute folds it into the key again
         # for the seed the backward kernels hash
         ("int32", (L,)),
-        ("bool", (L, _B, _FLASH_S, h)), ("bool", (L, _B, _FLASH_S, h)),
-        ("bfloat16", (L, _B, _FLASH_S, h)),
-        ("float32", (L, _B, _FLASH_S, h))])
+        ("bool", (L, _B, FLASH_S, h)), ("bool", (L, _B, FLASH_S, h)),
+        ("bfloat16", (L, _B, FLASH_S, h)),
+        ("float32", (L, _B, FLASH_S, h))])
 
     def kernels(scan_eqn):
         return sorted(e.params["name"]
-                      for _, e in _walk(scan_eqn.params["jaxpr"].jaxpr)
+                      for _, e in walk(scan_eqn.params["jaxpr"].jaxpr)
                       if e.primitive.name == "pallas_call")
 
     assert kernels(fwd) == [KERNEL_NAMES[0]]
